@@ -1,8 +1,6 @@
-"""Version shims for jax APIs that moved between 0.4.x and >=0.5.
-
-One home for the dual spellings (used by ops/pallas_kernels,
-distributed/pipelining, jit/train_step) so the branch logic cannot
-drift between call sites.
+"""The repo's one spelling of ``jax.shard_map`` (used by
+ops/pallas_kernels, distributed/pipelining, jit/train_step,
+jit/serving_step) so the defaults cannot drift between call sites.
 """
 from __future__ import annotations
 
@@ -11,33 +9,14 @@ import jax
 
 def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes=None,
                      check=False):
-    """shard_map across both jax APIs.
+    """``jax.shard_map`` with this repo's defaults.
 
     manual_axes: set of axis names to run manually (None = all axes).
-    check: run the vma/replication checker where the API supports it
-    (jax >= 0.5 check_vma; 0.4.x always runs with check_rep=False — its
-    checker has no rules for pallas outputs / several collectives).
-    jax >= 0.5 spells this jax.shard_map(axis_names=..., check_vma=...);
-    0.4.x has jax.experimental.shard_map with check_rep.  0.4.x cannot
-    lower partial-manual axis_index (SPMD PartitionId UNIMPLEMENTED),
-    so a manual_axes subset degrades to all-manual there — correct for
-    every in-repo caller, whose non-manual axes are trivial/replicated.
+    check: run the vma/replication checker (off by default: pallas_call
+    outputs carry no vma annotation, which the checker refuses to
+    guess).
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh,
-                             axis_names=set(manual_axes or
-                                            mesh.axis_names),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size(axis_name):
-    """Static size of a manual mesh axis (>=0.5 lax.axis_size; 0.4.x
-    core.axis_frame returns the size directly)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax.core import axis_frame
-    return axis_frame(axis_name)
+    return jax.shard_map(f, mesh=mesh,
+                         axis_names=set(manual_axes or mesh.axis_names),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check)

@@ -68,12 +68,9 @@ def spmd_pipeline(stage_fn: Callable, stage_params: Any, xs: jax.Array,
         idx = lax.axis_index(axis_name)
 
         mb_shape = stream.shape[1:]
-        # initial carries are device-varying (they hold per-stage values);
-        # jax 0.4.x has no varying-type tracking (and check_rep=False
-        # there), so pcast degrades to identity
+        # initial carries are device-varying (they hold per-stage values)
         def _vary(x):
-            return lax.pcast(x, (axis_name,), to="varying") \
-                if hasattr(lax, "pcast") else x
+            return lax.pcast(x, (axis_name,), to="varying")
 
         state0 = _vary(jnp.zeros(mb_shape, stream.dtype))
         out0 = _vary(jnp.zeros((n_micro,) + mb_shape, stream.dtype))

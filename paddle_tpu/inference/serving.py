@@ -460,7 +460,8 @@ class ContinuousBatchingEngine:
                     "every chunk must map to a compiled bucket"
                     % (self.chunk_size, buckets[-1]))
             self.prefill_step = PrefillStep(
-                model, self.caches, self.bt_width, tp=self.tp,
+                model, self.caches, self.bt_width,
+                use_pallas=use_pallas, tp=self.tp,
                 weight_qparams=self.weight_qtree,
                 quant_collectives=self.quant_collectives,
                 sampling=self.sampling)

@@ -224,8 +224,7 @@ def _cp_local_dest(dest_blocks, dest_offsets, bsl, cp_axis, sink):
 def _samp_knobs(samp):
     """Decode a packed per-row sampling operand ``[..., 4]`` int32 into
     ``(temps f32, top_ks i32, top_ps f32, seeds i32)``.  Temperature
-    and top-p ride BITCAST in the int32 lane (the same trick the quant
-    scales use on the scalar-prefetch path), so one dtype-uniform
+    and top-p ride BITCAST in the int32 lane, so one dtype-uniform
     buffer carries every knob and the packed host transfer stays a
     single int32 array."""
     t = jax.lax.bitcast_convert_type(samp[..., 0], jnp.float32)
@@ -705,14 +704,18 @@ class PrefillStep:
     """
 
     def __init__(self, model, caches: List, bt_width: int,
+                 use_pallas: Optional[bool] = None,
                  mesh=None, sharding=None,
                  tp: Optional[TPContext] = None,
                  weight_qparams=None, quant_collectives: bool = False,
                  sampling: bool = False):
+        from ..core.device import on_tpu
         self.model = model
         self.caches = caches
         self.cfg = model.config
         self.bt_width = bt_width
+        # the chunk attention is XLA; the flag decides the rope epilogue
+        self.use_pallas = on_tpu() if use_pallas is None else use_pallas
         self.sampling = bool(sampling)
         self.sink = caches[0].sink
         if self.sink < 0:
@@ -757,6 +760,7 @@ class PrefillStep:
         D = cfg.hidden_size // cfg.num_attention_heads
         scale = 1.0 / math.sqrt(D)
         sink = self.sink
+        use_pallas = self.use_pallas
         quant_kv = self._quant_kv
         q8_gather = self._q8_gather
         pdtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -793,7 +797,8 @@ class PrefillStep:
                     v = attn.v_proj(h).reshape([1, C, Hkv, D])
                     qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
                         q._value[0], k._value[0], v._value[0],
-                        cos_t, sin_t, with_amax=quant_kv)
+                        cos_t, sin_t, with_amax=quant_kv,
+                        use_pallas=use_pallas)
                     if quant_kv:
                         kc, vc, ks, vs = write_chunk_kv_q8(
                             kv_[None], v._value, kc, vc, kss[li],
@@ -983,7 +988,7 @@ class MixedStep:
                  weight_qparams=None, quant_collectives: bool = False,
                  sampling: bool = False, spec_k: int = 0,
                  return_probs: bool = False):
-        from ..ops.paged_attention import _HAS_PLTPU, _on_tpu
+        from ..core.device import on_tpu
         self.model = model
         self.caches = caches
         self.cfg = model.config
@@ -1022,7 +1027,7 @@ class MixedStep:
                              "(PagedKVCache(sink_block=True)) to mask "
                              "budget-padding writes")
         if use_pallas is None:
-            use_pallas = _HAS_PLTPU and _on_tpu()
+            use_pallas = on_tpu()
         self.use_pallas = use_pallas
         self._tp = _resolve_tp(model, mesh, sharding, tp)
         if self.spec_k and self._tp is not None:
@@ -1186,7 +1191,8 @@ class MixedStep:
                     # the projection outputs
                     qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
                         q._value[0], k._value[0], v._value[0],
-                        cos_t, sin_t, with_amax=quant_kv)
+                        cos_t, sin_t, with_amax=quant_kv,
+                        use_pallas=use_pallas)
                     if quant_kv:
                         kc, vc, ks, vs = write_ragged_kv_q8(
                             kv_, v._value[0], kc, vc, kss[li],
@@ -1332,13 +1338,20 @@ class MixedStep:
             span_tab[:, W + 4:] = 0
         return pack, pack[:4 * T].reshape(4, T), span_tab
 
-    def aot_lower(self, T: int):
+    def aot_lower(self, T: int, device_sharding=None):
         """AOT-lower (never execute) one budget-``T`` module with a
         zero pack and the caches' current pools — the artifact the
         graftlint hlo-contract pass asserts over (donation aliases the
         pools, no f64 op, ONE packed int32 host operand of the pinned
         length).  Uses the same cached jit as ``call_packed``, so a
-        subsequent real call does not re-trace."""
+        subsequent real call does not re-trace.
+
+        ``device_sharding`` lowers for ANOTHER device than the one the
+        arrays live on: every operand becomes a ``ShapeDtypeStruct``
+        carrying it.  With a compile-only TPU device
+        (``jax.experimental.topologies``) ``.compile()`` then runs the
+        real XLA:TPU + Mosaic compilers on a box with no chip —
+        tests/test_tpu_compile.py."""
         fn = self._fns.get(T)
         if fn is None:
             fn = self._fns[T] = self._build(T)
@@ -1354,7 +1367,12 @@ class MixedStep:
             args.append(tuple(
                 jnp.zeros((self.max_spans, V), jnp.float32)
                 for _ in range(self.spec_k)))
-        return fn.lower(*args, kcs, vcs, kss, vss)
+        args += [kcs, vcs, kss, vss]
+        if device_sharding is not None:
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=device_sharding), args)
+        return fn.lower(*args)
 
     def compiled_stats(self, T: int) -> dict:
         """Cached ``cost_analysis`` of one budget-``T`` compiled mixed
@@ -1422,12 +1440,12 @@ class DecodeStep:
                  tp: Optional[TPContext] = None,
                  weight_qparams=None, quant_collectives: bool = False,
                  sampling: bool = False):
-        from ..ops.paged_attention import _HAS_PLTPU, _on_tpu
+        from ..core.device import on_tpu
         self.model = model
         self.caches = caches
         self.cfg = model.config
         if use_pallas is None:
-            use_pallas = _HAS_PLTPU and _on_tpu()
+            use_pallas = on_tpu()
         self.use_pallas = use_pallas
         self.sampling = bool(sampling)
         self._tp = _resolve_tp(model, mesh, sharding, tp)
@@ -1469,7 +1487,8 @@ class DecodeStep:
         Hkv = cfg.num_key_value_heads // deg
         D = cfg.hidden_size // cfg.num_attention_heads
         scale = 1.0 / math.sqrt(D)
-        attn_fn = _paged_attention_pallas if self.use_pallas \
+        use_pallas = self.use_pallas
+        attn_fn = _paged_attention_pallas if use_pallas \
             else _paged_attention_xla
         quant_kv = self._quant_kv
         q8_gather = self._q8_gather
@@ -1515,7 +1534,8 @@ class DecodeStep:
                     v = attn.v_proj(h).reshape([S, 1, Hkv, D])
                     qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
                         q._value[:, 0], k._value[:, 0], v._value[:, 0],
-                        cos_t, sin_t, with_amax=quant_kv)
+                        cos_t, sin_t, with_amax=quant_kv,
+                        use_pallas=use_pallas)
                     if quant_kv:
                         kc, vc, ks, vs = write_decode_kv_q8(
                             kv_, v._value[:, 0], kc, vc,

@@ -149,12 +149,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     flash kernel on TPU when enabled, else an XLA-fused reference path."""
     use_dropout = dropout_p > 0.0 and training
     if get_flag("use_pallas_kernels") and not use_dropout:
-        try:
-            from ...ops.pallas_kernels import flash_attention_tpu
-            return flash_attention_tpu(query, key, value, attn_mask,
-                                       is_causal)
-        except Exception:
-            pass  # fall back to XLA path
+        # flash_attention_tpu picks kernel vs chunked reference itself,
+        # from the platform and the shapes; a failure in either is an
+        # error, not a reason to try another path
+        from ...ops.pallas_kernels import flash_attention_tpu
+        return flash_attention_tpu(query, key, value, attn_mask,
+                                   is_causal)
 
     def fn(q, k, v, *m):
         # trailing arg is the dropout key when use_dropout (visible arg so

@@ -27,14 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
+from ..core import device as _device
 from ..core.tensor import Tensor
 from .online_softmax import merge_partials, online_softmax_update
 
@@ -855,7 +850,7 @@ def ragged_paged_attention(q, key_cache, value_cache, block_tables,
     kl = jnp.asarray(np.asarray(kv_lens), jnp.int32)
     scale = 1.0 / math.sqrt(qv.shape[-1])
     if use_pallas is None:
-        use_pallas = _HAS_PLTPU and _on_tpu()
+        use_pallas = _device.on_tpu()
     if use_pallas or interpret:
         from .pallas_kernels import _ragged_paged_attention_pallas
         sq = int(span_q) if span_q else int(np.max(np.asarray(q_lens)))
@@ -933,7 +928,7 @@ def _paged_attention_xla(q, key_cache, value_cache, block_tables, seq_lens,
 # ---------------------------------------------------------------------------
 # decode attention: Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _paged_decode_kernel(# scalar prefetch (+2 bitcast scale tables
+def _paged_decode_kernel(# scalar prefetch (+2 f32 scale tables
                          # when quantized)
                          *refs,
                          block_size: int, pages_per_seq: int,
@@ -952,7 +947,7 @@ def _paged_decode_kernel(# scalar prefetch (+2 bitcast scale tables
     old-vs-new benching.  Online-softmax state stays in fp32 registers.
 
     An int8 pool's per-page-per-head fp32 scales ride as TWO EXTRA
-    scalar-prefetch tables bitcast to int32 ([Hkv, phys] — SMEM scalar
+    f32 scalar-prefetch tables ([Hkv, phys] — SMEM scalar
     reads with a dynamic page index, the same mechanism as the block
     table).  Pipelined, the q heads are quantized once per cell to
     per-row int8 and ``q·Kᵀ`` runs int8×int8 on the MXU with the q/k/
@@ -964,14 +959,14 @@ def _paged_decode_kernel(# scalar prefetch (+2 bitcast scale tables
     from ..quantization.functional import (fold_int8_scores,
                                            quantize_rows_symmetric)
     if quantized:
-        (block_tables_ref, seq_lens_ref, ks_bits_ref, vs_bits_ref,
+        (block_tables_ref, seq_lens_ref, ks_ref, vs_ref,
          q_ref, k_pages_ref, v_pages_ref, o_ref,
          k_vmem, v_vmem, sem) = refs
     else:
         (block_tables_ref, seq_lens_ref,
          q_ref, k_pages_ref, v_pages_ref, o_ref,
          k_vmem, v_vmem, sem) = refs
-        ks_bits_ref = vs_bits_ref = None
+        ks_ref = vs_ref = None
     b = pl.program_id(0)
     h = pl.program_id(1)
     seq_len = seq_lens_ref[b]
@@ -994,10 +989,8 @@ def _paged_decode_kernel(# scalar prefetch (+2 bitcast scale tables
 
     def page_math(p_idx, page, kbuf, vbuf, carry):
         if quantized:
-            sk = jax.lax.bitcast_convert_type(ks_bits_ref[h, page],
-                                              jnp.float32)
-            sv = jax.lax.bitcast_convert_type(vs_bits_ref[h, page],
-                                              jnp.float32)
+            sk = ks_ref[h, page]
+            sv = vs_ref[h, page]
         if int8_mxu:
             si = jax.lax.dot_general(
                 q_codes, kbuf, (((1,), (1,)), ((), ())),
@@ -1109,24 +1102,20 @@ def _paged_attention_pallas(q, key_cache, value_cache, block_tables,
                         pltpu.VMEM((bs, D), vp.dtype),
                         pltpu.SemaphoreType.DMA]
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         prefetch = [bt.astype(jnp.int32), seq_lens.astype(jnp.int32)]
         if quantized:
-            # fp32 scales ride the int32 scalar-prefetch lane bitcast;
             # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
-            prefetch += [
-                jax.lax.bitcast_convert_type(
-                    key_scale.astype(jnp.float32).T, jnp.int32),
-                jax.lax.bitcast_convert_type(
-                    value_scale.astype(jnp.float32).T, jnp.int32)]
+            prefetch += [key_scale.astype(jnp.float32).T,
+                         value_scale.astype(jnp.float32).T]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(B, Hkv),
             in_specs=[
                 pl.BlockSpec((1, 1, groups, D),
                              lambda b, h, *_: (b, h, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, 1, groups, D),
                                    lambda b, h, *_: (b, h, 0, 0)),
@@ -1137,15 +1126,9 @@ def _paged_attention_pallas(q, key_cache, value_cache, block_tables,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Hkv, groups, D), q.dtype),
             interpret=interpret,
+            name="paged_decode_attention",
         )(*prefetch, qg, kp, vp)
     return out.reshape(B, H, D)
-
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def paged_attention(q, key_cache, value_cache, block_tables, seq_lens,
@@ -1168,7 +1151,7 @@ def paged_attention(q, key_cache, value_cache, block_tables, seq_lens,
     sl = jnp.asarray(np.asarray(seq_lens), jnp.int32)
     scale = 1.0 / math.sqrt(qv.shape[-1])
     if use_pallas is None:
-        use_pallas = _HAS_PLTPU and _on_tpu()
+        use_pallas = _device.on_tpu()
     if use_pallas or interpret:
         out = _paged_attention_pallas(qv, kc, vc, bt, sl, scale,
                                       interpret=interpret,

@@ -3,8 +3,11 @@
 Parity: the reference's fused CUDA kernel library
 (paddle/phi/kernels/fusion/ — flash attention #18, fused_rms_norm #17).
 These are the only hand-written kernels in the framework; everything else
-is XLA.  Each kernel has an XLA fallback (the callers catch exceptions), so
-CPU tests exercise the same API.
+is XLA.  Each kernel has an XLA reference the dispatchers select when the
+process is NOT on a TPU (``core.device.on_tpu``) or the shape is outside
+the kernel's envelope, so CPU tests exercise the same API.  The choice is
+made once, from the platform and the shapes: a kernel chosen for the TPU
+that fails to compile is an error, never a switch to the reference.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
 - flash attention: one (batch*heads, q_block) grid cell holds a q tile in
@@ -26,36 +29,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU backend only
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
+from ..core import device as _device
 from ..core.dispatch import apply_op
-from ..core.jax_compat import axis_size as _axis_size, shard_map_compat
+from ..core.jax_compat import shard_map_compat
 from ..core.tensor import Tensor
 from ._helpers import targ
 from .online_softmax import online_softmax_update
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 def _x64_off():
     """Context manager tracing with x64 disabled (mosaic cannot legalize
-    the i64 scalars python-int arithmetic produces under jax_enable_x64).
-    jax >= 0.5 spells it jax.enable_x64(False); 0.4.x only has the
-    experimental form."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    return jax.experimental.disable_x64()
+    the i64 scalars python-int arithmetic produces under
+    jax_enable_x64)."""
+    return jax.enable_x64(False)
 
 
 # ---------------------------------------------------------------------------
@@ -302,9 +290,6 @@ def _flash_attention_value(q, k, v, causal: bool, block_q=512,
     (+ optional compact lse [B*H, Sq] when with_lse).
     rope=(cos, sin) with [S, D] f32 tables applies neox rotary to q/k
     inside the kernel (requires Sq == Sk)."""
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "pallas TPU support unavailable; use the chunked path")
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     block_q = _fit_block(block_q, Sq)
@@ -361,7 +346,7 @@ def _flash_attention_value(q, k, v, causal: bool, block_q=512,
                             pltpu.VMEM((block_q, D), q.dtype)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"))
-            if (_HAS_PLTPU and not _INTERPRET[0]) else None,
+            if not _INTERPRET[0] else None,
             interpret=_INTERPRET[0],
         )(*args)
     out = res[0].reshape(B, H, Sq, D)
@@ -591,9 +576,6 @@ def _flash_attention_bwd_fused(q, k, v, out, lse, g, causal: bool,
     f32 dq partials [n_kb, BH, Sq, D] are reduced by XLA right after —
     a cheap fused sum over the short k-tile axis (callers bound n_kb so
     this buffer stays a small multiple of dq)."""
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "pallas TPU support unavailable; use the chunked path")
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     block_q = _fit_block(block_q, Sq)
@@ -663,7 +645,7 @@ def _flash_attention_bwd_fused(q, k, v, out, lse, g, causal: bool,
                             pltpu.VMEM((block_k, D), k.dtype)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"))
-            if (_HAS_PLTPU and not _INTERPRET[0]) else None,
+            if not _INTERPRET[0] else None,
             interpret=_INTERPRET[0],
         )(*call_args)
 
@@ -677,8 +659,9 @@ def _flash_attention_bwd_fused(q, k, v, out, lse, g, causal: bool,
 # two-kernel scheme whose memory stays O(S*D + S) regardless
 _FUSED_BWD_MAX_SK = 8192
 # block_q=512 measured ~7-11% faster than 256 on v5e at both D=64 and
-# D=128 (tools/attn_sweep.py; BENCH_ATTN artifact).  Module constant so
-# the VMEM audit (tools/check_vmem_budget.py) sees tile edits.
+# D=128 (a builder-run round-5 sweep, not reproduced since).  Module
+# constant so the VMEM audit (tools/check_vmem_budget.py) sees tile
+# edits.
 _FUSED_BWD_BLOCK_Q = 512
 
 
@@ -705,9 +688,6 @@ def _flash_attention_bwd(q, k, v, out, lse, g, causal: bool,
     dq parallel over q tiles; dk/dv parallel over k tiles; both stream
     the reduction axis through the grid with VMEM scratch accumulators,
     recomputing p from the forward's lse — memory stays O(S·D + S)."""
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "pallas TPU support unavailable; use the chunked path")
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     block_q = _fit_block(block_q, Sq)
@@ -763,7 +743,7 @@ def _flash_attention_bwd(q, k, v, out, lse, g, causal: bool,
     params = dict(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not _INTERPRET[0]) else None,
+        if not _INTERPRET[0] else None,
         interpret=_INTERPRET[0])
 
     with _x64_off():
@@ -903,7 +883,7 @@ def _chunked_sdpa(q, k, v, causal, mask=None, block_k=256):
 
 
 def _pallas_ok(q, k, mask, block=256) -> bool:
-    return (_HAS_PLTPU and _on_tpu() and mask is None
+    return (_device.on_tpu() and mask is None
             and q.shape[3] <= 128                      # scratch is 128-lane
             and _fit_block(block, q.shape[2]) > 0
             and _fit_block(block, k.shape[2]) > 0)
@@ -1084,7 +1064,7 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 def rms_norm_tpu(x, weight, eps=1e-6, block_rows=512):
     """Row-tiled Pallas RMSNorm (used by the bench path on TPU)."""
-    if not (_HAS_PLTPU and _on_tpu()):
+    if not _device.on_tpu():
         raise RuntimeError("requires TPU")
 
     def fn(xv, wv):
@@ -1114,7 +1094,7 @@ def rms_norm_tpu(x, weight, eps=1e-6, block_rows=512):
 # ---------------------------------------------------------------------------
 def _ring_flash_ok(S, D) -> bool:
     """Can the per-rotation block run the Pallas flash kernels?"""
-    return (_HAS_PLTPU and (_on_tpu() or _INTERPRET[0])
+    return ((_device.on_tpu() or _INTERPRET[0])
             and D <= 128 and _fit_block(256, S) > 0)
 
 
@@ -1152,7 +1132,7 @@ def _ring_flash_impl(qh, k0, v0, axis_name, causal):
     """Forward ring: per-rotation flash blocks combined by running
     logsumexp (same online-softmax algebra as inside the kernel, one
     level up)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     B, H, S, D = qh.shape
@@ -1192,7 +1172,7 @@ def _ring_flash_bwd(axis_name, causal, res, g):
     the correct global softmax probability); dk/dv accumulators travel
     around the ring with their k/v shard and arrive home after n hops."""
     qh, k0, v0, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     B, H, S, D = qh.shape
@@ -1258,7 +1238,7 @@ def ring_attention(q, k, v, axis_name: str, is_causal=False):
                           axis_name, is_causal)
         return jnp.swapaxes(out, 1, 2)
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -1266,11 +1246,9 @@ def ring_attention(q, k, v, axis_name: str, is_causal=False):
     scale = 1.0 / math.sqrt(q.shape[-1])
     B, H, S, D = qh.shape
 
-    # carries are device-varying under shard_map vma checking (jax 0.4.x
-    # has no varying-type tracking — identity there)
+    # carries are device-varying under shard_map vma checking
     def vary(x):
-        return jax.lax.pcast(x, (axis_name,), to="varying") \
-            if hasattr(jax.lax, "pcast") else x
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     m = vary(jnp.full((B, H, S, 1), -jnp.inf, jnp.float32))
     l = vary(jnp.zeros((B, H, S, 1), jnp.float32))
@@ -1364,7 +1342,7 @@ def ulysses_attention(q, k, v, axis_name: str, is_causal=False):
     sequence sharding.  Two all-to-alls ride ICI; compute is exactly the
     dense/flash kernel, so Ulysses wins over ring when heads ≥ ranks and
     the per-rank full sequence fits.  Inputs [B, S_local, H, D]."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, S, H, D = q.shape
     if H % n:
         raise ValueError(f"ulysses needs heads ({H}) divisible by the "
@@ -1440,9 +1418,9 @@ def sdpa_ulysses(query, key, value, mesh, axis_name: str = "sep",
 # ---------------------------------------------------------------------------
 # ragged paged attention (serving: one launch for any prefill+decode mix)
 # ---------------------------------------------------------------------------
-def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
-                         # when quantized), operands (HBM/ANY), output,
-                         # scratch — unpacked below
+def _ragged_paged_kernel(# scalar prefetch (+2 f32 scale tables when
+                         # quantized), operands, output, scratch —
+                         # unpacked below
                          *refs,
                          block_size: int, pages_per_span: int,
                          span_q: int, scale: float, groups: int,
@@ -1452,24 +1430,26 @@ def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
     span, or a prefill chunk = length-C span) against one kv head's
     pages (arXiv:2604.15464 "Ragged Paged Attention").
 
-    The packed query batch lives flat on the token axis; each span's
-    rows are DMA'd HBM->VMEM as a fixed ``span_q`` window starting at
-    its (scalar-prefetched) offset, pages stream through TWO VMEM
-    buffers per operand (round 17, ``pipelined=True``): page *i+1*'s
-    async copy is issued before attention on page *i* runs, and the
-    only stall is the wait at the buffer swap — the TPP pipelining
-    argument (arXiv:2104.05755) applied to the page stream.  The
-    prefetch is CLAMPED to the span's used block count: page *i+1* is
-    fetched only when ``i+1 < n_pages``, so the kernel never reads the
-    block table — let alone a page — past what ``kv_len`` covers (the
-    r11 poisoned-unused-pages invariant survives the pipeline).
-    ``pipelined=False`` keeps the r16 issue-then-wait single-buffer
-    loop for old-vs-new benching.  The online-softmax state lives in
-    fp32 registers either way, and the output window is DMA'd back.
-    Rows past ``q_len`` inside the window compute garbage that the NEXT
-    span's cell overwrites (grid order is span-major and sequential),
-    so the packed buffer carries ``span_q`` padding rows at the tail
-    for the last span's overhang.
+    The wrapper regroups the packed token batch SPAN-MAJOR — each cell's
+    ``[span_q * groups, D]`` query rows (row ``r * groups + j`` = token
+    r of the span, q head j of the kv group) arrive as one BlockSpec
+    block and leave the same way, so the kernel body is 2-D throughout:
+    no in-kernel reshape, no ragged-offset q/o DMA.  (Mosaic for v5e
+    rejects the ``[span_q, groups, D]`` -> ``[g, D]`` shape cast and a
+    sub-tile bf16 window slice; the regroup is a gather XLA does.)
+    Pages stream through TWO VMEM buffers per operand (round 17,
+    ``pipelined=True``): page *i+1*'s async copy is issued before
+    attention on page *i* runs, and the only stall is the wait at the
+    buffer swap — the TPP pipelining argument (arXiv:2104.05755)
+    applied to the page stream.  The prefetch is CLAMPED to the span's
+    used block count: page *i+1* is fetched only when ``i+1 <
+    n_pages``, so the kernel never reads the block table — let alone a
+    page — past what ``kv_len`` covers (the r11 poisoned-unused-pages
+    invariant survives the pipeline).  ``pipelined=False`` keeps the
+    r16 issue-then-wait single-buffer loop for old-vs-new benching.
+    The online-softmax state lives in fp32 registers either way.  Rows
+    past ``q_len`` inside the window compute finite garbage no token
+    reads back; a ``q_len == 0`` (padding) span's block is zeroed.
 
     Causality is positional: row r of span s sits at global position
     ``kv_len - q_len + r`` and sees keys at positions <= that, so decode
@@ -1478,8 +1458,8 @@ def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
 
     int8 pools (``quantized=True``): the pages arrive as int8 and the
     per-page-per-head fp32 absmax scales ride as two extra
-    scalar-prefetch tables bitcast to int32 ([Hkv, phys] — the same
-    SMEM dynamic-index mechanism as the block table).  Pipelined, the
+    scalar-prefetch tables ([Hkv, phys] — the same SMEM dynamic-index
+    mechanism as the block table).  Pipelined, the
     MXU consumes the int8 codes DIRECTLY: the span's q window is
     quantized once per cell to per-row int8
     (``quantize_rows_symmetric``), ``q·Kᵀ`` runs as an int8×int8
@@ -1497,49 +1477,41 @@ def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
     from ..quantization.functional import (fold_int8_scores,
                                            quantize_rows_symmetric)
     if quantized:
-        (q_off_ref, q_len_ref, kv_len_ref, bt_ref,
-         ks_bits_ref, vs_bits_ref,
-         q_hbm, k_pages, v_pages, o_hbm,
-         q_vmem, o_vmem, k_vmem, v_vmem, sem) = refs
+        (q_len_ref, kv_len_ref, bt_ref, ks_ref, vs_ref,
+         q_ref, k_pages, v_pages, o_ref,
+         k_vmem, v_vmem, sem) = refs
     else:
-        (q_off_ref, q_len_ref, kv_len_ref, bt_ref,
-         q_hbm, k_pages, v_pages, o_hbm,
-         q_vmem, o_vmem, k_vmem, v_vmem, sem) = refs
-        ks_bits_ref = vs_bits_ref = None
+        (q_len_ref, kv_len_ref, bt_ref,
+         q_ref, k_pages, v_pages, o_ref,
+         k_vmem, v_vmem, sem) = refs
+        ks_ref = vs_ref = None
     s = pl.program_id(0)
     h = pl.program_id(1)
     q_len = q_len_ref[s]
     int8_mxu = quantized and pipelined
+    g = span_q * groups
+    d = q_ref.shape[-1]
+
+    @pl.when(q_len <= 0)
+    def _padding_span():
+        o_ref[0, 0] = jnp.zeros((g, d), o_ref.dtype)
 
     @pl.when(q_len > 0)
     def _span():
-        off = q_off_ref[s]
         kv_len = kv_len_ref[s]
-        # pipelined, the page slots own sem rows 0/1; the q/o window
-        # copies use row 2 (strictly before/after the page loop, so
-        # reuse would also be safe — separate rows keep it legible)
-        qo_sem = sem.at[2, 0] if pipelined else sem
-        cp = pltpu.make_async_copy(
-            q_hbm.at[pl.ds(off, span_q), h], q_vmem, qo_sem)
-        cp.start()
-        cp.wait()
-        d = q_vmem.shape[-1]
-        g = span_q * groups
         if int8_mxu:
-            # one quantization per span window; padded rows are zeros,
-            # so the floored per-row scale keeps them zero codes
-            q_codes, q_s = quantize_rows_symmetric(
-                q_vmem[...].reshape(g, d))
+            # one quantization per span window
+            q_codes, q_s = quantize_rows_symmetric(q_ref[0, 0])
             q = None
         else:
-            q = (q_vmem[...].astype(jnp.float32).reshape(g, d)
-                 * np.float32(scale))
-        # row r of the span (each repeated over its q heads) sits at
-        # global position kv_len - q_len + r; garbage rows (r >= q_len)
-        # get qpos >= kv_len and attend the whole context — finite,
-        # never read
-        qpos = (kv_len - q_len + lax.broadcasted_iota(
-            jnp.int32, (span_q, groups), 0)).reshape(g, 1)
+            q = q_ref[0, 0].astype(jnp.float32) * np.float32(scale)
+        # row i = token i // groups of the span (each repeated over its
+        # q heads), at global position kv_len - q_len + token; garbage
+        # rows (token >= q_len) get qpos >= kv_len and attend the whole
+        # context — finite, never read
+        tok = lax.div(lax.broadcasted_iota(jnp.int32, (g, 1), 0),
+                      jnp.int32(groups))
+        qpos = kv_len - q_len + tok
 
         m0 = jnp.full((g, 1), _F32_NEG_INF, jnp.float32)
         l0 = jnp.zeros((g, 1), jnp.float32)
@@ -1553,10 +1525,8 @@ def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
             the pipelined and legacy loops; kbuf/vbuf are the page's
             VMEM values, int8 when quantized)."""
             if quantized:
-                sk = lax.bitcast_convert_type(ks_bits_ref[h, page],
-                                              jnp.float32)
-                sv = lax.bitcast_convert_type(vs_bits_ref[h, page],
-                                              jnp.float32)
+                sk = ks_ref[h, page]
+                sv = vs_ref[h, page]
             if int8_mxu:
                 si = lax.dot_general(q_codes, kbuf, _DIMNUM_NT,
                                      preferred_element_type=jnp.int32)
@@ -1644,13 +1614,8 @@ def _ragged_paged_kernel(# scalar prefetch (+2 bitcast scale tables
 
         m, l, acc = lax.fori_loop(jnp.int32(0), n_pages, body,
                                   (m0, l0, acc0))
-        o_vmem[...] = (acc / jnp.maximum(l, np.float32(1e-30))).reshape(
-            span_q, groups, d).astype(o_vmem.dtype)
-        op = pltpu.make_async_copy(
-            o_vmem, o_hbm.at[pl.ds(off, span_q), h],
-            sem.at[2, 1] if pipelined else sem)
-        op.start()
-        op.wait()
+        o_ref[0, 0] = (acc / jnp.maximum(l, np.float32(1e-30))
+                       ).astype(o_ref.dtype)
 
 
 def _ragged_paged_attention_pallas(q, key_cache, value_cache,
@@ -1660,8 +1625,16 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
                                    key_scale=None, value_scale=None,
                                    pipelined: bool = True):
     """q: [T, H, D] packed ragged tokens; block_tables [S, W]; span
-    tables [S].  span_q: static max span length (>= max(q_lens)).
-    Returns [T, H, D].
+    tables [S] (q_offsets ascending, padding spans pinned past the last
+    token — the same contract as ``_ragged_attention_xla``).  span_q:
+    static max span length (>= max(q_lens)).  Returns [T, H, D].
+
+    The token-major pack is regrouped span-major around the launch:
+    span s's window ``q[q_offsets[s] : q_offsets[s] + span_q]`` becomes
+    block ``[s, hkv]`` of a ``[S, Hkv, span_q * groups, D]`` f32
+    operand (one XLA gather in), and each token reads its own row back
+    out of the matching f32 output (one gather out, cast to q.dtype
+    here — a bf16 ``[1, D]`` decode row is below Mosaic's packed tile).
 
     Head sharding (tensor-parallel serving): the kernel is
     shard-oblivious — every head index here is LOCAL.  Each chip calls
@@ -1684,10 +1657,17 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
     groups = H // Hkv
     S, W = block_tables.shape
     span_q = max(1, int(span_q))
+    g = span_q * groups
     quantized = key_scale is not None
-    qg = q.reshape(T, Hkv, groups, D).astype(jnp.float32)
-    # span_q tail padding: the last span's fixed DMA window may overhang
-    qg = jnp.pad(qg, ((0, span_q), (0, 0), (0, 0), (0, 0)))
+    q_offsets = q_offsets.astype(jnp.int32)
+    win = jnp.arange(span_q, dtype=jnp.int32)
+    # rows past the pack's end (the last span's window overhang, and
+    # every row of a padding span) clamp to a real token: finite, never
+    # read back
+    rows = jnp.minimum(q_offsets[:, None] + win[None, :], T - 1)
+    qs = q.astype(jnp.float32)[rows]              # [S, span_q, H, D]
+    qs = jnp.moveaxis(qs.reshape(S, span_q, Hkv, groups, D), 2, 1)
+    qs = qs.reshape(S, Hkv, g, D)
     kp = jnp.moveaxis(key_cache, 2, 0)
     vp = jnp.moveaxis(value_cache, 2, 0)
     if not quantized:
@@ -1700,48 +1680,48 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
         pipelined=pipelined)
     if pipelined:
         # double-buffered page stream: 2 VMEM slots per operand, one
-        # DMA sem row per slot (k col 0 / v col 1) + a q/o row
+        # DMA sem row per slot (k col 0 / v col 1)
         page_scratch = [pltpu.VMEM((2, bs, D), kp.dtype),
                         pltpu.VMEM((2, bs, D), vp.dtype),
-                        pltpu.SemaphoreType.DMA((3, 2))]
+                        pltpu.SemaphoreType.DMA((2, 2))]
     else:
         page_scratch = [pltpu.VMEM((bs, D), kp.dtype),
                         pltpu.VMEM((bs, D), vp.dtype),
                         pltpu.SemaphoreType.DMA]
 
     with _x64_off():
-        prefetch = [q_offsets.astype(jnp.int32), q_lens.astype(jnp.int32),
-                    kv_lens.astype(jnp.int32), bt.astype(jnp.int32)]
+        prefetch = [q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
+                    bt.astype(jnp.int32)]
         if quantized:
-            # fp32 scales ride the int32 scalar-prefetch lane bitcast;
             # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
-            prefetch += [
-                jax.lax.bitcast_convert_type(
-                    key_scale.astype(jnp.float32).T, jnp.int32),
-                jax.lax.bitcast_convert_type(
-                    value_scale.astype(jnp.float32).T, jnp.int32)]
+            prefetch += [key_scale.astype(jnp.float32).T,
+                         value_scale.astype(jnp.float32).T]
+        qo_spec = pl.BlockSpec((1, 1, g, D), lambda s, h, *_: (s, h, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(S, Hkv),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+                qo_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((span_q, groups, D), jnp.float32),
-                pltpu.VMEM((span_q, groups, D), q.dtype),
-            ] + page_scratch,
+            out_specs=qo_spec,
+            scratch_shapes=page_scratch,
         )
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((T + span_q, Hkv, groups, D),
-                                           q.dtype),
+            out_shape=jax.ShapeDtypeStruct((S, Hkv, g, D), jnp.float32),
             interpret=interpret,
-        )(*prefetch, qg, kp, vp)
-    return out[:T].reshape(T, H, D)
+            name="ragged_paged_attention",
+        )(*prefetch, qs, kp, vp)
+    # token t -> (its span, its row inside the span's window)
+    tok = jnp.arange(T, dtype=jnp.int32)
+    sid = jnp.clip(jnp.searchsorted(q_offsets, tok, side="right") - 1,
+                   0, S - 1).astype(jnp.int32)
+    r = jnp.clip(tok - q_offsets[sid], 0, span_q - 1)
+    out = out.reshape(S, Hkv, span_q, groups, D)[sid, :, r]
+    return out.reshape(T, H, D).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1848,7 +1828,7 @@ def rope_qkv_epilogue(q, k, v, cos, sin, with_amax: bool = False,
     math, so CPU dryrun engines stay byte-identical end-to-end.
     """
     if use_pallas is None:
-        use_pallas = _HAS_PLTPU and _on_tpu()
+        use_pallas = _device.on_tpu()
     if not (use_pallas or interpret):
         return _rope_qkv_epilogue_xla(q, k, v, cos, sin, with_amax)
 
@@ -1895,6 +1875,7 @@ def rope_qkv_epilogue(q, k, v, cos, sin, with_amax: bool = False,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name="rope_qkv_epilogue",
         )(*args)
     q_rot, k_rot = res[0][:N], res[1][:N]
     if with_amax:
@@ -1929,24 +1910,16 @@ def _tile_bytes(shape, itemsize: int) -> int:
     return lead * rows * cols * itemsize
 
 
-def ragged_kernel_vmem_bytes(*, span_q: int, groups: int, head_dim: int,
-                             block_size: int, q_itemsize: int = 4,
-                             kv_itemsize: int = 4, pipelined: bool = True,
-                             quantized: bool = False) -> int:
-    """Worst-case VMEM bytes of ONE _ragged_paged_kernel grid cell:
-    the span_q query window (f32 scratch + output-dtype staging), the
-    page buffers (×2 per operand when pipelined — the round-17 double
-    buffering), and the live compute tiles (online-softmax m/l/acc,
-    the [g, block_size] score/probability tile, and the int8 q codes
-    + per-row scales on the quantized MXU path).  Mirrors the
-    scratch_shapes in _ragged_paged_attention_pallas — edit both or
-    tools/check_vmem_budget.py fails."""
-    g = span_q * groups
-    d = head_dim
+def _paged_cell_vmem_bytes(g: int, d: int, block_size: int,
+                           kv_itemsize: int, pipelined: bool,
+                           quantized: bool) -> int:
+    """What both paged kernels hold besides their q/o blocks: the page
+    buffers (×2 per operand when pipelined — the round-17 double
+    buffering) and the live compute tiles (online-softmax m/l/acc, the
+    [g, block_size] score/probability tile, and the int8 q codes +
+    per-row scales on the quantized MXU path)."""
     bufs = 2 if pipelined else 1
-    total = _tile_bytes((span_q, groups, d), 4)           # q window f32
-    total += _tile_bytes((span_q, groups, d), q_itemsize)  # o staging
-    total += 2 * bufs * _tile_bytes((block_size, d), kv_itemsize)  # k+v
+    total = 2 * bufs * _tile_bytes((block_size, d), kv_itemsize)  # k+v
     total += _tile_bytes((g, d), 4)                       # acc
     total += 2 * _tile_bytes((g, 1), 4)                   # m, l
     total += 2 * _tile_bytes((g, block_size), 4)          # scores + p
@@ -1957,19 +1930,33 @@ def ragged_kernel_vmem_bytes(*, span_q: int, groups: int, head_dim: int,
     return total
 
 
+def ragged_kernel_vmem_bytes(*, span_q: int, groups: int, head_dim: int,
+                             block_size: int, kv_itemsize: int = 4,
+                             pipelined: bool = True,
+                             quantized: bool = False) -> int:
+    """Worst-case VMEM bytes of ONE _ragged_paged_kernel grid cell: the
+    span's [span_q * groups, D] f32 query block and its f32 output
+    block (both BlockSpec-streamed, so Mosaic double-buffers them: ×2
+    each — f32 whatever the model dtype, the wrapper casts) plus the
+    page buffers and compute tiles.  Mirrors the specs in
+    _ragged_paged_attention_pallas — edit both or
+    tools/check_vmem_budget.py fails."""
+    g = span_q * groups
+    return 4 * _tile_bytes((g, head_dim), 4) + _paged_cell_vmem_bytes(
+        g, head_dim, block_size, kv_itemsize, pipelined, quantized)
+
+
 def decode_kernel_vmem_bytes(*, groups: int, head_dim: int,
                              block_size: int, q_itemsize: int = 4,
                              kv_itemsize: int = 4, pipelined: bool = True,
                              quantized: bool = False) -> int:
     """Worst-case VMEM bytes of ONE _paged_decode_kernel grid cell.
-    The q/o operands are BlockSpec-streamed (Mosaic double-buffers
-    them: ×2); pages go through the manual 2-slot DMA buffers."""
-    return ragged_kernel_vmem_bytes(
-        span_q=1, groups=groups, head_dim=head_dim,
-        block_size=block_size, q_itemsize=q_itemsize,
-        kv_itemsize=kv_itemsize, pipelined=pipelined,
-        quantized=quantized) \
-        + _tile_bytes((groups, head_dim), q_itemsize) * 2  # q+o 2nd buf
+    The [groups, D] q/o operands are BlockSpec-streamed in the model
+    dtype (Mosaic double-buffers them: ×2 each); pages go through the
+    manual 2-slot DMA buffers."""
+    return 4 * _tile_bytes((groups, head_dim), q_itemsize) \
+        + _paged_cell_vmem_bytes(groups, head_dim, block_size,
+                                 kv_itemsize, pipelined, quantized)
 
 
 def rope_epilogue_vmem_bytes(*, heads: int, kv_heads: int,

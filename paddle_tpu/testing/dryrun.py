@@ -1,13 +1,12 @@
 """Multichip CPU dryrun setup — ONE helper instead of N hand-rolled
 ``--xla_force_host_platform_device_count`` blocks.
 
-Every multichip bench/test used to copy the same dance: set
-``JAX_PLATFORMS=cpu`` + the XLA flag before any jax import, with a
-``jax_num_cpu_devices`` fallback for newer jax.  The copies drifted
-(some handled an already-initialized backend, some didn't), so the
-logic now lives here and is consumed by ``tools/bench_serving.py
---tp``, ``bench.py --sharded-update`` (via ``tools/
-bench_sharded_update.py``), ``tools/bench_checkpoint.py``,
+Every multichip bench/test used to copy the same dance (set
+``JAX_PLATFORMS=cpu`` + a device count before any jax import).  The
+copies drifted (some handled an already-initialized backend, some
+didn't), so the logic now lives here and is consumed by
+``tools/bench_serving.py --tp``, ``bench.py --sharded-update`` (via
+``tools/bench_sharded_update.py``), ``tools/bench_checkpoint.py``,
 ``__graft_entry__``, and the multichip tests.
 
 Importing this module is safe at any point: ``paddle_tpu`` never
@@ -24,70 +23,33 @@ __all__ = ["force_cpu_devices", "cpu_mesh_2d", "cpu_mesh_cp"]
 def force_cpu_devices(n_devices: int = 8) -> None:
     """Force JAX onto ``n_devices`` virtual CPU devices, before OR
     after a backend has been initialized.  Must not touch any real TPU
-    client.
-
-    jax-version notes (0.4.x vs >= 0.5): 0.4.x only honors the
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` env path and
-    it must be set before the CPU client initializes; newer jax has the
-    ``jax_num_cpu_devices`` config instead.  Both are handled here.
-    """
-    import re
+    client."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.pop("JAX_PLATFORM_NAME", None)
-    flags = os.environ.get("XLA_FLAGS", "")
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    if m is None or int(m.group(1)) < n_devices:
-        # replace a pre-set smaller count (e.g. leftover single-device
-        # debugging) instead of keeping it — on jax 0.4.x this flag is
-        # the only path, so an under-sized value would fail the final
-        # device-count assert; a larger pre-set count is left alone
-        if m is not None:
-            flags = flags.replace(m.group(0), "").strip()
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n_devices}"
-        ).strip()
     import jax
+    import jax.extend.backend
     from jax._src import xla_bridge
 
-    def _drop_live_backends():
-        # jax_num_cpu_devices must be set before backends initialize, so
-        # if the caller already touched jax.devices() (even on a TPU),
-        # tear the clients down and let them re-initialize under the new
-        # config/env on the next jax.devices() call.
-        jax.clear_caches()
-        try:
-            jax.extend.backend.clear_backends()
-        except Exception:
-            xla_bridge._clear_backends()
-
-    if getattr(xla_bridge, "_backends", None):
+    if xla_bridge.backends_are_initialized():
         # a live backend that already satisfies the request must be a
         # NO-OP (tests import this after conftest forced the mesh —
         # tearing it down would invalidate every live array)
         if jax.devices()[0].platform == "cpu" \
                 and jax.device_count() >= n_devices:
             return
-        _drop_live_backends()
+        # jax_num_cpu_devices must be set before backends initialize:
+        # tear the clients down and let them re-initialize under the
+        # new config on the next jax.devices() call
+        jax.clear_caches()
+        jax.extend.backend.clear_backends()
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:
-        # jax 0.4.x has no jax_num_cpu_devices option; the
-        # xla_force_host_platform_device_count XLA_FLAGS path (set
-        # above, applied when the CPU client initializes) covers it
-        pass
-    except Exception:
-        _drop_live_backends()
-        try:
-            jax.config.update("jax_num_cpu_devices", n_devices)
-        except AttributeError:
-            pass
+    jax.config.update("jax_num_cpu_devices", n_devices)
     if not (jax.devices()[0].platform == "cpu"
             and jax.device_count() >= n_devices):
-        _drop_live_backends()
-    assert jax.devices()[0].platform == "cpu", "CPU forcing failed"
-    assert jax.device_count() >= n_devices, (
-        f"only {jax.device_count()} CPU devices, wanted {n_devices}")
+        raise RuntimeError(
+            f"CPU forcing failed: {jax.device_count()} "
+            f"{jax.devices()[0].platform} device(s), wanted "
+            f"{n_devices} cpu")
 
 
 def cpu_mesh_2d(fsdp: int, tp: int, replica: int = 1):

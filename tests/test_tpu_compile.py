@@ -1,0 +1,164 @@
+"""AOT-compile every Pallas kernel, and one whole serving step, with the
+REAL XLA:TPU + Mosaic compilers for v5e — on a box with no chip.
+
+``jax.experimental.topologies`` hands out compile-only ``TpuDevice``s;
+lowering against a ``ShapeDtypeStruct`` that carries one of them runs
+the same compiler the chip machine runs.  Interpret mode (every other
+kernel test in this suite) checks a kernel's arithmetic and nothing
+about whether Mosaic accepts it: the first chip bring-up found the
+ragged span kernel rejected for bf16, for GQA and for int8 pools while
+all its interpret tests were green.  Whether a kernel RUNS right is
+chip_smoke.py's job; whether it COMPILES is checked here, on every PR.
+
+Shapes are the published ones: head_dim 128, 16-token pages, the
+serving chunk 256, training sequence 2048.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.core import device as _device
+
+D, BLOCK, PAGES, WIDTH, SPANS, CHUNK = 128, 16, 512, 46, 4, 256
+HEADS = {"mha": (32, 1), "gqa4": (8, 4)}          # name -> (Hkv, groups)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One compile-only v5e device's sharding."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        print(f"SKIP test_tpu_compile: get_topology_desc raised {e!r}")
+        pytest.skip(f"no compile-only TPU topology: {e!r}")
+    dev = topo.devices[0]
+    assert dev.platform == "tpu" and "v5" in dev.device_kind
+    return SingleDeviceSharding(dev)
+
+
+@pytest.fixture
+def as_if_on_tpu(monkeypatch):
+    """The dispatchers ask core.device.on_tpu(); the target is a TPU."""
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text(), "no Mosaic kernel"
+    return lowered.compile()
+
+
+def _pool(hkv, dtype):
+    return ((PAGES, BLOCK, hkv, D), dtype)
+
+
+@pytest.mark.parametrize("seq,d", [(2048, 128), (2048, 64), (1024, 128)])
+def test_flash_forward(v5e, seq, d):
+    from paddle_tpu.ops.pallas_kernels import _flash_attention_value
+    qkv = ((1, 32, seq, d), jnp.bfloat16)
+    _compile(lambda q, k, v: _flash_attention_value(q, k, v, True),
+             v5e, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("d", [128, 64])
+def test_flash_rope_forward_backward(v5e, as_if_on_tpu, d):
+    from paddle_tpu.ops import pallas_kernels as pk
+    seq = 2048
+    cos, sin = pk.rope_tables(seq, d)
+
+    def loss(q, k, v):
+        return pk._flash_rope_sdpa(q, k, v, cos, sin, True).astype(
+            jnp.float32).sum()
+
+    qkv = ((1, 32, seq, d), jnp.bfloat16)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("rows", [8, CHUNK])
+@pytest.mark.parametrize("with_amax", [False, True])
+def test_rope_qkv_epilogue(v5e, rows, with_amax):
+    from paddle_tpu.ops.pallas_kernels import rope_qkv_epilogue
+    qkv = ((rows, 32, D), jnp.bfloat16)
+    cs = ((rows, D), jnp.float32)
+    _compile(lambda q, k, v, c, s: rope_qkv_epilogue(
+        q, k, v, c, s, with_amax=with_amax, use_pallas=True),
+        v5e, qkv, qkv, qkv, cs, cs)
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode(v5e, dtype, heads, kv):
+    from paddle_tpu.ops.paged_attention import _paged_attention_pallas
+    hkv, groups = HEADS[heads]
+    quant = kv == "int8"
+    shapes = [((SPANS, hkv * groups, D), dtype),
+              _pool(hkv, jnp.int8 if quant else dtype),
+              _pool(hkv, jnp.int8 if quant else dtype),
+              ((SPANS, WIDTH), jnp.int32), ((SPANS,), jnp.int32)]
+    if quant:
+        shapes += [((PAGES, hkv), jnp.float32)] * 2
+
+    def fn(q, kc, vc, bt, sl, ks=None, vs=None):
+        return _paged_attention_pallas(q, kc, vc, bt, sl, D ** -0.5,
+                                       key_scale=ks, value_scale=vs)
+    _compile(fn, v5e, *shapes)
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("span_q", [1, CHUNK])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ragged_span(v5e, dtype, heads, span_q, kv):
+    """The table of ISSUE 21: bf16 x MHA, GQA x span_q > 1 and every
+    int8 case were MosaicErrors before the span-major regroup and the
+    f32 scale tables."""
+    from paddle_tpu.ops.pallas_kernels import \
+        _ragged_paged_attention_pallas
+    hkv, groups = HEADS[heads]
+    quant = kv == "int8"
+    tokens = SPANS if span_q == 1 else SPANS - 1 + span_q
+    spans = ((SPANS,), jnp.int32)
+    shapes = [((tokens, hkv * groups, D), dtype),
+              _pool(hkv, jnp.int8 if quant else dtype),
+              _pool(hkv, jnp.int8 if quant else dtype),
+              ((SPANS, WIDTH), jnp.int32), spans, spans, spans]
+    if quant:
+        shapes += [((PAGES, hkv), jnp.float32)] * 2
+
+    def fn(q, kc, vc, bt, qo, ql, kl, ks=None, vs=None):
+        return _ragged_paged_attention_pallas(
+            q, kc, vc, bt, qo, ql, kl, D ** -0.5, span_q=span_q,
+            key_scale=ks, value_scale=vs)
+    _compile(fn, v5e, *shapes)
+
+
+def test_mixed_step_full_width_two_layers(v5e):
+    """One whole fused serving step at Llama-2-7B width, bf16, 2 layers:
+    the top token budget (decodes + one 256-token chunk)."""
+    import chip_smoke
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import LlamaForCausalLM
+    paddle.seed(0)
+    model = LlamaForCausalLM(chip_smoke.llama2_7b_width(depth=2))
+    model.bfloat16()
+    model.eval()
+    eng = ContinuousBatchingEngine(
+        model, max_batch_size=SPANS, num_blocks=PAGES, block_size=BLOCK,
+        max_seq_len=WIDTH * BLOCK, mixed_step=True,
+        prefill_chunk_size=CHUNK, use_pallas=True)
+    lowered = eng.mixed.aot_lower(eng.token_budgets[-1],
+                                  device_sharding=v5e)
+    text = lowered.as_text()
+    for name in ("ragged_paged_attention", "rope_qkv_epilogue"):
+        assert f'kernel_name = "{name}"' in text
+    mem = lowered.compile().memory_analysis()
+    # pools are donated: aliased, not copied
+    assert mem.alias_size_in_bytes >= sum(
+        2 * int(c.key_cache.nbytes) for c in eng.caches)

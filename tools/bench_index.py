@@ -1,15 +1,14 @@
 """Aggregate every ``BENCH_*.json`` bench artifact into ONE
 machine-readable trajectory: ``BENCH_INDEX.json``.
 
-Every round since r01 has written a per-feature artifact (see the
+Every round since r05 has written a per-feature artifact (see the
 ``provenance`` rules in BASELINE.md), but the HISTORY has only been
 readable by grepping prose — there was no single file answering "what
 was the headline number and did the gates pass, per round".  This tool
 closes that: it scans the repo root for ``BENCH_*.json``, extracts the
 headline metric/value, the gate verdicts and the provenance line from
-each (tolerant of the three artifact generations: the legacy
-``{n, cmd, rc, parsed}`` wrappers of r01-r05, the sectioned
-``{metric, value, passed, gates}`` artifacts of r06+, and the
+each (tolerant of the two artifact generations: the sectioned
+``{metric, value, passed, gates}`` artifacts of r06+ and the
 schema-less r07-r09 dicts), and writes:
 
 - ``artifacts``: one row per file — round, file, headline metric +
@@ -49,10 +48,6 @@ def _headline(data: dict):
             # under its own ratio name
             value = data.get("ring_over_full_ratio")
         return (data["metric"], value, data.get("unit"))
-    parsed = data.get("parsed")
-    if isinstance(parsed, dict) and isinstance(parsed.get("metric"), str):
-        return (parsed["metric"], parsed.get("value"),
-                parsed.get("unit"))
     # r07-r09 schema-less artifacts: pick a stable, documented headline
     for key in ("stall_ratio_async_over_sync", "state_bytes_ratio_stage2",
                 "overhead_frac_median"):
@@ -69,8 +64,6 @@ def _gates(data: dict):
     if notes is None and isinstance(data.get("gate"), (int, float, str)):
         notes = [f"gate={data['gate']!r}"]
     passed = data.get("passed")
-    if passed is None and "rc" in data:           # legacy wrapper
-        passed = (data.get("rc") == 0)
     if passed is None and "ok" in data:
         passed = bool(data.get("ok"))
     if passed is None and gates:

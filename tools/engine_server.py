@@ -12,7 +12,12 @@ to build the byte-parity in-process reference with IDENTICAL weights
 (same ``paddle.seed``) and knobs::
 
     {
-      "platform": "cpu",          // force JAX onto CPU (test/bench rigs)
+      "platform": "cpu",          // REQUIRED, no default: "cpu" forces
+                                  // JAX onto CPU (test/bench rigs);
+                                  // "tpu" serves from the chip (and
+                                  // fails without one); "inherit"
+                                  // leaves an already-set-up process
+                                  // alone (in-process reference builds)
       "host": "127.0.0.1", "port": 0,
       "engine_id": 0, "role": "mixed",
       "seed": 0,                  // paddle.seed before model build
@@ -41,9 +46,23 @@ def build_engine_from_config(cfg: dict):
     """Deterministic engine from the config dict (shared with
     tools/bench_fleet.py and the slow-lane fleet tests: the same config
     builds byte-identical weights in any process)."""
-    if cfg.get("platform", "cpu") == "cpu":
+    platform = cfg.get("platform")
+    if platform == "cpu":
         from paddle_tpu.testing.dryrun import force_cpu_devices
         force_cpu_devices(int(cfg.get("cpu_devices", 1)))
+    elif platform == "tpu":
+        # one process per chip: this server owns it, so whoever spawned
+        # it must have stayed off JAX (or the start-up below fails)
+        from paddle_tpu.core.device import enable_compile_cache, on_tpu
+        enable_compile_cache()
+        if not on_tpu():
+            raise RuntimeError('config asks for "platform": "tpu" but '
+                               'JAX found no TPU')
+    elif platform != "inherit":
+        # a missing key used to mean "cpu": a fleet started for the
+        # chip would then quietly serve from the CPU
+        raise ValueError('engine config needs "platform": "cpu", "tpu" '
+                         'or "inherit"; got %r' % (platform,))
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
