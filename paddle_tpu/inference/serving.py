@@ -88,9 +88,15 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
+from ..observability.trace_merge import span_log
 from ..ops.paged_attention import PagedKVCache
+
+# the name of the one record every engine step writes to the process-wide
+# ``observability.span_log`` (see ``ContinuousBatchingEngine.step``)
+STEP_SPAN = "serving.step"
 
 # process-wide engine-id sequence: a multi-engine router needs a stable
 # identity per engine for health gauges / the /healthz payload, and an
@@ -858,6 +864,14 @@ class ContinuousBatchingEngine:
         # admission/prefill (None outside a step: direct _admit calls,
         # e.g. benches, skip it)
         self._finished_this_step = None
+        # the step record (see step()): the index of the step that is
+        # running or, between steps, of the next one; what the running
+        # step has noted so far (None between steps); the request ids
+        # admitted since the last record; the open phase span
+        self._step_no = 0
+        self._rec: Optional[Dict] = None
+        self._admitted: List[int] = []
+        self._phase_ann = None
         # per-ENGINE cumulative host counters (round 20): the
         # process-wide prometheus counters aggregate across every
         # engine in the process, so the capacity plane's per-engine
@@ -980,35 +994,119 @@ class ContinuousBatchingEngine:
         step — including requests that completed DURING admission
         (a one-token budget or EOS on the first sampled token ends a
         request inside the prefill itself; multi-engine callers key on
-        the returned ids, so those must not go missing)."""
+        the returned ids, so those must not go missing).
+
+        The step records itself.  Under a profiler it is the span
+        ``engine.step`` (``step_num`` = its index) holding six
+        consecutive phase spans ``engine.admit | pack | fill | dispatch
+        | fetch | book`` on the device trace's own clock; and, unless
+        the engine was built with ``tracer=False``, it ends by writing
+        ONE ``serving.step`` record (``_write_step_record``) whose
+        ``step`` is the same index: the join between the two clocks."""
+        t0 = time.perf_counter()
         self._finished_this_step = fts = []
+        self._rec = {"budget": 0, "n_dec": 0, "n_pre": 0, "spans": [],
+                     "compiled": False}
         try:
-            self._admit()
-            if self.mixed is not None:
-                done = self._run_mixed_step()
-            else:
-                self._prefill_chunks()
-                done = self._decode_batch()
+            with jax.profiler.StepTraceAnnotation(
+                    "engine.step", step_num=self._step_no):
+                self._phase("engine.admit")
+                self._admit()
+                self._rec["t_admit"] = self._phase("engine.pack")
+                if self.mixed is not None:
+                    done = self._run_mixed_step()
+                else:
+                    self._prefill_chunks()
+                    done = self._decode_batch()
+                seen = set(done)
+                done += [rid for rid in fts if rid not in seen]
+                running = sum(s is not None for s in self.slots)
+                self._m_queue.set(len(self.waiting))
+                self._m_occupancy.set(
+                    running / max(1, self.max_batch_size))
+                cache = self.caches[0]
+                self._m_kv_util.set(
+                    1.0 - len(cache._free) / max(1, cache.num_blocks))
+                if self.chunk_size is not None:
+                    # mixed chunks no longer consume a dedicated engine
+                    # round, but the backlog gauge still reports what
+                    # is pending
+                    self._m_chunk_queue.set(self._pending_chunks())
+                self._sync_prefix_stats()
+                t_end = self._phase(None)
+            if self.tracer.enabled:
+                self._write_step_record(t0, t_end, running)
         finally:
-            # restore the documented outside-a-step invariant (None)
-            # even on a raising step, so direct _admit/_finish callers
-            # between steps don't feed a stale list
+            # restore the documented outside-a-step invariants even on
+            # a raising step, so direct _admit/_finish callers between
+            # steps don't feed a stale list
+            self._phase(None)
             self._finished_this_step = None
-        seen = set(done)
-        done += [rid for rid in fts if rid not in seen]
-        self._m_queue.set(len(self.waiting))
-        self._m_occupancy.set(
-            sum(s is not None for s in self.slots)
-            / max(1, self.max_batch_size))
-        cache = self.caches[0]
-        self._m_kv_util.set(
-            1.0 - len(cache._free) / max(1, cache.num_blocks))
-        if self.chunk_size is not None:
-            # mixed chunks no longer consume a dedicated engine round,
-            # but the backlog gauge still reports what is pending
-            self._m_chunk_queue.set(self._pending_chunks())
-        self._sync_prefix_stats()
+            self._rec = None
+            self._admitted = []
+            self._step_no += 1
         return done
+
+    def _phase(self, name: Optional[str]) -> float:
+        """Close the step's open phase span and open ``name`` (``None``
+        opens none: the launch's two phases are ``MixedStep``'s own).
+        A step's phases are consecutive, so one instant ends one and
+        starts the next; returns that instant."""
+        if self._phase_ann is not None:
+            self._phase_ann.__exit__(None, None, None)
+            self._phase_ann = None
+        if name is not None:
+            self._phase_ann = jax.profiler.TraceAnnotation(name)
+            self._phase_ann.__enter__()
+        return time.perf_counter()
+
+    def _write_step_record(self, t0: float, t_end: float, running: int):
+        """The step's ONE record: ``span_log.record("serving.step", t0,
+        t_end, cat="serving", ...)``, all clocks ``time.perf_counter``.
+
+        - ``engine``, ``step``: this engine's id in the process; the
+          step's index from 0, counted by the engine (= the
+          ``step_num`` of the profiler span ``engine.step``).
+        - ``t_admit``, ``t_pack``, ``t_fill``, ``t_dispatch``,
+          ``t_tokens``: phase boundaries.  ``_admit`` done; pages
+          grown, spans chosen and their tokens listed; the pack filled;
+          the jitted call has RETURNED (work enqueued); the sampled
+          token ids are on the host, which is where the wait for the
+          device ends and the instant at which every token this step
+          emits (``spans[:, 0]``) reached the host.  ``t_end`` closes
+          the bookkeeping.  A step that launched nothing has its
+          missing boundaries at the next one it has.
+        - ``budget``, ``tokens``, ``n_dec``, ``n_pre``: the padded
+          token budget launched (0: nothing launched), the real tokens
+          in the pack, its decode spans, its prefill tokens.
+        - ``spans``: int32 ``[n, 3]`` rows ``(req_id, q_len, kv_len)``
+          in pack order, as packed.  A prefix-cache hit is not in it:
+          it was never computed.
+        - ``admitted``: request ids admitted since the last record.
+        - ``running``, ``waiting``: occupied slots and queue depth at
+          the step's end.  ``compiled``: the launch traced a module.
+
+        The speculative round and the split path fill the same fields
+        with what they have: the draft launches and the split path's
+        chunk launch count as ``pack``, ``n_dec`` counts verify spans
+        (``q_len`` k+1), and the split decode pads to the slot count.
+
+        Bound: ``span_log`` keeps its newest 16,384 entries, from every
+        writer (eight minutes of 30 ms steps); ``spans`` is one array,
+        so a full 64-slot record stays under 1 KB."""
+        rec = self._rec
+        bounds, t = {}, t_end
+        for key in ("t_tokens", "t_dispatch", "t_fill", "t_pack",
+                    "t_admit"):
+            t = bounds[key] = rec.get(key, t)
+        spans = np.asarray(rec["spans"], np.int32).reshape(-1, 3)
+        span_log.record(
+            STEP_SPAN, t0, t_end, cat="serving", engine=self.engine_id,
+            step=self._step_no, **bounds, budget=rec["budget"],
+            tokens=int(spans[:, 1].sum()), n_dec=rec["n_dec"],
+            n_pre=rec["n_pre"], spans=spans,
+            admitted=tuple(self._admitted), running=running,
+            waiting=len(self.waiting), compiled=rec["compiled"])
 
     def run_to_completion(self) -> Dict[int, List[int]]:
         while self.has_work():
@@ -1248,9 +1346,11 @@ class ContinuousBatchingEngine:
         self._m_migrated_bytes.inc(buffer.nbytes)
         self.counters["requests_received"] += 1
         self.counters["requests_admitted"] += 1
+        self._admitted.append(req.req_id)
         self.tracer.event(req.req_id, "admit", slot=slot,
                           prefix_hit_tokens=0, prompt_tokens=L,
-                          enqueue_ts=req.t_submit, migrated=True)
+                          enqueue_ts=req.t_submit, migrated=True,
+                          step=self._step_no)
         return req.req_id
 
     def health_payload(self) -> Dict[str, int]:
@@ -1511,10 +1611,11 @@ class ContinuousBatchingEngine:
         self.counters["requests_admitted"] += 1
         # ONE admission record (enqueue ts rides as an arg — the
         # tracer is on the admission path, so records are budgeted)
+        self._admitted.append(req.req_id)
         self.tracer.event(req.req_id, "admit", slot=slot,
                           prefix_hit_tokens=hit_len,
                           prompt_tokens=L,
-                          enqueue_ts=req.t_submit)
+                          enqueue_ts=req.t_submit, step=self._step_no)
         if self.sampling:
             self._samp[slot] = self._samp_row(req)
         if self.mixed is not None:
@@ -1561,6 +1662,7 @@ class ContinuousBatchingEngine:
         self._prefill_warm_lens.add(L)
         self.tracer.span(req.req_id, "prefill_dense", t_prefill, t_end,
                          tokens=L)
+        self._note_split_prefill(req.req_id, L, L, False)
         req.prefill_pos = L
         self._complete_prefill(req, first, row)
 
@@ -1626,10 +1728,23 @@ class ContinuousBatchingEngine:
             self._m_prefill.observe(t_end - t0)
         self.tracer.span(req.req_id, "prefill_chunk", t0, t_end,
                          offset=start, tokens=size,
-                         warm=not traced)
+                         warm=not traced, step=self._step_no)
+        self._note_split_prefill(req.req_id, size, start + size,
+                                 bool(traced))
         req.prefill_pos += size
         if req.prefill_pos >= L:
             self._complete_prefill(req, first, row)
+
+    def _note_split_prefill(self, rid: int, size: int, kv_len: int,
+                            traced: bool):
+        """The split path prefills in launches of its own (at admission
+        or one chunk a step): the running step's record takes each as
+        a span."""
+        rec = self._rec
+        if rec is not None:
+            rec["spans"].append((rid, size, kv_len))
+            rec["n_pre"] += size
+            rec["compiled"] |= traced
 
     def _complete_prefill(self, req: GenerationRequest, first: int,
                           row: np.ndarray):
@@ -1683,24 +1798,32 @@ class ContinuousBatchingEngine:
         # ONE fused XLA call at the fixed slot count; masked slots
         # (empty OR still prefilling) ride along — their writes hit the
         # sink page, their token is ignored
-        t_decode = time.perf_counter()
+        rec = self._rec
+        rec["t_pack"] = rec["t_fill"] = t_decode = self._phase(None)
         # DecodeStep returns np.asarray(...) — the host fetch inside
         # the call is the device barrier, so this window is honest
         nxt = self.decode_step(self._tokens, self._seq_lens, self._bt,
                                self._samp if self.sampling else None)
-        t_end = time.perf_counter()
+        rec["t_tokens"] = t_end = self._phase("engine.book")
+        rec["t_dispatch"] = self.decode_step.t_dispatch
         if self._decode_warm:
             self._m_decode.observe(t_end - t_decode)
+        rec["compiled"] |= not self._decode_warm
         self._decode_warm = True
         if self.tp is not None:
             self._count_collectives(
                 self.decode_step.collective_bytes(self.max_batch_size))
         if self.tracer.enabled:
-            for r in self.slots:
-                if r is not None and r.state == "running":
-                    self.tracer.sample_span(
-                        r.req_id, "decode_step", t_decode, t_end,
-                        every=self.trace_decode_every)
+            running = [r for r in self.slots
+                       if r is not None and r.state == "running"]
+            for r in running:
+                self.tracer.sample_span(
+                    r.req_id, "decode_step", t_decode, t_end,
+                    every=self.trace_decode_every, step=self._step_no)
+            rec["budget"] = self.max_batch_size
+            rec["n_dec"] = len(running)
+            rec["spans"].extend(
+                (r.req_id, 1, r.seq_len + 1) for r in running)
         for i, r in enumerate(list(self.slots)):
             if r is None or r.state != "running":
                 continue
@@ -1814,6 +1937,7 @@ class ContinuousBatchingEngine:
         bookkeeping the split decode/prefill paths used."""
         if self.draft_step is not None:
             return self._run_spec_round()
+        rec = self._rec
         done = self._grow_pages() if self.lazy_alloc else []
         spans, total = self._pack_spans()
         if not spans:
@@ -1824,18 +1948,23 @@ class ContinuousBatchingEngine:
                  else r.prompt_ids[start:start + size].astype(np.int32),
                  start, 0, 0, False)
                 for r, kind, size, start in spans]
+        rec["t_pack"] = self._phase("engine.fill")
         pack, B = self._fill_mixed_pack(self.mixed, self.token_budgets,
                                         fill)
 
-        t0 = time.perf_counter()
+        rec["t_fill"] = t0 = self._phase(None)
         pre = self.mixed.total_compiles
         nxt = self.mixed.call_packed(pack, B)
+        rec["t_tokens"] = t1 = self._phase("engine.book")
+        rec["t_dispatch"] = self.mixed.t_dispatch
         traced = self.mixed.total_compiles - pre
-        dt = time.perf_counter() - t0
+        dt = t1 - t0
         if self.tp is not None:
             self._count_collectives(self.mixed.collective_bytes(B))
         n_dec = sum(1 for _, kind, _, _ in spans if kind == "decode")
         n_pre = total - n_dec
+        rec.update(budget=B, n_dec=n_dec, n_pre=n_pre,
+                   compiled=bool(traced))
         if n_dec:
             self._m_mixed_tok_decode.inc(n_dec)
         if n_pre:
@@ -1858,17 +1987,19 @@ class ContinuousBatchingEngine:
             if n_pre:
                 self._m_prefill.observe(dt)
         if self.tracer.enabled:
+            rec["spans"] = [(r.req_id, size, start + size)
+                            for r, _, size, start in spans]
             # every span in the pack shares the one launch window
-            t1 = t0 + dt
             for r, kind, size, start in spans:
                 if kind == "decode":
                     self.tracer.sample_span(
                         r.req_id, "decode_step", t0, t1,
-                        every=self.trace_decode_every)
+                        every=self.trace_decode_every,
+                        step=self._step_no)
                 else:
                     self.tracer.span(r.req_id, "prefill_chunk", t0, t1,
                                      offset=start, tokens=size,
-                                     warm=not traced)
+                                     warm=not traced, step=self._step_no)
 
         for si, (r, kind, size, start) in enumerate(spans):
             tok = int(nxt[si])
@@ -2051,6 +2182,8 @@ class ContinuousBatchingEngine:
         for r, size, start in chunk_spans:
             v_spans.append((r, r.prompt_ids[start:start + size]
                             .astype(np.int32), start, 0, 0, False))
+        rec = self._rec
+        rec["t_pack"] = self._phase("engine.fill")
         pack, B = self._fill_mixed_pack(self.mixed, self.token_budgets,
                                         v_spans)
         q_probs = None
@@ -2059,12 +2192,16 @@ class ContinuousBatchingEngine:
                 q_list.append(self._zero_q)
             q_probs = tuple(q_list)
 
-        t0 = time.perf_counter()
+        rec["t_fill"] = t0 = self._phase(None)
         pre = self.mixed.total_compiles
         nxt, n_acc = self.mixed.call_packed(pack, B, q_probs=q_probs)
+        rec["t_tokens"] = t1 = self._phase("engine.book")
+        rec["t_dispatch"] = self.mixed.t_dispatch
         traced = self.mixed.total_compiles - pre
-        dt = time.perf_counter() - t0
+        dt = t1 - t0
         n_pre = sum(size for _, size, _ in chunk_spans)
+        rec.update(budget=B, n_dec=len(run_spans), n_pre=n_pre,
+                   compiled=bool(traced))
         if traced:
             self._m_mixed_compiles.inc(traced)
         else:
@@ -2075,18 +2212,20 @@ class ContinuousBatchingEngine:
         if n_pre:
             self._m_mixed_tok_prefill.inc(n_pre)
         if self.tracer.enabled:
+            rec["spans"] = [(r.req_id, len(toks), start + len(toks))
+                            for r, toks, start, _, _, _ in v_spans]
             # one verify launch advanced every slot (and the chunk
             # mirrors): sampled decode spans + chunk spans share its
             # window, exactly like the non-speculative mixed step
-            t1 = t0 + dt
             for r, _k in run_spans:
                 self.tracer.sample_span(
                     r.req_id, "decode_step", t0, t1,
-                    every=self.trace_decode_every, speculative=True)
+                    every=self.trace_decode_every, speculative=True,
+                    step=self._step_no)
             for r, size, start in chunk_spans:
                 self.tracer.span(r.req_id, "prefill_chunk", t0, t1,
                                  offset=start, tokens=size,
-                                 warm=not traced)
+                                 warm=not traced, step=self._step_no)
 
         emitted = 0
         for si, (r, toks, start, nd, _x, _m) in enumerate(v_spans):
@@ -2170,7 +2309,7 @@ class ContinuousBatchingEngine:
             self.tracer.event(
                 req.req_id, "first_token", ts=req.t_first_token,
                 ttft=(req.t_first_token - req.t_submit
-                      if req.t_submit else 0.0))
+                      if req.t_submit else 0.0), step=self._step_no)
         hit_eos = (req.eos_token_id is not None
                    and token == req.eos_token_id)
         if len(req.output_ids) >= req.max_new_tokens or hit_eos:

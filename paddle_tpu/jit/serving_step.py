@@ -86,7 +86,9 @@ DMA and the int8 MXU path (see BASELINE.md "round 17").
 from __future__ import annotations
 
 import math
-from typing import List, Optional
+import re
+import time
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -100,7 +102,78 @@ from .spmd import (TPContext, tp_embed, tp_gather_logits,
 
 __all__ = ["DecodeStep", "PrefillStep", "MixedStep", "prefill_scatter",
            "copy_block", "extract_blocks", "inject_blocks",
-           "migration_compiles", "migration_transfers"]
+           "migration_compiles", "migration_transfers", "STEP_SCOPES",
+           "hlo_op_scopes"]
+
+# The ``jax.named_scope`` names inside the traced step bodies (and the
+# ops they call: ``ops/pallas_kernels._ragged_paged_attention_pallas``,
+# ``ops/moe_gate.moe_ffn``).  No layer index: layers aggregate, and a
+# later ``lax.scan`` over layers keeps the names.  A device op belongs
+# to the INNERMOST of these on its ``op_name`` path (``ffn`` holds a
+# MoE layer's norm and residual, ``moe.*`` what lies inside it).
+STEP_SCOPES = frozenset((
+    "embed", "attn.qkv", "attn.rope", "attn.kv_write", "attn.kv_upcast",
+    "attn.regroup", "attn.kernel", "attn.ungroup", "attn.out", "ffn",
+    "moe.gate", "moe.dispatch", "moe.experts", "moe.combine",
+    "ep.all_to_all", "lm_head", "sample"))
+
+_HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_OPERAND = re.compile(r"\(%([\w.\-]+)")
+
+
+def hlo_op_scopes(hlo_text: str) -> Dict[str, Optional[str]]:
+    """``{instruction name: scope}`` for every instruction of an
+    OPTIMIZED HLO module's text (``compiled.as_text()``): the scope is
+    the innermost ``STEP_SCOPES`` name on the instruction's
+    ``metadata op_name`` path, ``None`` where the path holds none.  A
+    profiler trace names each device event by its instruction, so this
+    is what turns ``fusion.12`` into ``moe.experts``.  An instruction
+    the compiler made itself (no metadata: a fusion of bitcasts, a
+    layout copy) takes its called computation's root's scope, else
+    that of any instruction inside it, else its first operand's."""
+    own, calls, operand, body, root = {}, {}, {}, {}, {}
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            if line.endswith("{") and not line.startswith(" "):
+                comp = line.split("(", 1)[0].split()[-1].lstrip("%")
+            continue
+        name = m.group(1)
+        body.setdefault(comp, []).append(name)
+        if line.lstrip().startswith("ROOT "):
+            root[comp] = name
+        meta = _HLO_OP_NAME.search(line)
+        own[name] = next(
+            (part for part in reversed(meta.group(1).split("/"))
+             if part in STEP_SCOPES), None) if meta else None
+        c = _HLO_CALLS.search(line)
+        if c:
+            calls[name] = c.group(1)
+        o = _HLO_OPERAND.search(line[m.end():])
+        if o:
+            operand[name] = o.group(1)
+
+    out: Dict[str, Optional[str]] = {}
+
+    def resolve(name, depth=0):
+        if name in out or name not in own:
+            return out.get(name)
+        scope = own[name]
+        if scope is None and name in calls:
+            inner = calls[name]
+            scope = own.get(root.get(inner)) or next(
+                (own[i] for i in body.get(inner, ()) if own[i]), None)
+        if scope is None and name in operand and depth < 8:
+            scope = resolve(operand[name], depth + 1)
+        out[name] = scope
+        return scope
+
+    for name in own:
+        resolve(name)
+    return out
 
 
 def _resolve_tp(model, mesh, sharding, tp: Optional[TPContext]
@@ -309,6 +382,13 @@ def _ensure_quant_specs(tp: Optional[TPContext], qtree) -> None:
                 % (k, v.shape[0], tp.degree, spec))
 
 
+def _named(fn, name: str):
+    """``fn`` under the name its compiled module should carry: a
+    profiler trace prints ``jit_<name>(<fingerprint>)`` per launch."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _wrap_sharded(step, tp: TPContext, params_dict, n_layers: int,
                   n_repl: int, donate, quant_kv: bool = False):
     """Wrap a serving-step body as the explicit SPMD program: shard_map
@@ -342,6 +422,7 @@ def _wrap_sharded(step, tp: TPContext, params_dict, n_layers: int,
             params = {k: fsdp_gather(v, pspecs[k], faxis)
                       for k, v in params.items()}
             return inner(params, *rest)
+        _named(step, inner.__name__)
     fn = shard_map_compat(step, tp.mesh, in_specs=in_specs,
                           out_specs=out_specs)
     return jax.jit(fn, donate_argnums=donate,
@@ -782,97 +863,107 @@ class PrefillStep:
             new_kcs, new_vcs = [], []
             new_kss, new_vss = [], []
             with model.bind_state(params), no_grad():
-                x = _embed(llama, tokens, tp)
-                if cfg.dtype == "bfloat16":
-                    x = x.astype("bfloat16")
-                pos = start + jnp.arange(C, dtype=jnp.int32)
-                cos_t, sin_t = rope_tables_for_positions(
-                    pos, D, cfg.rope_theta)
+                with jax.named_scope("embed"):
+                    x = _embed(llama, tokens, tp)
+                    if cfg.dtype == "bfloat16":
+                        x = x.astype("bfloat16")
+                with jax.named_scope("attn.rope"):
+                    pos = start + jnp.arange(C, dtype=jnp.int32)
+                    cos_t, sin_t = rope_tables_for_positions(
+                        pos, D, cfg.rope_theta)
                 for li, (layer, kc, vc) in enumerate(
                         zip(llama.layers, kcs, vcs)):
-                    h = layer.input_layernorm(x)
                     attn = layer.self_attn
-                    q = attn.q_proj(h).reshape([1, C, H, D])
-                    k = attn.k_proj(h).reshape([1, C, Hkv, D])
-                    v = attn.v_proj(h).reshape([1, C, Hkv, D])
-                    qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
-                        q._value[0], k._value[0], v._value[0],
-                        cos_t, sin_t, with_amax=quant_kv,
-                        use_pallas=use_pallas)
-                    if quant_kv:
-                        kc, vc, ks, vs = write_chunk_kv_q8(
-                            kv_[None], v._value, kc, vc, kss[li],
-                            vss[li], bt, start, n_valid, sink,
-                            k_amax=k_amax, v_amax=v_amax)
-                        new_kss.append(ks)
-                        new_vss.append(vs)
-                    else:
-                        ks = vs = None
-                        if cp_deg > 1:
-                            # chunked prefill writes ONLY the owning
-                            # stripe (sequence-parallel scatter): the
-                            # global destination mirrors write_chunk_kv
-                            # at the GLOBAL block size, then the
-                            # stripe-local translation routes non-owned
-                            # rows to this chip's sink stripe
-                            bsl = kc.shape[1]
-                            gbs = bsl * cp_deg
-                            idx_c = jnp.arange(C, dtype=jnp.int32)
-                            pos_c = start.astype(jnp.int32) + idx_c
-                            blk_g = bt[0, pos_c // gbs]
-                            valid = idx_c < n_valid
-                            blk_g = jnp.where(valid, blk_g,
-                                              jnp.int32(sink))
-                            goff = jnp.where(valid, pos_c % gbs, 0)
-                            blk, off = _cp_local_dest(
-                                blk_g, goff, bsl, cp_axis, sink)
-                            kc, vc = write_ragged_kv(
-                                kv_, v._value[0], kc, vc, blk, off)
+                    with jax.named_scope("attn.qkv"):
+                        h = layer.input_layernorm(x)
+                        q = attn.q_proj(h).reshape([1, C, H, D])
+                        k = attn.k_proj(h).reshape([1, C, Hkv, D])
+                        v = attn.v_proj(h).reshape([1, C, Hkv, D])
+                    with jax.named_scope("attn.rope"):
+                        qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
+                            q._value[0], k._value[0], v._value[0],
+                            cos_t, sin_t, with_amax=quant_kv,
+                            use_pallas=use_pallas)
+                    with jax.named_scope("attn.kv_write"):
+                        if quant_kv:
+                            kc, vc, ks, vs = write_chunk_kv_q8(
+                                kv_[None], v._value, kc, vc, kss[li],
+                                vss[li], bt, start, n_valid, sink,
+                                k_amax=k_amax, v_amax=v_amax)
+                            new_kss.append(ks)
+                            new_vss.append(vs)
                         else:
-                            kc, vc = write_chunk_kv(
-                                kv_[None], v._value, kc, vc, bt, start,
-                                n_valid, sink)
+                            ks = vs = None
+                            if cp_deg > 1:
+                                # chunked prefill writes ONLY the owning
+                                # stripe (sequence-parallel scatter): the
+                                # global destination mirrors write_chunk_kv
+                                # at the GLOBAL block size, then the
+                                # stripe-local translation routes non-owned
+                                # rows to this chip's sink stripe
+                                bsl = kc.shape[1]
+                                gbs = bsl * cp_deg
+                                idx_c = jnp.arange(C, dtype=jnp.int32)
+                                pos_c = start.astype(jnp.int32) + idx_c
+                                blk_g = bt[0, pos_c // gbs]
+                                valid = idx_c < n_valid
+                                blk_g = jnp.where(valid, blk_g,
+                                                  jnp.int32(sink))
+                                goff = jnp.where(valid, pos_c % gbs, 0)
+                                blk, off = _cp_local_dest(
+                                    blk_g, goff, bsl, cp_axis, sink)
+                                kc, vc = write_ragged_kv(
+                                    kv_, v._value[0], kc, vc, blk, off)
+                            else:
+                                kc, vc = write_chunk_kv(
+                                    kv_[None], v._value, kc, vc, bt, start,
+                                    n_valid, sink)
                     new_kcs.append(kc)
                     new_vcs.append(vc)
-                    if cp_deg > 1:
-                        bsl = kc.shape[1]
-                        stripe = jax.lax.axis_index(cp_axis) * bsl
-                        o_p, m_p, l_p = chunk_prefill_attention_partial(
-                            qv[None], kc, vc, bt, start, scale,
-                            stripe, bsl * cp_deg)
-                        out = cross_chip_merge(
-                            o_p[0], m_p[0], l_p[0], cp_axis)[None]
+                    with jax.named_scope("attn.kernel"):
+                        if cp_deg > 1:
+                            bsl = kc.shape[1]
+                            stripe = jax.lax.axis_index(cp_axis) * bsl
+                            o_p, m_p, l_p = chunk_prefill_attention_partial(
+                                qv[None], kc, vc, bt, start, scale,
+                                stripe, bsl * cp_deg)
+                            out = cross_chip_merge(
+                                o_p[0], m_p[0], l_p[0], cp_axis)[None]
+                        else:
+                            out = chunk_prefill_attention(
+                                qv[None], kc, vc, bt, start, scale,
+                                key_scale=ks, value_scale=vs)
+                    with jax.named_scope("attn.out"):
+                        out = Tensor._from_value(out.reshape(1, C, H * D))
+                        x = x + _tp_psum(attn.o_proj(out), tp)
+                    with jax.named_scope("ffn"):
+                        h2 = layer.post_attention_layernorm(x)
+                        x = x + _ffn(layer, h2, tp)
+                with jax.named_scope("lm_head"):
+                    x = llama.norm(x)
+                    # only the last VALID position reaches the LM head:
+                    # [1, 1, h] @ [h, V], never the [C, V] logits block
+                    last = jax.lax.dynamic_slice_in_dim(
+                        x._value, n_valid - 1, 1, axis=1)
+                    last = Tensor._from_value(last)
+                    if model.lm_head is None:
+                        from ..ops.linalg import matmul
+                        logits = matmul(last, llama.embed_tokens.weight,
+                                        transpose_y=True)
                     else:
-                        out = chunk_prefill_attention(
-                            qv[None], kc, vc, bt, start, scale,
-                            key_scale=ks, value_scale=vs)
-                    out = Tensor._from_value(out.reshape(1, C, H * D))
-                    x = x + _tp_psum(attn.o_proj(out), tp)
-                    h2 = layer.post_attention_layernorm(x)
-                    x = x + _ffn(layer, h2, tp)
-                x = llama.norm(x)
-                # only the last VALID position reaches the LM head:
-                # [1, 1, h] @ [h, V], never the [C, V] logits block
-                last = jax.lax.dynamic_slice_in_dim(
-                    x._value, n_valid - 1, 1, axis=1)
-                last = Tensor._from_value(last)
-                if model.lm_head is None:
-                    from ..ops.linalg import matmul
-                    logits = matmul(last, llama.embed_tokens.weight,
-                                    transpose_y=True)
+                        logits = model.lm_head(last)
+                    logits = _tp_logits(logits, tp, q8=q8_gather)
+            with jax.named_scope("sample"):
+                if samp is None:
+                    nxt = jnp.argmax(logits._value[0, 0]
+                                     .astype(jnp.float32)).astype(jnp.int32)
                 else:
-                    logits = model.lm_head(last)
-                logits = _tp_logits(logits, tp, q8=q8_gather)
-            if samp is None:
-                nxt = jnp.argmax(logits._value[0, 0]
-                                 .astype(jnp.float32)).astype(jnp.int32)
-            else:
-                # first-token sample: counter = the prompt length
-                # start + n_valid (= the sampled token's position)
-                t, k, p, sd = _samp_knobs(samp[None, :])
-                toks = sample_logits(logits._value[:, 0, :], t, k,
-                                        p, sd, (start + n_valid)[None])
-                nxt = toks[0]
+                    # first-token sample: counter = the prompt length
+                    # start + n_valid (= the sampled token's position)
+                    t, k, p, sd = _samp_knobs(samp[None, :])
+                    toks = sample_logits(logits._value[:, 0, :], t, k,
+                                            p, sd, (start + n_valid)[None])
+                    nxt = toks[0]
             return (nxt, tuple(new_kcs), tuple(new_vcs),
                     tuple(new_kss), tuple(new_vss))
 
@@ -884,6 +975,7 @@ class PrefillStep:
                 return step(params, tokens, start, n_valid, bt, None,
                             kcs, vcs, kss, vss)
             donate, n_repl = (5, 6, 7, 8), 4
+        fn = _named(fn, "prefill_step")
         if tp is None:
             return jax.jit(fn, donate_argnums=donate)
         return _wrap_sharded(fn, tp, self._wq or self._param_tensors,
@@ -1048,6 +1140,9 @@ class MixedStep:
         self._param_tensors = dict(model.state_dict())
         self._fns = {}                 # token budget -> jitted step
         self.compile_counts = {}       # token budget -> trace count
+        # perf_counter instant at which the last call_packed's jitted
+        # call RETURNED (work enqueued, nothing fetched yet)
+        self.t_dispatch = 0.0
 
     @property
     def total_compiles(self) -> int:
@@ -1106,20 +1201,23 @@ class MixedStep:
                      ks=None, vs=None):
                 bsl = kc.shape[1]
                 stripe = jax.lax.axis_index(cp_axis) * bsl
-                o, m, l = _ragged_attention_xla_partial(
-                    q, kc, vc, bt, q_off, q_len, kv_len, scale,
-                    stripe, bsl * cp_deg)
-                return cross_chip_merge(o, m, l, cp_axis)
+                with jax.named_scope("attn.kernel"):
+                    o, m, l = _ragged_attention_xla_partial(
+                        q, kc, vc, bt, q_off, q_len, kv_len, scale,
+                        stripe, bsl * cp_deg)
+                    return cross_chip_merge(o, m, l, cp_axis)
         else:
             def attn(q, kc, vc, bt, q_off, q_len, kv_len,
                      ks=None, vs=None):
                 if use_pallas:
+                    # the wrapper names its own attn.* scopes
                     return _ragged_paged_attention_pallas(
                         q, kc, vc, bt, q_off, q_len, kv_len, scale,
                         span_q=span_q, key_scale=ks, value_scale=vs)
-                return _ragged_attention_xla(q, kc, vc, bt, q_off,
-                                             q_len, kv_len, scale,
-                                             ks, vs)
+                with jax.named_scope("attn.kernel"):
+                    return _ragged_attention_xla(q, kc, vc, bt, q_off,
+                                                 q_len, kv_len, scale,
+                                                 ks, vs)
 
         W = self.bt_width
         S = self.max_spans
@@ -1171,117 +1269,127 @@ class MixedStep:
             new_kcs, new_vcs = [], []
             new_kss, new_vss = [], []
             with model.bind_state(params), no_grad():
-                x = _embed(llama, tokens[None, :], tp)         # [1, T, h]
-                if cfg.dtype == "bfloat16":
-                    x = x.astype("bfloat16")
+                with jax.named_scope("embed"):
+                    x = _embed(llama, tokens[None, :], tp)     # [1, T, h]
+                    if cfg.dtype == "bfloat16":
+                        x = x.astype("bfloat16")
                 # rope tables built ONCE per step (positions are
                 # layer-invariant) and consumed by the fused epilogue
                 # in every layer
-                cos_t, sin_t = rope_tables_for_positions(
-                    positions, D, cfg.rope_theta)
+                with jax.named_scope("attn.rope"):
+                    cos_t, sin_t = rope_tables_for_positions(
+                        positions, D, cfg.rope_theta)
                 for li, (layer, kc, vc) in enumerate(
                         zip(llama.layers, kcs, vcs)):
-                    h = layer.input_layernorm(x)
                     at = layer.self_attn
-                    q = at.q_proj(h).reshape([1, T, H, D])
-                    k = at.k_proj(h).reshape([1, T, Hkv, D])
-                    v = at.v_proj(h).reshape([1, T, Hkv, D])
+                    with jax.named_scope("attn.qkv"):
+                        h = layer.input_layernorm(x)
+                        q = at.q_proj(h).reshape([1, T, H, D])
+                        k = at.k_proj(h).reshape([1, T, Hkv, D])
+                        v = at.v_proj(h).reshape([1, T, Hkv, D])
                     # fused RoPE+QKV epilogue: rope(q), rope(k) and the
                     # quantize-on-write absmax rows in ONE pass over
                     # the projection outputs
-                    qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
-                        q._value[0], k._value[0], v._value[0],
-                        cos_t, sin_t, with_amax=quant_kv,
-                        use_pallas=use_pallas)
-                    if quant_kv:
-                        kc, vc, ks, vs = write_ragged_kv_q8(
-                            kv_, v._value[0], kc, vc, kss[li],
-                            vss[li], dest_blocks, dest_offsets,
-                            k_amax=k_amax, v_amax=v_amax)
-                        new_kss.append(ks)
-                        new_vss.append(vs)
-                    else:
-                        ks = vs = None
-                        kc, vc = write_ragged_kv(
-                            kv_, v._value[0], kc, vc,
-                            dest_blocks, dest_offsets)
+                    with jax.named_scope("attn.rope"):
+                        qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
+                            q._value[0], k._value[0], v._value[0],
+                            cos_t, sin_t, with_amax=quant_kv,
+                            use_pallas=use_pallas)
+                    with jax.named_scope("attn.kv_write"):
+                        if quant_kv:
+                            kc, vc, ks, vs = write_ragged_kv_q8(
+                                kv_, v._value[0], kc, vc, kss[li],
+                                vss[li], dest_blocks, dest_offsets,
+                                k_amax=k_amax, v_amax=v_amax)
+                            new_kss.append(ks)
+                            new_vss.append(vs)
+                        else:
+                            ks = vs = None
+                            kc, vc = write_ragged_kv(
+                                kv_, v._value[0], kc, vc,
+                                dest_blocks, dest_offsets)
                     new_kcs.append(kc)
                     new_vcs.append(vc)
                     out = attn(qv, kc, vc, bt, q_offsets,
                                q_lens, kv_lens, ks, vs)
-                    out = Tensor._from_value(out.reshape(1, T, H * D))
-                    x = x + _tp_psum(at.o_proj(out), tp)
-                    h2 = layer.post_attention_layernorm(x)
-                    x = x + _ffn(layer, h2, tp)
-                x = llama.norm(x)
-                # only each span's sampled rows reach the LM head:
-                # one row per span normally ([max_spans, 1, h] @
-                # [h, V]); under spec_k each span's K+1 verify rows
-                # plus its last-valid row ([S*(K+2), 1, h]) — the
-                # [T, V] logits block is never materialized either way
+                    with jax.named_scope("attn.out"):
+                        out = Tensor._from_value(
+                            out.reshape(1, T, H * D))
+                        x = x + _tp_psum(at.o_proj(out), tp)
+                    with jax.named_scope("ffn"):
+                        h2 = layer.post_attention_layernorm(x)
+                        x = x + _ffn(layer, h2, tp)
+                with jax.named_scope("lm_head"):
+                    x = llama.norm(x)
+                    # only each span's sampled rows reach the LM head:
+                    # one row per span normally ([max_spans, 1, h] @
+                    # [h, V]); under spec_k each span's K+1 verify rows
+                    # plus its last-valid row ([S*(K+2), 1, h]) — the
+                    # [T, V] logits block is never materialized either way
+                    if spec_k:
+                        vrow = (q_offsets[:, None]
+                                + jnp.arange(spec_k + 1,
+                                             dtype=jnp.int32)[None, :])
+                        last = q_offsets + jnp.maximum(q_lens - 1, 0)
+                        vrow = jnp.minimum(vrow, last[:, None])
+                        rows_idx = jnp.clip(
+                            jnp.concatenate([vrow, sample_rows[:, None]],
+                                            axis=1).reshape(-1), 0, T - 1)
+                    else:
+                        rows_idx = sample_rows
+                    rows = Tensor._from_value(
+                        x._value[0][rows_idx][:, None, :])
+                    if model.lm_head is None:
+                        from ..ops.linalg import matmul
+                        logits = matmul(rows, llama.embed_tokens.weight,
+                                        transpose_y=True)
+                    else:
+                        logits = model.lm_head(rows)
+                    logits = _tp_logits(logits, tp, q8=q8_gather)
+            with jax.named_scope("sample"):
+                lv = logits._value[:, 0, :].astype(jnp.float32)
                 if spec_k:
-                    vrow = (q_offsets[:, None]
-                            + jnp.arange(spec_k + 1,
-                                         dtype=jnp.int32)[None, :])
-                    last = q_offsets + jnp.maximum(q_lens - 1, 0)
-                    vrow = jnp.minimum(vrow, last[:, None])
-                    rows_idx = jnp.clip(
-                        jnp.concatenate([vrow, sample_rows[:, None]],
-                                        axis=1).reshape(-1), 0, T - 1)
-                else:
-                    rows_idx = sample_rows
-                rows = Tensor._from_value(
-                    x._value[0][rows_idx][:, None, :])
-                if model.lm_head is None:
-                    from ..ops.linalg import matmul
-                    logits = matmul(rows, llama.embed_tokens.weight,
-                                    transpose_y=True)
-                else:
-                    logits = model.lm_head(rows)
-                logits = _tp_logits(logits, tp, q8=q8_gather)
-            lv = logits._value[:, 0, :].astype(jnp.float32)
-            if spec_k:
-                # speculative verify: rows [:, :K+1] feed the
-                # accept/reject scan, row K+1 is the plain-span sample
-                lv3 = lv.reshape(S, spec_k + 2, -1)
-                didx = jnp.clip(
-                    q_offsets[:, None] + 1
-                    + jnp.arange(spec_k, dtype=jnp.int32)[None, :],
-                    0, T - 1)
-                d_toks = tokens[didx]          # the spans' fed drafts
-                base_pos = kv_lens - q_lens + 1
+                    # speculative verify: rows [:, :K+1] feed the
+                    # accept/reject scan, row K+1 is the plain-span sample
+                    lv3 = lv.reshape(S, spec_k + 2, -1)
+                    didx = jnp.clip(
+                        q_offsets[:, None] + 1
+                        + jnp.arange(spec_k, dtype=jnp.int32)[None, :],
+                        0, T - 1)
+                    d_toks = tokens[didx]          # the spans' fed drafts
+                    base_pos = kv_lens - q_lens + 1
+                    if sampling:
+                        q = jnp.stack(q_probs, axis=1)        # [S, K, V]
+                        n_acc, e_v = spec_verify(
+                            lv3[:, :spec_k + 1], d_toks, n_draft, s_t, s_k,
+                            s_p, s_sd, base_pos, q)
+                        e_p = sample_logits(lv3[:, spec_k + 1], s_t,
+                                               s_k, s_p, s_sd, kv_lens)
+                    else:
+                        zf = jnp.zeros((S,), jnp.float32)
+                        zi = jnp.zeros((S,), jnp.int32)
+                        n_acc, e_v = spec_verify(
+                            lv3[:, :spec_k + 1], d_toks, n_draft, zf, zi,
+                            zf, zi, base_pos)
+                        e_p = jnp.argmax(lv3[:, spec_k + 1],
+                                         axis=-1).astype(jnp.int32)
+                    nxt = jnp.where(n_draft > 0, e_v, e_p)
+                    return (nxt, n_acc, tuple(new_kcs), tuple(new_vcs),
+                            tuple(new_kss), tuple(new_vss))
                 if sampling:
-                    q = jnp.stack(q_probs, axis=1)        # [S, K, V]
-                    n_acc, e_v = spec_verify(
-                        lv3[:, :spec_k + 1], d_toks, n_draft, s_t, s_k,
-                        s_p, s_sd, base_pos, q)
-                    e_p = sample_logits(lv3[:, spec_k + 1], s_t,
-                                           s_k, s_p, s_sd, kv_lens)
+                    # counter = kv_len — the sampled token's global
+                    # position, the SAME counter the split steps use, so
+                    # seeded tokens agree across engines
+                    nxt = sample_logits(lv, s_t, s_k, s_p, s_sd,
+                                           kv_lens)
                 else:
-                    zf = jnp.zeros((S,), jnp.float32)
-                    zi = jnp.zeros((S,), jnp.int32)
-                    n_acc, e_v = spec_verify(
-                        lv3[:, :spec_k + 1], d_toks, n_draft, zf, zi,
-                        zf, zi, base_pos)
-                    e_p = jnp.argmax(lv3[:, spec_k + 1],
-                                     axis=-1).astype(jnp.int32)
-                nxt = jnp.where(n_draft > 0, e_v, e_p)
-                return (nxt, n_acc, tuple(new_kcs), tuple(new_vcs),
+                    nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
+                if return_probs:
+                    return (nxt, filtered_probs(lv, s_t, s_k, s_p),
+                            tuple(new_kcs), tuple(new_vcs),
+                            tuple(new_kss), tuple(new_vss))
+                return (nxt, tuple(new_kcs), tuple(new_vcs),
                         tuple(new_kss), tuple(new_vss))
-            if sampling:
-                # counter = kv_len — the sampled token's global
-                # position, the SAME counter the split steps use, so
-                # seeded tokens agree across engines
-                nxt = sample_logits(lv, s_t, s_k, s_p, s_sd,
-                                       kv_lens)
-            else:
-                nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
-            if return_probs:
-                return (nxt, filtered_probs(lv, s_t, s_k, s_p),
-                        tuple(new_kcs), tuple(new_vcs),
-                        tuple(new_kss), tuple(new_vss))
-            return (nxt, tuple(new_kcs), tuple(new_vcs),
-                    tuple(new_kss), tuple(new_vss))
 
         if spec_k and sampling:
             fn, donate = step, (3, 4, 5, 6)
@@ -1291,6 +1399,7 @@ class MixedStep:
             def fn(params, pack, kcs, vcs, kss, vss):
                 return step(params, pack, None, kcs, vcs, kss, vss)
             donate = (2, 3, 4, 5)
+        fn = _named(fn, "mixed_step")
         if tp is None:
             return jax.jit(fn, donate_argnums=donate)
         return _wrap_sharded(fn, tp, self._wq or self._param_tensors,
@@ -1397,31 +1506,44 @@ class MixedStep:
         (a tuple of K device-resident [max_spans, V] draft
         distributions) when sampled; a draft (``return_probs``)
         returns ``(tokens, probs)`` with probs left ON DEVICE."""
-        fn = self._fns.get(T)
-        if fn is None:
-            fn = self._fns[T] = self._build(T)
-        params = _step_params(self._param_tensors, self._tp, self._wq)
-        kcs = tuple(c.key_cache for c in self.caches)
-        vcs = tuple(c.value_cache for c in self.caches)
-        kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params, jnp.asarray(pack)]
-        if self.spec_k and self.sampling:
-            if q_probs is None:
-                raise ValueError(
-                    "sampled speculative verify needs the draft's "
-                    "q_probs tuple (zeros when no span drafts)")
-            args.append(tuple(q_probs))
-        out = fn(*args, kcs, vcs, kss, vss)
-        if self.spec_k:
-            nxt, n_acc = out[0], out[1]
-            _rebind_caches(self.caches, *out[2:])
-            return np.asarray(nxt), np.asarray(n_acc)
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            fn = self._fns.get(T)
+            if fn is None:
+                fn = self._fns[T] = self._build(T)
+            params = _step_params(self._param_tensors, self._tp,
+                                  self._wq)
+            kcs = tuple(c.key_cache for c in self.caches)
+            vcs = tuple(c.value_cache for c in self.caches)
+            kss, vss = _cache_scales(self.caches, self._quant_kv)
+            args = [params, jnp.asarray(pack)]
+            if self.spec_k and self.sampling:
+                if q_probs is None:
+                    raise ValueError(
+                        "sampled speculative verify needs the draft's "
+                        "q_probs tuple (zeros when no span drafts)")
+                args.append(tuple(q_probs))
+            out = fn(*args, kcs, vcs, kss, vss)
+            n_out = 2 if self.spec_k or self.return_probs else 1
+            _rebind_caches(self.caches, *out[n_out:])
+        # the launch is enqueued: what follows is the wait for the
+        # device (the step record's t_dispatch .. t_tokens)
+        self.t_dispatch = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.fetch"):
+            nxt = np.asarray(out[0])
+            if self.spec_k:
+                return nxt, np.asarray(out[1])
         if self.return_probs:
-            nxt, probs = out[0], out[1]
-            _rebind_caches(self.caches, *out[2:])
-            return np.asarray(nxt), probs
-        _rebind_caches(self.caches, *out[1:])
-        return np.asarray(out[0])
+            return nxt, out[1]
+        return nxt
+
+    def op_scopes(self, T: int) -> Dict[str, Optional[str]]:
+        """``{optimized-HLO instruction name: scope}`` of the compiled
+        budget-``T`` module (see :func:`hlo_op_scopes`): what a trace
+        reader needs to give each device op of a launch its part of
+        the step.  Lowers through the ``call_packed`` jit cache and
+        compiles through the compile cache, so on a budget that has
+        run it builds nothing new."""
+        return hlo_op_scopes(self.aot_lower(T).compile().as_text())
 
 
 class DecodeStep:
@@ -1461,6 +1583,7 @@ class DecodeStep:
         # tests can assert the decode step compiles exactly once across
         # admission/eviction churn
         self.compile_count = 0
+        self.t_dispatch = 0.0          # as MixedStep.t_dispatch
 
     def collective_bytes(self, slots: int):
         """Per-chip collective payload of one sharded decode step over
@@ -1518,89 +1641,99 @@ class DecodeStep:
             new_kcs, new_vcs = [], []
             new_kss, new_vss = [], []
             with model.bind_state(params), no_grad():
-                x = _embed(llama, tokens[:, None], tp)        # [S, 1, h]
-                if cfg.dtype == "bfloat16":
-                    x = x.astype("bfloat16")
+                with jax.named_scope("embed"):
+                    x = _embed(llama, tokens[:, None], tp)        # [S, 1, h]
+                    if cfg.dtype == "bfloat16":
+                        x = x.astype("bfloat16")
                 # one-token-per-slot rows: positions = seq_lens; rope
                 # tables built once per step, shared by every layer
-                cos_t, sin_t = rope_tables_for_positions(
-                    seq_lens, D, cfg.rope_theta)
+                with jax.named_scope("attn.rope"):
+                    cos_t, sin_t = rope_tables_for_positions(
+                        seq_lens, D, cfg.rope_theta)
                 for li, (layer, kc, vc) in enumerate(
                         zip(llama.layers, kcs, vcs)):
-                    h = layer.input_layernorm(x)
                     attn = layer.self_attn
-                    q = attn.q_proj(h).reshape([S, 1, H, D])
-                    k = attn.k_proj(h).reshape([S, 1, Hkv, D])
-                    v = attn.v_proj(h).reshape([S, 1, Hkv, D])
-                    qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
-                        q._value[:, 0], k._value[:, 0], v._value[:, 0],
-                        cos_t, sin_t, with_amax=quant_kv,
-                        use_pallas=use_pallas)
-                    if quant_kv:
-                        kc, vc, ks, vs = write_decode_kv_q8(
-                            kv_, v._value[:, 0], kc, vc,
-                            kss[li], vss[li], block_tables, seq_lens,
-                            k_amax=k_amax, v_amax=v_amax)
-                        new_kss.append(ks)
-                        new_vss.append(vs)
-                    else:
-                        ks = vs = None
-                        if cp_deg > 1:
-                            # global destination (block table at the
-                            # GLOBAL block size), then stripe-local
-                            # translation + the plain ragged scatter
-                            bsl = kc.shape[1]
-                            gbs = bsl * cp_deg
-                            blk_g = jnp.take_along_axis(
-                                block_tables,
-                                (seq_lens // gbs)[:, None],
-                                axis=1)[:, 0]
-                            blk, off = _cp_local_dest(
-                                blk_g, seq_lens % gbs, bsl, cp_axis,
-                                sink)
-                            kc, vc = write_ragged_kv(
-                                kv_, v._value[:, 0], kc, vc, blk, off)
-                        else:
-                            kc, vc = write_decode_kv(
+                    with jax.named_scope("attn.qkv"):
+                        h = layer.input_layernorm(x)
+                        q = attn.q_proj(h).reshape([S, 1, H, D])
+                        k = attn.k_proj(h).reshape([S, 1, Hkv, D])
+                        v = attn.v_proj(h).reshape([S, 1, Hkv, D])
+                    with jax.named_scope("attn.rope"):
+                        qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
+                            q._value[:, 0], k._value[:, 0], v._value[:, 0],
+                            cos_t, sin_t, with_amax=quant_kv,
+                            use_pallas=use_pallas)
+                    with jax.named_scope("attn.kv_write"):
+                        if quant_kv:
+                            kc, vc, ks, vs = write_decode_kv_q8(
                                 kv_, v._value[:, 0], kc, vc,
-                                block_tables, seq_lens)
+                                kss[li], vss[li], block_tables, seq_lens,
+                                k_amax=k_amax, v_amax=v_amax)
+                            new_kss.append(ks)
+                            new_vss.append(vs)
+                        else:
+                            ks = vs = None
+                            if cp_deg > 1:
+                                # global destination (block table at the
+                                # GLOBAL block size), then stripe-local
+                                # translation + the plain ragged scatter
+                                bsl = kc.shape[1]
+                                gbs = bsl * cp_deg
+                                blk_g = jnp.take_along_axis(
+                                    block_tables,
+                                    (seq_lens // gbs)[:, None],
+                                    axis=1)[:, 0]
+                                blk, off = _cp_local_dest(
+                                    blk_g, seq_lens % gbs, bsl, cp_axis,
+                                    sink)
+                                kc, vc = write_ragged_kv(
+                                    kv_, v._value[:, 0], kc, vc, blk, off)
+                            else:
+                                kc, vc = write_decode_kv(
+                                    kv_, v._value[:, 0], kc, vc,
+                                    block_tables, seq_lens)
                     new_kcs.append(kc)
                     new_vcs.append(vc)
-                    if cp_deg > 1:
-                        bsl = kc.shape[1]
-                        stripe = jax.lax.axis_index(cp_axis) * bsl
-                        o_p, m_p, l_p = _paged_attention_xla_partial(
-                            qv, kc, vc, block_tables, seq_lens + 1,
-                            scale, stripe, bsl * cp_deg)
-                        out = cross_chip_merge(o_p, m_p, l_p, cp_axis)
+                    with jax.named_scope("attn.kernel"):
+                        if cp_deg > 1:
+                            bsl = kc.shape[1]
+                            stripe = jax.lax.axis_index(cp_axis) * bsl
+                            o_p, m_p, l_p = _paged_attention_xla_partial(
+                                qv, kc, vc, block_tables, seq_lens + 1,
+                                scale, stripe, bsl * cp_deg)
+                            out = cross_chip_merge(o_p, m_p, l_p, cp_axis)
+                        else:
+                            out = attn_fn(qv, kc, vc, block_tables,
+                                          seq_lens + 1, scale,
+                                          key_scale=ks, value_scale=vs)
+                    with jax.named_scope("attn.out"):
+                        out = Tensor._from_value(out.reshape(S, 1, H * D))
+                        x = x + _tp_psum(attn.o_proj(out), tp)
+                    with jax.named_scope("ffn"):
+                        h2 = layer.post_attention_layernorm(x)
+                        x = x + _ffn(layer, h2, tp)
+                with jax.named_scope("lm_head"):
+                    x = llama.norm(x)
+                    if model.lm_head is None:
+                        from ..ops.linalg import matmul
+                        logits = matmul(x, llama.embed_tokens.weight,
+                                        transpose_y=True)
                     else:
-                        out = attn_fn(qv, kc, vc, block_tables,
-                                      seq_lens + 1, scale,
-                                      key_scale=ks, value_scale=vs)
-                    out = Tensor._from_value(out.reshape(S, 1, H * D))
-                    x = x + _tp_psum(attn.o_proj(out), tp)
-                    h2 = layer.post_attention_layernorm(x)
-                    x = x + _ffn(layer, h2, tp)
-                x = llama.norm(x)
-                if model.lm_head is None:
-                    from ..ops.linalg import matmul
-                    logits = matmul(x, llama.embed_tokens.weight,
-                                    transpose_y=True)
-                else:
-                    logits = model.lm_head(x)
-                logits = _tp_logits(logits, tp, q8=q8_gather)
+                        logits = model.lm_head(x)
+                    logits = _tp_logits(logits, tp, q8=q8_gather)
             # sampling ON DEVICE: only the [S] token ids cross the
             # link, never the [S, V] logits.  samp=None is the greedy
             # default path — the exact argmax, trace unchanged.
-            if samp is None:
-                nxt = jnp.argmax(
-                    logits._value[:, 0, :].astype(jnp.float32),
-                    axis=-1).astype(jnp.int32)
-            else:
-                t, k, p, sd = _samp_knobs(samp)
-                # counter = the sampled token's global position
-                nxt = sample_logits(logits._value[:, 0, :], t, k, p,
-                                       sd, seq_lens + 1)
+            with jax.named_scope("sample"):
+                if samp is None:
+                    nxt = jnp.argmax(
+                        logits._value[:, 0, :].astype(jnp.float32),
+                        axis=-1).astype(jnp.int32)
+                else:
+                    t, k, p, sd = _samp_knobs(samp)
+                    # counter = the sampled token's global position
+                    nxt = sample_logits(logits._value[:, 0, :], t, k, p,
+                                           sd, seq_lens + 1)
             return (nxt, tuple(new_kcs), tuple(new_vcs),
                     tuple(new_kss), tuple(new_vss))
 
@@ -1614,6 +1747,7 @@ class DecodeStep:
                 return step(params, tokens, seq_lens, block_tables,
                             None, kcs, vcs, kss, vss)
             donate, n_repl = (4, 5, 6, 7), 3
+        fn = _named(fn, "decode_step")
         if tp is None:
             self._fn = jax.jit(fn, donate_argnums=donate)
         else:
@@ -1675,7 +1809,11 @@ class DecodeStep:
                     "sampling DecodeStep needs the per-slot knob array "
                     "(engine fills it; greedy slots are temperature 0)")
             args.append(jnp.asarray(np.asarray(samp, np.int32)))
-        nxt, new_kcs, new_vcs, new_kss, new_vss = self._fn(
-            *args, kcs, vcs, kss, vss)
-        _rebind_caches(self.caches, new_kcs, new_vcs, new_kss, new_vss)
-        return np.asarray(nxt)
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            nxt, new_kcs, new_vcs, new_kss, new_vss = self._fn(
+                *args, kcs, vcs, kss, vss)
+            _rebind_caches(self.caches, new_kcs, new_vcs, new_kss,
+                           new_vss)
+        self.t_dispatch = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.fetch"):
+            return np.asarray(nxt)

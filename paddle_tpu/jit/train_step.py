@@ -398,7 +398,7 @@ class TrainStep:
         trainable = self._trainable
         clip_norm = self.clip_norm
 
-        def step(params, frozen_vals, opt_states, lr, key, *batch):
+        def train_step(params, frozen_vals, opt_states, lr, key, *batch):
             self.compile_count += 1
             loss_fn = self._make_loss_fn(frozen_vals, batch, key)
             (loss, new_bufs), grads = jax.value_and_grad(
@@ -423,7 +423,7 @@ class TrainStep:
             return loss, new_params, new_states, new_bufs
 
         # donate params + opt states: in-place HBM update
-        self._step_fn = jax.jit(step, donate_argnums=(0, 2))
+        self._step_fn = jax.jit(train_step, donate_argnums=(0, 2))
 
     # -- sharded build --------------------------------------------------------
     def _grad_buckets(self):
@@ -516,7 +516,7 @@ class TrainStep:
                     off += n
             return out
 
-        def step(params, frozen_vals, opt_states, lr, key, *batch):
+        def train_step(params, frozen_vals, opt_states, lr, key, *batch):
             self.compile_count += 1
             idx = jax.lax.axis_index(axis)
             # distinct dropout stream per replica (true-DP semantics)
@@ -582,7 +582,7 @@ class TrainStep:
         in_specs = (repl_spec, repl_spec, state_specs, repl_spec,
                     repl_spec) + batch_specs
         out_specs = (repl_spec, repl_spec, state_specs, repl_spec)
-        fn = shard_map_compat(step, mesh, in_specs=in_specs,
+        fn = shard_map_compat(train_step, mesh, in_specs=in_specs,
                               out_specs=out_specs)
 
         def to_sh(spec_tree):
@@ -650,7 +650,7 @@ class TrainStep:
                 out[k] = g
             return out
 
-        def step(params, frozen_vals, opt_states, lr, key, *batch):
+        def train_step(params, frozen_vals, opt_states, lr, key, *batch):
             self.compile_count += 1
             # the ZeRO-3 compute gather: full value per spec-named axis
             full = {k: gather_spec_axes(params[k], specs[k])
@@ -716,7 +716,7 @@ class TrainStep:
         in_specs = (param_specs, repl_spec, state_specs, repl_spec,
                     repl_spec) + batch_specs
         out_specs = (repl_spec, param_specs, state_specs, repl_spec)
-        fn = shard_map_compat(step, mesh, in_specs=in_specs,
+        fn = shard_map_compat(train_step, mesh, in_specs=in_specs,
                               out_specs=out_specs)
 
         def to_sh(spec_tree):

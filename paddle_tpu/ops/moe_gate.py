@@ -127,37 +127,48 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
     n, d = x.shape
     e_local = wg.shape[0]
     if ep_axis is None or ep_degree <= 1:
-        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-        top_w, top_i, _ = topk_gate(logits, top_k)
-        slot, _ = assignment_slots(top_i, e_local)
-        disp = dispatch_to_buffers(x, top_i, slot, None, e_local,
-                                   n * top_k)
-        eo = grouped_expert_swiglu(disp, wg, wu, wd)
-        return combine_from_buffers(eo, top_i, slot, top_w).astype(x.dtype)
+        with jax.named_scope("moe.gate"):
+            logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+            top_w, top_i, _ = topk_gate(logits, top_k)
+            slot, _ = assignment_slots(top_i, e_local)
+        with jax.named_scope("moe.dispatch"):
+            disp = dispatch_to_buffers(x, top_i, slot, None, e_local,
+                                       n * top_k)
+        with jax.named_scope("moe.experts"):
+            eo = grouped_expert_swiglu(disp, wg, wu, wd)
+        with jax.named_scope("moe.combine"):
+            return combine_from_buffers(eo, top_i, slot,
+                                        top_w).astype(x.dtype)
 
     e_total = e_local * ep_degree
     tl = n // ep_degree                 # token stripe per chip
     cl = tl * top_k                     # dropless send capacity
-    r = jax.lax.axis_index(ep_axis)
-    x_r = jax.lax.dynamic_slice_in_dim(x, r * tl, tl, axis=0)
-    logits = x_r.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    top_w, top_i, _ = topk_gate(logits, top_k)
-    slot, _ = assignment_slots(top_i, e_total)
-    disp = dispatch_to_buffers(x_r, top_i, slot, None, e_total, cl)
-    # dispatch: chip g receives [ep, El, Cl, D]; recv[r] = chip r's
-    # assignments destined to chip g's experts
-    recv = jax.lax.all_to_all(disp, ep_axis, split_axis=0,
-                              concat_axis=0, tiled=True)
-    work = jnp.swapaxes(recv.reshape(ep_degree, e_local, cl, d),
-                        0, 1).reshape(e_local, ep_degree * cl, d)
-    eo = grouped_expert_swiglu(work, wg, wu, wd)
-    back = jnp.swapaxes(eo.reshape(e_local, ep_degree, cl, d),
-                        0, 1).reshape(e_total, cl, d)
-    # combine: ship outputs back to each assignment's home chip; after
-    # the exchange chip r holds [E_total, Cl, D] aligned with its own
-    # (top_i, slot) tables
-    back = jax.lax.all_to_all(back, ep_axis, split_axis=0,
-                              concat_axis=0, tiled=True)
-    out_r = combine_from_buffers(back, top_i, slot,
-                                 top_w).astype(x.dtype)
-    return jax.lax.all_gather(out_r, ep_axis, axis=0, tiled=True)
+    with jax.named_scope("moe.gate"):
+        r = jax.lax.axis_index(ep_axis)
+        x_r = jax.lax.dynamic_slice_in_dim(x, r * tl, tl, axis=0)
+        logits = x_r.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        top_w, top_i, _ = topk_gate(logits, top_k)
+        slot, _ = assignment_slots(top_i, e_total)
+    with jax.named_scope("moe.dispatch"):
+        disp = dispatch_to_buffers(x_r, top_i, slot, None, e_total, cl)
+        # dispatch: chip g receives [ep, El, Cl, D]; recv[r] = chip r's
+        # assignments destined to chip g's experts
+        with jax.named_scope("ep.all_to_all"):
+            recv = jax.lax.all_to_all(disp, ep_axis, split_axis=0,
+                                      concat_axis=0, tiled=True)
+        work = jnp.swapaxes(recv.reshape(ep_degree, e_local, cl, d),
+                            0, 1).reshape(e_local, ep_degree * cl, d)
+    with jax.named_scope("moe.experts"):
+        eo = grouped_expert_swiglu(work, wg, wu, wd)
+    with jax.named_scope("moe.combine"):
+        back = jnp.swapaxes(eo.reshape(e_local, ep_degree, cl, d),
+                            0, 1).reshape(e_total, cl, d)
+        # combine: ship outputs back to each assignment's home chip;
+        # after the exchange chip r holds [E_total, Cl, D] aligned with
+        # its own (top_i, slot) tables
+        with jax.named_scope("ep.all_to_all"):
+            back = jax.lax.all_to_all(back, ep_axis, split_axis=0,
+                                      concat_axis=0, tiled=True)
+        out_r = combine_from_buffers(back, top_i, slot,
+                                     top_w).astype(x.dtype)
+        return jax.lax.all_gather(out_r, ep_axis, axis=0, tiled=True)

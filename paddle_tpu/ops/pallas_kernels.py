@@ -348,6 +348,7 @@ def _flash_attention_value(q, k, v, causal: bool, block_q=512,
                 dimension_semantics=("parallel", "parallel", "arbitrary"))
             if not _INTERPRET[0] else None,
             interpret=_INTERPRET[0],
+            name="flash_attention_fwd",
         )(*args)
     out = res[0].reshape(B, H, Sq, D)
     if with_lse:
@@ -647,6 +648,7 @@ def _flash_attention_bwd_fused(q, k, v, out, lse, g, causal: bool,
                 dimension_semantics=("parallel", "parallel", "arbitrary"))
             if not _INTERPRET[0] else None,
             interpret=_INTERPRET[0],
+            name="flash_attention_bwd",
         )(*call_args)
 
     dq = jnp.sum(dq_part, axis=0).astype(q.dtype)
@@ -765,6 +767,7 @@ def _flash_attention_bwd(q, k, v, out, lse, g, causal: bool,
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
                             pltpu.VMEM((block_q, 128), jnp.float32),
                             pltpu.VMEM((block_q, D), q.dtype)],
+            name="flash_attention_bwd_dq",
             **params,
         )(*dq_args)
 
@@ -787,6 +790,7 @@ def _flash_attention_bwd(q, k, v, out, lse, g, causal: bool,
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, D), k.dtype)],
+            name="flash_attention_bwd_dkv",
             **params,
         )(*kv_args)
 
@@ -1083,6 +1087,7 @@ def rms_norm_tpu(x, weight, eps=1e-6, block_rows=512):
                           pl.BlockSpec((d,), lambda i: (0,))],
                 out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
                 out_shape=jax.ShapeDtypeStruct((rows, d), xv.dtype),
+                name="rms_norm",
             )(xr, wv)
         return out.reshape(shape)
 
@@ -1664,14 +1669,16 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
     # rows past the pack's end (the last span's window overhang, and
     # every row of a padding span) clamp to a real token: finite, never
     # read back
-    rows = jnp.minimum(q_offsets[:, None] + win[None, :], T - 1)
-    qs = q.astype(jnp.float32)[rows]              # [S, span_q, H, D]
-    qs = jnp.moveaxis(qs.reshape(S, span_q, Hkv, groups, D), 2, 1)
-    qs = qs.reshape(S, Hkv, g, D)
-    kp = jnp.moveaxis(key_cache, 2, 0)
-    vp = jnp.moveaxis(value_cache, 2, 0)
-    if not quantized:
-        kp, vp = kp.astype(jnp.float32), vp.astype(jnp.float32)
+    with jax.named_scope("attn.regroup"):
+        rows = jnp.minimum(q_offsets[:, None] + win[None, :], T - 1)
+        qs = q.astype(jnp.float32)[rows]          # [S, span_q, H, D]
+        qs = jnp.moveaxis(qs.reshape(S, span_q, Hkv, groups, D), 2, 1)
+        qs = qs.reshape(S, Hkv, g, D)
+    with jax.named_scope("attn.kv_upcast"):
+        kp = jnp.moveaxis(key_cache, 2, 0)
+        vp = jnp.moveaxis(value_cache, 2, 0)
+        if not quantized:
+            kp, vp = kp.astype(jnp.float32), vp.astype(jnp.float32)
     bt = jnp.maximum(block_tables, 0)
 
     kernel = functools.partial(
@@ -1689,7 +1696,7 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
                         pltpu.VMEM((bs, D), vp.dtype),
                         pltpu.SemaphoreType.DMA]
 
-    with _x64_off():
+    with _x64_off(), jax.named_scope("attn.kernel"):
         prefetch = [q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
                     bt.astype(jnp.int32)]
         if quantized:
@@ -1716,12 +1723,14 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
             name="ragged_paged_attention",
         )(*prefetch, qs, kp, vp)
     # token t -> (its span, its row inside the span's window)
-    tok = jnp.arange(T, dtype=jnp.int32)
-    sid = jnp.clip(jnp.searchsorted(q_offsets, tok, side="right") - 1,
-                   0, S - 1).astype(jnp.int32)
-    r = jnp.clip(tok - q_offsets[sid], 0, span_q - 1)
-    out = out.reshape(S, Hkv, span_q, groups, D)[sid, :, r]
-    return out.reshape(T, H, D).astype(q.dtype)
+    with jax.named_scope("attn.ungroup"):
+        tok = jnp.arange(T, dtype=jnp.int32)
+        sid = jnp.clip(
+            jnp.searchsorted(q_offsets, tok, side="right") - 1,
+            0, S - 1).astype(jnp.int32)
+        r = jnp.clip(tok - q_offsets[sid], 0, span_q - 1)
+        out = out.reshape(S, Hkv, span_q, groups, D)[sid, :, r]
+        return out.reshape(T, H, D).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -156,9 +156,26 @@ def test_mixed_step_full_width_two_layers(v5e):
     lowered = eng.mixed.aot_lower(eng.token_budgets[-1],
                                   device_sharding=v5e)
     text = lowered.as_text()
+    assert text.splitlines()[0].startswith("module @jit_mixed_step")
     for name in ("ragged_paged_attention", "rope_qkv_epilogue"):
         assert f'kernel_name = "{name}"' in text
-    mem = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    # the v5e program keeps the step's named scopes: each Mosaic kernel
+    # and the ops a trace could not tell apart (every matmul a `fusion`,
+    # the pool up-cast a `convert_bitcast_fusion`) belong to a part
+    from paddle_tpu.jit.serving_step import hlo_op_scopes
+    scopes = hlo_op_scopes(compiled.as_text())
+    kernels = {n: s for n, s in scopes.items()
+               if n.startswith(("ragged_paged_attention",
+                                "rope_qkv_epilogue"))}
+    assert sorted(kernels.values()) == ["attn.kernel"] * 2 \
+        + ["attn.rope"] * 2
+    upcast = {s for n, s in scopes.items()
+              if n.startswith("convert_bitcast_fusion")}
+    assert upcast == {"attn.kv_upcast"}
+    assert {"attn.regroup", "attn.ungroup", "attn.kv_write", "attn.qkv",
+            "attn.out", "ffn", "lm_head", "embed"} <= set(scopes.values())
+    mem = compiled.memory_analysis()
     # pools are donated: aliased, not copied
     assert mem.alias_size_in_bytes >= sum(
         2 * int(c.key_cache.nbytes) for c in eng.caches)
