@@ -266,13 +266,12 @@ def ragged_check(heads: int, kv_heads: int, head_dim: int,
               for _ in range(max_spans)]
     start = block_size * 3 + 5                  # chunk begins mid-page
     mixed = decode[:max_spans - 1] + [(chunk, start + chunk)]
-    cases = {"decode_only": (decode, budget_of(max_spans), 1),
-             "with_chunk": (mixed, budget_of(max_spans - 1 + chunk),
-                            chunk)}
+    cases = {"decode_only": (decode, budget_of(max_spans)),
+             "with_chunk": (mixed, budget_of(max_spans - 1 + chunk))}
     groups = heads // kv_heads
     head_slice = max(1, kv_heads // 8)
     out = {}
-    for name, (spans, budget, span_q) in cases.items():
+    for name, (spans, budget) in cases.items():
         bt, q_off, q_len, kv_len, real = pack(spans, budget)
         q = jnp.asarray(rng.randn(budget, heads, head_dim), jnp.bfloat16)
 
@@ -280,7 +279,7 @@ def ragged_check(heads: int, kv_heads: int, head_dim: int,
         def kernel(q, kc, vc):
             return ragged_paged_attention(
                 q, kc, vc, bt, q_off, q_len, kv_len,
-                use_pallas=expect_kernels, span_q=span_q)
+                use_pallas=expect_kernels)
 
         @jax.jit
         def reference(q, kc, vc, i):
@@ -304,7 +303,7 @@ def ragged_check(heads: int, kv_heads: int, head_dim: int,
         err = _rel_err(got, ref)
         assert err <= RAGGED_TOL, (
             f"ragged {name}: rel err {err:.3e} > declared {RAGGED_TOL}")
-        out[name] = {"tokens": real, "budget": budget, "span_q": span_q,
+        out[name] = {"tokens": real, "budget": budget,
                      "rel_err": float(f"{err:.3e}")}
     out["tol"] = RAGGED_TOL
     return out

@@ -504,12 +504,6 @@ class ContinuousBatchingEngine:
                     self.ep_degree, budgets=budgets)
             self.mixed = MixedStep(model, self.caches, self.bt_width,
                                    max_spans=max_batch_size,
-                                   # a verify span is spec_k+1 tokens —
-                                   # the kernel's static span window
-                                   # must cover it as well as a chunk
-                                   span_q=min(max(self.chunk_size,
-                                                  self.spec_k + 1),
-                                              budgets[-1]),
                                    use_pallas=use_pallas, tp=self.tp,
                                    weight_qparams=self.weight_qtree,
                                    quant_collectives=
@@ -542,7 +536,6 @@ class ContinuousBatchingEngine:
             self.draft_step = MixedStep(
                 draft_model, self.draft_caches, self.bt_width,
                 max_spans=max_batch_size,
-                span_q=min(self.chunk_size, self.token_budgets[-1]),
                 use_pallas=use_pallas, sampling=self.sampling,
                 return_probs=self.sampling)
             # draft packs are SMALL (proposal launches carry one token
@@ -1082,6 +1075,11 @@ class ContinuousBatchingEngine:
         - ``spans``: int32 ``[n, 3]`` rows ``(req_id, q_len, kv_len)``
           in pack order, as packed.  A prefix-cache hit is not in it:
           it was never computed.
+        - ``attn_rows``: the q rows per kv head that the mixed launch's
+          attention computes in each layer (``MixedStep.attn_rows``):
+          beside ``tokens`` x the GQA group size it says how much of
+          the launch is real.  0 on the split path, which has no ragged
+          launch.
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.
@@ -1105,6 +1103,9 @@ class ContinuousBatchingEngine:
             step=self._step_no, **bounds, budget=rec["budget"],
             tokens=int(spans[:, 1].sum()), n_dec=rec["n_dec"],
             n_pre=rec["n_pre"], spans=spans,
+            attn_rows=(self.mixed.attn_rows(rec["budget"], spans[:, 1])
+                       if self.mixed is not None and rec["budget"]
+                       else 0),
             admitted=tuple(self._admitted), running=running,
             waiting=len(self.waiting), compiled=rec["compiled"])
 

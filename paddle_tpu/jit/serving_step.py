@@ -112,10 +112,9 @@ __all__ = ["DecodeStep", "PrefillStep", "MixedStep", "prefill_scatter",
 # to the INNERMOST of these on its ``op_name`` path (``ffn`` holds a
 # MoE layer's norm and residual, ``moe.*`` what lies inside it).
 STEP_SCOPES = frozenset((
-    "embed", "attn.qkv", "attn.rope", "attn.kv_write", "attn.kv_upcast",
-    "attn.regroup", "attn.kernel", "attn.ungroup", "attn.out", "ffn",
-    "moe.gate", "moe.dispatch", "moe.experts", "moe.combine",
-    "ep.all_to_all", "lm_head", "sample"))
+    "embed", "attn.qkv", "attn.rope", "attn.kv_write", "attn.kernel",
+    "attn.out", "ffn", "moe.gate", "moe.dispatch", "moe.experts",
+    "moe.combine", "ep.all_to_all", "lm_head", "sample"))
 
 _HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -1073,7 +1072,7 @@ class MixedStep:
     """
 
     def __init__(self, model, caches: List, bt_width: int,
-                 max_spans: int, span_q: int,
+                 max_spans: int,
                  use_pallas: Optional[bool] = None,
                  mesh=None, sharding=None,
                  tp: Optional[TPContext] = None,
@@ -1086,7 +1085,6 @@ class MixedStep:
         self.cfg = model.config
         self.bt_width = bt_width
         self.max_spans = max_spans
-        self.span_q = max(1, int(span_q))   # static max span length
         self.sampling = bool(sampling)
         self.spec_k = int(spec_k)
         self.return_probs = bool(return_probs)
@@ -1100,13 +1098,6 @@ class MixedStep:
             raise ValueError(
                 "MixedStep cannot be verifier (spec_k) and draft "
                 "(return_probs) at once")
-        if self.spec_k and self.span_q < self.spec_k + 1:
-            raise ValueError(
-                "span_q=%d cannot cover a length-%d verify span "
-                "(spec_k=%d): the Pallas kernel's static span window "
-                "must be >= every q_len" % (self.span_q,
-                                            self.spec_k + 1,
-                                            self.spec_k))
         # span-row tail past the block-table columns: the 4 standard
         # descriptors, +1 n_draft column under spec, +4 bitcast
         # sampling-knob columns under sampling.  4 == the round-13
@@ -1157,6 +1148,29 @@ class MixedStep:
         return self._tp.collective_bytes(self.cfg, T, self.max_spans,
                                          quant_gather=self._q8_gather)
 
+    def attn_rows(self, T: int, q_lens) -> int:
+        """The q rows per kv head that a budget-``T`` launch carrying
+        spans of lengths ``q_lens`` computes in each layer's attention.
+        The Pallas launch computes whole tiles (``ops/pallas_kernels.
+        ragged_attn_rows``: a decode span one tile, a chunk
+        ceil(q_len / tile)); the XLA reference and the context-parallel
+        partial compute every row of the budget."""
+        from ..ops.pallas_kernels import (ragged_attn_rows,
+                                          ragged_tile_geometry)
+        cfg = self.cfg
+        deg = self._tp.degree if self._tp is not None else 1
+        H = cfg.num_attention_heads // deg
+        Hkv = cfg.num_key_value_heads // deg
+        if not self.use_pallas or (self._tp is not None
+                                   and self._tp.cp_degree > 1):
+            return T * (H // Hkv)
+        cache = self.caches[0]
+        tile, _ = ragged_tile_geometry(
+            H, Hkv, cache.head_dim, cache.block_size, self.bt_width,
+            "bfloat16" if cfg.dtype == "bfloat16" else "float32",
+            cache.kv_dtype)
+        return ragged_attn_rows(q_lens, tile, H // Hkv)
+
     def _build(self, T: int):
         from ..autograd.tape import no_grad
         from ..ops.paged_attention import (_ragged_attention_xla,
@@ -1176,7 +1190,6 @@ class MixedStep:
         Hkv = cfg.num_key_value_heads // deg
         D = cfg.hidden_size // cfg.num_attention_heads
         scale = 1.0 / math.sqrt(D)
-        span_q = min(self.span_q, T)
         use_pallas = self.use_pallas
         quant_kv = self._quant_kv
         q8_gather = self._q8_gather
@@ -1210,10 +1223,10 @@ class MixedStep:
             def attn(q, kc, vc, bt, q_off, q_len, kv_len,
                      ks=None, vs=None):
                 if use_pallas:
-                    # the wrapper names its own attn.* scopes
+                    # the wrapper names its own attn.kernel scope
                     return _ragged_paged_attention_pallas(
                         q, kc, vc, bt, q_off, q_len, kv_len, scale,
-                        span_q=span_q, key_scale=ks, value_scale=vs)
+                        key_scale=ks, value_scale=vs)
                 with jax.named_scope("attn.kernel"):
                     return _ragged_attention_xla(q, kc, vc, bt, q_off,
                                                  q_len, kv_len, scale,

@@ -53,7 +53,8 @@ _KV_BNT = 127.0
 
 # Round-17 declared tolerance (r13 convention: int8 paths are
 # tolerance-gated, never byte-gated) for the int8 MXU kernels vs the
-# dequantizing XLA reference: the pipelined kernels quantize the q rows
+# dequantizing XLA reference: those kernels (the ragged launch, the
+# pipelined decode kernel) quantize the q rows
 # to int8 in-kernel (per-row absmax), so scores pick up one extra
 # quantization (<= q_absmax/254 per element before the dot) the
 # reference doesn't have.  The bound is RELATIVE to the pool's
@@ -66,8 +67,8 @@ _KV_BNT = 127.0
 # a few tens at D=16..128 — comfortably covering rope'd projection
 # outputs); beyond it, softmax exponentiation amplifies without bound
 # and the meaningful gate is the engine-level token-match rate, not a
-# tensor atol.  The legacy (pipelined=False) kernels keep the r13
-# dequant math and stay within 1e-5 of the reference.
+# tensor atol.  The legacy (pipelined=False) decode kernel keeps the
+# r13 dequant math and stays within 1e-5 of the reference.
 KERNEL_INT8_REL_TOL = 0.02
 
 
@@ -826,9 +827,8 @@ def chunk_prefill_attention_partial(q, key_cache, value_cache,
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,
                            q_offsets, q_lens, kv_lens,
                            use_pallas: Optional[bool] = None,
-                           interpret=False, span_q: Optional[int] = None,
-                           key_scale=None, value_scale=None,
-                           pipelined: bool = True):
+                           interpret=False,
+                           key_scale=None, value_scale=None):
     """One fused attention launch over a packed ragged query batch
     against the paged KV pool (arXiv:2604.15464).
 
@@ -838,8 +838,9 @@ def ragged_paged_attention(q, key_cache, value_cache, block_tables,
     q_offsets/q_lens/kv_lens: [S] int32 span tables (kv_len INCLUDES the
     span's own tokens, which must already be written to the pages).
     key_scale/value_scale: per-page-per-head [phys, Hkv] fp32 absmax
-    tables of an int8 pool (dequantized into the fp32 online-softmax);
-    None for fp pools.  Returns [T, H, D].
+    tables of an int8 pool (folded into the int8 MXU products on the
+    kernel path, dequantized pages on the reference path); None for fp
+    pools.  Returns [T, H, D].
     """
     tensor_in = isinstance(q, Tensor)
     qv = _val(q)
@@ -853,11 +854,9 @@ def ragged_paged_attention(q, key_cache, value_cache, block_tables,
         use_pallas = _device.on_tpu()
     if use_pallas or interpret:
         from .pallas_kernels import _ragged_paged_attention_pallas
-        sq = int(span_q) if span_q else int(np.max(np.asarray(q_lens)))
         out = _ragged_paged_attention_pallas(
-            qv, kc, vc, bt, qo, ql, kl, scale, span_q=sq,
-            interpret=interpret, key_scale=key_scale,
-            value_scale=value_scale, pipelined=pipelined)
+            qv, kc, vc, bt, qo, ql, kl, scale, interpret=interpret,
+            key_scale=key_scale, value_scale=value_scale)
     else:
         out = _ragged_attention_xla(qv, kc, vc, bt, qo, ql, kl, scale,
                                     key_scale, value_scale)

@@ -1423,236 +1423,352 @@ def sdpa_ulysses(query, key, value, mesh, axis_name: str = "sep",
 # ---------------------------------------------------------------------------
 # ragged paged attention (serving: one launch for any prefill+decode mix)
 # ---------------------------------------------------------------------------
-def _ragged_paged_kernel(# scalar prefetch (+2 f32 scale tables when
-                         # quantized), operands, output, scratch —
-                         # unpacked below
-                         *refs,
-                         block_size: int, pages_per_span: int,
-                         span_q: int, scale: float, groups: int,
-                         quantized: bool = False,
-                         pipelined: bool = True):
-    """Grid cell (s, h): one ragged query SPAN (a decode slot = length-1
-    span, or a prefill chunk = length-C span) against one kv head's
-    pages (arXiv:2604.15464 "Ragged Paged Attention").
+# Tile sizes.  A span's q rows are cut into tiles of as many tokens as
+# give one kv head _RAGGED_TILE_ROWS query rows (the MXU's height; 32
+# tokens at GQA 4:1): a decode or speculative-verify span is one tile,
+# a chunk ceil(q_len / tile).  ONE size, because the kernel's body is
+# traced and lowered anew for every token budget at every start: a
+# second size (8-token tiles for decode spans beside 128-token tiles
+# for chunks read 1-4% better end to end: PERF.md, PR 25) doubled that
+# and put seven seconds on a warm start.  Keys stream in blocks of about _RAGGED_KV_BLOCK
+# tokens (whole pages), so the matmuls have N = 128 whatever the page
+# size.
+_RAGGED_TILE_ROWS = 128
+_RAGGED_MIN_TILE = 8
+_RAGGED_KV_BLOCK = 128
+# what the tile-sized buffers of one grid cell may take of the 16 MiB
+# the serving kernels are held to (tools/graftlint/vmem.py)
+_RAGGED_TILE_VMEM = 12 << 20
 
-    The wrapper regroups the packed token batch SPAN-MAJOR — each cell's
-    ``[span_q * groups, D]`` query rows (row ``r * groups + j`` = token
-    r of the span, q head j of the kv group) arrive as one BlockSpec
-    block and leave the same way, so the kernel body is 2-D throughout:
-    no in-kernel reshape, no ragged-offset q/o DMA.  (Mosaic for v5e
-    rejects the ``[span_q, groups, D]`` -> ``[g, D]`` shape cast and a
-    sub-tile bf16 window slice; the regroup is a gather XLA does.)
-    Pages stream through TWO VMEM buffers per operand (round 17,
-    ``pipelined=True``): page *i+1*'s async copy is issued before
-    attention on page *i* runs, and the only stall is the wait at the
-    buffer swap — the TPP pipelining argument (arXiv:2104.05755)
-    applied to the page stream.  The prefetch is CLAMPED to the span's
-    used block count: page *i+1* is fetched only when ``i+1 <
-    n_pages``, so the kernel never reads the block table — let alone a
-    page — past what ``kv_len`` covers (the r11 poisoned-unused-pages
-    invariant survives the pipeline).  ``pipelined=False`` keeps the
-    r16 issue-then-wait single-buffer loop for old-vs-new benching.
-    The online-softmax state lives in fp32 registers either way.  Rows
-    past ``q_len`` inside the window compute finite garbage no token
-    reads back; a ``q_len == 0`` (padding) span's block is zeroed.
+
+def _ragged_compute_dtype(q_dtype, kv_dtype):
+    """What the two matmuls run in: the pool's own type for an fp pool
+    (bf16 x bf16 -> f32 on the MXU), int8 codes for a quantized one."""
+    if jnp.dtype(kv_dtype) == jnp.int8:
+        return jnp.dtype(jnp.int8)
+    return jnp.promote_types(q_dtype, kv_dtype)
+
+
+def _ragged_cell_vmem_bytes(bq: int, heads: int, kv_heads: int,
+                            head_dim: int, kv_block: int, q_itemsize: int,
+                            c_itemsize: int, kv_itemsize: int) -> int:
+    """VMEM bytes of one _ragged_paged_kernel grid cell at a q tile of
+    ``bq`` tokens: the [bq, H, D] q and o staging buffers, the per-kv-
+    head folded q / m / l / acc state (and the q rows' scales of an
+    int8 pool), the 2-slot K and V page blocks ([kv_block, Hkv, D]
+    each, as stored), the current block head-major, and the live score
+    and probability tiles.  Mirrors the scratch_shapes in
+    _ragged_paged_attention_pallas — edit both."""
+    rows = bq * (heads // kv_heads)
+    total = 2 * _tile_bytes((bq, heads, head_dim), q_itemsize)    # q, o
+    total += _tile_bytes((kv_heads, rows, head_dim), c_itemsize)  # q2
+    total += _tile_bytes((kv_heads, rows, head_dim), 4)           # acc
+    total += 2 * _tile_bytes((kv_heads, rows, 1), 4)              # m, l
+    total += 4 * _tile_bytes((kv_block, kv_heads, head_dim),
+                             kv_itemsize)                   # k, v x 2 slots
+    total += 2 * _tile_bytes((kv_heads, kv_block, head_dim),
+                             c_itemsize)                    # head-major
+    total += 2 * _tile_bytes((rows, kv_block), 4)           # scores + p
+    if kv_itemsize == 1:
+        total += _tile_bytes((kv_heads, rows, 1), 4)        # q scales
+    return total
+
+
+@functools.lru_cache(maxsize=None)
+def ragged_tile_geometry(heads: int, kv_heads: int, head_dim: int,
+                         block_size: int, bt_width: int, q_dtype,
+                         kv_dtype):
+    """``(tile, pages_per_block)``: the q tile in tokens and the pages
+    one key block holds, from what the launch sees — the head geometry,
+    the page size and the two dtypes, not the budget, so every budget's
+    launch has the same cell.  The tile halves while a cell's buffers
+    overrun _RAGGED_TILE_VMEM."""
+    q_item = jnp.dtype(q_dtype).itemsize
+    kv_item = jnp.dtype(kv_dtype).itemsize
+    c_item = _ragged_compute_dtype(q_dtype, kv_dtype).itemsize
+    kb = max(1, min(_RAGGED_KV_BLOCK // block_size, bt_width))
+    tile = max(_RAGGED_MIN_TILE,
+               _RAGGED_TILE_ROWS // (heads // kv_heads))
+    while tile > _RAGGED_MIN_TILE and _ragged_cell_vmem_bytes(
+            tile, heads, kv_heads, head_dim, kb * block_size, q_item,
+            c_item, kv_item) > _RAGGED_TILE_VMEM:
+        tile //= 2
+    return tile, kb
+
+
+def ragged_attn_rows(q_lens, tile: int, groups: int) -> int:
+    """The q rows per kv head one launch computes for spans of these
+    lengths: every tile's full height, garbage rows included."""
+    return groups * tile * sum(-(-max(int(n), 0) // tile) for n in q_lens)
+
+
+def _ragged_work_list(q_lens, tile: int, n_tiles: int):
+    """Traced ``(span, first_row, rows)`` int32 [n_tiles] tables: span
+    s contributes ceil(q_len / tile) consecutive tiles, spans in
+    order; entries past the last real tile carry rows = 0."""
+    S = q_lens.shape[0]
+    ql = jnp.maximum(q_lens, 0)
+    per_span = (ql + (tile - 1)) // tile
+    ends = jnp.cumsum(per_span)
+    i = jnp.arange(n_tiles, dtype=jnp.int32)
+    span = jnp.sum((ends[None, :] <= i[:, None]).astype(jnp.int32), axis=1)
+    live = span < S
+    span = jnp.minimum(span, S - 1)
+    first = (i - (ends[span] - per_span[span])) * tile
+    rows = jnp.where(live, jnp.minimum(ql[span] - first, tile), 0)
+    return (span.astype(jnp.int32), first.astype(jnp.int32),
+            rows.astype(jnp.int32))
+
+
+def _kv_heads(buf):
+    """``(h, rows [n, D])`` for every kv head of a ``[n, Hkv, D]`` VMEM
+    block that holds pages AS STORED.  Head is the second-minor dim, so
+    for a packed type (bf16: 2, int8: 4 rows a 32-bit sublane) the rows
+    of ``packing`` heads share each word: one strided load of the
+    words, then each head's bits are shifted out (both exact)."""
+    n, hkv, d = buf.shape
+    pack = 4 // jnp.dtype(buf.dtype).itemsize
+    if pack == 1 or hkv % pack:
+        for h in range(hkv):
+            yield h, buf[:, h, :]
+        return
+    words = buf.reshape(n * hkv, d).bitcast(jnp.uint32)
+    for hp in range(hkv // pack):
+        w = words[pl.ds(hp, n, stride=hkv // pack), :]
+        x = None if pack == 2 else pltpu.bitcast(w, jnp.int32)
+        for lane in range(pack):
+            if pack == 2:                       # bf16: the word's halves
+                bits = (w << 16) if lane == 0 \
+                    else (w & jnp.uint32(0xFFFF0000))
+                rows = pltpu.bitcast(bits, jnp.float32).astype(
+                    jnp.bfloat16)
+            else:
+                rows = ((x << (24 - 8 * lane)) >> 24).astype(buf.dtype)
+            yield hp * pack + lane, rows
+
+
+def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
+                         pages_per_block: int, scale: float,
+                         groups: int, quantized: bool):
+    """Grid cell i: one q TILE — ``rows`` consecutive tokens of one
+    span, from the work list — against every local kv head's pages
+    (arXiv:2604.15464 "Ragged Paged Attention").
+
+    Rows follow tokens.  The tile's tokens are DMA'd from the
+    token-major pack ``[T, H, D]`` at their real offset and its output
+    rows go back the same way; a decode or verify span costs one
+    tile, a chunk ceil(q_len / tile) tiles, a ``q_len == 0`` span
+    nothing.  A tile writes its full height: the
+    rows past ``rows`` are finite garbage that land on the NEXT tile's
+    tokens (tiles run in token order on one core, so the owner
+    overwrites them) or in the pack's padding.
+
+    Pages arrive as stored: one contiguous ``[block_size, Hkv, D]``
+    copy a page serves every kv head, ``pages_per_block`` pages make
+    one key block, and blocks stream through two VMEM slots (block
+    b+1's copies are issued before block b's math); a block that has
+    arrived is laid head-major (``[Hkv, n, D]``: each head's rows are
+    shifted out of the packed sublanes once) and the math loops over
+    the kv heads with a traced index, so the body is traced once, not
+    once a head.  No copy is issued
+    and no block-table entry is read for a page past what the tile can
+    see — ``min(kv_len, position of its last row + 1)`` — so unused
+    pages are never touched (the poisoned-pages invariant) and a chunk
+    tile skips the keys its causal mask would drop anyway.
 
     Causality is positional: row r of span s sits at global position
     ``kv_len - q_len + r`` and sees keys at positions <= that, so decode
     steps, mid-prompt chunks, and prefix-hit suffixes are all the same
-    span shape to this kernel.
+    span shape to this kernel.  Online softmax in fp32; ``q.K^T`` and
+    ``p.V`` run in the pool's type with fp32 accumulation, the softmax
+    scale applied to the fp32 scores.
 
-    int8 pools (``quantized=True``): the pages arrive as int8 and the
-    per-page-per-head fp32 absmax scales ride as two extra
-    scalar-prefetch tables ([Hkv, phys] — the same SMEM dynamic-index
-    mechanism as the block table).  Pipelined, the
-    MXU consumes the int8 codes DIRECTLY: the span's q window is
-    quantized once per cell to per-row int8
-    (``quantize_rows_symmetric``), ``q·Kᵀ`` runs as an int8×int8
-    matmul with int32 accumulate, and ``fold_int8_scores`` folds the
-    per-row q scale, the per-page-per-head k scale and the softmax
-    scale into the accumulated scores — no fp32 page ever materializes
-    in VMEM, so each page buffer is 1/4 the fp32 footprint and the
-    matmul runs at the MXU's native int8 rate.  ``p·V`` is int8×int8
-    too (probability rows quantized per row, p/v scales folded into
-    the [g, D] product — measured ≤1% of value magnitude vs the
-    declared 2% tolerance).  The legacy path dequantizes each page
-    after its DMA (the r13/r16 behavior), kept under
-    ``pipelined=False``.
+    int8 pools (``quantized=True``): the MXU consumes the int8 codes
+    directly.  The tile's q rows are quantized once per cell to per-row
+    int8 (``quantize_rows_symmetric``), ``q.K^T`` is int8 x int8 with
+    int32 accumulate, and the per-row q scale, the per-page-per-head k
+    scales (two scalar-prefetch tables, laid along the block's columns)
+    and the softmax scale fold into the scores; ``p.V`` is int8 x int8
+    too, the v scales folded into p before its per-row quantization.
     """
     from ..quantization.functional import (fold_int8_scores,
                                            quantize_rows_symmetric)
-    if quantized:
-        (q_len_ref, kv_len_ref, bt_ref, ks_ref, vs_ref,
-         q_ref, k_pages, v_pages, o_ref,
-         k_vmem, v_vmem, sem) = refs
-    else:
-        (q_len_ref, kv_len_ref, bt_ref,
-         q_ref, k_pages, v_pages, o_ref,
-         k_vmem, v_vmem, sem) = refs
-        ks_ref = vs_ref = None
-    s = pl.program_id(0)
-    h = pl.program_id(1)
-    q_len = q_len_ref[s]
-    int8_mxu = quantized and pipelined
-    g = span_q * groups
-    d = q_ref.shape[-1]
+    (ts_ref, tr_ref, tn_ref, qoff_ref, qlen_ref, kvlen_ref,
+     bt_ref) = refs[:7]
+    n_pref = 9 if quantized else 7
+    ks_ref, vs_ref = (refs[7], refs[8]) if quantized else (None, None)
+    (q_hbm, k_hbm, v_hbm, _, o_hbm, qbuf, obuf, q2, m_s, l_s, acc_s,
+     kbuf, vbuf, k_hm, v_hm, kv_sem, qo_sem) = refs[n_pref:n_pref + 17]
+    qs_s = refs[-1] if quantized else None      # per-row q scales
+    i = pl.program_id(0)
+    rows = tn_ref[i]
+    hkv = kbuf.shape[2]
+    bq, _, d = qbuf.shape
+    r = bq * groups
+    bs, kb = block_size, pages_per_block
+    n = kb * bs
+    cdt = q2.dtype
 
-    @pl.when(q_len <= 0)
-    def _padding_span():
-        o_ref[0, 0] = jnp.zeros((g, d), o_ref.dtype)
+    @pl.when(i == 0)
+    def _clean_slots():
+        # a partly filled last block multiplies p = 0 into whatever its
+        # unfetched rows hold: make that finite once
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
 
-    @pl.when(q_len > 0)
-    def _span():
-        kv_len = kv_len_ref[s]
-        if int8_mxu:
-            # one quantization per span window
-            q_codes, q_s = quantize_rows_symmetric(q_ref[0, 0])
-            q = None
-        else:
-            q = q_ref[0, 0].astype(jnp.float32) * np.float32(scale)
-        # row i = token i // groups of the span (each repeated over its
-        # q heads), at global position kv_len - q_len + token; garbage
-        # rows (token >= q_len) get qpos >= kv_len and attend the whole
-        # context — finite, never read
-        tok = lax.div(lax.broadcasted_iota(jnp.int32, (g, 1), 0),
-                      jnp.int32(groups))
-        qpos = kv_len - q_len + tok
+    @pl.when(rows > 0)
+    def _tile():
+        s = ts_ref[i]
+        first = tr_ref[i]
+        kv_len = kvlen_ref[s]
+        tok0 = qoff_ref[s] + first
+        pos0 = kv_len - qlen_ref[s] + first
+        kv_end = jnp.minimum(kv_len, pos0 + rows)
+        n_pages = jnp.minimum((kv_end + (bs - 1)) // bs,
+                              jnp.int32(pages_per_span))
+        n_blk = (n_pages + (kb - 1)) // kb
 
-        m0 = jnp.full((g, 1), _F32_NEG_INF, jnp.float32)
-        l0 = jnp.zeros((g, 1), jnp.float32)
-        acc0 = jnp.zeros((g, d), jnp.float32)
-        n_pages = jnp.minimum(
-            (kv_len + jnp.int32(block_size - 1)) // jnp.int32(block_size),
-            jnp.int32(pages_per_span))
+        def block_copies(b, slot, go):
+            """``go`` (start or wait) each copy of key block b's pages,
+            as many as the tile can see."""
+            def page(j, _):
+                page = bt_ref[s, b * kb + j]
+                dst = pl.ds(j * bs, bs)
+                go(pltpu.make_async_copy(
+                    k_hbm.at[page], kbuf.at[slot, dst],
+                    kv_sem.at[slot, 0]))
+                go(pltpu.make_async_copy(
+                    v_hbm.at[page], vbuf.at[slot, dst],
+                    kv_sem.at[slot, 1]))
+                return 0
+            lax.fori_loop(jnp.int32(0),
+                          jnp.minimum(n_pages - b * kb, jnp.int32(kb)),
+                          page, 0)
 
-        def page_math(p_idx, page, kbuf, vbuf, carry):
-            """Online-softmax update for one resident page (shared by
-            the pipelined and legacy loops; kbuf/vbuf are the page's
-            VMEM values, int8 when quantized)."""
+        q_copy = pltpu.make_async_copy(q_hbm.at[pl.ds(tok0, bq)], qbuf,
+                                       qo_sem.at[0])
+        q_copy.start()
+        block_copies(jnp.int32(0), 0, lambda c: c.start())
+        q_copy.wait()
+
+        for h in range(hkv):
+            # [bq, groups, D] -> [bq * groups, D]: row t * groups + j
+            # is token t, q head j of the group (a sub-word second-minor
+            # slice folds in f32)
+            qh = qbuf[:, h * groups:(h + 1) * groups, :]
+            if groups % (4 // qh.dtype.itemsize):
+                qh = qh.astype(jnp.float32)
+            qh = qh.reshape(r, d)
             if quantized:
-                sk = ks_ref[h, page]
-                sv = vs_ref[h, page]
-            if int8_mxu:
-                si = lax.dot_general(q_codes, kbuf, _DIMNUM_NT,
+                qh, qs_s[h] = quantize_rows_symmetric(qh)
+            q2[h] = qh.astype(cdt)
+        m_s[...] = jnp.full(m_s.shape, _F32_NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+        tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
+                      jnp.int32(groups))
+        qpos = pos0 + tok
+        page_of_col = lax.div(
+            lax.broadcasted_iota(jnp.int32, (1, n), 1), jnp.int32(bs))
+
+        def col_scales(tab_ref, h, b):
+            """[1, n] f32: each column's page's scale of kv head h."""
+            out = jnp.zeros((1, n), jnp.float32)
+            for j in range(kb):
+                p = jnp.minimum(b * kb + j, n_pages - 1)
+                out = jnp.where(page_of_col == j,
+                                tab_ref[h, bt_ref[s, p]], out)
+            return out
+
+        def head_math(h, b):
+            """Key block b against kv head h (a traced index: the
+            per-head state and the head-major block are indexed on
+            their leading dim, so the body is traced once)."""
+            k, v, qh = k_hm[h], v_hm[h], q2[h]
+            if quantized:
+                si = lax.dot_general(qh, k, _DIMNUM_NT,
                                      preferred_element_type=jnp.int32)
-                sc = fold_int8_scores(si, q_s, sk, scale)
+                sc = fold_int8_scores(si, qs_s[h],
+                                      col_scales(ks_ref, h, b), scale)
+                v_s = col_scales(vs_ref, h, b)
             else:
-                k = kbuf.astype(jnp.float32)           # [bs, D]
-                if quantized:
-                    k = k * (sk / np.float32(127.0))
-                sc = lax.dot_general(q, k, _DIMNUM_NT,
-                                     preferred_element_type=jnp.float32)
-            base = p_idx * jnp.int32(block_size)
-            cols = base + lax.broadcasted_iota(
-                jnp.int32, (g, block_size), 1)
-            ok = (cols <= qpos) & (cols < kv_len)
+                sc = lax.dot_general(
+                    qh, k, _DIMNUM_NT,
+                    preferred_element_type=jnp.float32) * np.float32(scale)
+            cols = b * n + lax.broadcasted_iota(jnp.int32, (r, n), 1)
+            ok = (cols <= qpos) & (cols < kv_end)
             sc = jnp.where(ok, sc, _F32_NEG_INF)
 
             def pv_of_p(p):
-                if int8_mxu:
-                    # p·V runs int8×int8 too: the probability rows are
-                    # quantized per row (max p per row is the scale)
-                    # and the p/v scales fold into the [g, d] product —
-                    # the page NEVER materializes in fp32 (measured
-                    # ≤1% of value magnitude vs the declared 2%
-                    # tolerance)
-                    p_codes, p_s = quantize_rows_symmetric(p)
-                    pvi = lax.dot_general(
-                        p_codes, vbuf, _DIMNUM_NN,
-                        preferred_element_type=jnp.int32)
-                    return fold_int8_scores(pvi, p_s, sv)
-                v = vbuf.astype(jnp.float32)
                 if quantized:
-                    v = v * (sv / np.float32(127.0))
-                return lax.dot_general(p, v, _DIMNUM_NN,
+                    p_codes, p_s = quantize_rows_symmetric(p * v_s)
+                    pvi = lax.dot_general(
+                        p_codes, v, _DIMNUM_NN,
+                        preferred_element_type=jnp.int32)
+                    return fold_int8_scores(pvi, p_s, 1.0)
+                return lax.dot_general(p.astype(cdt), v, _DIMNUM_NN,
                                        preferred_element_type=jnp.float32)
 
-            return online_softmax_update(carry, sc, ok, pv_of_p)
+            m_s[h], l_s[h], acc_s[h] = online_softmax_update(
+                (m_s[h], l_s[h], acc_s[h]), sc, ok, pv_of_p)
+            return b
 
-        if pipelined:
-            def start_page(p_idx, slot):
-                page = bt_ref[s, p_idx]
-                pltpu.make_async_copy(k_pages.at[h, page],
-                                      k_vmem.at[slot],
-                                      sem.at[slot, 0]).start()
-                pltpu.make_async_copy(v_pages.at[h, page],
-                                      v_vmem.at[slot],
-                                      sem.at[slot, 1]).start()
+        def body(b, _):
+            slot = lax.rem(b, jnp.int32(2))
 
-            def wait_page(p_idx, slot):
-                page = bt_ref[s, p_idx]
-                pltpu.make_async_copy(k_pages.at[h, page],
-                                      k_vmem.at[slot],
-                                      sem.at[slot, 0]).wait()
-                pltpu.make_async_copy(v_pages.at[h, page],
-                                      v_vmem.at[slot],
-                                      sem.at[slot, 1]).wait()
+            @pl.when(b + 1 < n_blk)
+            def _prefetch():
+                block_copies(b + 1, 1 - slot, lambda c: c.start())
+            block_copies(b, slot, lambda c: c.wait())
+            # the block head-major: [n, Hkv, D] as stored -> [Hkv, n, D]
+            for src, dst in ((kbuf, k_hm), (vbuf, v_hm)):
+                for h, rows_h in _kv_heads(src.at[slot]):
+                    dst[h] = rows_h.astype(cdt)
+            lax.fori_loop(jnp.int32(0), jnp.int32(hkv), head_math, b)
+            return 0
 
-            @pl.when(n_pages > 0)
-            def _warm():
-                start_page(jnp.int32(0), jnp.int32(0))
+        lax.fori_loop(jnp.int32(0), n_blk, body, 0)
 
-            def body(p_idx, carry):
-                slot = lax.rem(p_idx, jnp.int32(2))
-                # prefetch clamp: the last used page issues NO copy —
-                # bt_ref[s, n_pages] (and anything past the span's
-                # block count) is never read
-                @pl.when(p_idx + 1 < n_pages)
-                def _prefetch():
-                    start_page(p_idx + 1, jnp.int32(1) - slot)
-                wait_page(p_idx, slot)
-                return page_math(p_idx, bt_ref[s, p_idx],
-                                 k_vmem[slot], v_vmem[slot], carry)
-        else:
-            def body(p_idx, carry):
-                page = bt_ref[s, p_idx]
-                kc = pltpu.make_async_copy(k_pages.at[h, page], k_vmem,
-                                           sem)
-                kc.start()
-                kc.wait()
-                vc = pltpu.make_async_copy(v_pages.at[h, page], v_vmem,
-                                           sem)
-                vc.start()
-                vc.wait()
-                return page_math(p_idx, page, k_vmem[...], v_vmem[...],
-                                 carry)
-
-        m, l, acc = lax.fori_loop(jnp.int32(0), n_pages, body,
-                                  (m0, l0, acc0))
-        o_ref[0, 0] = (acc / jnp.maximum(l, np.float32(1e-30))
-                       ).astype(o_ref.dtype)
+        for h in range(hkv):
+            o = acc_s[h] / jnp.maximum(l_s[h], np.float32(1e-30))
+            obuf[:, h * groups:(h + 1) * groups, :] = (
+                o.reshape(bq, groups, d).astype(obuf.dtype))
+        o_copy = pltpu.make_async_copy(obuf, o_hbm.at[pl.ds(tok0, bq)],
+                                       qo_sem.at[1])
+        o_copy.start()
+        o_copy.wait()
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _ragged_paged_attention_pallas(q, key_cache, value_cache,
                                    block_tables, q_offsets, q_lens,
-                                   kv_lens, scale, span_q: int,
-                                   interpret=False,
-                                   key_scale=None, value_scale=None,
-                                   pipelined: bool = True):
+                                   kv_lens, scale, interpret=False,
+                                   key_scale=None, value_scale=None):
     """q: [T, H, D] packed ragged tokens; block_tables [S, W]; span
     tables [S] (q_offsets ascending, padding spans pinned past the last
-    token — the same contract as ``_ragged_attention_xla``).  span_q:
-    static max span length (>= max(q_lens)).  Returns [T, H, D].
+    token — the same contract as ``_ragged_attention_xla``); pools
+    ``[num_blocks, block_size, Hkv, D]`` in their stored type.  Returns
+    [T, H, D].  Jitted so that a step traces and lowers the launch once
+    a budget, not once a layer (the kernel body is unrolled over the kv
+    heads: about a second of Python a trace).
 
-    The token-major pack is regrouped span-major around the launch:
-    span s's window ``q[q_offsets[s] : q_offsets[s] + span_q]`` becomes
-    block ``[s, hkv]`` of a ``[S, Hkv, span_q * groups, D]`` f32
-    operand (one XLA gather in), and each token reads its own row back
-    out of the matching f32 output (one gather out, cast to q.dtype
-    here — a bf16 ``[1, D]`` decode row is below Mosaic's packed tile).
+    Nothing here is sized by a span window or by the pool: the work
+    list has ``ceil(T / tile) + S`` tiles (a static bound; the real
+    count and every descriptor are traced data), the pack is padded by
+    one tile so the last tile's window stays inside it, and the pools
+    go to the kernel untouched.
 
     Head sharding (tensor-parallel serving): the kernel is
     shard-oblivious — every head index here is LOCAL.  Each chip calls
     it with its own head shard (H/tp queries, Hkv/tp kv heads) against
-    its head shard of every page, the grid is (span, local_kv_head),
-    and no global head id ever appears, so the same kernel serves
-    single-chip and per-chip-shard launches without index plumbing.
-    The only cross-shard invariant is that the GQA group size H/Hkv
-    survives the shard (both divide by tp) — checked below.
+    its head shard of every page, and no global head id ever appears,
+    so the same kernel serves single-chip and per-chip-shard launches
+    without index plumbing.  The only cross-shard invariant is that the
+    GQA group size H/Hkv survives the shard (both divide by tp) —
+    checked below.
     """
     T, H, D = q.shape
-    Hkv = key_cache.shape[2]
-    bs = key_cache.shape[1]
+    bs, Hkv = key_cache.shape[1], key_cache.shape[2]
     if Hkv <= 0 or H % Hkv:
         raise ValueError(
             "ragged paged attention: %d query heads do not group over "
@@ -1661,76 +1777,64 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
             % (H, Hkv))
     groups = H // Hkv
     S, W = block_tables.shape
-    span_q = max(1, int(span_q))
-    g = span_q * groups
     quantized = key_scale is not None
-    q_offsets = q_offsets.astype(jnp.int32)
-    win = jnp.arange(span_q, dtype=jnp.int32)
-    # rows past the pack's end (the last span's window overhang, and
-    # every row of a padding span) clamp to a real token: finite, never
-    # read back
-    with jax.named_scope("attn.regroup"):
-        rows = jnp.minimum(q_offsets[:, None] + win[None, :], T - 1)
-        qs = q.astype(jnp.float32)[rows]          # [S, span_q, H, D]
-        qs = jnp.moveaxis(qs.reshape(S, span_q, Hkv, groups, D), 2, 1)
-        qs = qs.reshape(S, Hkv, g, D)
-    with jax.named_scope("attn.kv_upcast"):
-        kp = jnp.moveaxis(key_cache, 2, 0)
-        vp = jnp.moveaxis(value_cache, 2, 0)
-        if not quantized:
-            kp, vp = kp.astype(jnp.float32), vp.astype(jnp.float32)
-    bt = jnp.maximum(block_tables, 0)
+    tile, kb = ragged_tile_geometry(H, Hkv, D, bs, W, q.dtype,
+                                    key_cache.dtype)
+    n_tiles = -(-T // tile) + S
+    cdt = _ragged_compute_dtype(q.dtype, key_cache.dtype)
+    rows = tile * groups
 
     kernel = functools.partial(
         _ragged_paged_kernel, block_size=bs, pages_per_span=W,
-        span_q=span_q, scale=scale, groups=groups, quantized=quantized,
-        pipelined=pipelined)
-    if pipelined:
-        # double-buffered page stream: 2 VMEM slots per operand, one
-        # DMA sem row per slot (k col 0 / v col 1)
-        page_scratch = [pltpu.VMEM((2, bs, D), kp.dtype),
-                        pltpu.VMEM((2, bs, D), vp.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))]
-    else:
-        page_scratch = [pltpu.VMEM((bs, D), kp.dtype),
-                        pltpu.VMEM((bs, D), vp.dtype),
-                        pltpu.SemaphoreType.DMA]
-
+        pages_per_block=kb, scale=scale, groups=groups,
+        quantized=quantized)
     with _x64_off(), jax.named_scope("attn.kernel"):
-        prefetch = [q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
-                    bt.astype(jnp.int32)]
+        q_lens = q_lens.astype(jnp.int32)
+        prefetch = list(_ragged_work_list(q_lens, tile, n_tiles))
+        prefetch += [q_offsets.astype(jnp.int32), q_lens,
+                     kv_lens.astype(jnp.int32),
+                     jnp.maximum(block_tables, 0).astype(jnp.int32)]
         if quantized:
             # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
             prefetch += [key_scale.astype(jnp.float32).T,
                          value_scale.astype(jnp.float32).T]
-        qo_spec = pl.BlockSpec((1, 1, g, D), lambda s, h, *_: (s, h, 0, 0))
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(S, Hkv),
-            in_specs=[
-                qo_spec,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=qo_spec,
-            scratch_shapes=page_scratch,
+            grid=(n_tiles,),
+            in_specs=[any_spec] * 4,
+            out_specs=any_spec,
+            scratch_shapes=[
+                pltpu.VMEM((tile, H, D), q.dtype),             # q tile
+                pltpu.VMEM((tile, H, D), q.dtype),             # o tile
+                pltpu.VMEM((Hkv, rows, D), cdt),               # folded q
+                pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # m
+                pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # l
+                pltpu.VMEM((Hkv, rows, D), jnp.float32),       # acc
+                pltpu.VMEM((2, kb * bs, Hkv, D), key_cache.dtype),
+                pltpu.VMEM((2, kb * bs, Hkv, D), value_cache.dtype),
+                pltpu.VMEM((Hkv, kb * bs, D), cdt),   # block, head-major
+                pltpu.VMEM((Hkv, kb * bs, D), cdt),
+                pltpu.SemaphoreType.DMA((2, 2)),     # [slot, k | v]
+                pltpu.SemaphoreType.DMA((2,)),       # q in, o out
+            ] + ([pltpu.VMEM((Hkv, rows, 1), jnp.float32)]   # q scales
+                 if quantized else []),
         )
+        # one tile of padding: the last tile's [tok0, tok0 + tile)
+        # window stays inside the operand and the output
+        q_pad = jnp.pad(q, ((0, tile), (0, 0), (0, 0)))
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, Hkv, g, D), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(q_pad.shape, q.dtype),
+            # rows no tile owns (the pack's padding) read zeros
+            input_output_aliases={len(prefetch) + 3: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="ragged_paged_attention",
-        )(*prefetch, qs, kp, vp)
-    # token t -> (its span, its row inside the span's window)
-    with jax.named_scope("attn.ungroup"):
-        tok = jnp.arange(T, dtype=jnp.int32)
-        sid = jnp.clip(
-            jnp.searchsorted(q_offsets, tok, side="right") - 1,
-            0, S - 1).astype(jnp.int32)
-        r = jnp.clip(tok - q_offsets[sid], 0, span_q - 1)
-        out = out.reshape(S, Hkv, span_q, groups, D)[sid, :, r]
-        return out.reshape(T, H, D).astype(q.dtype)
+        )(*prefetch, q_pad, key_cache, value_cache, jnp.zeros_like(q_pad))
+        return out[:T]
 
 
 # ---------------------------------------------------------------------------
@@ -1905,11 +2009,7 @@ _VMEM_LANE = 128
 
 def _tile_bytes(shape, itemsize: int) -> int:
     """Lane/sublane-padded bytes of one VMEM-resident tile."""
-    shape = tuple(int(s) for s in shape)
-    if not shape:
-        shape = (1, 1)
-    elif len(shape) == 1:
-        shape = (1,) + shape
+    shape = (1, 1) + tuple(int(s) for s in shape)     # at least 2-D
     sub = 8 * (4 // max(1, min(itemsize, 4)))  # f32:8, bf16:16, int8:32
     lead = 1
     for s in shape[:-2]:
@@ -1922,7 +2022,7 @@ def _tile_bytes(shape, itemsize: int) -> int:
 def _paged_cell_vmem_bytes(g: int, d: int, block_size: int,
                            kv_itemsize: int, pipelined: bool,
                            quantized: bool) -> int:
-    """What both paged kernels hold besides their q/o blocks: the page
+    """What the paged decode kernel holds besides its q/o blocks: the page
     buffers (×2 per operand when pipelined — the round-17 double
     buffering) and the live compute tiles (online-softmax m/l/acc, the
     [g, block_size] score/probability tile, and the int8 q codes +
@@ -1939,20 +2039,23 @@ def _paged_cell_vmem_bytes(g: int, d: int, block_size: int,
     return total
 
 
-def ragged_kernel_vmem_bytes(*, span_q: int, groups: int, head_dim: int,
-                             block_size: int, kv_itemsize: int = 4,
-                             pipelined: bool = True,
-                             quantized: bool = False) -> int:
-    """Worst-case VMEM bytes of ONE _ragged_paged_kernel grid cell: the
-    span's [span_q * groups, D] f32 query block and its f32 output
-    block (both BlockSpec-streamed, so Mosaic double-buffers them: ×2
-    each — f32 whatever the model dtype, the wrapper casts) plus the
-    page buffers and compute tiles.  Mirrors the specs in
-    _ragged_paged_attention_pallas — edit both or
-    tools/check_vmem_budget.py fails."""
-    g = span_q * groups
-    return 4 * _tile_bytes((g, head_dim), 4) + _paged_cell_vmem_bytes(
-        g, head_dim, block_size, kv_itemsize, pipelined, quantized)
+def ragged_kernel_vmem_bytes(*, heads: int, kv_heads: int,
+                             head_dim: int, block_size: int,
+                             bt_width: int, q_dtype="bfloat16",
+                             kv_dtype="bfloat16") -> int:
+    """Worst-case VMEM bytes of ONE _ragged_paged_kernel grid cell: a q
+    tile's buffers and state plus the two-slot K/V page blocks, at the
+    tile size the launch itself would pick (``ragged_tile_geometry``;
+    scratch shapes accounted in ``_ragged_cell_vmem_bytes`` — edit both
+    or tools/check_vmem_budget.py fails)."""
+    tile, kb = ragged_tile_geometry(
+        heads, kv_heads, head_dim, block_size, bt_width, q_dtype,
+        kv_dtype)
+    return _ragged_cell_vmem_bytes(
+        tile, heads, kv_heads, head_dim, kb * block_size,
+        jnp.dtype(q_dtype).itemsize,
+        _ragged_compute_dtype(q_dtype, kv_dtype).itemsize,
+        jnp.dtype(kv_dtype).itemsize)
 
 
 def decode_kernel_vmem_bytes(*, groups: int, head_dim: int,
@@ -2034,10 +2137,12 @@ def kernel_vmem_report(envelope=None):
     tools/check_vmem_budget.py gates this against the per-core budget;
     grow the envelope here FIRST when a new config is introduced."""
     env = {
-        # serving envelope: the TPU bench line (bench_serving.py) —
-        # chunk/span_q 256, 16-token pages, head_dim 128, and GQA
-        # grouping up to 8 q heads per kv head
-        "span_q": 256, "groups": 8, "head_dim": 128, "block_size": 16,
+        # serving envelope: the benchmark's cells — 32 q heads over 8
+        # kv heads, 16-token pages, head_dim 128, block tables up to
+        # 388 pages; the decode and rope kernels are sized for GQA
+        # grouping up to 8
+        "heads": 32, "kv_heads": 8, "bt_width": 388,
+        "groups": 8, "head_dim": 128, "block_size": 16,
         # training envelope: the default/autotuned flash tiles
         "block_q": 512, "block_k": 512,
         "bwd_block_q": _FUSED_BWD_BLOCK_Q,
@@ -2045,14 +2150,13 @@ def kernel_vmem_report(envelope=None):
     }
     if envelope:
         env.update(envelope)
+    ragged = {k: env[k] for k in ("heads", "kv_heads", "head_dim",
+                                  "block_size", "bt_width")}
     return {
         "ragged_paged_fp32": ragged_kernel_vmem_bytes(
-            span_q=env["span_q"], groups=env["groups"],
-            head_dim=env["head_dim"], block_size=env["block_size"]),
+            q_dtype="float32", kv_dtype="float32", **ragged),
         "ragged_paged_int8": ragged_kernel_vmem_bytes(
-            span_q=env["span_q"], groups=env["groups"],
-            head_dim=env["head_dim"], block_size=env["block_size"],
-            kv_itemsize=1, quantized=True),
+            kv_dtype="int8", **ragged),
         "paged_decode_fp32": decode_kernel_vmem_bytes(
             groups=env["groups"], head_dim=env["head_dim"],
             block_size=env["block_size"]),

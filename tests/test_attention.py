@@ -898,8 +898,8 @@ def test_causal_stream_remap_lockstep_with_run_predicate():
 # VMEM budget lint (round-17 satellite: runs in the verify flow here)
 # ---------------------------------------------------------------------------
 def test_vmem_budget_lint():
-    """Every Pallas kernel family's worst-case VMEM footprint (span_q
-    window + double-buffered page DMA slots + accumulators, lane/
+    """Every Pallas kernel family's worst-case VMEM footprint (q tile
+    + double-buffered page DMA slots + accumulators, lane/
     sublane-padded) must fit its declared per-core budget at the
     serving/training envelope — a tile-size edit that blows VMEM fails
     here, not as a Mosaic allocation error on first TPU contact."""
@@ -926,11 +926,28 @@ def test_vmem_budget_lint():
     base = kernel_vmem_report()
     grown = kernel_vmem_report({"bwd_block_k": 2 * 2048})
     assert grown["flash_bwd_fused"] > 1.5 * base["flash_bwd_fused"]
-    # and the double-buffer accounting is visible: the pipelined ragged
-    # kernel carries exactly one extra page buffer pair vs sync-DMA
-    from paddle_tpu.ops.pallas_kernels import ragged_kernel_vmem_bytes
-    pip = ragged_kernel_vmem_bytes(span_q=8, groups=2, head_dim=128,
-                                   block_size=16)
-    sync = ragged_kernel_vmem_bytes(span_q=8, groups=2, head_dim=128,
-                                    block_size=16, pipelined=False)
-    assert pip - sync == 2 * 16 * 128 * 4
+    # and the ragged cell's accounting is visible: its page blocks
+    # hold pages AS STORED ([tokens, Hkv, D], two slots each of K and
+    # V: 8 heads pad to a whole sublane tile in bf16 and in int8
+    # alike), so an int8 pool's cell differs from the bf16 pool's by
+    # its folded q, its head-major block and its q scales; the q tile
+    # is 128 rows a kv head, so fewer heads a group means more tokens
+    from paddle_tpu.ops.pallas_kernels import (_tile_bytes,
+                                               ragged_kernel_vmem_bytes,
+                                               ragged_tile_geometry)
+    cell = dict(heads=32, kv_heads=8, head_dim=128, block_size=16,
+                bt_width=224)
+    bf16 = ragged_kernel_vmem_bytes(**cell)
+    int8 = ragged_kernel_vmem_bytes(kv_dtype="int8", **cell)
+    assert bf16 - int8 == 4 * (_tile_bytes((128, 8, 128), 2)
+                               - _tile_bytes((128, 8, 128), 1)) \
+        + _tile_bytes((8, 128, 128), 2) - _tile_bytes((8, 128, 128), 1) \
+        + 2 * (_tile_bytes((8, 128, 128), 2)
+               - _tile_bytes((8, 128, 128), 1)) \
+        - _tile_bytes((8, 128, 1), 4)
+    assert ragged_tile_geometry(32, 8, 128, 16, 224, "bfloat16",
+                                "bfloat16") == (32, 8)
+    # (float32 MHA: 128 tokens would overrun the cell's VMEM, so the
+    # tile halves; a 4-page table caps the key block)
+    assert ragged_tile_geometry(32, 32, 128, 16, 4, "float32",
+                                "float32") == (32, 4)

@@ -829,61 +829,79 @@ def test_mixed_lazy_victim_truncation_leak_free():
     assert not eng.finished[r2].truncated
 
 
-@pytest.mark.slow
-def test_ragged_kernel_interpret_matches_reference_sweep():
+# each case: [(q_len, kv_len)] spans (kv_len INCLUDES the span); the
+# page size where it is not 4
+RAGGED_SWEEP = {
+    "decode_only": [(1, 5), (1, 9), (1, 1), (1, 16)],
+    "fresh_chunk_and_decode": [(6, 6), (1, 7)],
+    "mid_prompt_chunk": [(4, 12), (8, 8), (1, 3)],
+    "ragged_mix": [(3, 11), (1, 13), (5, 5), (2, 10)],
+    # a prefix hit: the span's first row already sits at position 8
+    "page_aligned_suffix": [(8, 16)],
+    "padded_span_tail": [(1, 6), (7, 15), (0, 1), (0, 1)],
+    # spans that end mid-page beside a q_len == 0 padding span
+    "ends_mid_page": [(1, 40), (20, 37), (9, 9), (0, 1)],
+    # a chunk of several q tiles (32 tokens at its GQA 4:1) that
+    # starts mid-page behind a prefix, between two decode spans
+    "chunk_over_one_tile": [(1, 300), (140, 290), (1, 7)],
+}
+_TIER1_RAGGED = {("ragged_mix", "float32"),
+                 ("chunk_over_one_tile", "bfloat16")}
+
+
+def _ragged_case(spans, bs, Hkv, H, D, dtype, seed=42):
+    """The packed operands of one sweep case, pools in ``dtype``."""
+    rng_ = np.random.RandomState(seed)
+    W = max(2, max(-(-kv // bs) for _, kv in spans))
+    nb = W * len(spans)
+    kc = jnp.asarray(rng_.randn(nb, bs, Hkv, D), dtype)
+    vc = jnp.asarray(rng_.randn(nb, bs, Hkv, D), dtype)
+    cache = PagedKVCache(nb, bs, Hkv, D)
+    bt = np.stack([
+        cache.build_block_table([kv_len], max_blocks=W)[0] if q_len
+        else np.full((W,), -1, np.int32) for q_len, kv_len in spans])
+    T = sum(q for q, _ in spans)
+    q = jnp.asarray(rng_.randn(T, H, D), dtype)
+    q_offsets, off = [], 0
+    for q_len, _ in spans:
+        q_offsets.append(off if q_len else T)
+        off += q_len
+    return (q, kc, vc, bt, np.asarray(q_offsets, np.int32),
+            np.asarray([q for q, _ in spans], np.int32),
+            np.asarray([kv for _, kv in spans], np.int32))
+
+
+@pytest.mark.parametrize("case,dtype", [
+    pytest.param(c, d, marks=() if (c, d) in _TIER1_RAGGED
+                 else pytest.mark.slow)
+    for c in sorted(RAGGED_SWEEP) for d in ("float32", "bfloat16")])
+def test_ragged_kernel_interpret_matches_reference_sweep(case, dtype):
     """Pallas ragged-paged-attention kernel (interpret mode) vs the XLA
     gather reference across span mixes: decode-only packs, chunks
     starting mid-page and page-aligned, prefix-hit-style suffix spans,
-    varying span counts, GQA grouping, and budget padding (zero-length
-    spans)."""
+    spans that end mid-page, a chunk longer than one q tile, GQA
+    grouping, and budget padding (zero-length spans), in float32 and in
+    bfloat16 (where the probabilities are rounded to the pool's type
+    for ``p.V``, as on the chip).  Two cases run in tier 1, the rest in
+    the slow lane."""
     from paddle_tpu.ops.paged_attention import (_ragged_attention_xla,
                                                 ragged_paged_attention)
-    bs, Hkv, H, D, nb = 4, 2, 4, 16, 64
-    scale = 1.0 / np.sqrt(D)
-    rng_ = np.random.RandomState(42)
-    kc = jnp.asarray(rng_.randn(nb, bs, Hkv, D).astype(np.float32))
-    vc = jnp.asarray(rng_.randn(nb, bs, Hkv, D).astype(np.float32))
-    cache = PagedKVCache(nb, bs, Hkv, D)
-
-    # each case: [(q_len, kv_len)] spans (kv_len INCLUDES the span)
-    cases = [
-        [(1, 5), (1, 9), (1, 1), (1, 16)],          # decode-only pack
-        [(6, 6), (1, 7)],                           # fresh chunk + decode
-        [(4, 12), (8, 8), (1, 3)],                  # mid-prompt chunk
-        [(3, 11), (1, 13), (5, 5), (2, 10)],        # ragged mix
-        [(8, 16)],                                  # page-aligned suffix
-        [(1, 6), (7, 15), (0, 1), (0, 1)],          # padded span tail
-    ]
-    for spans in cases:
-        W = max(2, max(-(-kv // bs) for _, kv in spans))
-        rows = []
-        for q_len, kv_len in spans:
-            if q_len == 0:
-                rows.append(np.full((W,), -1, np.int32))
-                continue
-            tab = cache.build_block_table([kv_len], max_blocks=W)[0]
-            rows.append(tab)
-        bt = np.stack(rows)
-        T = sum(q for q, _ in spans)
-        q = rng_.randn(T, H, D).astype(np.float32)
-        q_offsets, off = [], 0
-        for q_len, _ in spans:
-            q_offsets.append(off if q_len else T)
-            off += q_len
-        q_offsets = np.asarray(q_offsets, np.int32)
-        q_lens = np.asarray([q for q, _ in spans], np.int32)
-        kv_lens = np.asarray([kv for _, kv in spans], np.int32)
-        want = _ragged_attention_xla(
-            jnp.asarray(q), kc, vc, jnp.asarray(bt),
-            jnp.asarray(q_offsets), jnp.asarray(q_lens),
-            jnp.asarray(kv_lens), scale)
-        got = ragged_paged_attention(
-            q, kc, vc, bt, q_offsets, q_lens, kv_lens, interpret=True,
-            span_q=int(max(1, q_lens.max())))
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-5, err_msg=str(spans))
-        for row in rows:
-            cache.free_sequence(row)
+    spans = RAGGED_SWEEP[case]
+    bs, H = (16, 8) if case == "chunk_over_one_tile" else (4, 4)
+    q, kc, vc, bt, q_offsets, q_lens, kv_lens = _ragged_case(
+        spans, bs, 2, H, 16, dtype)
+    want = _ragged_attention_xla(
+        q.astype(jnp.float32), kc.astype(jnp.float32),
+        vc.astype(jnp.float32), jnp.asarray(bt), jnp.asarray(q_offsets),
+        jnp.asarray(q_lens), jnp.asarray(kv_lens), 1.0 / np.sqrt(16))
+    got = ragged_paged_attention(
+        q, kc, vc, bt, q_offsets, q_lens, kv_lens, interpret=True)
+    assert got.dtype == q.dtype
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), err_msg=str(spans),
+                               **tol)
 
 
 @pytest.mark.slow
@@ -925,9 +943,10 @@ def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
     boundary (a span whose used count fills the whole table, where an
     unclamped prefetch would read bt[s, W]).  Every unused page (and
     the poison page the padded table entries point at) is NaN'd; the
-    kernel's output must be BYTE-IDENTICAL to its clean-pool run, for
-    both the pipelined and the legacy sync-DMA kernel, and match the
-    XLA reference on the clean pool."""
+    kernel's output must be BYTE-IDENTICAL to its clean-pool run —
+    for the ragged launch (whose key blocks hold several pages, so a
+    partly filled block must not fetch its tail) and for both decode
+    kernels — and match the XLA reference on the clean pool."""
     from paddle_tpu.ops.paged_attention import _ragged_attention_xla
     bs, Hkv, H, D, nb = 4, 2, 4, 16, 32
     rng_ = np.random.RandomState(3)
@@ -959,17 +978,14 @@ def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
     want = _ragged_attention_xla(
         jnp.asarray(q), kc, vc, jnp.asarray(bt), jnp.asarray(q_offsets),
         jnp.asarray(q_lens), jnp.asarray(kv_lens), 1.0 / np.sqrt(D))
-    for pipelined in (True, False):
-        clean = np.asarray(ragged_paged_attention(
-            q, kc, vc, *args, interpret=True, span_q=8,
-            pipelined=pipelined))
-        poisoned = np.asarray(ragged_paged_attention(
-            q, kc_p, vc_p, *args, interpret=True, span_q=8,
-            pipelined=pipelined))
-        assert np.isfinite(poisoned).all()
-        np.testing.assert_array_equal(clean, poisoned)
-        np.testing.assert_allclose(clean, np.asarray(want),
-                                   rtol=2e-4, atol=2e-5)
+    clean = np.asarray(ragged_paged_attention(
+        q, kc, vc, *args, interpret=True))
+    poisoned = np.asarray(ragged_paged_attention(
+        q, kc_p, vc_p, *args, interpret=True))
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(clean, poisoned)
+    np.testing.assert_allclose(clean, np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
     # decode kernel: same invariant (full-table sequence included)
     sl = np.asarray([5, 16], np.int32)
     bt2 = np.stack([rows[0], rows[3]])
@@ -986,10 +1002,14 @@ def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
 
 @pytest.mark.slow
 def test_ragged_pipelined_matches_sync_fp32_byte_identical():
-    """Double buffering only reorders DMA issue/wait — the fp32
-    compute stream is the SAME ops on the same values, so the
-    pipelined kernel must be byte-identical to the r16 sync-DMA
-    kernel (interpret mode)."""
+    """The ragged launch against the XLA reference at float tolerance
+    (its sync-DMA twin went with the span window: the tiles sum in
+    another order than the reference, so not byte for byte).  The
+    decode kernel keeps its twin: double buffering only reorders DMA
+    issue/wait — the fp32 compute stream is the SAME ops on the same
+    values, so the pipelined kernel must be byte-identical to the r16
+    sync-DMA kernel (interpret mode)."""
+    from paddle_tpu.ops.paged_attention import _ragged_attention_xla
     bs, Hkv, H, D, nb = 4, 2, 4, 16, 64
     rng_ = np.random.RandomState(11)
     kc = jnp.asarray(rng_.randn(nb, bs, Hkv, D).astype(np.float32))
@@ -1004,10 +1024,12 @@ def test_ragged_pipelined_matches_sync_fp32_byte_identical():
     q_offsets = np.cumsum([0] + [q for q, _ in spans[:-1]]).astype(np.int32)
     q_lens = np.asarray([q for q, _ in spans], np.int32)
     kv_lens = np.asarray([kv for _, kv in spans], np.int32)
-    outs = [np.asarray(ragged_paged_attention(
-        q, kc, vc, bt, q_offsets, q_lens, kv_lens, interpret=True,
-        span_q=5, pipelined=p)) for p in (True, False)]
-    np.testing.assert_array_equal(outs[0], outs[1])
+    got = np.asarray(ragged_paged_attention(
+        q, kc, vc, bt, q_offsets, q_lens, kv_lens, interpret=True))
+    want = np.asarray(_ragged_attention_xla(
+        jnp.asarray(q), kc, vc, jnp.asarray(bt), jnp.asarray(q_offsets),
+        jnp.asarray(q_lens), jnp.asarray(kv_lens), 1.0 / np.sqrt(D)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     sl = np.asarray([7, 12], np.int32)
     d_outs = [np.asarray(paged_attention(
         q[:2], kc, vc, bt[:2], sl, interpret=True, pipelined=p))

@@ -282,9 +282,9 @@ def test_quant_write_paths_match_fp32_within_bound():
 
     # the quantized Pallas ragged + decode kernels (interpret mode)
     # agree with the dequantizing XLA references: the legacy
-    # (pipelined=False) kernels keep the r13 dequant math and stay
-    # within 1e-5; the r17 int8-MXU kernels additionally quantize the
-    # q rows in-kernel and are gated at the DECLARED tolerance
+    # (pipelined=False) decode kernel keeps the r13 dequant math and
+    # stays within 1e-5; the int8-MXU kernels additionally quantize
+    # the q rows in-kernel and are gated at the DECLARED tolerance
     # (KERNEL_INT8_REL_TOL of the pool's dequantized magnitude)
     from paddle_tpu.ops.paged_attention import (KERNEL_INT8_REL_TOL,
                                                 paged_attention,
@@ -303,13 +303,12 @@ def test_quant_write_paths_match_fp32_within_bound():
         jnp.asarray(q), cq.key_cache, cq.value_cache, bt2, qo, ql, kl,
         use_pallas=False, key_scale=cq.key_scale,
         value_scale=cq.value_scale))
-    for pipelined, atol in ((False, 1e-5),
-                            (True, KERNEL_INT8_REL_TOL * vmag)):
-        o_pal = np.asarray(ragged_paged_attention(
-            jnp.asarray(q), cq.key_cache, cq.value_cache, bt2, qo, ql,
-            kl, interpret=True, span_q=5, key_scale=cq.key_scale,
-            value_scale=cq.value_scale, pipelined=pipelined))
-        np.testing.assert_allclose(o_pal, o_ref, atol=atol)
+    o_pal = np.asarray(ragged_paged_attention(
+        jnp.asarray(q), cq.key_cache, cq.value_cache, bt2, qo, ql,
+        kl, interpret=True, key_scale=cq.key_scale,
+        value_scale=cq.value_scale))
+    np.testing.assert_allclose(o_pal, o_ref,
+                               atol=KERNEL_INT8_REL_TOL * vmag)
     sl = np.array([7, 5], np.int32)
     d_ref = np.asarray(paged_attention(
         jnp.asarray(q[:2]), cq.key_cache, cq.value_cache, bt2, sl,
@@ -430,8 +429,8 @@ def _q8_pool(nb, bs, hkv, d, rounds, mag_growth, rng_, seed_cache=None):
     return cq
 
 
-def _int8_parity_case(cq, spans, W, H, d, rng_, span_q):
-    """One interpret-pipelined vs XLA-reference comparison; returns
+def _int8_parity_case(cq, spans, W, H, d, rng_):
+    """One interpret-mode vs XLA-reference comparison; returns
     (max_abs_err, declared_atol)."""
     import jax.numpy as jnp
     from paddle_tpu.ops.paged_attention import (KERNEL_INT8_REL_TOL,
@@ -457,22 +456,24 @@ def _int8_parity_case(cq, spans, W, H, d, rng_, span_q):
         key_scale=cq.key_scale, value_scale=cq.value_scale))
     got = np.asarray(ragged_paged_attention(
         q, cq.key_cache, cq.value_cache, *common, interpret=True,
-        span_q=span_q, key_scale=cq.key_scale,
-        value_scale=cq.value_scale, pipelined=True))
+        key_scale=cq.key_scale, value_scale=cq.value_scale))
     vmag = float(np.abs(np.asarray(dequant_pages(
         cq.value_cache, cq.value_scale))).max())
     return float(np.abs(got - ref).max()), KERNEL_INT8_REL_TOL * vmag
 
 
-def test_int8_mxu_kernel_parity_representative():
+@pytest.mark.parametrize("hkv", [2, 4])
+def test_int8_mxu_kernel_parity_representative(hkv):
     """Tier-1 representative case (the full sweep is slow-lane): one
     small decode+chunk mix through the int8 MXU ragged kernel stays
-    inside the declared tolerance of the dequantizing XLA reference."""
+    inside the declared tolerance of the dequantizing XLA reference.
+    With 4 kv heads a 32-bit sublane of a stored page holds all four
+    heads' codes, and the kernel shifts each head's byte out."""
     rng_ = np.random.RandomState(21)
-    cq = _q8_pool(nb=8, bs=4, hkv=2, d=8, rounds=2, mag_growth=2.0,
+    cq = _q8_pool(nb=8, bs=4, hkv=hkv, d=8, rounds=2, mag_growth=2.0,
                   rng_=rng_)
     err, atol = _int8_parity_case(
-        cq, spans=[(1, 7), (4, 8)], W=2, H=4, d=8, rng_=rng_, span_q=4)
+        cq, spans=[(1, 7), (4, 8)], W=2, H=2 * hkv, d=8, rng_=rng_)
     assert err <= atol, (err, atol)
 
 
@@ -496,13 +497,13 @@ def test_int8_mxu_kernel_parity_sweep():
         rng_ = np.random.RandomState(100 + rounds)
         cq = _q8_pool(nb=16, bs=4, hkv=2, d=16, rounds=rounds,
                       mag_growth=growth, rng_=rng_)
-        for spans, W, span_q in (
-                ([(1, 5), (1, 9), (1, 1), (1, 16)], 4, 1),   # decode
-                ([(6, 6), (1, 7), (4, 12)], 4, 8),           # mixed
-                ([(8, 16)], 4, 8),                           # aligned
-                ([(3, 11), (0, 1), (2, 10)], 8, 4)):         # padded
-            err, atol = _int8_parity_case(cq, spans, W, 4, 16, rng_,
-                                          span_q)
+        for spans, W in (
+                ([(1, 5), (1, 9), (1, 1), (1, 16)], 4),      # decode
+                ([(6, 6), (1, 7), (4, 12)], 4),              # mixed
+                ([(8, 16)], 4),                              # aligned
+                ([(3, 11), (0, 1), (2, 10)], 8),             # padded
+                ([(1, 30), (20, 29), (9, 9)], 8)):   # mid-page, > tile
+            err, atol = _int8_parity_case(cq, spans, W, 4, 16, rng_)
             assert err <= atol, (rounds, growth, spans, err, atol)
     # decode kernel, same declared tolerance
     rng_ = np.random.RandomState(7)

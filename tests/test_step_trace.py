@@ -272,8 +272,10 @@ def test_compiled_step_names_its_parts(family):
 
 def test_ragged_wrapper_names_its_parts():
     """The TPU path's wrapper round the ragged kernel, traced and not
-    run: the regroup, the pool up-cast, the kernel and the ungroup
-    (tests/test_tpu_compile.py reads them off the v5e program)."""
+    run: everything it does (the work list, the pack's padding, the
+    launch) lies under ``attn.kernel``, and the scopes of the old
+    operand contract are gone (tests/test_tpu_compile.py reads the v5e
+    program)."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas_kernels import (
         _ragged_paged_attention_pallas)
@@ -281,16 +283,61 @@ def test_ragged_wrapper_names_its_parts():
     pool = jnp.zeros((8, bs, Hkv, D), jnp.float32)
     jaxpr = jax.make_jaxpr(
         lambda q, kc, vc, bt, qo, ql, kl: _ragged_paged_attention_pallas(
-            q, kc, vc, bt, qo, ql, kl, 0.25, span_q=4, interpret=True))(
+            q, kc, vc, bt, qo, ql, kl, 0.25, interpret=True))(
         jnp.zeros((T, H, D), jnp.float32), pool, pool,
         jnp.zeros((S, W), jnp.int32), jnp.zeros((S,), jnp.int32),
         jnp.ones((S,), jnp.int32), jnp.ones((S,), jnp.int32))
-    stacks = {str(eqn.source_info.name_stack) for eqn in jaxpr.eqns}
-    assert {"attn.regroup", "attn.kv_upcast", "attn.ungroup",
-            "attn.kernel/ragged_paged_attention"} <= stacks
-    assert [str(eqn.source_info.name_stack) for eqn in jaxpr.eqns
+    # the wrapper is jitted (a step traces it once a budget, not once
+    # a layer): its body is the one call's jaxpr
+    (call,) = jaxpr.eqns
+    body = call.params["jaxpr"].jaxpr
+    stacks = {str(eqn.source_info.name_stack) for eqn in body.eqns}
+    assert all(s.split("/")[0] == "attn.kernel" for s in stacks), stacks
+    assert not {"attn.regroup", "attn.kv_upcast", "attn.ungroup"} \
+        & STEP_SCOPES
+    assert [str(eqn.source_info.name_stack) for eqn in body.eqns
             if eqn.primitive.name == "pallas_call"] \
         == ["attn.kernel/ragged_paged_attention"]
+    # the pools reach the launch as they are stored: no op but the
+    # pallas_call takes one
+    pools = body.invars[1:3]
+    assert [eqn.primitive.name for eqn in body.eqns
+            if any(v is p for v in eqn.invars for p in pools)] \
+        == ["pallas_call"]
+
+
+def test_attn_rows_follow_the_spans():
+    """The record's ``attn_rows``: q rows per kv head the launch's
+    attention computes.  The CPU engine attends through the XLA
+    reference, which computes every row of the budget; the TPU launch
+    (sized here, never run) computes whole tiles: at most one partial
+    tile of slack a span."""
+    from paddle_tpu.ops.pallas_kernels import (ragged_attn_rows,
+                                               ragged_tile_geometry)
+    eng = _engine()
+    _drive(eng)
+    cfg = eng.mixed.cfg
+    H, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    groups = H // Hkv
+    recs = [f for _, _, f in _records(eng)]
+    launched = [f for f in recs if f["budget"]]
+    assert launched
+    for f in recs:
+        assert f["attn_rows"] == f["budget"] * groups
+    tpu = _engine(use_pallas=True).mixed
+    tile, _ = ragged_tile_geometry(H, Hkv, cfg.hidden_size // H, 4,
+                                   tpu.bt_width, "float32", "float32")
+    for f in launched:
+        q_lens = f["spans"][:, 1]
+        rows = tpu.attn_rows(f["budget"], q_lens)
+        assert groups * f["tokens"] <= rows <= groups * (
+            f["tokens"] + len(q_lens) * (tile - 1))
+    # the cells' own step: 40 decode spans beside two 512-token chunks
+    # at budget 1024, 32-token tiles (it was 64 spans x 512 rows x 4 =
+    # 131,072)
+    assert ragged_attn_rows([1] * 40 + [512, 512] + [0] * 22, 32, 4) \
+        == 4 * (40 * 32 + 1024)
+    assert ragged_attn_rows([131, 7, 33], 32, 4) == 4 * 32 * (5 + 1 + 2)
 
 
 def test_scopes_are_metadata_only(monkeypatch):

@@ -46,7 +46,7 @@ __all__ = ["Artifact", "build_artifacts", "check_donation",
 TINY = dict(num_hidden_layers=1, hidden_size=32, num_attention_heads=2,
             num_key_value_heads=2, vocab_size=64, intermediate_size=64)
 NUM_BLOCKS, BLOCK_SIZE = 8, 4
-BT_WIDTH, MAX_SPANS, SPAN_Q = 4, 2, 4
+BT_WIDTH, MAX_SPANS = 4, 2
 MIXED_T, DECODE_SLOTS, PREFILL_C = 8, 2, 8
 # round 21: the 2D fsdp x tp mesh the extra artifacts lower under —
 # every TINY dim divides by 2, so the composed specs survive pruning
@@ -236,8 +236,7 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
             packed_len=packed_len, min_aliases=min_aliases)
 
     mixed = MixedStep(model, caches(), bt_width=BT_WIDTH,
-                      max_spans=MAX_SPANS, span_q=SPAN_Q,
-                      use_pallas=False)
+                      max_spans=MAX_SPANS, use_pallas=False)
     packed_len = 4 * MIXED_T + MAX_SPANS * (BT_WIDTH + mixed.row_extra)
     art(f"mixed_step@T{MIXED_T}", mixed.aot_lower(MIXED_T),
         n_pool=2 * L, psig=pool_sig, expect_i32=1,
@@ -291,7 +290,7 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     from paddle_tpu.jit.spmd import ShardingConfig, mesh_2d
     mesh2d = mesh_2d(MESH_FSDP, MESH_TP)
     mixed2d = MixedStep(model, caches(), bt_width=BT_WIDTH,
-                        max_spans=MAX_SPANS, span_q=SPAN_Q,
+                        max_spans=MAX_SPANS,
                         use_pallas=False, mesh=mesh2d)
     # the sharded module's entry layout is PER-SHARD: the pool's kv
     # heads arrive already divided by tp (fsdp never names the pools)
@@ -312,7 +311,7 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     MESH_CP = 2
     meshcp = cp_mesh(MESH_CP)
     mixedcp = MixedStep(model, caches(), bt_width=BT_WIDTH,
-                        max_spans=MAX_SPANS, span_q=SPAN_Q,
+                        max_spans=MAX_SPANS,
                         use_pallas=False, mesh=meshcp)
     cp_shard_shape = list(probe.shape)
     cp_shard_shape[1] //= MESH_CP
@@ -338,7 +337,7 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     moe_model.eval()
     meshep = ep_mesh(MESH_EP)
     mixedep = MixedStep(moe_model, caches(), bt_width=BT_WIDTH,
-                        max_spans=MAX_SPANS, span_q=SPAN_Q,
+                        max_spans=MAX_SPANS,
                         use_pallas=False, mesh=meshep)
     art(f"mixed_step_ep@T{MIXED_T}", mixedep.aot_lower(MIXED_T),
         n_pool=2 * L, psig=pool_sig, expect_i32=1,

@@ -4,8 +4,8 @@ registered graftlint rule (``vmem-budget``); the old CLI remains as a
 thin shim over this module.
 
 Every kernel's worst-case per-core VMEM footprint is computed from its
-TILE SHAPES (``ops/pallas_kernels.kernel_vmem_report``: span_q query
-window + 2× double-buffered page DMA buffers + online-softmax
+TILE SHAPES (``ops/pallas_kernels.kernel_vmem_report``: q tile
++ 2× double-buffered page DMA buffers + online-softmax
 accumulators + score tiles, lane/sublane-padded the way Mosaic pads
 them) at the declared serving/training envelope, and gated against the
 per-core budget below.  A tile-size edit — a wider span window, a
