@@ -122,7 +122,7 @@ def run_serve(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         log(f"compile cache: {program.enable_compile_cache()}")
     cfg, deploy = cell.config, cell.deploy
     t = time.perf_counter()
-    model = program.build_model(cfg, seed)
+    model = program.build_model(cell, seed)
     eng = program.build_engine(model, deploy["engine"])
     t_build = time.perf_counter() - t
     t = time.perf_counter()
@@ -172,7 +172,7 @@ def run_serve(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del eng, model
     gc.collect()
     t = time.perf_counter()
-    checks, read = correct.judge(cfg, seed, samples, deploy["correct"],
+    checks, read = correct.judge(cell, seed, samples,
                                  sum(not tr.done for tr in measured))
     log(f"reference: {time.perf_counter() - t:.1f}s over "
         f"{read.get('positions')} positions")

@@ -1,9 +1,10 @@
 """Both kinds of cell end to end at a tiny size on the CPU, in a temp copy
-of the benchmark's data to which a throw-away cell of each kind and a
-throw-away metric were ADDED (no existing file edited): what a later PR
-does.  The measurement path itself refuses the CPU; these tests skip that
+of the benchmark's data to which a throw-away cell of each kind, a
+throw-away metric and a throw-away family whose layers are of two kinds
+were ADDED (no existing file edited): what a later PR does.  The measurement path itself refuses the CPU; these tests skip that
 look for a chip and drive the rest of a run.
 """
+import hashlib
 import json
 import os
 import subprocess
@@ -73,6 +74,75 @@ def test_closed_loop_moe_cell_traced_with_added_metric(root):
     assert "device_idle.docs" not in result["metrics"]
 
 
+def test_family_of_two_kinds_of_layer_comes_as_files_only(root):
+    """``densefirst`` (layer 0 dense, layers 1-2 sparse: its reference, its
+    configuration and its cell are files of the throw-away tree) through
+    the real entry point: the program serves what its reference puts
+    first, and the router's margins come from the sparse layers alone."""
+    result, rc = bench_run.run_cell("tiny-densefirst.docs", 12, 2.0, False,
+                                    root=root, require_chip=False)
+    assert rc == 0 and result["correct"] is True
+    assert result["reference"]["tokens_off_reference_best"] == 0
+    assert result["reference"]["positions"] >= 12
+    assert result["compared"]["undecided_share"][0] < 0.2
+    assert not os.path.exists(
+        os.path.join(tinytree.BENCH, "references", "densefirst.py"))
+
+
+def test_kinds_that_disagree_with_the_layers_fail_at_the_leaves(root):
+    """The reference says layer 0 is dense; a configuration that maps the
+    kinds to the other classes builds a sparse layer there."""
+    from harness import program, spec
+    cell = spec.Cell("tiny-densefirst.docs", root)
+    prog = cell.config["program"]
+    cls = prog["layer_class"]
+    cell.config = dict(cell.config, program=dict(prog, layer_class={
+        "dense": cls["sparse"], "sparse": cls["dense"]}))
+    with pytest.raises(RuntimeError, match=r"layer 0 \(kind 'dense'\).*"
+                       r"MixtralDecoderLayer"):
+        program.build_model(cell, 0)
+
+
+# sha256 (16 hex digits) of every group's leaves, in the order of the
+# reference's shapes, as float32 bytes: taken on PR 25's tree (one
+# ``layer_class``, one ``layer_shapes``) at tinytree's sizes, seed
+# 2**31 + 7, groups top, layer 0, layer 1
+PARENTS_GROUPS = {
+    ("mistral", "float32"): ["23c097ad2bab10cb", "ec24dadad0f1e2bc",
+                             "f10ee93e6cbc6703"],
+    ("mistral", "bfloat16"): ["b101362a087826cf", "1c4d16fd8142fba8",
+                              "bb6066634d509367"],
+    ("mixtral", "float32"): ["23c097ad2bab10cb", "f2aff05d6d42a7a3",
+                             "3a4699a8967ad4c7"],
+    ("mixtral", "bfloat16"): ["b101362a087826cf", "83937e1bfa725a5d",
+                              "98027114ade32000"],
+}
+
+
+@pytest.mark.parametrize("family,dtype", sorted(PARENTS_GROUPS))
+def test_seeded_weights_are_the_parents(root, family, dtype):
+    """A family of one kind gets the group index, the shapes and so the
+    values it got before layers could be of more than one kind."""
+    import numpy as np
+    from harness import spec, weights
+    cell = spec.Cell(f"tiny-{family}."
+                     + ("chat" if family == "mistral" else "docs"), root)
+    cfg, ref = dict(cell.config, dtype=dtype), cell.reference()
+    kinds, shapes = spec.family_layers(ref, cfg)
+    assert kinds == [None, None]
+    groups = [(weights.TOP, ref.top_shapes(cfg))] + [
+        (li, shapes[kind]) for li, kind in enumerate(kinds)]
+    sums = []
+    for group, leaves in groups:
+        vals = weights.make_group(2 ** 31 + 7, group, leaves, dtype)
+        h = hashlib.sha256()
+        for name in leaves:
+            h.update(name.encode())
+            h.update(np.asarray(vals[name].astype("float32")).tobytes())
+        sums.append(h.hexdigest()[:16])
+    assert sums == PARENTS_GROUPS[family, dtype]
+
+
 def test_same_seed_same_requests(root):
     from harness import spec, traffic
     cell = spec.Cell("tiny-mistral.chat", root)
@@ -82,9 +152,14 @@ def test_same_seed_same_requests(root):
         [(r.due, r.n_out, r.prompt.tolist()) for r in b.requests]
 
 
-def test_altered_token_is_not_correct(root, monkeypatch):
+@pytest.mark.parametrize("cell, number", [
+    ("tiny-mistral.chat", "logit_gap_max"),
+    ("tiny-mixtral.docs", "wide_gap_share")])
+def test_altered_token_is_not_correct(root, monkeypatch, cell, number):
     """The timed path broken underneath: every seventh launch's tokens are
-    altered where they are produced.  ``correct`` must come out false."""
+    altered where they are produced.  ``correct`` must come out false, by
+    the widest gap where that is compared and by the share of wide gaps
+    in the sparse cell."""
     from harness import program
     build_engine = program.build_engine
 
@@ -103,14 +178,15 @@ def test_altered_token_is_not_correct(root, monkeypatch):
         return eng
 
     monkeypatch.setattr(program, "build_engine", build_tampered)
-    result, rc = bench_run.run_cell("tiny-mistral.chat", 3, 2.0, False,
-                                    root=root, require_chip=False)
+    result, rc = bench_run.run_cell(cell, 3, 2.0, False, root=root,
+                                    require_chip=False)
     assert rc == 0 and result["correct"] is False
-    value, limit = result["compared"]["logit_gap_max"]
+    value, limit = result["compared"][number]
     assert value > limit
 
 
-@pytest.mark.parametrize("cell", ["tiny-mistral.chat", "tiny-mixtral.docs"])
+@pytest.mark.parametrize("cell", ["tiny-mistral.chat", "tiny-mixtral.docs",
+                                  "tiny-densefirst.docs"])
 def test_int8_control_is_not_correct(root, monkeypatch, cell):
     """The control (the reference computed in int8, in the program's place,
     on the same prompts and served tokens) goes through the run's own
@@ -123,7 +199,8 @@ def test_int8_control_is_not_correct(root, monkeypatch, cell):
     assert rc == 0 and result["correct"] is False
     compared, ref = result["compared"], result["reference"]
     assert ref["in_programs_place"] == "int8"
-    names = ("logit_gap_max", "logit_gap_mean")
+    names = [n for n in compared if n in ref["program"]]
+    assert len(names) == 2
     assert any(compared[n][0] > compared[n][1] for n in names)
     assert all(ref["program"][n] <= compared[n][1] for n in names)
     assert compared["unfinished_after_drain"] == [0, 0]
@@ -145,7 +222,7 @@ def test_bf16_witness_reports_its_routing(root, monkeypatch):
 def test_adding_a_cell_edited_no_existing_file(root):
     """Every data file of the real tree is byte-identical in the copy that
     runs the added cells."""
-    for sub in ("configs", "traffic", "cells", "metrics"):
+    for sub in tinytree.DATA:
         for name in os.listdir(os.path.join(tinytree.BENCH, sub)):
             if name.startswith("__"):
                 continue

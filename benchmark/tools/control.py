@@ -39,12 +39,14 @@ def main() -> int:
     witness = {}
     judge = correct.judge
 
-    def judge_with_witness(config, seed, samples, limits, unfinished):
+    def judge_with_witness(cell, seed, samples, unfinished):
         if args.witness and samples:
+            limits = cell.deploy["correct"]
             witness.update(correct.served_gap(
-                config, seed, samples,
-                float(limits.get("router_margin_min", 0.0)), args.witness))
-        return judge(config, seed, samples, limits, unfinished)
+                cell, seed, samples,
+                float(limits.get("router_margin_min", 0.0)), args.witness,
+                limits.get("wide_gap")))
+        return judge(cell, seed, samples, unfinished)
 
     correct.judge = judge_with_witness
     result, rc = run.run_cell(args.workload, args.seed, args.seconds, False)

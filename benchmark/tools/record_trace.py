@@ -38,7 +38,7 @@ def main() -> int:
     program.enable_compile_cache()
     cell = spec.Cell(args.workload)
     cfg = cell.config
-    model = program.build_model(cfg, 0)
+    model = program.build_model(cell, 0)
     eng = program.build_engine(model, cell.deploy["engine"])
     serve.warm_budgets(eng)
     rng = np.random.default_rng(0)

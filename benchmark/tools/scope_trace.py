@@ -193,7 +193,7 @@ def trace_cell(workload: str, seed: int, seconds: float, out: str,
         program.enable_compile_cache()
     cell = spec.Cell(workload, root)
     cfg, deploy = cell.config, cell.deploy
-    model = program.build_model(cfg, seed)
+    model = program.build_model(cell, seed)
     eng = program.build_engine(model, deploy["engine"])
     serve.warm_budgets(eng)
     shutil.rmtree(out, ignore_errors=True)

@@ -44,7 +44,7 @@ def main() -> int:
     program.enable_compile_cache()
     cell = spec.Cell(args.workload)
     cfg = cell.config
-    model = program.build_model(cfg, args.seed)
+    model = program.build_model(cell, args.seed)
     eng = program.build_engine(
         model, dict(cell.deploy["engine"], **json.loads(args.engine)))
     serve.warm_budgets(eng)
