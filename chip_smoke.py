@@ -309,6 +309,76 @@ def ragged_check(heads: int, kv_heads: int, head_dim: int,
     return out
 
 
+def latent_check(heads: int = 128, kv_lora: int = 512, rope: int = 64,
+                 block_size: int = 128, prefix: int = 8192,
+                 chunk: int = 512, decodes: int = 16,
+                 expect_kernels: bool = True) -> dict:
+    """One latent-attention (MLA, absorbed form) launch at DeepSeek-V2's
+    widths, bf16: a ``chunk``-row span over a ``prefix`` of cached rows
+    beside ``decodes`` one-row spans, the Pallas launch against the XLA
+    fallback in float32.  The fallback gathers each token's whole
+    context, so it goes span by span and the chunk in slices."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.paged_attention import _ragged_latent_attention_xla
+    from paddle_tpu.ops.pallas_kernels import (
+        _ragged_latent_attention_pallas, latent_row_width)
+
+    rng = np.random.RandomState(3)
+    row = latent_row_width(kv_lora, rope)
+    width = -(-(prefix + chunk) // block_size)
+    spans = decodes + 1
+    num_blocks = spans * width
+    scale = (kv_lora // 4 + rope) ** -0.5
+    pool = rng.randn(num_blocks + 1, block_size, row)
+    pool[..., kv_lora + rope:] = 0.0
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    pages = rng.permutation(num_blocks).reshape(spans, width).astype(
+        np.int32)
+    kv = [int(rng.randint(1, width * block_size + 1))
+          for _ in range(decodes)]
+    q_len = np.asarray([1] * decodes + [chunk], np.int32)
+    kv_len = np.asarray(kv + [prefix + chunk], np.int32)
+    q_off = np.concatenate([[0], np.cumsum(q_len)[:-1]]).astype(np.int32)
+    tokens = int(q_len.sum())
+    q = rng.randn(tokens, heads, row) / 8.0
+    q[..., kv_lora + rope:] = 0.0
+    q = jnp.asarray(q, jnp.bfloat16)
+
+    launch = _ragged_latent_attention_pallas if expect_kernels \
+        else _ragged_latent_attention_xla
+    got = np.asarray(launch(q, pool, jnp.asarray(pages),
+                            jnp.asarray(q_off), jnp.asarray(q_len),
+                            jnp.asarray(kv_len), scale, kv_lora),
+                     np.float32)
+    assert np.isfinite(got).all(), "latent: non-finite output"
+
+    @jax.jit
+    def reference(qs, pool, bt, off, ql, kl):
+        return _ragged_latent_attention_xla(
+            qs.astype(jnp.float32), pool.astype(jnp.float32), bt, off,
+            ql, kl, scale, kv_lora)
+
+    ref = [np.asarray(reference(
+        q[:decodes], pool, jnp.asarray(pages[:decodes]),
+        jnp.asarray(q_off[:decodes]), jnp.asarray(q_len[:decodes]),
+        jnp.asarray(kv_len[:decodes])))]
+    step = 32
+    with jax.default_matmul_precision("highest"):
+        for a in range(0, chunk, step):
+            n = min(step, chunk - a)
+            ref.append(np.asarray(reference(
+                q[decodes + a:decodes + a + n], pool,
+                jnp.asarray(pages[decodes:]), jnp.zeros((1,), jnp.int32),
+                jnp.asarray([n], jnp.int32),
+                jnp.asarray([prefix + a + n], jnp.int32))))
+    err = _rel_err(got, np.concatenate(ref, axis=0))
+    assert err <= RAGGED_TOL, (
+        f"latent: rel err {err:.3e} > declared {RAGGED_TOL}")
+    return {"tokens": tokens, "row": row, "pages_a_span": width,
+            "rel_err": float(f"{err:.3e}"), "tol": RAGGED_TOL}
+
+
 # ---------------------------------------------------------------------------
 # phase 1: serve — ContinuousBatchingEngine(mixed_step=True)
 # ---------------------------------------------------------------------------
@@ -538,6 +608,10 @@ def main() -> int:
     train_kw = dict(batch=4, seq=2048, steps=8)
 
     report("serve", serve_phase(cfg, **serve_kw))
+    gc.collect()
+    # the latent (MLA) launch at DeepSeek-V2's widths: a broken lowering
+    # shows here in a minute and not in a cell
+    report("latent_check", latent_check())
     gc.collect()
     report("flash_check", flash_check(
         train_kw["batch"], cfg.num_attention_heads, train_kw["seq"],

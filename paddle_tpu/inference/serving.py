@@ -390,6 +390,7 @@ class ContinuousBatchingEngine:
         # mixed pack is routed to top_k experts in each MoE layer —
         # static per pack, counted host-side next to the collectives
         self._moe_layers = (cfg.num_hidden_layers
+                            - int(getattr(cfg, "first_k_dense_replace", 0))
                             if getattr(cfg, "num_local_experts", 0)
                             else 0)
         self._moe_topk = int(getattr(cfg, "num_experts_per_tok", 0))
@@ -398,11 +399,41 @@ class ContinuousBatchingEngine:
         self.head_dim = cfg.hidden_size // cfg.num_attention_heads
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.kv_quant = kv_dtype == "int8"
+        # a page's geometry comes from the layers' ATTENTION: a latent
+        # (MLA) model caches one row a token, read by every head, in
+        # one pool a layer.  What moves, shards or converts K/V pages
+        # and is not taught that row refuses here, each with its name.
+        from ..jit.serving_step import _inner_model, latent_attention
+        self.latent = latent_attention(model)
+        if self.latent is not None:
+            for on, what in (
+                    (not mixed_step, "the split prefill/decode path "
+                     "(pass mixed_step=True)"),
+                    (kv_dtype == "int8", "kv_dtype='int8' (its scales "
+                     "are per kv head)"),
+                    (self.tp is not None, "a mesh (tp/cp/ep shard "
+                     "pools over kv heads or slots)"),
+                    (draft_model is not None, "a draft model"),
+                    (role != "mixed", "role=%r (page migration moves "
+                     "K and V pages)" % (role,)),
+                    (enable_prefix_cache, "enable_prefix_cache "
+                     "(copy-on-write and the host tier copy K and V "
+                     "pages)")):
+                if on:
+                    raise ValueError(
+                        "ContinuousBatchingEngine: %s is not taught "
+                        "the latent (MLA) cache row of %s"
+                        % (what, type(model).__name__))
+        latent_width = self.latent.latent_row \
+            if self.latent is not None else None
         self.caches = [
             PagedKVCache(num_blocks, block_size,
                          cfg.num_key_value_heads, self.head_dim, dtype,
-                         sink_block=True, kv_dtype=kv_dtype)
-            for _ in range(cfg.num_hidden_layers)]
+                         sink_block=True, kv_dtype=kv_dtype,
+                         latent_width=getattr(
+                             getattr(layer, "self_attn", None),
+                             "latent_row", None))
+            for layer in _inner_model(model).layers]
         # per-channel absmax PTQ: quantize ONCE at construction; every
         # step consumes the same int8+scales tree via dequant-on-use
         if weight_quant == "int8":
@@ -779,6 +810,26 @@ class ContinuousBatchingEngine:
         # resolve the 'dropped' child eagerly so /metrics always shows
         # the 0 that documents droplessness
         self._m_moe_dropped = self._m_moe_dispatch.labels(fate="dropped")
+        # a bank that holds a share of its router's experts: the rows
+        # each HELD expert was given (the step's own count, fetched
+        # with the tokens); the rest of ``routed`` landed elsewhere
+        self._m_moe_expert_load = r.counter(
+            "serving_moe_expert_load_total",
+            "token->expert assignments that landed on each expert this "
+            "engine holds (index within the held bank), summed over "
+            "the MoE layers", labels=("expert",))
+        self._m_moe_expert = [
+            self._m_moe_expert_load.labels(expert=str(e))
+            for e in range(max(0, (self.mixed.n_stats
+                                   if self.mixed is not None else 0) - 2))]
+        self._m_latent_row = r.gauge(
+            "serving_kv_latent_row_bytes",
+            "bytes one token's cached latent row takes in one layer's "
+            "pool of the most recently constructed engine, padding to "
+            "whole lanes included (0 = K and V pools, no latent row)")
+        self._m_latent_row.set(
+            latent_width * self.caches[0].key_cache.dtype.itemsize
+            if latent_width else 0)
         self._m_ep_collective = r.counter(
             "serving_ep_collective_bytes_total",
             "per-chip bytes moved by the expert-parallel dispatch "
@@ -1079,7 +1130,13 @@ class ContinuousBatchingEngine:
           attention computes in each layer (``MixedStep.attn_rows``):
           beside ``tokens`` x the GQA group size it says how much of
           the launch is real.  0 on the split path, which has no ragged
-          launch.
+          launch.  For a latent model the rows are tokens x heads.
+        - ``moe_rows``, ``moe_rows_top``: where the bank holds a share
+          of its router's experts, the assignments that landed on held
+          experts, summed over the routed layers, and the fullest held
+          expert's; counted by the step and fetched with the token
+          ids.  0 elsewhere (every assignment lands: ``tokens`` x
+          ``top_k`` x layers).
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.
@@ -1106,6 +1163,8 @@ class ContinuousBatchingEngine:
             attn_rows=(self.mixed.attn_rows(rec["budget"], spans[:, 1])
                        if self.mixed is not None and rec["budget"]
                        else 0),
+            moe_rows=rec.get("moe_rows", 0),
+            moe_rows_top=rec.get("moe_rows_top", 0),
             admitted=tuple(self._admitted), running=running,
             waiting=len(self.waiting), compiled=rec["compiled"])
 
@@ -1167,7 +1226,8 @@ class ContinuousBatchingEngine:
         pre-check this so they never extract a buffer no target can
         take (a failed migration degrades to paying the prefill
         twice)."""
-        if self.tp is not None or self.draft_step is not None:
+        if self.tp is not None or self.draft_step is not None \
+                or self.latent is not None:
             return None
         return (len(self.caches),) + self.caches[0].page_geometry()
 
@@ -1241,6 +1301,10 @@ class ContinuousBatchingEngine:
                 "page migration is single-chip for now: a tensor-"
                 "parallel engine's pools are head-sharded and the "
                 "batched inject moves whole pages")
+        if self.latent is not None:
+            raise ValueError(
+                "page migration is not taught the latent (MLA) cache "
+                "row: a KVPageBuffer carries K and V pages per kv head")
         if self.draft_step is not None:
             raise ValueError(
                 "a speculative engine cannot accept migrated pages: "
@@ -1975,6 +2039,11 @@ class ContinuousBatchingEngine:
             # top_k experts per MoE layer, none are dropped
             self._m_moe_routed.inc(total * self._moe_topk
                                    * self._moe_layers)
+        if self.mixed.n_stats:
+            stats = self.mixed.last_stats
+            rec.update(moe_rows=int(stats[0]), moe_rows_top=int(stats[1]))
+            for child, n in zip(self._m_moe_expert, stats[2:]):
+                child.inc(int(n))
         if traced:
             # first trace of this budget: count it, keep the compile
             # warmup out of every latency histogram

@@ -114,7 +114,16 @@ __all__ = ["DecodeStep", "PrefillStep", "MixedStep", "prefill_scatter",
 STEP_SCOPES = frozenset((
     "embed", "attn.qkv", "attn.rope", "attn.kv_write", "attn.kernel",
     "attn.out", "ffn", "moe.gate", "moe.dispatch", "moe.experts",
-    "moe.combine", "ep.all_to_all", "lm_head", "sample"))
+    "moe.combine", "ep.all_to_all", "lm_head", "sample",
+    # a latent-attention layer (MLA) and a held share of routed experts
+    "attn.q_lora", "attn.kv_latent", "attn.absorb", "attn.unabsorb",
+    "moe.shared", "moe.sort"))
+
+# Kernels XLA:TPU makes itself and names itself (their ``op_name`` is the
+# kernel's, the path of scopes is gone): instruction-name prefix -> scope.
+# ``jax.lax.ragged_dot`` becomes ``ragged-dot-metadata`` (tile tables from
+# the group sizes) and ``ragged-dot-none`` (the grouped matmul).
+_XLA_KERNEL_SCOPES = {"ragged-dot": "moe.experts"}
 
 _HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -148,6 +157,9 @@ def hlo_op_scopes(hlo_text: str) -> Dict[str, Optional[str]]:
         own[name] = next(
             (part for part in reversed(meta.group(1).split("/"))
              if part in STEP_SCOPES), None) if meta else None
+        if own[name] is None:
+            own[name] = next((s for prefix, s in _XLA_KERNEL_SCOPES.items()
+                              if name.startswith(prefix)), None)
         c = _HLO_CALLS.search(line)
         if c:
             calls[name] = c.group(1)
@@ -193,15 +205,43 @@ def _inner_model(model):
     ``embed_tokens / layers / norm`` surface, which is everything the
     traced bodies touch; per-layer FFN dispatch branches on the LAYER
     (``block_sparse_moe`` vs ``mlp``), not the wrapper."""
-    inner = getattr(model, "llama", None)
-    if inner is None:
-        inner = getattr(model, "mixtral", None)
-    if inner is None:
+    for name in ("llama", "mixtral", "deepseek"):
+        inner = getattr(model, name, None)
+        if inner is not None:
+            return inner
+    raise ValueError(
+        "serving steps need a LlamaForCausalLM-shaped model (an inner "
+        ".llama, .mixtral or .deepseek decoder stack); got %r"
+        % type(model).__name__)
+
+
+def _step_body(module, default: str) -> str:
+    """The per-layer function of the step that a layer's attention or
+    FFN module asks for: its own ``serving_body`` attribute (declared
+    by the module's class beside what that body needs of it, as
+    ``latent_row`` is), else ``default``.  A new kind of layer is a new
+    name and a new body (ROADMAP D3), not a branch inside another's."""
+    return getattr(module, "serving_body", default)
+
+
+def latent_attention(model):
+    """The model's first attention module that caches ONE latent row a
+    token (``serving_body`` "mla": ``latent_row`` columns, no kv-head
+    axis), else ``None``.  Whatever moves or converts K/V pages refuses
+    such a model."""
+    for layer in _inner_model(model).layers:
+        at = getattr(layer, "self_attn", None)
+        if _step_body(at, "gqa") == "mla":
+            return at
+    return None
+
+
+def _refuse_latent(model, who: str) -> None:
+    if latent_attention(model) is not None:
         raise ValueError(
-            "serving steps need a LlamaForCausalLM-shaped model (an "
-            "inner .llama or .mixtral decoder stack); got %r"
-            % type(model).__name__)
-    return inner
+            "%s (the split prefill/decode path) is not taught the "
+            "latent (MLA) cache row: serve this model with "
+            "mixed_step=True" % who)
 
 
 def _embed(llama, tokens, tp: Optional[TPContext]) -> Tensor:
@@ -249,13 +289,51 @@ def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext]) -> Tensor:
     return Tensor._from_value(out.reshape(v.shape))
 
 
-def _ffn(layer, h2: Tensor, tp: Optional[TPContext]) -> Tensor:
-    """Per-layer FFN dispatch shared by all three traced bodies: the
-    Megatron-sharded dense MLP (+ its psum boundary) for llama layers,
-    the fused MoE path for Mixtral layers."""
-    if hasattr(layer, "block_sparse_moe"):
-        return _moe_ffn(layer.block_sparse_moe, h2, tp)
-    return _tp_psum(layer.mlp(h2), tp)
+def _held_moe_ffn(blk, h2: Tensor, real, loads: Optional[list]) -> Tensor:
+    """A bank that holds a SHARE of its router's experts, with shared
+    experts beside it (``ops.moe_gate.moe_ffn_held``: group-limited
+    routing over the router's full width, the held assignments sorted
+    and multiplied by a grouped product sized by real rows).  ``real
+    [T]`` marks the pack's real tokens: its padding is given to no
+    expert and counted in no load.  The rows each held expert was
+    given go to ``loads``."""
+    v = h2._value
+    with jax.named_scope("moe.shared"):
+        shared = blk.shared_experts(h2)
+    out, load = blk.routed(v.reshape(-1, v.shape[-1]), real)
+    if loads is not None:
+        loads.append(load)
+    return shared + Tensor._from_value(out.reshape(v.shape))
+
+
+def _ffn_module(layer):
+    blk = getattr(layer, "block_sparse_moe", None)
+    return layer.mlp if blk is None else blk
+
+
+def _ffn(layer, h2: Tensor, tp: Optional[TPContext], real=None,
+         loads: Optional[list] = None) -> Tensor:
+    """Per-layer FFN dispatch shared by all three traced bodies, by the
+    body the layer's FFN module declares (``_step_body``): the fused
+    dense-dispatch MoE, the sorted held-share MoE, and for a module
+    that declares none its own forward (a Megatron-sharded dense MLP)
+    with its psum boundary."""
+    blk = _ffn_module(layer)
+    body = _step_body(blk, "dense")
+    if body == "moe_dense_dispatch":
+        return _moe_ffn(blk, h2, tp)
+    if body == "moe_held":
+        return _held_moe_ffn(blk, h2, real, loads)
+    return _tp_psum(blk(h2), tp)
+
+
+def _real_rows(T: int, q_offsets, q_lens):
+    """bool ``[T]``: the rows of a budget-``T`` pack that some span
+    owns (the rest is the pack's padding)."""
+    tok = jnp.arange(T, dtype=jnp.int32)[:, None]
+    first = q_offsets.astype(jnp.int32)[None, :]
+    return jnp.any((tok >= first) & (tok < first + q_lens[None, :]),
+                   axis=1)
 
 
 def _tp_logits(logits: Tensor, tp: Optional[TPContext],
@@ -825,6 +903,7 @@ class PrefillStep:
 
     def _build(self, C: int):
         from ..autograd.tape import no_grad
+        _refuse_latent(self.model, "PrefillStep")
         from ..ops.paged_attention import (chunk_prefill_attention,
                                            write_chunk_kv,
                                            write_chunk_kv_q8)
@@ -1125,6 +1204,35 @@ class MixedStep:
             from .spmd import validate_cp_serving
             validate_cp_serving(self._tp.cp_degree,
                                 caches[0].block_size, quantized_kv=True)
+        # a latent-attention model (MLA): one pool a layer, one row a
+        # token; what shards, quantizes or drafts K/V pages is not
+        # taught that row
+        self._latent = latent_attention(model)
+        for li, (layer, c) in enumerate(zip(_inner_model(model).layers,
+                                            caches)):
+            at = getattr(layer, "self_attn", None)
+            if (_step_body(at, "gqa") == "mla") != bool(
+                    getattr(c, "latent", False)):
+                raise ValueError(
+                    "MixedStep: layer %d's pool does not fit its "
+                    "attention: a latent-attention layer needs a "
+                    "latent pool (PagedKVCache(latent_width=its "
+                    "latent_row)), any other K and V pools" % li)
+        if self._latent is not None:
+            for on, what in ((self._tp is not None, "a mesh (tp/cp/ep)"),
+                             (self.spec_k or self.return_probs,
+                              "speculative decoding")):
+                if on:
+                    raise ValueError(
+                        "MixedStep: %s is not taught the latent "
+                        "(MLA) cache row" % what)
+        # a bank that holds a share of its router's experts reports the
+        # rows its experts were given: [moe_rows, moe_rows_top, load x El]
+        # int32 behind the sampled tokens, in the same fetch
+        held = [_ffn_module(l) for l in _inner_model(model).layers]
+        held = [b for b in held if _step_body(b, "dense") == "moe_held"]
+        self.n_stats = 2 + int(held[0].w_gate.shape[0]) if held else 0
+        self.last_stats = None         # the last call's tail (np int32)
         self._wq = weight_qparams
         self._q8_gather = bool(quant_collectives)
         _ensure_quant_specs(self._tp, weight_qparams)
@@ -1149,15 +1257,23 @@ class MixedStep:
                                          quant_gather=self._q8_gather)
 
     def attn_rows(self, T: int, q_lens) -> int:
-        """The q rows per kv head that a budget-``T`` launch carrying
+        """The q rows per kv head (for a latent model: per cached row,
+        i.e. tokens x heads) that a budget-``T`` launch carrying
         spans of lengths ``q_lens`` computes in each layer's attention.
         The Pallas launch computes whole tiles (``ops/pallas_kernels.
         ragged_attn_rows``: a decode span one tile, a chunk
         ceil(q_len / tile)); the XLA reference and the context-parallel
         partial compute every row of the budget."""
-        from ..ops.pallas_kernels import (ragged_attn_rows,
+        from ..ops.pallas_kernels import (latent_attn_rows,
+                                          ragged_attn_rows,
                                           ragged_tile_geometry)
         cfg = self.cfg
+        if self._latent is not None:
+            # one cached row for all heads: the rows are tokens x heads
+            # (the Pallas launch walks whole sub-tiles of real tokens)
+            H = cfg.num_attention_heads
+            return latent_attn_rows(q_lens, H) if self.use_pallas \
+                else T * H
         deg = self._tp.degree if self._tp is not None else 1
         H = cfg.num_attention_heads // deg
         Hkv = cfg.num_key_value_heads // deg
@@ -1174,9 +1290,13 @@ class MixedStep:
     def _build(self, T: int):
         from ..autograd.tape import no_grad
         from ..ops.paged_attention import (_ragged_attention_xla,
+                                           _ragged_latent_attention_xla,
                                            write_ragged_kv,
-                                           write_ragged_kv_q8)
-        from ..ops.pallas_kernels import (rope_qkv_epilogue,
+                                           write_ragged_kv_q8,
+                                           write_ragged_latent)
+        from ..ops.pallas_kernels import (_ragged_latent_attention_pallas,
+                                          rope_qkv_epilogue,
+                                          rope_interleaved,
                                           rope_tables_for_positions)
         model = self.model
         cfg = self.cfg
@@ -1191,6 +1311,7 @@ class MixedStep:
         D = cfg.hidden_size // cfg.num_attention_heads
         scale = 1.0 / math.sqrt(D)
         use_pallas = self.use_pallas
+        n_stats = self.n_stats
         quant_kv = self._quant_kv
         q8_gather = self._q8_gather
         pdtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -1286,15 +1407,27 @@ class MixedStep:
                     x = _embed(llama, tokens[None, :], tp)     # [1, T, h]
                     if cfg.dtype == "bfloat16":
                         x = x.astype("bfloat16")
-                # rope tables built ONCE per step (positions are
-                # layer-invariant) and consumed by the fused epilogue
-                # in every layer
-                with jax.named_scope("attn.rope"):
-                    cos_t, sin_t = rope_tables_for_positions(
-                        positions, D, cfg.rope_theta)
-                for li, (layer, kc, vc) in enumerate(
-                        zip(llama.layers, kcs, vcs)):
+                # rope tables built ONCE per step and kind of
+                # attention (positions are layer-invariant) and
+                # consumed by every layer of that kind
+                rope_tables = {}
+
+                def rope_of(body, at):
+                    if body not in rope_tables:
+                        with jax.named_scope("attn.rope"):
+                            rope_tables[body] = (
+                                at.rope_tables(positions)
+                                if body == "mla" else
+                                rope_tables_for_positions(
+                                    positions, D, cfg.rope_theta))
+                    return rope_tables[body]
+
+                def gqa_attention(layer, x, li, kc, vc):
+                    """Grouped-query attention over K and V pools
+                    (Llama, Mixtral): x plus the block's output, and
+                    the pools (and their scales) as written."""
                     at = layer.self_attn
+                    cos_t, sin_t = rope_of("gqa", at)
                     with jax.named_scope("attn.qkv"):
                         h = layer.input_layernorm(x)
                         q = at.q_proj(h).reshape([1, T, H, D])
@@ -1314,24 +1447,83 @@ class MixedStep:
                                 kv_, v._value[0], kc, vc, kss[li],
                                 vss[li], dest_blocks, dest_offsets,
                                 k_amax=k_amax, v_amax=v_amax)
-                            new_kss.append(ks)
-                            new_vss.append(vs)
                         else:
                             ks = vs = None
                             kc, vc = write_ragged_kv(
                                 kv_, v._value[0], kc, vc,
                                 dest_blocks, dest_offsets)
-                    new_kcs.append(kc)
-                    new_vcs.append(vc)
                     out = attn(qv, kc, vc, bt, q_offsets,
                                q_lens, kv_lens, ks, vs)
                     with jax.named_scope("attn.out"):
                         out = Tensor._from_value(
                             out.reshape(1, T, H * D))
                         x = x + _tp_psum(at.o_proj(out), tp)
+                    return x, kc, vc, ks, vs
+
+                def mla_attention(layer, x, li, kc, vc):
+                    """Latent attention in its ABSORBED form over the
+                    one pool of ``[c_kv | k_rope | 0]`` rows: the
+                    cache is never expanded, whatever the span."""
+                    at = layer.self_attn
+                    cos_t, sin_t = rope_of("mla", at)
+                    pad = at.latent_row - at.kv_lora - at.rope
+                    with jax.named_scope("attn.q_lora"):
+                        h = layer.input_layernorm(x)
+                        q_nope, q_r = at.queries(h)
+                    with jax.named_scope("attn.kv_latent"):
+                        c_kv, k_r = at.latent(h)
+                    with jax.named_scope("attn.rope"):
+                        q_r = rope_interleaved(
+                            q_r[0], cos_t[:, None, :], sin_t[:, None, :])
+                        k_r = rope_interleaved(k_r[0], cos_t, sin_t)
+                    wk, wv = at.kv_b()
+                    with jax.named_scope("attn.absorb"):
+                        qt = jnp.einsum("thn,chn->thc", q_nope[0], wk)
+                        q_abs = jnp.concatenate(
+                            [qt, q_r, jnp.zeros((T, H, pad), qt.dtype)],
+                            axis=-1)
+                    with jax.named_scope("attn.kv_write"):
+                        rows = jnp.concatenate(
+                            [c_kv[0], k_r,
+                             jnp.zeros((T, pad), k_r.dtype)], axis=-1)
+                        kc = write_ragged_latent(rows, kc, dest_blocks,
+                                                 dest_offsets)
+                    if use_pallas:
+                        # the wrapper names its own attn.kernel scope
+                        o_lat = _ragged_latent_attention_pallas(
+                            q_abs, kc, bt, q_offsets, q_lens, kv_lens,
+                            at.softmax_scale, at.kv_lora)
+                    else:
+                        with jax.named_scope("attn.kernel"):
+                            o_lat = _ragged_latent_attention_xla(
+                                q_abs, kc, bt, q_offsets, q_lens,
+                                kv_lens, at.softmax_scale, at.kv_lora)
+                    with jax.named_scope("attn.unabsorb"):
+                        out = jnp.einsum("thc,chv->thv", o_lat, wv)
+                    with jax.named_scope("attn.out"):
+                        out = Tensor._from_value(
+                            out.reshape(1, T, H * at.v_dim))
+                        x = x + at.o_proj(out)
+                    return x, kc, None, None, None
+
+                attention = {"gqa": gqa_attention, "mla": mla_attention}
+                loads = []      # [El] int32 a held-share MoE layer
+                real = None
+                if n_stats:
+                    with jax.named_scope("moe.sort"):
+                        real = _real_rows(T, q_offsets, q_lens)
+                for li, (layer, kc, vc) in enumerate(
+                        zip(llama.layers, kcs, vcs)):
+                    body = attention[_step_body(layer.self_attn, "gqa")]
+                    x, kc, vc, ks, vs = body(layer, x, li, kc, vc)
+                    new_kcs.append(kc)
+                    new_vcs.append(vc)
+                    if quant_kv:
+                        new_kss.append(ks)
+                        new_vss.append(vs)
                     with jax.named_scope("ffn"):
                         h2 = layer.post_attention_layernorm(x)
-                        x = x + _ffn(layer, h2, tp)
+                        x = x + _ffn(layer, h2, tp, real, loads)
                 with jax.named_scope("lm_head"):
                     x = llama.norm(x)
                     # only each span's sampled rows reach the LM head:
@@ -1397,12 +1589,30 @@ class MixedStep:
                                            kv_lens)
                 else:
                     nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
+                if n_stats:
+                    # rows the held experts were given, summed over the
+                    # layers: total, the fullest expert's, each one's
+                    load = sum(loads[1:], loads[0]).astype(jnp.int32)
+                    nxt = jnp.concatenate(
+                        [nxt, jnp.sum(load)[None], jnp.max(load)[None],
+                         load])
                 if return_probs:
                     return (nxt, filtered_probs(lv, s_t, s_k, s_p),
                             tuple(new_kcs), tuple(new_vcs),
                             tuple(new_kss), tuple(new_vss))
                 return (nxt, tuple(new_kcs), tuple(new_vcs),
                         tuple(new_kss), tuple(new_vss))
+
+        if n_stats:
+            # XLA:TPU's pass that rewrites 64-bit element types does not
+            # know ragged-dot, and stops at one in a module that holds
+            # any (the argmax's i64 is enough): a step with a grouped
+            # product is traced with x64 off, its operands being 32-bit
+            traced_x64 = step
+
+            def step(*args):                           # noqa: F811
+                with jax.enable_x64(False):
+                    return traced_x64(*args)
 
         if spec_k and sampling:
             fn, donate = step, (3, 4, 5, 6)
@@ -1543,6 +1753,9 @@ class MixedStep:
         self.t_dispatch = time.perf_counter()
         with jax.profiler.TraceAnnotation("engine.fetch"):
             nxt = np.asarray(out[0])
+            if self.n_stats:
+                nxt, self.last_stats = (nxt[:self.max_spans],
+                                        nxt[self.max_spans:])
             if self.spec_k:
                 return nxt, np.asarray(out[1])
         if self.return_probs:
@@ -1608,6 +1821,7 @@ class DecodeStep:
 
     def _build(self):
         from ..autograd.tape import no_grad
+        _refuse_latent(self.model, "DecodeStep")
         from ..ops.paged_attention import (_paged_attention_pallas,
                                            _paged_attention_xla,
                                            write_decode_kv,

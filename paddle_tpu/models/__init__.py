@@ -17,3 +17,5 @@ from .qwen import (Qwen2Config, Qwen2Model, Qwen2ForCausalLM,
 from .mixtral import (MixtralConfig, MixtralModel, MixtralForCausalLM,
                       MixtralPretrainingCriterion, MixtralSparseMoeBlock,
                       mixtral_tiny_config, shard_mixtral)
+from .deepseek_v2 import (DeepseekV2Config, DeepseekV2Model,
+                          DeepseekV2ForCausalLM, deepseek_v2_tiny_config)

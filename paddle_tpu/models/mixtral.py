@@ -48,6 +48,10 @@ class MixtralSparseMoeBlock(Layer):
     dense-dispatch einsum pipeline: route -> capacity buffers [E, C, D]
     -> three batched expert einsums -> weighted combine."""
 
+    # the serving step's FFN body for this module
+    # (``jit/serving_step.py``): the fused dense-dispatch ``moe_ffn``
+    serving_body = "moe_dense_dispatch"
+
     def __init__(self, config: MixtralConfig):
         super().__init__()
         D = config.hidden_size

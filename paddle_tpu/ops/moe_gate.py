@@ -26,6 +26,7 @@ import jax.numpy as jnp
 __all__ = [
     "topk_gate", "assignment_slots", "dispatch_to_buffers",
     "grouped_expert_swiglu", "combine_from_buffers", "moe_ffn",
+    "group_limited_topk", "moe_ffn_held",
 ]
 
 
@@ -126,6 +127,12 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
     """
     n, d = x.shape
     e_local = wg.shape[0]
+    if gate_w.shape[-1] != e_local * max(1, ep_degree):
+        raise ValueError(
+            "moe_ffn: the router is %d experts wide and the bank holds "
+            "%d (x ep %d): a bank narrower than its router has to be "
+            "told which experts it holds — moe_ffn_held(first_held=...)"
+            % (gate_w.shape[-1], e_local, max(1, ep_degree)))
     if ep_axis is None or ep_degree <= 1:
         with jax.named_scope("moe.gate"):
             logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
@@ -172,3 +179,83 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
         out_r = combine_from_buffers(back, top_i, slot,
                                      top_w).astype(x.dtype)
         return jax.lax.all_gather(out_r, ep_axis, axis=0, tiled=True)
+
+
+def group_limited_topk(scores, k, n_group, topk_group):
+    """Group-limited greedy top-k over ``scores [N, E]`` (any float;
+    the caller's softmax): the experts lie in ``n_group`` groups of
+    ``E / n_group`` consecutive ones, a group scores as its best
+    expert, the ``topk_group`` best groups are kept, and the ``k`` best
+    experts among the kept groups are chosen.  Returns ``(top_s [N, k]``
+    the chosen experts' own scores, not renormalised, ``top_i int32
+    [N, k])``."""
+    n, e = scores.shape
+    if e % n_group:
+        raise ValueError("group_limited_topk: %d experts do not divide "
+                         "into %d groups" % (e, n_group))
+    per = e // n_group
+    group_s = jnp.max(scores.reshape(n, n_group, per), axis=-1)
+    _, group_i = jax.lax.top_k(group_s, topk_group)           # [N, g]
+    kept = jnp.any(group_i[..., None] == jnp.arange(n_group), axis=1)
+    masked = jnp.where(jnp.repeat(kept, per, axis=1), scores, -jnp.inf)
+    top_s, top_i = jax.lax.top_k(masked, k)
+    return top_s, top_i.astype(jnp.int32)
+
+
+def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
+                 n_group=1, topk_group=1, routed_scale=1.0, valid=None):
+    """Dropless routed-expert FFN over ``x [N, D]`` for a bank that
+    holds a SHARE of the experts its router scores: ``gate_w [D, E]``
+    is the router at its full width, ``wg/wu [El, D, M]`` and ``wd [El,
+    M, D]`` the experts ``first_held .. first_held + El`` held here.
+
+    The router (softmax in float32, group-limited top-k, the weights
+    ``score * routed_scale``, never renormalised over the k) is computed
+    over all E; the assignments that land on held experts are
+    sorted by expert and multiplied by a grouped product sized by the
+    rows that are real (``jax.lax.ragged_dot``: on the TPU XLA's own
+    grouped-matmul kernel): one static ``[N * k, D]`` buffer of sorted
+    rows, never one a held expert.  What the experts held elsewhere
+    would add is left out: the result is this bank's part of the sum.
+    ``valid`` (bool ``[N]``, all true if ``None``) marks the rows that
+    are tokens: a row of padding is given to no expert, its output is
+    0 and no load counts it.
+
+    Returns ``(out [N, D] in x's type, load int32 [El])``: the rows
+    each held expert was given."""
+    n, d = x.shape
+    e_held = wg.shape[0]
+    e_all = gate_w.shape[-1]
+    if not 0 <= first_held <= e_all - e_held:
+        raise ValueError(
+            "moe_ffn_held: experts %d..%d held of a router %d wide"
+            % (first_held, first_held + e_held, e_all))
+    with jax.named_scope("moe.gate"):
+        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = group_limited_topk(probs, top_k, n_group,
+                                          topk_group)
+        top_w = top_w * jnp.float32(routed_scale)
+    with jax.named_scope("moe.sort"):
+        local = top_i.reshape(-1) - first_held                # [N*k]
+        held = (local >= 0) & (local < e_held)
+        if valid is not None:
+            held &= jnp.repeat(valid, top_k)
+        key = jnp.where(held, local, e_held)    # not held: sorted last
+        order = jnp.argsort(key, stable=True)
+        load = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
+                       axis=0)[:e_held]
+        xs = x[order // top_k]                                # [N*k, D]
+    with jax.named_scope("moe.experts"):
+        g = jax.lax.ragged_dot(xs, wg, load)
+        u = jax.lax.ragged_dot(xs, wu, load)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        ys = jax.lax.ragged_dot(h, wd, load)                  # [N*k, D]
+    with jax.named_scope("moe.combine"):
+        # back to assignment order; a row no held expert computed is
+        # whatever the grouped product left there: masked, not weighted
+        back = jnp.argsort(order)
+        y = jnp.where(held[:, None], ys[back].astype(jnp.float32), 0.0)
+        out = jnp.sum(y.reshape(n, top_k, d)
+                      * top_w[..., None], axis=1).astype(x.dtype)
+    return out, load

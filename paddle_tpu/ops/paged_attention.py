@@ -36,7 +36,8 @@ from .online_softmax import merge_partials, online_softmax_update
 __all__ = ["PagedKVCache", "KVPageBuffer",
            "paged_attention", "write_kv_to_cache",
            "write_decode_kv", "write_prefill_kv", "write_chunk_kv",
-           "write_ragged_kv", "chunk_prefill_attention",
+           "write_ragged_kv", "write_ragged_latent",
+           "chunk_prefill_attention",
            "chunk_prefill_attention_partial",
            "ragged_paged_attention",
            "write_decode_kv_q8", "write_chunk_kv_q8",
@@ -155,13 +156,30 @@ class PagedKVCache:
     sharing (``share_blocks``), copy-on-write (``serving_step.
     copy_block`` copies the scale row with the page) and refcounted
     release all carry scales with their pages for free.
+
+    ``latent_width=R`` makes the pool a LATENT one (MLA): ``key_cache``
+    is ``[phys, block_size, R]``, one row a token that every query head
+    reads (keys the whole row, values its leading columns), and
+    ``value_cache`` is ``None``.  The page bookkeeping is the same.
     """
 
     def __init__(self, num_blocks: int, block_size: int, num_kv_heads: int,
                  head_dim: int, dtype=jnp.float32, sink_block: bool = False,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None,
+                 latent_width: Optional[int] = None):
         self.num_blocks = num_blocks
         self.block_size = block_size
+        # a LATENT pool (MLA): one row of ``latent_width`` values a
+        # token, read by every query head — no kv-head axis, and no
+        # second pool (values are columns of the same row)
+        self.latent = latent_width is not None
+        if self.latent:
+            if kv_dtype == "int8":
+                raise ValueError(
+                    "PagedKVCache: a latent pool (latent_width=) is not "
+                    "quantized — kv_dtype='int8' scales pages per kv "
+                    "head, and a latent row has none")
+            num_kv_heads, head_dim = 1, int(latent_width)
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         if kv_dtype not in (None, "float32", "bfloat16", "int8"):
@@ -180,9 +198,11 @@ class PagedKVCache:
         # change any traced shape.
         self.sink = num_blocks if sink_block else -1
         phys = num_blocks + (1 if sink_block else 0)
-        shape = (phys, block_size, num_kv_heads, head_dim)
+        shape = ((phys, block_size, head_dim) if self.latent
+                 else (phys, block_size, num_kv_heads, head_dim))
         self.key_cache = jnp.zeros(shape, dtype)
-        self.value_cache = jnp.zeros(shape, dtype)
+        self.value_cache = None if self.latent \
+            else jnp.zeros(shape, dtype)
         if self.quantized:
             # per-page-per-head absmax; 0 = "nothing written yet" (the
             # quantized writes grow it monotonically per page lifetime)
@@ -205,6 +225,10 @@ class PagedKVCache:
         refcount state is host bookkeeping and needs no placement.
         Call once at engine construction, before any compiled step
         consumes (donates) the arrays."""
+        if self.latent:
+            raise ValueError(
+                "PagedKVCache.place: a latent pool has no kv-head axis "
+                "to shard over")
         self.key_cache = jax.device_put(self.key_cache, sharding)
         self.value_cache = jax.device_put(self.value_cache, sharding)
         if self.quantized and scale_sharding is not None:
@@ -221,7 +245,8 @@ class PagedKVCache:
         quantized pool COUNTS ITS SCALE TABLES, so the capacity claim
         stays honest."""
         total = 0
-        arrs = [self.key_cache, self.value_cache]
+        arrs = [self.key_cache] if self.latent \
+            else [self.key_cache, self.value_cache]
         if self.quantized:
             arrs += [self.key_scale, self.value_scale]
         for arr in arrs:
@@ -502,6 +527,14 @@ def write_ragged_kv(k_new, v_new, key_cache, value_cache, dest_blocks,
     return key_cache, value_cache
 
 
+def write_ragged_latent(rows, latent_cache, dest_blocks, dest_offsets):
+    """``write_ragged_kv`` for a latent pool: ``rows [T, row]`` (one
+    row a packed token, ``[c_kv | k_rope | 0]``) land at
+    ``(dest_blocks[t], dest_offsets[t])`` of ``[phys, block, row]``."""
+    return latent_cache.at[dest_blocks, dest_offsets].set(
+        rows.astype(latent_cache.dtype))
+
+
 # ---------------------------------------------------------------------------
 # quantized (int8) write paths: quantize ON WRITE inside the compiled step
 # ---------------------------------------------------------------------------
@@ -695,6 +728,33 @@ def _ragged_attention_xla(q, key_cache, value_cache, block_tables,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("thl,tlhd->thd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _ragged_latent_attention_xla(q, latent_cache, block_tables,
+                                 q_offsets, q_lens, kv_lens, scale,
+                                 v_width):
+    """Ragged paged LATENT attention, XLA reference path (CPU + parity
+    tests): ``_ragged_attention_xla`` where every head of a token reads
+    the same cached row.  q ``[T, H, row]`` absorbed queries, pool
+    ``[phys, block, row]``; keys are the whole row, values its first
+    ``v_width`` columns.  Returns ``[T, H, v_width]``."""
+    T = q.shape[0]
+    bs = latent_cache.shape[1]
+    W = block_tables.shape[1]
+    tok = jnp.arange(T, dtype=jnp.int32)
+    sid = jnp.clip(
+        jnp.searchsorted(q_offsets.astype(jnp.int32), tok, side="right")
+        - 1, 0, q_offsets.shape[0] - 1).astype(jnp.int32)
+    qpos = jnp.maximum(
+        kv_lens[sid] - q_lens[sid] + (tok - q_offsets[sid]), 0)
+    bt = jnp.maximum(block_tables, 0)[sid]               # [T, W]
+    c = latent_cache[bt].reshape(T, W * bs, -1).astype(jnp.float32)
+    s = jnp.einsum("thd,tld->thl",
+                   q.astype(jnp.float32) * jnp.float32(scale), c)
+    cols = jnp.arange(W * bs, dtype=jnp.int32)
+    s = jnp.where(cols[None, None, :] <= qpos[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("thl,tlv->thv", p, c[..., :v_width]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

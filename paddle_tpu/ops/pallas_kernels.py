@@ -1520,6 +1520,124 @@ def _ragged_work_list(q_lens, tile: int, n_tiles: int):
             rows.astype(jnp.int32))
 
 
+def _tile_extent(i, rows, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref, *,
+                 block_size: int, pages_per_span: int,
+                 pages_per_block: int):
+    """Work-list entry ``i`` of a ragged launch, for a tile of ``rows``
+    real tokens: ``(span, tok0, pos0, kv_end, n_pages, n_blk)`` — the
+    tile's first token in the pack, that token's global position, one
+    past the last key any of its rows may see, and the pages and key
+    blocks that hold those keys."""
+    bs, kb = block_size, pages_per_block
+    s = ts_ref[i]
+    first = tr_ref[i]
+    kv_len = kvlen_ref[s]
+    tok0 = qoff_ref[s] + first
+    pos0 = kv_len - qlen_ref[s] + first
+    kv_end = jnp.minimum(kv_len, pos0 + rows)
+    n_pages = jnp.minimum((kv_end + (bs - 1)) // bs,
+                          jnp.int32(pages_per_span))
+    n_blk = (n_pages + (kb - 1)) // kb
+    return s, tok0, pos0, kv_end, n_pages, n_blk
+
+
+def _page_block_copies(bt_ref, s, n_pages, block_size: int,
+                       pages_per_block: int, streams):
+    """``block_copies(b, slot, go)`` for a ragged launch's page stream:
+    ``go`` (start or wait) each copy of key block ``b``'s pages into
+    VMEM slot ``slot``, as many pages as the tile can see.  ``streams``
+    is ``[(pool in HBM, two-slot VMEM buffer, slot -> semaphore)]``: a
+    page is copied as stored, once a pool."""
+    bs, kb = block_size, pages_per_block
+
+    def block_copies(b, slot, go):
+        def page(j, _):
+            page = bt_ref[s, b * kb + j]
+            dst = pl.ds(j * bs, bs)
+            for hbm, buf, sem in streams:
+                go(pltpu.make_async_copy(hbm.at[page], buf.at[slot, dst],
+                                         sem(slot)))
+            return 0
+        lax.fori_loop(jnp.int32(0),
+                      jnp.minimum(n_pages - b * kb, jnp.int32(kb)),
+                      page, 0)
+    return block_copies
+
+
+def _stream_key_blocks(n_blk, block_copies, block_math):
+    """Walk a tile's key blocks through the two VMEM slots: block b+1's
+    copies are issued before block b's are waited for and
+    ``block_math(b, slot)`` runs.  Block 0's copies are already started
+    (beside the q tile's)."""
+    def body(b, _):
+        slot = lax.rem(b, jnp.int32(2))
+
+        @pl.when(b + 1 < n_blk)
+        def _prefetch():
+            block_copies(b + 1, 1 - slot, lambda c: c.start())
+        block_copies(b, slot, lambda c: c.wait())
+        block_math(b, slot)
+        return 0
+
+    lax.fori_loop(jnp.int32(0), n_blk, body, 0)
+
+
+def _reset_softmax_state(m_s, l_s, acc_s):
+    m_s[...] = jnp.full(m_s.shape, _F32_NEG_INF, jnp.float32)
+    l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+    acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+
+def _write_tile(obuf, o_hbm, tok0, sem):
+    """The finished tile back to its tokens' rows of the pack."""
+    o_copy = pltpu.make_async_copy(
+        obuf, o_hbm.at[pl.ds(tok0, obuf.shape[0])], sem)
+    o_copy.start()
+    o_copy.wait()
+
+
+def _ragged_launch(kernel, name: str, q, pools, out_width: int,
+                   block_tables, q_offsets, q_lens, kv_lens, tile: int,
+                   scratch_shapes, extra_prefetch=(), interpret=False):
+    """One ragged ``pallas_call``: the work list of ``ceil(T / tile) +
+    S`` q tiles (a static bound; the real count and every descriptor
+    are traced data) and the span tables go in as scalar prefetch
+    (then ``extra_prefetch``), the pack padded by one tile (the last
+    tile's ``[tok0, tok0 + tile)`` window stays inside the operand and
+    the output) and the ``pools`` untouched stay in HBM, and the output
+    ``[T, H, out_width]`` aliases a zero buffer, so rows no tile owns
+    (the pack's padding) read zeros.  Call under ``_x64_off()``."""
+    T, H, _ = q.shape
+    n_tiles = -(-T // tile) + block_tables.shape[0]
+    q_lens = q_lens.astype(jnp.int32)
+    prefetch = list(_ragged_work_list(q_lens, tile, n_tiles))
+    prefetch += [q_offsets.astype(jnp.int32), q_lens,
+                 kv_lens.astype(jnp.int32),
+                 jnp.maximum(block_tables, 0).astype(jnp.int32)]
+    prefetch += list(extra_prefetch)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_tiles,),
+        in_specs=[any_spec] * (len(pools) + 2),
+        out_specs=any_spec,
+        scratch_shapes=scratch_shapes,
+    )
+    q_pad = jnp.pad(q, ((0, tile), (0, 0), (0, 0)))
+    o_init = jnp.zeros((T + tile, H, out_width), q.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(o_init.shape, q.dtype),
+        input_output_aliases={len(prefetch) + len(pools) + 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, q_pad, *pools, o_init)
+    return out[:T]
+
+
 def _kv_heads(buf):
     """``(h, rows [n, D])`` for every kv head of a ``[n, Hkv, D]`` VMEM
     block that holds pages AS STORED.  Head is the second-minor dim, so
@@ -1617,32 +1735,14 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
 
     @pl.when(rows > 0)
     def _tile():
-        s = ts_ref[i]
-        first = tr_ref[i]
-        kv_len = kvlen_ref[s]
-        tok0 = qoff_ref[s] + first
-        pos0 = kv_len - qlen_ref[s] + first
-        kv_end = jnp.minimum(kv_len, pos0 + rows)
-        n_pages = jnp.minimum((kv_end + (bs - 1)) // bs,
-                              jnp.int32(pages_per_span))
-        n_blk = (n_pages + (kb - 1)) // kb
-
-        def block_copies(b, slot, go):
-            """``go`` (start or wait) each copy of key block b's pages,
-            as many as the tile can see."""
-            def page(j, _):
-                page = bt_ref[s, b * kb + j]
-                dst = pl.ds(j * bs, bs)
-                go(pltpu.make_async_copy(
-                    k_hbm.at[page], kbuf.at[slot, dst],
-                    kv_sem.at[slot, 0]))
-                go(pltpu.make_async_copy(
-                    v_hbm.at[page], vbuf.at[slot, dst],
-                    kv_sem.at[slot, 1]))
-                return 0
-            lax.fori_loop(jnp.int32(0),
-                          jnp.minimum(n_pages - b * kb, jnp.int32(kb)),
-                          page, 0)
+        s, tok0, pos0, kv_end, n_pages, n_blk = _tile_extent(
+            i, rows, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref,
+            block_size=bs, pages_per_span=pages_per_span,
+            pages_per_block=kb)
+        block_copies = _page_block_copies(
+            bt_ref, s, n_pages, bs, kb,
+            [(k_hbm, kbuf, lambda slot: kv_sem.at[slot, 0]),
+             (v_hbm, vbuf, lambda slot: kv_sem.at[slot, 1])])
 
         q_copy = pltpu.make_async_copy(q_hbm.at[pl.ds(tok0, bq)], qbuf,
                                        qo_sem.at[0])
@@ -1661,9 +1761,7 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
             if quantized:
                 qh, qs_s[h] = quantize_rows_symmetric(qh)
             q2[h] = qh.astype(cdt)
-        m_s[...] = jnp.full(m_s.shape, _F32_NEG_INF, jnp.float32)
-        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
-        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        _reset_softmax_state(m_s, l_s, acc_s)
 
         tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
                       jnp.int32(groups))
@@ -1713,30 +1811,20 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
                 (m_s[h], l_s[h], acc_s[h]), sc, ok, pv_of_p)
             return b
 
-        def body(b, _):
-            slot = lax.rem(b, jnp.int32(2))
-
-            @pl.when(b + 1 < n_blk)
-            def _prefetch():
-                block_copies(b + 1, 1 - slot, lambda c: c.start())
-            block_copies(b, slot, lambda c: c.wait())
+        def block_math(b, slot):
             # the block head-major: [n, Hkv, D] as stored -> [Hkv, n, D]
             for src, dst in ((kbuf, k_hm), (vbuf, v_hm)):
                 for h, rows_h in _kv_heads(src.at[slot]):
                     dst[h] = rows_h.astype(cdt)
             lax.fori_loop(jnp.int32(0), jnp.int32(hkv), head_math, b)
-            return 0
 
-        lax.fori_loop(jnp.int32(0), n_blk, body, 0)
+        _stream_key_blocks(n_blk, block_copies, block_math)
 
         for h in range(hkv):
             o = acc_s[h] / jnp.maximum(l_s[h], np.float32(1e-30))
             obuf[:, h * groups:(h + 1) * groups, :] = (
                 o.reshape(bq, groups, d).astype(obuf.dtype))
-        o_copy = pltpu.make_async_copy(obuf, o_hbm.at[pl.ds(tok0, bq)],
-                                       qo_sem.at[1])
-        o_copy.start()
-        o_copy.wait()
+        _write_tile(obuf, o_hbm, tok0, qo_sem.at[1])
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -1752,11 +1840,9 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
     a budget, not once a layer (the kernel body is unrolled over the kv
     heads: about a second of Python a trace).
 
-    Nothing here is sized by a span window or by the pool: the work
-    list has ``ceil(T / tile) + S`` tiles (a static bound; the real
-    count and every descriptor are traced data), the pack is padded by
-    one tile so the last tile's window stays inside it, and the pools
-    go to the kernel untouched.
+    Nothing here is sized by a span window or by the pool
+    (``_ragged_launch``: the work list, the pack's padding of one tile,
+    the pools handed over untouched).
 
     Head sharding (tensor-parallel serving): the kernel is
     shard-oblivious — every head index here is LOCAL.  Each chip calls
@@ -1780,7 +1866,6 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
     quantized = key_scale is not None
     tile, kb = ragged_tile_geometry(H, Hkv, D, bs, W, q.dtype,
                                     key_cache.dtype)
-    n_tiles = -(-T // tile) + S
     cdt = _ragged_compute_dtype(q.dtype, key_cache.dtype)
     rows = tile * groups
 
@@ -1789,59 +1874,236 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
         pages_per_block=kb, scale=scale, groups=groups,
         quantized=quantized)
     with _x64_off(), jax.named_scope("attn.kernel"):
-        q_lens = q_lens.astype(jnp.int32)
-        prefetch = list(_ragged_work_list(q_lens, tile, n_tiles))
-        prefetch += [q_offsets.astype(jnp.int32), q_lens,
-                     kv_lens.astype(jnp.int32),
-                     jnp.maximum(block_tables, 0).astype(jnp.int32)]
-        if quantized:
-            # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
-            prefetch += [key_scale.astype(jnp.float32).T,
-                         value_scale.astype(jnp.float32).T]
-        any_spec = pl.BlockSpec(memory_space=pl.ANY)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(n_tiles,),
-            in_specs=[any_spec] * 4,
-            out_specs=any_spec,
-            scratch_shapes=[
-                pltpu.VMEM((tile, H, D), q.dtype),             # q tile
-                pltpu.VMEM((tile, H, D), q.dtype),             # o tile
-                pltpu.VMEM((Hkv, rows, D), cdt),               # folded q
-                pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # m
-                pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # l
-                pltpu.VMEM((Hkv, rows, D), jnp.float32),       # acc
-                pltpu.VMEM((2, kb * bs, Hkv, D), key_cache.dtype),
-                pltpu.VMEM((2, kb * bs, Hkv, D), value_cache.dtype),
-                pltpu.VMEM((Hkv, kb * bs, D), cdt),   # block, head-major
-                pltpu.VMEM((Hkv, kb * bs, D), cdt),
-                pltpu.SemaphoreType.DMA((2, 2)),     # [slot, k | v]
-                pltpu.SemaphoreType.DMA((2,)),       # q in, o out
-            ] + ([pltpu.VMEM((Hkv, rows, 1), jnp.float32)]   # q scales
-                 if quantized else []),
-        )
-        # one tile of padding: the last tile's [tok0, tok0 + tile)
-        # window stays inside the operand and the output
-        q_pad = jnp.pad(q, ((0, tile), (0, 0), (0, 0)))
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q_pad.shape, q.dtype),
-            # rows no tile owns (the pack's padding) read zeros
-            input_output_aliases={len(prefetch) + 3: 0},
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-            name="ragged_paged_attention",
-        )(*prefetch, q_pad, key_cache, value_cache, jnp.zeros_like(q_pad))
-        return out[:T]
+        # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
+        scales = [key_scale.astype(jnp.float32).T,
+                  value_scale.astype(jnp.float32).T] if quantized else []
+        scratch = [
+            pltpu.VMEM((tile, H, D), q.dtype),             # q tile
+            pltpu.VMEM((tile, H, D), q.dtype),             # o tile
+            pltpu.VMEM((Hkv, rows, D), cdt),               # folded q
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # m
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),       # l
+            pltpu.VMEM((Hkv, rows, D), jnp.float32),       # acc
+            pltpu.VMEM((2, kb * bs, Hkv, D), key_cache.dtype),
+            pltpu.VMEM((2, kb * bs, Hkv, D), value_cache.dtype),
+            pltpu.VMEM((Hkv, kb * bs, D), cdt),   # block, head-major
+            pltpu.VMEM((Hkv, kb * bs, D), cdt),
+            pltpu.SemaphoreType.DMA((2, 2)),     # [slot, k | v]
+            pltpu.SemaphoreType.DMA((2,)),       # q in, o out
+        ] + ([pltpu.VMEM((Hkv, rows, 1), jnp.float32)]   # q scales
+             if quantized else [])
+        return _ragged_launch(
+            kernel, "ragged_paged_attention", q,
+            (key_cache, value_cache), D, block_tables, q_offsets, q_lens,
+            kv_lens, tile, scratch, extra_prefetch=scales,
+            interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# ragged paged LATENT attention (MLA, absorbed form): every query head
+# of a token reads the SAME cached row
+# ---------------------------------------------------------------------------
+# A page row is one token's latent: ``[c_kv | k_rope | zeros]`` padded to
+# whole 128-lane tiles (``latent_row_width``).  Keys are the whole row
+# (the absorbed query is zero over the padding), values its first
+# ``v_width`` columns, so one DMA a page serves both matmuls and there
+# is no second pool.  A q tile is _LATENT_TILE_TOKENS tokens x H heads
+# rows; inside a cell the rows are walked _LATENT_SUB_TOKENS tokens at a
+# time with a traced trip count, so a one-token decode span multiplies
+# H rows and a chunk tile all of them, through one traced body.
+_LATENT_TILE_TOKENS = 8
+_LATENT_SUB_TOKENS = 2
+
+
+def latent_row_width(kv_lora_rank: int, rope_dim: int) -> int:
+    """Columns of one cached latent row: ``kv_lora_rank + rope_dim``
+    rounded up to whole 128-lane tiles (576 -> 640), which is what the
+    row takes in HBM's tiled layout and in VMEM whatever is declared."""
+    return -(-(int(kv_lora_rank) + int(rope_dim)) // 128) * 128
+
+
+def _latent_cell_vmem_bytes(heads: int, row: int, v_width: int,
+                            kv_block: int, itemsize: int) -> int:
+    """VMEM bytes of one _latent_paged_kernel grid cell (mirrors the
+    scratch_shapes of _ragged_latent_attention_pallas — edit both)."""
+    bq, sub = _LATENT_TILE_TOKENS, _LATENT_SUB_TOKENS
+    total = _tile_bytes((bq, heads, row), itemsize)             # q
+    total += _tile_bytes((bq, heads, v_width), itemsize)        # o
+    total += _tile_bytes((bq // sub, sub * heads, v_width), 4)  # acc
+    total += 2 * _tile_bytes((bq // sub, sub * heads, 1), 4)    # m, l
+    total += _tile_bytes((2, kv_block, row), itemsize)          # 2 slots
+    total += 2 * _tile_bytes((sub * heads, kv_block), 4)        # scores, p
+    return total
+
+
+def latent_kernel_vmem_bytes(*, heads: int, kv_lora_rank: int,
+                             rope_dim: int, block_size: int,
+                             bt_width: int, dtype="bfloat16") -> int:
+    kb = max(1, min(_RAGGED_KV_BLOCK // block_size, bt_width))
+    return _latent_cell_vmem_bytes(
+        heads, latent_row_width(kv_lora_rank, rope_dim), kv_lora_rank,
+        kb * block_size, jnp.dtype(dtype).itemsize)
+
+
+def latent_attn_rows(q_lens, heads: int) -> int:
+    """The q rows (tokens x heads) one latent launch multiplies for
+    spans of these lengths: whole sub-tiles of the real tokens."""
+    sub = _LATENT_SUB_TOKENS
+    return heads * sub * sum(-(-max(int(n), 0) // sub) for n in q_lens)
+
+
+def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,
+                         pages_per_block: int, scale: float,
+                         v_width: int):
+    """Grid cell i: one q tile (``rows`` consecutive tokens of one span,
+    every head of each) against the span's latent pages.  As
+    ``_ragged_paged_kernel`` in its work list, its q / o copies at the
+    tokens' real offset in the token-major pack, its two-slot page
+    stream and its causal rule; what differs is that there is ONE
+    cached row a token for all heads: a page ``[block_size, row]`` is
+    the key block as stored (no head-major copy), the value block is its
+    first ``v_width`` columns, and the per-head loop is a loop over
+    sub-tiles of tokens with a traced bound."""
+    (ts_ref, tr_ref, tn_ref, qoff_ref, qlen_ref, kvlen_ref,
+     bt_ref) = refs[:7]
+    (q_hbm, c_hbm, _, o_hbm, qbuf, obuf, m_s, l_s, acc_s, cbuf, kv_sem,
+     qo_sem) = refs[7:]
+    i = pl.program_id(0)
+    rows = tn_ref[i]
+    bq, heads, dk = qbuf.shape
+    n_sub_max, r, _ = acc_s.shape
+    sub = bq // n_sub_max
+    bs, kb = block_size, pages_per_block
+    n = kb * bs
+
+    @pl.when(i == 0)
+    def _clean_slots():
+        # a partly filled last block multiplies p = 0 into whatever its
+        # unfetched rows hold: make that finite once
+        cbuf[...] = jnp.zeros(cbuf.shape, cbuf.dtype)
+
+    @pl.when(rows > 0)
+    def _tile():
+        s, tok0, pos0, kv_end, n_pages, n_blk = _tile_extent(
+            i, rows, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref,
+            block_size=bs, pages_per_span=pages_per_span,
+            pages_per_block=kb)
+        n_sub = (rows + (sub - 1)) // sub
+        block_copies = _page_block_copies(
+            bt_ref, s, n_pages, bs, kb,
+            [(c_hbm, cbuf, lambda slot: kv_sem.at[slot])])
+
+        q_copy = pltpu.make_async_copy(q_hbm.at[pl.ds(tok0, bq)], qbuf,
+                                       qo_sem.at[0])
+        q_copy.start()
+        block_copies(jnp.int32(0), 0, lambda c: c.start())
+        _reset_softmax_state(m_s, l_s, acc_s)
+        q_copy.wait()
+
+        tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
+                      jnp.int32(heads))
+
+        def block_math(b, slot):
+            cols = b * n + lax.broadcasted_iota(jnp.int32, (r, n), 1)
+
+            def sub_math(t, _):
+                k = cbuf[slot]                               # [n, row]
+                qh = qbuf[pl.ds(t * sub, sub)].reshape(r, dk)
+                sc = lax.dot_general(
+                    qh, k, _DIMNUM_NT,
+                    preferred_element_type=jnp.float32) * np.float32(scale)
+                ok = (cols <= pos0 + t * sub + tok) & (cols < kv_end)
+                sc = jnp.where(ok, sc, _F32_NEG_INF)
+
+                def pv_of_p(p):
+                    return lax.dot_general(
+                        p.astype(k.dtype), k[:, :v_width], _DIMNUM_NN,
+                        preferred_element_type=jnp.float32)
+
+                m_s[t], l_s[t], acc_s[t] = online_softmax_update(
+                    (m_s[t], l_s[t], acc_s[t]), sc, ok, pv_of_p)
+                return 0
+
+            lax.fori_loop(jnp.int32(0), n_sub, sub_math, 0)
+
+        _stream_key_blocks(n_blk, block_copies, block_math)
+
+        o = acc_s[...] / jnp.maximum(l_s[...], np.float32(1e-30))
+        obuf[...] = o.reshape(bq, heads, v_width).astype(obuf.dtype)
+        _write_tile(obuf, o_hbm, tok0, qo_sem.at[1])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "v_width", "interpret"))
+def _ragged_latent_attention_pallas(q, latent_cache, block_tables,
+                                    q_offsets, q_lens, kv_lens, scale,
+                                    v_width, interpret=False):
+    """q: ``[T, H, row]`` absorbed queries (``[q_nope W_kvb[K] | q_rope |
+    0]``) of a packed ragged batch; ``latent_cache [num_blocks,
+    block_size, row]``; span tables as ``_ragged_paged_attention_pallas``
+    (whose work list this reuses).  Returns ``[T, H, v_width]``: each
+    head's probabilities over the cached ``c_kv`` rows, still to be
+    multiplied by ``W_kvb[V]``."""
+    T, H, row = q.shape
+    bs = latent_cache.shape[1]
+    S, W = block_tables.shape
+    tile, sub = _LATENT_TILE_TOKENS, _LATENT_SUB_TOKENS
+    kb = max(1, min(_RAGGED_KV_BLOCK // bs, W))
+    kernel = functools.partial(
+        _latent_paged_kernel, block_size=bs, pages_per_span=W,
+        pages_per_block=kb, scale=scale, v_width=v_width)
+    with _x64_off(), jax.named_scope("attn.kernel"):
+        scratch = [
+            pltpu.VMEM((tile, H, row), q.dtype),             # q tile
+            pltpu.VMEM((tile, H, v_width), q.dtype),         # o tile
+            pltpu.VMEM((tile // sub, sub * H, 1), jnp.float32),   # m
+            pltpu.VMEM((tile // sub, sub * H, 1), jnp.float32),   # l
+            pltpu.VMEM((tile // sub, sub * H, v_width),
+                       jnp.float32),                         # acc
+            pltpu.VMEM((2, kb * bs, row), latent_cache.dtype),
+            pltpu.SemaphoreType.DMA((2,)),       # page slots
+            pltpu.SemaphoreType.DMA((2,)),       # q in, o out
+        ]
+        return _ragged_launch(
+            kernel, "ragged_latent_attention", q, (latent_cache,),
+            v_width, block_tables, q_offsets, q_lens, kv_lens, tile,
+            scratch, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # fused RoPE + QKV epilogue (serving: one HBM round trip per layer's
 # pre-attention transforms instead of three)
 # ---------------------------------------------------------------------------
-def rope_tables_for_positions(positions, dim, base=10000.0):
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's blended inverse frequencies ``[dim / 2]`` (float32): the
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are interpolated by ``factor``, and a linear
+    ramp over the pair index blends the ones between."""
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    f = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return ((f / factor) * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature term ``0.1 mscale ln(factor) + 1``
+    (1 where nothing is scaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_tables_for_positions(positions, dim, base=10000.0,
+                              inv_freq=None):
     """Neox cos/sin tables for a TOKEN-INDEXED position vector:
     positions [N] int32 (each token's GLOBAL position) -> (cos, sin)
     [N, dim] f32.  Bit-identical to the tables
@@ -1851,11 +2113,26 @@ def rope_tables_for_positions(positions, dim, base=10000.0):
     epilogue keeps fp32 engines byte-identical end-to-end.  Traceable;
     the serving steps call it ONCE per step and reuse the tables across
     every layer (the per-layer rebuild was pure waste — positions do
-    not change between layers)."""
-    inv = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    not change between layers).  ``inv_freq [dim / 2]`` replaces the
+    plain ``base`` frequencies (``yarn_inv_freq``)."""
+    inv = (1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+           if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     freqs = positions.astype(jnp.float32)[:, None] * inv[None, :]
     emb = jnp.concatenate([freqs, freqs], axis=-1)
     return jnp.cos(emb), jnp.sin(emb)
+
+
+def rope_interleaved(t, cos, sin):
+    """RoPE over the last dim of ``t`` whose pairs are ``(2i, 2i + 1)``:
+    de-interleave (evens, then odds), then rotate halves, as the
+    published code does.  ``cos``/``sin`` broadcast against ``t`` and are
+    ``[.., d]`` tables of ``[f, f]``.  float32 inside, ``t``'s type out."""
+    d = t.shape[-1]
+    tf = t.astype(jnp.float32)
+    tf = jnp.swapaxes(tf.reshape(t.shape[:-1] + (d // 2, 2)), -1, -2
+                      ).reshape(t.shape)
+    rot = jnp.concatenate([-tf[..., d // 2:], tf[..., :d // 2]], axis=-1)
+    return (tf * cos + rot * sin).astype(t.dtype)
 
 
 def _rope_rows(t, cos, sin):
@@ -2143,6 +2420,10 @@ def kernel_vmem_report(envelope=None):
         # grouping up to 8
         "heads": 32, "kv_heads": 8, "bt_width": 388,
         "groups": 8, "head_dim": 128, "block_size": 16,
+        # latent (MLA) envelope: 128 heads over one 512 + 64 row a
+        # token, 128-token pages, contexts to 33,280
+        "latent_heads": 128, "kv_lora_rank": 512, "latent_rope_dim": 64,
+        "latent_block_size": 128, "latent_bt_width": 260,
         # training envelope: the default/autotuned flash tiles
         "block_q": 512, "block_k": 512,
         "bwd_block_q": _FUSED_BWD_BLOCK_Q,
@@ -2167,6 +2448,11 @@ def kernel_vmem_report(envelope=None):
         "rope_qkv_epilogue": rope_epilogue_vmem_bytes(
             heads=8 * env["groups"], kv_heads=env["groups"],
             head_dim=env["head_dim"]),
+        "ragged_latent_bf16": latent_kernel_vmem_bytes(
+            heads=env["latent_heads"], kv_lora_rank=env["kv_lora_rank"],
+            rope_dim=env["latent_rope_dim"],
+            block_size=env["latent_block_size"],
+            bt_width=env["latent_bt_width"]),
         "flash_fwd": flash_fwd_vmem_bytes(
             block_q=env["block_q"], block_k=env["block_k"],
             head_dim=env["head_dim"], with_rope=True),

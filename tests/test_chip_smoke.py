@@ -50,6 +50,17 @@ def test_ragged_check_reference_head_slices():
     assert r["with_chunk"]["tokens"] == 10
 
 
+def test_latent_check_slices_its_reference():
+    """The chunk's reference goes in slices of 32 rows, each a span of
+    its own over the same pages: a wrong slice offset shows as an error
+    of order 1 against the (here XLA) launch over the whole pack."""
+    r = chip_smoke.latent_check(heads=4, kv_lora=16, rope=8, block_size=4,
+                                prefix=37, chunk=70, decodes=3,
+                                expect_kernels=False)
+    assert r["tokens"] == 73 and r["row"] == 128
+    assert r["rel_err"] < 1e-2          # bf16 inputs, float32 both sides
+
+
 def test_train_phase_and_flash_check_tiny_cpu():
     r = chip_smoke.train_phase(llama_tiny_config(), **TRAIN_KW)
     assert r["compile_count"] == 1 and r["last_loss"] < r["first_loss"]

@@ -217,3 +217,74 @@ def test_mixed_step_full_width_two_layers(v5e):
     # pools are donated: aliased, not copied
     assert mem.alias_size_in_bytes >= sum(
         2 * int(c.key_cache.nbytes) for c in eng.caches)
+
+
+# the latent (MLA) launch at DeepSeek-V2's widths: 128 heads over one
+# 512 + 64 row a token stored 640 wide, 128-token pages, 260 a span
+LATENT = {"decode": (16, 16), "top": (1024, 16)}
+
+
+@pytest.mark.parametrize("pack", sorted(LATENT))
+def test_ragged_latent(v5e, pack):
+    """Every head of a token against ONE cached row: keys the whole row,
+    values its first 512 columns, no second pool.  q tiles of 8 tokens x
+    128 heads DMA'd from the token-major pack, pages as stored."""
+    from paddle_tpu.ops.pallas_kernels import \
+        _ragged_latent_attention_pallas
+    tokens, n_spans = LATENT[pack]
+    spans = ((n_spans,), jnp.int32)
+    compiled = _compile(
+        lambda q, c, bt, qo, ql, kl: _ragged_latent_attention_pallas(
+            q, c, bt, qo, ql, kl, 0.1147, 512),
+        v5e, ((tokens, 128, 640), jnp.bfloat16),
+        ((4161, 128, 640), jnp.bfloat16), ((n_spans, 260), jnp.int32),
+        spans, spans, spans)
+    assert "ragged_latent_attention" in compiled.as_text()
+
+
+def test_mixed_step_latent_two_kinds(v5e):
+    """One fused step of a DeepSeek-V2-shaped model (a dense layer, then
+    a layer holding 8 of 32 experts; widths cut, the kernel's geometry
+    kept: 128 heads, a 640-wide row): the optimized v5e program launches
+    the latent kernel, has ONE pool a layer, no buffer a held expert,
+    and every new scope owns an op."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.jit.serving_step import STEP_SCOPES, hlo_op_scopes
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+    paddle.seed(0)
+    cfg = DeepseekV2Config(
+        vocab_size=1024, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256, num_hidden_layers=2,
+        num_attention_heads=128, num_key_value_heads=128, q_lora_rank=256,
+        n_routed_experts=8, router_experts=32, first_held_expert=8,
+        rope_scaling={"type": "yarn", "factor": 40,
+                      "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707}, dtype="bfloat16")
+    model = DeepseekV2ForCausalLM(cfg)
+    model.bfloat16()
+    model.eval()
+    eng = ContinuousBatchingEngine(
+        model, max_batch_size=4, num_blocks=64, block_size=128,
+        max_seq_len=2048, mixed_step=True, prefill_chunk_size=256,
+        use_pallas=True)
+    top = eng.token_budgets[-1]
+    lowered = eng.mixed.aot_lower(top, device_sharding=v5e)
+    assert 'kernel_name = "ragged_latent_attention"' in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    scopes = hlo_op_scopes(hlo)
+    assert {"attn.q_lora", "attn.kv_latent", "attn.absorb",
+            "attn.unabsorb", "attn.kernel", "attn.kv_write", "moe.gate",
+            "moe.sort", "moe.experts", "moe.combine", "moe.shared"} \
+        <= set(scopes.values()) <= STEP_SCOPES | {None}
+    # the kernels XLA:TPU names itself keep a part of the step too
+    kernels = {s for n, s in scopes.items()
+               if n.startswith(("ragged-dot", "ragged_latent_attention"))}
+    assert kernels == {"moe.experts", "attn.kernel"}
+    # the experts multiply rows, not experts x rows
+    k = cfg.num_experts_per_tok
+    assert not re.search(r"\[8,%d,\d+\]" % (top * k), hlo)
+    assert not re.search(r"\[32,%d,\d+\]" % (top * k), hlo)
+    assert [c.value_cache for c in eng.caches] == [None, None]

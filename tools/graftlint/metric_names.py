@@ -204,6 +204,8 @@ LABEL_DOMAINS = {
     # spmd_allgather_bytes_total{site}
     "site": frozenset({"train_params", "serving_params"}),
     "engine": DYNAMIC,              # engine ids: bounded by pool size
+    "expert": DYNAMIC,              # index within the bank an engine
+                                    # holds: bounded by the model config
     "metric": DYNAMIC,              # bench line names: bounded by the
                                     # bench's own mode set
     "unit": DYNAMIC,                # bench units: one per bench line

@@ -42,6 +42,7 @@ BUDGETS = {
     "paged_decode_fp32": 16 * MIB,
     "paged_decode_int8": 16 * MIB,
     "rope_qkv_epilogue": 16 * MIB,
+    "ragged_latent_bf16": 16 * MIB,
     "flash_fwd": 64 * MIB,
     "flash_bwd_fused": 64 * MIB,
 }
