@@ -810,9 +810,10 @@ class ContinuousBatchingEngine:
         # resolve the 'dropped' child eagerly so /metrics always shows
         # the 0 that documents droplessness
         self._m_moe_dropped = self._m_moe_dispatch.labels(fate="dropped")
-        # a bank that holds a share of its router's experts: the rows
-        # each HELD expert was given (the step's own count, fetched
-        # with the tokens); the rest of ``routed`` landed elsewhere
+        # a step with the sorted grouped product (every one-chip MoE):
+        # the rows each expert HELD here was given (the step's own
+        # count, fetched with the tokens); where the bank holds a share
+        # of its router's experts the rest of ``routed`` landed elsewhere
         self._m_moe_expert_load = r.counter(
             "serving_moe_expert_load_total",
             "token->expert assignments that landed on each expert this "
@@ -1131,12 +1132,14 @@ class ContinuousBatchingEngine:
           beside ``tokens`` x the GQA group size it says how much of
           the launch is real.  0 on the split path, which has no ragged
           launch.  For a latent model the rows are tokens x heads.
-        - ``moe_rows``, ``moe_rows_top``: where the bank holds a share
-          of its router's experts, the assignments that landed on held
-          experts, summed over the routed layers, and the fullest held
-          expert's; counted by the step and fetched with the token
-          ids.  0 elsewhere (every assignment lands: ``tokens`` x
-          ``top_k`` x layers).
+        - ``moe_rows``, ``moe_rows_top``: the assignments that landed
+          on the experts this engine holds, summed over the routed
+          layers, and the fullest held expert's; counted by the step
+          (real tokens only, the pack's padding nowhere) and fetched
+          with the token ids.  A bank that holds every expert of its
+          router reads ``tokens`` x ``top_k`` x layers, a share of
+          them its share.  0 for a dense model, and under an ``ep``
+          mesh, whose step does not count.
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.

@@ -265,14 +265,20 @@ def _tp_psum(t: Tensor, tp: Optional[TPContext]) -> Tensor:
     return Tensor._from_value(jax.lax.psum(t._value, tp.axis))
 
 
-def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext]) -> Tensor:
-    """The fused dropless MoE FFN (round 24), traced into the step body
-    in place of ``layer.mlp``: shared top-k gate over the block's
-    tokens, GShard dense dispatch into per-expert buffers sized so no
-    assignment ever drops, grouped expert SwiGLU, weighted combine.
-    Under an ``ep`` mesh axis the dispatch/combine pair crosses the
-    axis as two ``all_to_all`` exchanges plus one token-stripe
-    ``all_gather`` (see ``ops.moe_gate.moe_ffn``).
+def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext], real,
+             loads: Optional[list]) -> Tensor:
+    """The fused dropless MoE FFN of a bank that holds every expert of
+    its router, traced into the step body in place of ``layer.mlp``
+    (``ops.moe_gate.moe_ffn``): the shared top-k gate over the block's
+    tokens, then on one chip the assignments sorted by expert and
+    multiplied by a grouped product sized by the rows each expert
+    really has, un-sorted and summed over the k.  ``real [T]`` marks
+    the pack's real tokens: its padding is given to no expert and
+    counted in no load; the rows each expert was given go to
+    ``loads``.  Under an ``ep`` mesh axis the gate's assignments are
+    scattered into per-expert buffers sized so that none drops, which
+    cross the axis as two ``all_to_all`` exchanges plus one
+    token-stripe ``all_gather``, and no load is counted.
 
     No ``_tp_psum`` boundary here: the combine output is the FULL
     activation (each assignment contributes exactly one expert's
@@ -283,9 +289,12 @@ def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext]) -> Tensor:
     ep_deg = tp.ep_degree if tp is not None else 1
     v = h2._value
     flat = v.reshape(-1, v.shape[-1])
-    out = moe_ffn(flat, blk.gate.weight._value, blk.w_gate._value,
-                  blk.w_up._value, blk.w_down._value, top_k=blk.top_k,
-                  ep_axis=ep_axis, ep_degree=ep_deg)
+    out, load = moe_ffn(flat, blk.gate.weight._value, blk.w_gate._value,
+                        blk.w_up._value, blk.w_down._value,
+                        top_k=blk.top_k, ep_axis=ep_axis,
+                        ep_degree=ep_deg, valid=real)
+    if loads is not None and load is not None:
+        loads.append(load)
     return Tensor._from_value(out.reshape(v.shape))
 
 
@@ -314,14 +323,16 @@ def _ffn_module(layer):
 def _ffn(layer, h2: Tensor, tp: Optional[TPContext], real=None,
          loads: Optional[list] = None) -> Tensor:
     """Per-layer FFN dispatch shared by all three traced bodies, by the
-    body the layer's FFN module declares (``_step_body``): the fused
-    dense-dispatch MoE, the sorted held-share MoE, and for a module
-    that declares none its own forward (a Megatron-sharded dense MLP)
-    with its psum boundary."""
+    body the layer's FFN module declares (``_step_body``): the MoE
+    whose bank holds every expert of its router, the MoE that holds a
+    share of them, and for a module that declares none its own forward
+    (a Megatron-sharded dense MLP) with its psum boundary.  ``real``
+    and ``loads`` are the MoE bodies' (the pack's real rows in, the
+    rows each expert was given out)."""
     blk = _ffn_module(layer)
     body = _step_body(blk, "dense")
-    if body == "moe_dense_dispatch":
-        return _moe_ffn(blk, h2, tp)
+    if body == "moe_full_bank":
+        return _moe_ffn(blk, h2, tp, real, loads)
     if body == "moe_held":
         return _held_moe_ffn(blk, h2, real, loads)
     return _tp_psum(blk(h2), tp)
@@ -334,6 +345,42 @@ def _real_rows(T: int, q_offsets, q_lens):
     first = q_offsets.astype(jnp.int32)[None, :]
     return jnp.any((tok >= first) & (tok < first + q_lens[None, :]),
                    axis=1)
+
+
+def _grouped_product_experts(model, tp: Optional[TPContext]) -> int:
+    """The experts in the bank of the model's first FFN module whose
+    step body holds the sorted grouped product
+    (``ops.moe_gate.sorted_expert_swiglu``: a held share always, a full
+    bank everywhere but on an ``ep`` axis), 0 where no layer's does.
+    A step that holds one reports the rows its experts were given, and
+    is traced through :func:`_traced_x64_off`."""
+    bodies = ("moe_held",) if tp is not None and tp.ep_degree > 1 \
+        else ("moe_held", "moe_full_bank")
+    for layer in _inner_model(model).layers:
+        blk = _ffn_module(layer)
+        if _step_body(blk, "dense") in bodies:
+            return int(blk.w_gate.shape[0])
+    return 0
+
+
+def _traced_x64_off(step):
+    """XLA:TPU's pass that rewrites 64-bit element types does not know
+    ragged-dot, and stops at one in a module that holds any (the
+    argmax's i64 is enough): a step with a grouped product is traced
+    with x64 off, its operands being 32-bit."""
+    def traced(*args):
+        with jax.enable_x64(False):
+            return step(*args)
+    return traced
+
+
+def _shapes_on(args, device_sharding):
+    """``args`` as ``ShapeDtypeStruct``s that carry ``device_sharding``:
+    what ``aot_lower`` hands ``lower`` to compile for another device
+    than the one the arrays live on."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=device_sharding), args)
 
 
 def _tp_logits(logits: Tensor, tp: Optional[TPContext],
@@ -1045,6 +1092,8 @@ class PrefillStep:
             return (nxt, tuple(new_kcs), tuple(new_vcs),
                     tuple(new_kss), tuple(new_vss))
 
+        if _grouped_product_experts(model, tp):
+            step = _traced_x64_off(step)
         if sampling:
             fn, donate, n_repl = step, (6, 7, 8, 9), 5
         else:
@@ -1226,12 +1275,11 @@ class MixedStep:
                     raise ValueError(
                         "MixedStep: %s is not taught the latent "
                         "(MLA) cache row" % what)
-        # a bank that holds a share of its router's experts reports the
-        # rows its experts were given: [moe_rows, moe_rows_top, load x El]
+        # a step that holds the sorted grouped product reports the rows
+        # its experts were given: [moe_rows, moe_rows_top, load x El]
         # int32 behind the sampled tokens, in the same fetch
-        held = [_ffn_module(l) for l in _inner_model(model).layers]
-        held = [b for b in held if _step_body(b, "dense") == "moe_held"]
-        self.n_stats = 2 + int(held[0].w_gate.shape[0]) if held else 0
+        experts = _grouped_product_experts(model, self._tp)
+        self.n_stats = 2 + experts if experts else 0
         self.last_stats = None         # the last call's tail (np int32)
         self._wq = weight_qparams
         self._q8_gather = bool(quant_collectives)
@@ -1507,7 +1555,7 @@ class MixedStep:
                     return x, kc, None, None, None
 
                 attention = {"gqa": gqa_attention, "mla": mla_attention}
-                loads = []      # [El] int32 a held-share MoE layer
+                loads = []      # [El] int32 a sorted MoE layer
                 real = None
                 if n_stats:
                     with jax.named_scope("moe.sort"):
@@ -1590,8 +1638,8 @@ class MixedStep:
                 else:
                     nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
                 if n_stats:
-                    # rows the held experts were given, summed over the
-                    # layers: total, the fullest expert's, each one's
+                    # rows the experts held here were given, summed over
+                    # the layers: total, the fullest expert's, each one's
                     load = sum(loads[1:], loads[0]).astype(jnp.int32)
                     nxt = jnp.concatenate(
                         [nxt, jnp.sum(load)[None], jnp.max(load)[None],
@@ -1604,16 +1652,7 @@ class MixedStep:
                         tuple(new_kss), tuple(new_vss))
 
         if n_stats:
-            # XLA:TPU's pass that rewrites 64-bit element types does not
-            # know ragged-dot, and stops at one in a module that holds
-            # any (the argmax's i64 is enough): a step with a grouped
-            # product is traced with x64 off, its operands being 32-bit
-            traced_x64 = step
-
-            def step(*args):                           # noqa: F811
-                with jax.enable_x64(False):
-                    return traced_x64(*args)
-
+            step = _traced_x64_off(step)
         if spec_k and sampling:
             fn, donate = step, (3, 4, 5, 6)
         else:
@@ -1701,9 +1740,7 @@ class MixedStep:
                 for _ in range(self.spec_k)))
         args += [kcs, vcs, kss, vss]
         if device_sharding is not None:
-            args = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype, sharding=device_sharding), args)
+            args = _shapes_on(args, device_sharding)
         return fn.lower(*args)
 
     def compiled_stats(self, T: int) -> dict:
@@ -1964,6 +2001,8 @@ class DecodeStep:
             return (nxt, tuple(new_kcs), tuple(new_vcs),
                     tuple(new_kss), tuple(new_vss))
 
+        if _grouped_product_experts(model, tp):
+            step = _traced_x64_off(step)
         if sampling:
             fn, donate, n_repl = step, (5, 6, 7, 8), 4
         else:
@@ -1984,11 +2023,13 @@ class DecodeStep:
                                      donate=donate,
                                      quant_kv=quant_kv)
 
-    def aot_lower(self, slots: int):
+    def aot_lower(self, slots: int, device_sharding=None):
         """AOT-lower (never execute) the decode module at ``slots``
         slots with zero host operands — the graftlint hlo-contract
         artifact (donation aliases the pools, no f64, the split-step
-        host-operand count stays pinned at 3)."""
+        host-operand count stays pinned at 3).  ``device_sharding`` as
+        in ``MixedStep.aot_lower``: lower for another device than the
+        one the arrays live on (a compile-only TPU)."""
         if self._fn is None:
             self._build()
         W = self.caches[0].num_blocks      # any width works for lint
@@ -2002,7 +2043,10 @@ class DecodeStep:
                 jnp.zeros((slots, W), jnp.int32)]
         if self.sampling:
             args.append(jnp.zeros((slots, 4), jnp.int32))
-        return self._fn.lower(*args, kcs, vcs, kss, vss)
+        args += [kcs, vcs, kss, vss]
+        if device_sharding is not None:
+            args = _shapes_on(args, device_sharding)
+        return self._fn.lower(*args)
 
     def compiled_stats(self, slots: int) -> dict:
         """Cached ``cost_analysis`` of the compiled decode launch at

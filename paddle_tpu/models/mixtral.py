@@ -49,8 +49,11 @@ class MixtralSparseMoeBlock(Layer):
     -> three batched expert einsums -> weighted combine."""
 
     # the serving step's FFN body for this module
-    # (``jit/serving_step.py``): the fused dense-dispatch ``moe_ffn``
-    serving_body = "moe_dense_dispatch"
+    # (``jit/serving_step.py: _moe_ffn``): a bank that holds every
+    # expert of its router, through ``ops.moe_gate.moe_ffn`` (on one
+    # chip the sorted grouped product, under ep the buffer exchange);
+    # ``forward`` below is the eager form, with capacity and drops
+    serving_body = "moe_full_bank"
 
     def __init__(self, config: MixtralConfig):
         super().__init__()

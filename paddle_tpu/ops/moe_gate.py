@@ -8,11 +8,17 @@ The ONE top-k gate / dispatch implementation in the repo.  Callers:
 - ``incubate/distributed/models/moe/gate.py`` — NaiveGate/GShardGate/
   SwitchGate all route through :func:`topk_gate` (no second
   softmax/top-k copy drifting out of sync);
-- ``jit/serving_step.py`` — :func:`moe_ffn` is the fused dropless MoE
-  FFN inside the compiled serving steps, optionally expert-parallel
-  over an ``ep`` mesh axis with ``jax.lax.all_to_all`` dispatch/combine
-  (the reference's global_scatter/global_gather pair, emitted inside
-  the ONE compiled launch).
+- ``jit/serving_step.py`` — :func:`moe_ffn` (a bank that holds every
+  expert of its router) and :func:`moe_ffn_held` (a share of them) are
+  the dropless MoE FFNs inside the compiled serving steps.  Each has
+  its own gate; on one chip both hand their assignments to the ONE
+  expert product, :func:`sorted_expert_swiglu`: the assignments sorted
+  by expert into one ``[N*k, D]`` buffer and three
+  ``jax.lax.ragged_dot``s sized by the rows each expert really has.
+  Under an ``ep`` mesh axis :func:`moe_ffn` still scatters into
+  per-expert buffers (the ``all_to_all`` pair wants static per-expert
+  slices: the reference's global_scatter/global_gather, emitted inside
+  the ONE compiled launch); those buffers are the eager block's too.
 
 Everything here is pure jnp -> safe both under ``apply_op`` eager
 dispatch and inside jit/shard_map traced bodies.  No host transfers, no
@@ -25,8 +31,9 @@ import jax.numpy as jnp
 
 __all__ = [
     "topk_gate", "assignment_slots", "dispatch_to_buffers",
-    "grouped_expert_swiglu", "combine_from_buffers", "moe_ffn",
-    "group_limited_topk", "moe_ffn_held",
+    "grouped_expert_swiglu", "combine_from_buffers",
+    "sorted_expert_swiglu", "moe_ffn", "group_limited_topk",
+    "moe_ffn_held",
 ]
 
 
@@ -104,17 +111,67 @@ def combine_from_buffers(eo, top_i, slot, top_w, keep=None):
     return jnp.sum(picked.astype(jnp.float32) * w_eff[..., None], axis=1)
 
 
-def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
+def sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, first_held=0,
+                         valid=None):
+    """The experts' part of a dropless MoE FFN, after the gate: the
+    assignments ``top_i [N, k]`` (indices into the ROUTER's experts)
+    that land on the experts held here (``first_held .. first_held +
+    El``, ``wg/wu [El, D, M]``, ``wd [El, M, D]``) are sorted by expert
+    into one static ``[N * k, D]`` buffer and multiplied by a grouped
+    product sized by the rows each expert really has
+    (``jax.lax.ragged_dot``: on the TPU XLA's own grouped-matmul
+    kernel), never a buffer an expert; then un-sorted and summed over
+    the k with ``top_w [N, k]`` (float32).  ``valid`` (bool ``[N]``,
+    all true if ``None``) marks the rows that are tokens: a row of
+    padding is given to no expert, its output is 0 and no load counts
+    it.  A step that holds this is traced with x64 off (XLA:TPU's
+    64-bit rewriter stops at a ragged-dot).
+
+    Returns ``(out [N, D] in x's type, load int32 [El])``: the rows
+    each held expert was given."""
+    n, d = x.shape
+    top_k = top_i.shape[1]
+    e_held = wg.shape[0]
+    with jax.named_scope("moe.sort"):
+        local = top_i.reshape(-1) - first_held                # [N*k]
+        held = (local >= 0) & (local < e_held)
+        if valid is not None:
+            held &= jnp.repeat(valid, top_k)
+        key = jnp.where(held, local, e_held)    # not held: sorted last
+        order = jnp.argsort(key, stable=True)
+        load = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
+                       axis=0)[:e_held]
+        xs = x[order // top_k]                                # [N*k, D]
+    with jax.named_scope("moe.experts"):
+        g = jax.lax.ragged_dot(xs, wg, load)
+        u = jax.lax.ragged_dot(xs, wu, load)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        ys = jax.lax.ragged_dot(h, wd, load)                  # [N*k, D]
+    with jax.named_scope("moe.combine"):
+        # back to assignment order; a row no held expert computed is
+        # whatever the grouped product left there: masked, not weighted
+        back = jnp.argsort(order)
+        y = jnp.where(held[:, None], ys[back].astype(jnp.float32), 0.0)
+        out = jnp.sum(y.reshape(n, top_k, d)
+                      * top_w[..., None], axis=1).astype(x.dtype)
+    return out, load
+
+
+def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1,
+            valid=None):
     """Dropless fused MoE FFN over a flat token block ``x [N, D]``.
 
     ``gate_w [D, E_total]`` replicated; ``wg/wu/wd`` the LOCAL expert
     shard ``[El, ., .]`` (``El = E_total/ep``; the full bank when
     ``ep_degree == 1``).
 
-    Local path (``ep_degree <= 1``): dropless capacity ``N*top_k``
-    bounds the worst-case per-expert load, so no assignment is ever
-    dropped — the buffers are the GShard layout of the eager block with
-    the drop mask provably all-True.
+    One chip (``ep_degree <= 1``): the shared top-k gate
+    (:func:`topk_gate`, renormalised over the k), then
+    :func:`sorted_expert_swiglu` over the whole bank: every assignment
+    is a row of the one sorted ``[N*top_k, D]`` buffer, so none is
+    dropped and no expert multiplies a row it was not given.  ``valid``
+    (bool ``[N]``) marks the rows that are tokens; a pack's padding is
+    given to no expert.
 
     ep path (inside shard_map over ``ep_axis``): chip ``r`` gates its
     token stripe ``x[r*Tl:(r+1)*Tl]``, scatters into a per-expert send
@@ -123,7 +180,14 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
     shard, ``all_to_all`` ships outputs back, the weighted combine runs
     on the token's home chip, and ``all_gather`` rebuilds the
     replicated ``[N, D]`` activation.  Requires ``ep | N`` and
-    ``ep | E_total`` (validated at engine construction).
+    ``ep | E_total`` (validated at engine construction).  The send
+    buffers are dropless by capacity (``Tl*k`` bounds any expert's
+    load), so the exchange ships E times the rows that carry a token;
+    ``valid`` is not looked at (padding is computed and discarded).
+
+    Returns ``(out [N, D] in x's type, load)``: ``load int32 [E]`` the
+    rows each expert was given on one chip, ``None`` under ep (the
+    count is not made there).
     """
     n, d = x.shape
     e_local = wg.shape[0]
@@ -137,15 +201,8 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
         with jax.named_scope("moe.gate"):
             logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
             top_w, top_i, _ = topk_gate(logits, top_k)
-            slot, _ = assignment_slots(top_i, e_local)
-        with jax.named_scope("moe.dispatch"):
-            disp = dispatch_to_buffers(x, top_i, slot, None, e_local,
-                                       n * top_k)
-        with jax.named_scope("moe.experts"):
-            eo = grouped_expert_swiglu(disp, wg, wu, wd)
-        with jax.named_scope("moe.combine"):
-            return combine_from_buffers(eo, top_i, slot,
-                                        top_w).astype(x.dtype)
+        return sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
+                                    valid=valid)
 
     e_total = e_local * ep_degree
     tl = n // ep_degree                 # token stripe per chip
@@ -178,7 +235,8 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1):
                                       concat_axis=0, tiled=True)
         out_r = combine_from_buffers(back, top_i, slot,
                                      top_w).astype(x.dtype)
-        return jax.lax.all_gather(out_r, ep_axis, axis=0, tiled=True)
+        return jax.lax.all_gather(out_r, ep_axis, axis=0,
+                                  tiled=True), None
 
 
 def group_limited_topk(scores, k, n_group, topk_group):
@@ -211,11 +269,8 @@ def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
 
     The router (softmax in float32, group-limited top-k, the weights
     ``score * routed_scale``, never renormalised over the k) is computed
-    over all E; the assignments that land on held experts are
-    sorted by expert and multiplied by a grouped product sized by the
-    rows that are real (``jax.lax.ragged_dot``: on the TPU XLA's own
-    grouped-matmul kernel): one static ``[N * k, D]`` buffer of sorted
-    rows, never one a held expert.  What the experts held elsewhere
+    over all E; the assignments that land on held experts go through
+    :func:`sorted_expert_swiglu`.  What the experts held elsewhere
     would add is left out: the result is this bank's part of the sum.
     ``valid`` (bool ``[N]``, all true if ``None``) marks the rows that
     are tokens: a row of padding is given to no expert, its output is
@@ -223,7 +278,6 @@ def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
 
     Returns ``(out [N, D] in x's type, load int32 [El])``: the rows
     each held expert was given."""
-    n, d = x.shape
     e_held = wg.shape[0]
     e_all = gate_w.shape[-1]
     if not 0 <= first_held <= e_all - e_held:
@@ -236,26 +290,5 @@ def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
         top_w, top_i = group_limited_topk(probs, top_k, n_group,
                                           topk_group)
         top_w = top_w * jnp.float32(routed_scale)
-    with jax.named_scope("moe.sort"):
-        local = top_i.reshape(-1) - first_held                # [N*k]
-        held = (local >= 0) & (local < e_held)
-        if valid is not None:
-            held &= jnp.repeat(valid, top_k)
-        key = jnp.where(held, local, e_held)    # not held: sorted last
-        order = jnp.argsort(key, stable=True)
-        load = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
-                       axis=0)[:e_held]
-        xs = x[order // top_k]                                # [N*k, D]
-    with jax.named_scope("moe.experts"):
-        g = jax.lax.ragged_dot(xs, wg, load)
-        u = jax.lax.ragged_dot(xs, wu, load)
-        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        ys = jax.lax.ragged_dot(h, wd, load)                  # [N*k, D]
-    with jax.named_scope("moe.combine"):
-        # back to assignment order; a row no held expert computed is
-        # whatever the grouped product left there: masked, not weighted
-        back = jnp.argsort(order)
-        y = jnp.where(held[:, None], ys[back].astype(jnp.float32), 0.0)
-        out = jnp.sum(y.reshape(n, top_k, d)
-                      * top_w[..., None], axis=1).astype(x.dtype)
-    return out, load
+    return sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
+                                first_held=first_held, valid=valid)

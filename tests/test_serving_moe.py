@@ -320,3 +320,142 @@ def test_router_pool_mixes_dense_and_moe_engines():
     # the dead MoE pool drained leak-free
     c = e_moe_ep.caches[0]
     assert len(c._free) == c.num_blocks
+
+
+# --- the sorted grouped product (PR 28): the one-chip expert product ---
+
+def _routing(kind, rng, n, k, e):
+    """``top_i [n, k]`` int32, k DISTINCT experts a row (as a top-k
+    gives them): drawn evenly, all rows on the same k experts, or
+    evenly over every expert but the last, which gets no row."""
+    if kind == "one_expert":
+        return np.tile(np.arange(k, dtype=np.int32), (n, 1))
+    pool = e - 1 if kind == "empty_expert" else e
+    return np.stack([rng.permutation(pool)[:k] for _ in range(n)]
+                    ).astype(np.int32)
+
+
+@pytest.mark.parametrize("routing",
+                         ["balanced", "one_expert", "empty_expert"])
+@pytest.mark.parametrize("n,k,e", [(16, 2, 8), (7, 1, 4), (33, 3, 5)])
+def test_sorted_product_equals_the_buffer_dispatch(n, k, e, routing):
+    """``sorted_expert_swiglu`` (one ``[N*k, D]`` buffer, three
+    ``ragged_dot``s) against the form it replaced in the one-chip
+    step, ``dispatch_to_buffers`` -> ``grouped_expert_swiglu`` ->
+    ``combine_from_buffers`` at dropless capacity: float32, random
+    inputs; rows marked not ``valid`` come back 0 and are in no
+    ``load``, and ``sum(load)`` is the valid rows x k."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.moe_gate import (
+        assignment_slots, combine_from_buffers, dispatch_to_buffers,
+        grouped_expert_swiglu, sorted_expert_swiglu)
+    d, m = 16, 24
+    rng = np.random.default_rng(n * 100 + k * 10 + e)
+    x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    wg = jnp.asarray(rng.standard_normal((e, d, m)) * 0.3, jnp.float32)
+    wu = jnp.asarray(rng.standard_normal((e, d, m)) * 0.3, jnp.float32)
+    wd = jnp.asarray(rng.standard_normal((e, m, d)) * 0.3, jnp.float32)
+    top_i = jnp.asarray(_routing(routing, rng, n, k, e))
+    top_w = jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32)
+
+    slot, _ = assignment_slots(top_i, e)
+    disp = dispatch_to_buffers(x, top_i, slot, None, e, n * k)
+    want = np.asarray(combine_from_buffers(
+        grouped_expert_swiglu(disp, wg, wu, wd), top_i, slot, top_w))
+
+    out, load = sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5,
+                               atol=2e-5)
+    counts = np.bincount(np.asarray(top_i).reshape(-1), minlength=e)
+    assert np.asarray(load).tolist() == counts.tolist()
+    if routing == "one_expert":
+        assert counts[:k].tolist() == [n] * k and not counts[k:].any()
+    if routing == "empty_expert":
+        assert counts[-1] == 0
+
+    valid = rng.random(n) < 0.6
+    valid[0], valid[-1] = True, False
+    out_v, load_v = sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
+                                         valid=jnp.asarray(valid))
+    out_v = np.asarray(out_v)
+    assert not out_v[~valid].any()
+    np.testing.assert_allclose(out_v[valid], want[valid], rtol=2e-5,
+                               atol=2e-5)
+    assert np.asarray(load_v).tolist() == np.bincount(
+        np.asarray(top_i)[valid].reshape(-1), minlength=e).tolist()
+    assert int(np.asarray(load_v).sum()) == int(valid.sum()) * k
+
+
+def test_moe_ffn_one_chip_is_the_gate_and_the_sorted_product():
+    """``moe_ffn`` on one chip: ITS gate (``topk_gate``, renormalised
+    over the k) and then the product the held path calls, with the
+    bank at the router's width; it returns the experts' loads, and a
+    bank narrower than its router is still refused."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.moe_gate import (moe_ffn, sorted_expert_swiglu,
+                                         topk_gate)
+    n, d, m, e, k = 12, 16, 24, 4, 2
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    gw = jnp.asarray(rng.standard_normal((d, e)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.standard_normal((e, d, m)) * 0.3,
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((e, m, d)) * 0.3, jnp.float32)
+    valid = jnp.asarray(np.arange(n) < 9)
+    out, load = moe_ffn(x, gw, wg, wu, wd, top_k=k, valid=valid)
+    top_w, top_i, _ = topk_gate(x @ gw, k)
+    np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, rtol=1e-6)
+    want, want_load = sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
+                                           valid=valid)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert np.asarray(load).tolist() == np.asarray(want_load).tolist()
+    assert int(np.asarray(load).sum()) == 9 * k
+    with pytest.raises(ValueError, match="moe_ffn_held"):
+        moe_ffn(x, gw, wg[:2], wu[:2], wd[:2], top_k=k)
+
+
+def test_one_chip_step_counts_its_experts_rows():
+    """The one-chip Mixtral engine fills what only the held-share path
+    filled: ``moe_rows`` in every step record and
+    ``serving_moe_expert_load_total{expert}``, real tokens only; the
+    ep engine's step (the buffer exchange) counts nothing."""
+    from paddle_tpu.observability import default_registry, span_log
+    from paddle_tpu.inference.serving import STEP_SPAN
+    model = _model()
+    cfg = model.config
+
+    def counted():
+        fam = default_registry().get("serving_moe_expert_load_total")
+        return 0.0 if fam is None else sum(
+            fam.labels(expert=str(e)).value
+            for e in range(cfg.num_local_experts))
+
+    before = counted()
+    eng, _ = _run(model)
+    assert eng.mixed.n_stats == 2 + cfg.num_local_experts
+    recs = [e[5] for e in span_log.events()
+            if e[1] == STEP_SPAN and e[5]["engine"] == eng.engine_id]
+    per_token = cfg.num_experts_per_tok * cfg.num_hidden_layers
+    assert recs and all(f["moe_rows"] == f["tokens"] * per_token
+                        for f in recs)
+    assert counted() - before == sum(f["moe_rows"] for f in recs)
+    e_ep, _ = _run(model, mesh=ep_mesh(2))
+    assert e_ep.mixed.n_stats == 0
+
+
+def test_split_steps_run_the_sorted_product():
+    """The split path (``PrefillStep`` buckets + ``DecodeStep``) of a
+    Mixtral model calls the same ``_ffn``: same tokens as eager
+    ``generate``, and both traced steps hold the sorted form's scopes
+    and none of the buffer dispatch's (what the v5e compiler makes of
+    them: tests/test_tpu_compile.py)."""
+    model = _model()
+    refs = [_ref_tokens(model, p, 4) for p in PROMPTS]
+    eng, toks = _run(model, mixed_step=False, prefill_buckets=(4, 8),
+                     prefill_chunk_size=4)
+    assert toks == refs
+    for lowered in (eng.decode_step.aot_lower(4),
+                    eng.prefill_step.aot_lower(4)):
+        text = lowered.as_text(debug_info=True)
+        assert "/moe.sort/" in text and "/moe.experts/" in text
+        assert "/moe.dispatch/" not in text

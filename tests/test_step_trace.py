@@ -127,7 +127,20 @@ def test_one_record_a_step(family):
     assert sum(f["n_pre"] for f in launched) == sum(map(len, PROMPTS))
     assert recs[-1][2]["running"] == 0 and recs[-1][2]["waiting"] == 0
     assert sorted(r for _, _, f in recs for r in f["admitted"]) == rids
-
+    # the step's own count of the rows its experts were given: every
+    # real token lands on top_k experts in each layer, the budget's
+    # padding on none; a dense model counts nothing
+    cfg = eng.mixed.cfg
+    assert any(f["tokens"] < f["budget"] for f in launched)
+    for _, _, f in recs:
+        if family == "llama":
+            assert f["moe_rows"] == f["moe_rows_top"] == 0
+            continue
+        k, experts = cfg.num_experts_per_tok, cfg.num_local_experts
+        assert f["moe_rows"] == f["tokens"] * k * cfg.num_hidden_layers, f
+        # the fullest expert: at least the mean, at most every token
+        assert f["moe_rows_top"] * k <= f["moe_rows"] \
+            <= f["moe_rows_top"] * experts
 
 def test_requests_name_their_step():
     eng = _engine()
@@ -250,10 +263,13 @@ def test_compiled_step_names_its_parts(family):
     want = {"embed", "attn.qkv", "attn.rope", "attn.kv_write",
             "attn.kernel", "attn.out", "ffn", "lm_head", "sample"}
     if family == "mixtral":
-        want |= {"moe.gate", "moe.dispatch", "moe.experts", "moe.combine"}
+        want |= {"moe.gate", "moe.sort", "moe.experts", "moe.combine"}
     for scope in want:
         assert f"/{scope}/" in text, scope
     assert want <= STEP_SCOPES
+    # the ep branch's scopes stay named, and are not in a one-chip step
+    assert {"moe.dispatch", "ep.all_to_all"} <= STEP_SCOPES
+    assert "/moe.dispatch/" not in text and "/ep.all_to_all/" not in text
     if family == "llama":
         assert "moe." not in text
     # the optimized module: every matmul belongs to a part of the step

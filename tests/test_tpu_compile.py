@@ -219,6 +219,72 @@ def test_mixed_step_full_width_two_layers(v5e):
         2 * int(c.key_cache.nbytes) for c in eng.caches)
 
 
+def test_mixed_step_mixtral_full_width_two_layers(v5e):
+    """One whole fused step at Mixtral-8x7B's widths (hidden 4096, 8
+    experts of 14336, 2 a token, 32 x 128 heads over 8 kv heads), bf16,
+    2 layers, under the framework's own x64 setting: the optimized v5e
+    program multiplies the experts' rows with XLA:TPU's grouped matmul
+    (``ragged-dot*`` under ``moe.experts``) and holds no buffer an
+    expert (no shape that leads with ``[E, N*k``); the split
+    ``DecodeStep`` of the same model compiles too."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.jit.serving_step import (STEP_SCOPES, DecodeStep,
+                                             hlo_op_scopes)
+    from paddle_tpu.models.mixtral import (MixtralConfig,
+                                           MixtralForCausalLM)
+    assert jax.config.jax_enable_x64
+    paddle.seed(0)
+    E, K, HID, FFN = 8, 2, 4096, 14336
+    # built with thin experts, then every bank handed ONE full-width
+    # array of its shape: the compiler sees the published widths and the
+    # host neither draws nor holds 2.8 G random weights
+    cfg = MixtralConfig(
+        vocab_size=32000, hidden_size=HID, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=32,
+        num_key_value_heads=8, max_position_embeddings=2048,
+        num_local_experts=E, num_experts_per_tok=K, dtype="bfloat16")
+    model = MixtralForCausalLM(cfg)
+    model.bfloat16()
+    model.eval()
+    up = jnp.zeros((E, HID, FFN), jnp.bfloat16)
+    down = jnp.zeros((E, FFN, HID), jnp.bfloat16)
+    for layer in model.mixtral.layers:
+        blk = layer.block_sparse_moe
+        blk.w_gate._value, blk.w_up._value, blk.w_down._value = up, up, down
+    eng = ContinuousBatchingEngine(
+        model, max_batch_size=SPANS, num_blocks=PAGES, block_size=BLOCK,
+        max_seq_len=WIDTH * BLOCK, mixed_step=True,
+        prefill_chunk_size=CHUNK, use_pallas=True)
+    top = eng.token_budgets[-1]
+    assert eng.mixed.n_stats == 2 + E
+    lowered = eng.mixed.aot_lower(top, device_sharding=v5e)
+    assert "ragged_dot" in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    scopes = hlo_op_scopes(hlo)
+    assert {"moe.gate", "moe.sort", "moe.experts", "moe.combine",
+            "attn.kernel"} <= set(scopes.values()) <= STEP_SCOPES | {None}
+    assert not {"moe.dispatch", "ep.all_to_all"} & set(scopes.values())
+    grouped = [n for n in scopes if n.startswith("ragged-dot")]
+    assert len(grouped) >= 3 * cfg.num_hidden_layers
+    assert {scopes[n] for n in grouped} == {"moe.experts"}
+    # the buffers are gone: no instruction's shape leads with [E, N*k
+    assert not re.search(r"\[%d,%d[,\]]" % (E, top * K), hlo)
+    # the split decode step of the same model, for the same chip
+    # at the docs cell's 16 slots.  (XLA:TPU keeps its grouped-matmul
+    # kernel only where the sorted buffer's N*k rows are a multiple of
+    # 8: at 6 slots, 12 rows, it expands the product to the dense
+    # [E, N*k, .] form itself.  Every budget and slot count of the
+    # cells is such a multiple.)
+    slots = 16
+    dec = DecodeStep(model, eng.caches, use_pallas=True)
+    dhlo = dec.aot_lower(slots, device_sharding=v5e).compile().as_text()
+    dscopes = hlo_op_scopes(dhlo)
+    assert {dscopes[n] for n in dscopes if n.startswith("ragged-dot")} \
+        == {"moe.experts"}
+    assert not re.search(r"\[%d,%d[,\]]" % (E, slots * K), dhlo)
+
+
 # the latent (MLA) launch at DeepSeek-V2's widths: 128 heads over one
 # 512 + 64 row a token stored 640 wide, 128-token pages, 260 a span
 LATENT = {"decode": (16, 16), "top": (1024, 16)}
