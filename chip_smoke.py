@@ -3,7 +3,7 @@
 
 One process, no arguments: ``python3 chip_smoke.py``.  It drives the two
 main paths through the entry points a user calls — the fused ``TrainStep``
-and ``ContinuousBatchingEngine(mixed_step=True)`` — at the full WIDTH of
+and ``ContinuousBatchingEngine`` — at the full WIDTH of
 Llama-2-7B (hidden 4096, 32 heads x 128, 32 KV heads, ffn 11008, vocab
 32000, bf16), cut by DEPTH only (``DEPTH`` layers) to what one 16 GB v5e
 chip holds, with seeded random weights and prompts.  On a host with four
@@ -380,7 +380,7 @@ def latent_check(heads: int = 128, kv_lora: int = 512, rope: int = 64,
 
 
 # ---------------------------------------------------------------------------
-# phase 1: serve — ContinuousBatchingEngine(mixed_step=True)
+# phase 1: serve — ContinuousBatchingEngine
 # ---------------------------------------------------------------------------
 def serve_phase(cfg, prompt_lens, max_new_tokens: int, chunk: int,
                 num_blocks: int, max_batch_size: int, block_size: int = 16,
@@ -406,7 +406,7 @@ def serve_phase(cfg, prompt_lens, max_new_tokens: int, chunk: int,
         * block_size
     eng = ContinuousBatchingEngine(
         model, max_batch_size=max_batch_size, num_blocks=num_blocks,
-        block_size=block_size, max_seq_len=max_seq_len, mixed_step=True,
+        block_size=block_size, max_seq_len=max_seq_len,
         prefill_chunk_size=chunk, mesh=mesh)
     assert eng.mixed.use_pallas is expect_kernels, (
         f"mixed step use_pallas={eng.mixed.use_pallas!r}, expected "
@@ -459,8 +459,6 @@ def serve_phase(cfg, prompt_lens, max_new_tokens: int, chunk: int,
     assert eng.mixed.total_compiles <= len(eng.token_budgets), (
         f"{eng.mixed.total_compiles} compiles for "
         f"{len(eng.token_budgets)} budgets")
-    assert eng.decode_step.compile_count == 0, (
-        "mixed mode fell back to the split decode module")
     assert len(eng.caches[0]._free) == free0, (
         f"page leak: {len(eng.caches[0]._free)} free, started {free0}")
     after = _memory(devices)

@@ -6,78 +6,55 @@ block_multihead_attention
 driven by a request scheduler around AnalysisPredictor
 (paddle/fluid/inference/api/analysis_predictor.h:210 ZeroCopyRun).
 
-TPU-native design: the scheduler keeps a fixed number of decode SLOTS and
-one engine step is ONE jitted XLA module (jit/serving_step.DecodeStep)
-at that fixed slot count — all layers, the paged cache append, paged
-attention, the LM head and greedy sampling fused, with the per-layer KV
-pools donated so the append is an in-place HBM write.  Inactive slots
-are masked (token 0, seq_len 0, block table aimed at the cache's sink
-page), never dropped, so admission/eviction churn never changes a traced
-shape and the decode step compiles exactly once for the engine's
-lifetime.
+TPU-native design: the scheduler keeps a fixed number of SLOTS and one
+engine step is ONE jitted XLA module (``jit/serving_step.MixedStep``):
+every step packs the whole admission mix — each running slot as a
+length-1 decode span, each prefilling slot's next chunk as a span of up
+to ``prefill_chunk_size`` tokens, as many chunks as the budget holds —
+into one launch over the ragged paged attention kernel (Ragged Paged
+Attention, arXiv:2604.15464) with all layers, the paged cache write,
+the LM head and the sampler fused and the per-layer KV pools donated,
+so the write is an in-place HBM update.  Total tokens pad to a small
+geometric set of budgets and every span descriptor is traced data, so
+compiles are bounded by the budget count whatever the admission and
+eviction churn, a long prompt never stalls the running requests' TPOT,
+and prefill pays no engine round of its own.
 
-Prefill (Ragged Paged Attention, arXiv:2604.15464: mixed-length prefill
-without per-shape recompilation) has three coordinated layers:
-
-- **Bucketed**: with ``prefill_buckets`` set, prompts pad to a small
-  geometric set of length buckets and admission runs ONE compiled
-  ``PrefillStep`` per bucket (masked forward + fused page scatter +
-  on-device first-token sample), so total prefill compiles are bounded
-  by the bucket count instead of the prompt-length distribution.
-- **Chunked**: prompts longer than ``prefill_chunk_size`` split into
-  fixed-size chunks processed one per ``step()`` interleaved with
-  decode, so a long prompt never stalls every running request's TPOT.
-  Chunk offset is a traced scalar — chunks reuse the bucket compiles.
-- **Prefix cached** (``enable_prefix_cache``): refcounted KV pages plus
+- **Chunked prefill**: a prompt longer than ``prefill_chunk_size``
+  advances one chunk a step per prefilling slot, round-robin while the
+  top budget has room.
+- **Prefix cache** (``enable_prefix_cache``): refcounted KV pages plus
   a block-granularity prompt-prefix hash table
   (inference/prefix_cache.PrefixPageCache); an admitted request whose
   prefix hits shares those pages (refcount++, copy-on-write on the
   first partial page) and only prefills the suffix.  Eviction honors
   refcounts — a shared page is never reclaimed from under a live
   request's block table.
-
-**Mixed single-step mode** (``mixed_step=True``) supersedes the
-prefill/decode module split entirely: every engine step packs the whole
-admission mix — each running slot as a length-1 decode span, each
-prefilling slot's next chunk as a length-C span, as many chunks as the
-budget holds — into ONE fused ``MixedStep`` launch over the ragged
-paged attention kernel (arXiv:2604.15464).  Total tokens pad to a small
-geometric budget set, so compiles are bounded by the budget count, long
-prompts no longer pay one engine round per chunk, and prefill never
-stalls running TPOT.  The bucketed PrefillStep and legacy dense paths
-remain for ``mixed_step=False`` (the default — existing engines are
-byte-identical).
-
-Sampling + speculative decoding (round 14, both OFF by default):
-
 - **Stochastic sampling** (``sampling=True``): per-request temperature
-  / top-k / top-p / seed ride ``add_request`` and reach the fused
-  steps as traced data (the mixed pack grows four bitcast columns, the
-  split steps one [.., 4] int32 operand), sampled on device with a
-  counter-based PRNG keyed on (request seed, token position) — so a
-  sampled request's tokens are identical alone or batched, split or
-  mixed, single-chip or tp, and changing knobs/seeds never retraces.
+  / top-k / top-p / seed ride ``add_request`` and reach the step as
+  traced data (the pack grows four bitcast columns), sampled on device
+  with a counter-based PRNG keyed on (request seed, token position) —
+  so a sampled request's tokens are identical alone or batched,
+  single-chip or tp, and changing knobs/seeds never retraces.
   ``temperature=0`` requests take the exact greedy argmax.
-- **Speculative decoding** (``draft_model=``, needs ``mixed_step``): a
-  small draft model with its OWN per-layer paged pools — addressed by
-  the SAME page ids, so allocation/refcount/COW bookkeeping is shared
-  and prefix-cache hits carry draft KV for free — proposes ``spec_k``
-  tokens per engine round (k fused draft launches; prefill chunks
-  mirror into the draft pool in the same launches), and the target
-  verifies every slot's k+1 positions in ONE MixedStep launch using
-  length-(k+1) ragged spans.  Standard accept/reject with
-  rejection-resampling keeps the sampled output distribution exact;
-  greedy speculative output is BYTE-IDENTICAL to non-speculative
-  greedy (the CPU-checkable gate in ``bench_serving.py
-  --speculative``).  Pages grown for rejected draft positions roll
-  back through the refcounted release path (lazy mode).
+- **Speculative decoding** (``draft_model=``): a small draft model with
+  its OWN per-layer paged pools — addressed by the SAME page ids, so
+  allocation/refcount/COW bookkeeping is shared and prefix-cache hits
+  carry draft KV for free — proposes ``spec_k`` tokens per engine round
+  (k fused draft launches; prefill chunks mirror into the draft pool in
+  the same launches), and the target verifies every slot's k+1
+  positions in ONE MixedStep launch using length-(k+1) ragged spans.
+  Standard accept/reject with rejection-resampling keeps the sampled
+  output distribution exact; greedy speculative output is
+  BYTE-IDENTICAL to non-speculative greedy.  Pages grown for rejected
+  draft positions roll back through the refcounted release path (lazy
+  mode).
 
 Admission/eviction is host control flow; all math is jitted device
-compute, and the only per-step host traffic is the [slots] int32
-next-token fetch (plus one int32 scalar per non-mixed prefill chunk;
-a speculative round adds the k [slots] draft-token fetches and the
-verifier's [slots] accepted-count row — draft DISTRIBUTIONS stay on
-device).
+compute, and the only per-step host traffic is the one packed int32
+operand in and the [slots] int32 next-token fetch out (a speculative
+round adds the k [slots] draft-token fetches and the verifier's [slots]
+accepted-count row — draft DISTRIBUTIONS stay on device).
 """
 from __future__ import annotations
 
@@ -148,74 +125,60 @@ class GenerationRequest:
 
 
 class ContinuousBatchingEngine:
-    """Slot scheduler + single-compile batched paged decode for
-    LlamaForCausalLM.
+    """Slot scheduler around the fused mixed prefill+decode step, for a
+    LlamaForCausalLM-shaped model (dense, Mixtral MoE, DeepSeek-V2 MLA).
 
     add_request() may be called at any time (including between steps
     while other requests are mid-decode); step() advances every running
-    request by one token.  Greedy decoding — interleaved execution is
-    bit-identical to running each request alone (the test contract).
+    request by one token and every prefilling one by a chunk.  Greedy
+    decoding — interleaved execution is bit-identical to running each
+    request alone (the test contract).
 
     ``max_seq_len`` bounds prompt + generation per request and fixes the
-    block-table width (the compiled decode step's shape); it defaults to
-    the pool's fair share per slot, num_blocks * block_size //
+    block-table width (a traced shape of the step); it defaults to the
+    pool's fair share per slot, num_blocks * block_size //
     max_batch_size.
 
-    ``prefill_buckets``: None (default) keeps the legacy dense prefill
-    (one eager forward per prompt, re-traced per distinct length);
-    ``"auto"`` derives a geometric 32/64/.../top set from max_seq_len;
-    a tuple uses those widths.  ``prefill_chunk_size`` defaults to the
-    top bucket.  ``enable_prefix_cache`` requires buckets or
-    ``mixed_step`` (suffix-only prefill needs an offset-carrying
-    compiled step).
-
-    ``mixed_step=True`` replaces BOTH the decode module and the
-    prefill buckets with one fused step per total-token budget
-    (``token_budgets``: ``"auto"`` geometric set covering all-decode up
-    to slots+chunk, or an explicit tuple whose top must fit an
-    all-decode pack).  ``prefill_chunk_size`` bounds a single span.
+    ``prefill_chunk_size`` bounds a single prefill span (default: the
+    pow2 ceil of ``max_seq_len``, capped at 512).  ``token_budgets``:
+    ``"auto"`` is the geometric set covering all-decode up to
+    slots+chunk, or an explicit tuple whose top must fit an all-decode
+    pack; one module compiles per budget.
 
     ``mesh=`` (+ optional ``sharding=ShardingConfig(axis='tp')``)
-    makes the engine multi-chip: every fused step runs tensor-parallel
-    over the mesh's ``tp`` axis (see ``jit/spmd.py`` for the
-    per-weight-family spec layout), with KV pools sharded over kv
-    heads — per-chip pool HBM is 1/tp — and tokens byte-identical to
-    the single-chip engine (BENCH_SERVE_r12.json gates this).
-    Requires ``mixed_step=True`` or ``prefill_buckets`` (the legacy
-    dense prefill is eager, single-chip math).
+    makes the engine multi-chip: the step runs tensor-parallel over the
+    mesh's ``tp`` axis (see ``jit/spmd.py`` for the per-weight-family
+    spec layout), with KV pools sharded over kv heads — per-chip pool
+    HBM is 1/tp — and tokens byte-identical to the single-chip engine.
+    A ``cp`` axis stripes every pool's slot dim, an ``ep`` axis shards
+    the MoE expert banks.
 
-    Quantization (round 13; defaults off — the fp32/bf16 engine stays
-    byte-identical):
+    Quantization (tolerance-gated, not parity-gated: greedy token-match
+    rate against the fp32 engine, per workload, against declared
+    thresholds):
 
     - ``kv_dtype="int8"``: the paged pools store int8 codes plus
       per-page-per-head fp32 absmax scales — ~4× (fp32) / ~2× (bf16)
       pages per HBM byte, scales counted.  Writes quantize inside the
-      compiled steps, every attention path dequantizes into the same
-      fp32 online-softmax, COW/prefix sharing carry scales with pages.
+      compiled step, attention dequantizes into the same fp32
+      online-softmax, COW/prefix sharing carry scales with pages.
     - ``weight_quant="int8"``: per-output-channel absmax PTQ over the
       projection weights (``quantization.functional.
-      quantize_param_tree``); the steps dequantize on use, so HBM
+      quantize_param_tree``); the step dequantizes on use, so HBM
       holds the int8 tree (+ scale vectors) — ~4× smaller weights.
     - ``quant_collectives=True`` (needs ``mesh``): the tp logits
       all-gather moves int8 codes + per-shard scales (EQuARX-style,
       arXiv:2506.17615) instead of fp words.
 
-    All three are TOLERANCE-gated, not parity-gated: the quantization
-    bench (BENCH_QUANT_r13.json) reports greedy token-match rate vs
-    the fp32 engine per workload against declared thresholds.  Both
-    quant modes need a compiled prefill path (``mixed_step=True`` or
-    ``prefill_buckets``) — the legacy dense prefill runs eager fp
-    math and is rejected at construction.
+    Request tracing: the engine owns a bounded ``RequestTracer``
+    (``tracer=`` kwarg; default ON, ``False`` = the no-op stub)
+    recording typed per-request phase spans: enqueue, admit (+prefix
+    hit), per-chunk prefill, sampled decode steps, first token,
+    preempt, finish.  Host-side appends only, on the shared
+    ``perf_counter`` clock; a ``ServingRouter`` merges every pool
+    engine's spans into one fleet chrome trace (``fleet_trace``).
 
-    Request tracing (round 16): the engine owns a bounded
-    ``RequestTracer`` (``tracer=`` kwarg; default ON, ``False`` = the
-    no-op stub) recording typed per-request phase spans: enqueue,
-    admit (+prefix hit), per-chunk prefill, sampled decode steps,
-    first token, preempt, finish.  Host-side appends only, on the
-    shared ``perf_counter`` clock; a ``ServingRouter`` merges every
-    pool engine's spans into one fleet chrome trace (``fleet_trace``).
-
-    KV page migration + disaggregation (round 19, defaults off):
+    KV page migration + disaggregation:
     ``extract_request``/``inject_request`` move a running request's
     physical KV pages between engines as ONE batched host buffer per
     dtype (int8 scale rows travel free), so a preempted or
@@ -233,10 +196,9 @@ class ContinuousBatchingEngine:
                  max_seq_len: Optional[int] = None,
                  use_pallas: Optional[bool] = None,
                  lazy_alloc: bool = False,
-                 prefill_buckets=None,
                  prefill_chunk_size: Optional[int] = None,
                  enable_prefix_cache: bool = False,
-                 mixed_step: bool = False,
+                 mixed_step: bool = True,
                  token_budgets="auto",
                  mesh=None, sharding=None,
                  kv_dtype: Optional[str] = None,
@@ -248,7 +210,16 @@ class ContinuousBatchingEngine:
                  tracer=None,
                  role: str = "mixed",
                  host_tier_bytes: int = 0):
-        from ..jit.serving_step import DecodeStep, MixedStep, PrefillStep
+        from ..jit.serving_step import MixedStep
+        # not an option: the fused mixed step is the one serving path.
+        # The keyword survives only because benchmark/harness/program.py
+        # passes ``mixed_step=True`` literally (ROADMAP D1: the next
+        # benchmark PR drops it there, then this parameter goes)
+        if not mixed_step:
+            raise ValueError(
+                "mixed_step=False: the split prefill/decode path and "
+                "the eager dense prefill were removed in PR 29; the "
+                "fused mixed step is the engine's one serving path")
         self.model = model
         # disaggregated serving (round 19): a router routes fresh
         # prompts to "prefill" specialists (big token budgets, chunked)
@@ -267,19 +238,7 @@ class ContinuousBatchingEngine:
                              else engine_id)
         # ---- sampling / speculative validation (construction-time) --
         self.sampling = bool(sampling)
-        if self.sampling and not mixed_step and not prefill_buckets:
-            raise ValueError(
-                "stochastic sampling needs a compiled prefill path: "
-                "pass mixed_step=True or prefill_buckets='auto' — the "
-                "legacy dense prefill argmaxes its first token eagerly "
-                "and cannot apply per-request temperature/top-k/top-p")
         if draft_model is not None:
-            if not mixed_step:
-                raise ValueError(
-                    "speculative decoding (draft_model=) needs "
-                    "mixed_step=True: the target verifies all slots' "
-                    "k+1 positions as length-(k+1) ragged spans in one "
-                    "MixedStep launch")
             if mesh is not None or sharding is not None:
                 raise ValueError(
                     "speculative decoding is single-chip for now: the "
@@ -310,14 +269,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "ContinuousBatchingEngine weight_quant must be None or "
                 "'int8'; got %r" % (weight_quant,))
-        if (kv_dtype == "int8" or weight_quant == "int8") \
-                and not mixed_step and not prefill_buckets:
-            raise ValueError(
-                "quantized serving (kv_dtype='int8' / weight_quant="
-                "'int8') needs a compiled prefill path: pass "
-                "mixed_step=True or prefill_buckets='auto' — the legacy "
-                "dense prefill runs the model eagerly in fp and writes "
-                "unquantized K/V")
         if quant_collectives and mesh is None and sharding is None:
             raise ValueError(
                 "quant_collectives=True quantizes the tensor-parallel "
@@ -343,39 +294,31 @@ class ContinuousBatchingEngine:
         # ---- context-parallel serving (round 22) --------------------
         # a 'cp' mesh axis stripes every pool's slot dim: validated
         # HERE with actionable messages (block_size divisibility, no
-        # int8 pools, no legacy dense prefill, no spec-decode), never
-        # as a shard_map shape failure deep in tracing
+        # int8 pools, no spec-decode), never as a shard_map shape
+        # failure deep in tracing
         if self.cp_degree > 1:
             from ..jit.spmd import validate_cp_serving
             validate_cp_serving(
                 self.cp_degree, block_size,
                 quantized_kv=(kv_dtype == "int8"),
-                dense_prefill=(not mixed_step and not prefill_buckets),
                 spec_decode=draft_model is not None)
         # ---- expert-parallel MoE serving (round 24) -----------------
         # an 'ep' mesh axis shards the expert banks' E dim: validated
         # HERE with actionable messages (expert-count divisibility, no
-        # legacy dense prefill, no spec-decode), never as a shard_map
-        # shape failure; the token budgets are re-checked after they
-        # resolve below (every budget must stripe evenly over ep)
+        # spec-decode), never as a shard_map shape failure; the token
+        # budgets are re-checked after they resolve below (every budget
+        # must stripe evenly over ep)
         if self.ep_degree > 1:
             from ..jit.spmd import validate_ep_serving
             validate_ep_serving(
                 getattr(model.config, "num_local_experts", 0),
-                self.ep_degree, mixed_step=bool(mixed_step),
-                dense_prefill=(not mixed_step and not prefill_buckets),
+                self.ep_degree,
                 spec_decode=draft_model is not None)
         if quant_collectives and self.tp is None:
             raise ValueError(
                 "quant_collectives=True but the mesh's tp axis "
                 "degenerates to 1 chip — there is no logits all-gather "
                 "to quantize; use tp >= 2 or drop the flag")
-        if self.tp is not None and not mixed_step and not prefill_buckets:
-            raise ValueError(
-                "tensor-parallel serving needs a compiled prefill path: "
-                "pass mixed_step=True or prefill_buckets='auto' (the "
-                "legacy dense prefill runs the model eagerly on one "
-                "chip and cannot feed head-sharded KV pools)")
         # lazy_alloc: pages are allocated as a sequence actually grows
         # instead of reserving the full prompt+budget footprint at
         # admission — higher occupancy for the same pool, at the cost
@@ -407,8 +350,6 @@ class ContinuousBatchingEngine:
         self.latent = latent_attention(model)
         if self.latent is not None:
             for on, what in (
-                    (not mixed_step, "the split prefill/decode path "
-                     "(pass mixed_step=True)"),
                     (kv_dtype == "int8", "kv_dtype='int8' (its scales "
                      "are per kv head)"),
                     (self.tp is not None, "a mesh (tp/cp/ep shard "
@@ -466,87 +407,44 @@ class ContinuousBatchingEngine:
         self.waiting: List[GenerationRequest] = []
         self.finished: Dict[int, GenerationRequest] = {}
         self._next_id = 0
-        # slot-padded device-step inputs (fixed shapes forever): masked
-        # slots hold token 0 / seq_len 0 / an all-sink block-table row
+        # each slot's pending token: the one its next decode span feeds
         self._tokens = np.zeros((max_batch_size,), np.int32)
-        self._seq_lens = np.zeros((max_batch_size,), np.int32)
-        self._bt = np.full((max_batch_size, self.bt_width), self._sink,
-                           np.int32)
-        # per-slot packed sampling knobs (temperature bits, top_k,
-        # top_p bits, seed); all-zero = greedy, the masked-slot default
-        self._samp = np.zeros((max_batch_size, 4), np.int32)
-        self.decode_step = DecodeStep(
-            model, self.caches, use_pallas=use_pallas, tp=self.tp,
-            weight_qparams=self.weight_qtree,
-            quant_collectives=self.quant_collectives,
-            sampling=self.sampling)
 
-        # ---- bucketed / chunked prefill ------------------------------
-        if prefill_buckets == "auto":
-            buckets = self._auto_buckets(self.max_seq_len)
-        elif prefill_buckets:
-            buckets = tuple(sorted({int(b) for b in prefill_buckets}))
-        else:
-            buckets = None
-        self.prefill_buckets = buckets
-        if buckets:
-            self.chunk_size = int(prefill_chunk_size or buckets[-1])
-            if self.chunk_size > buckets[-1]:
-                raise ValueError(
-                    "prefill_chunk_size %d exceeds the top bucket %d — "
-                    "every chunk must map to a compiled bucket"
-                    % (self.chunk_size, buckets[-1]))
-            self.prefill_step = PrefillStep(
-                model, self.caches, self.bt_width,
-                use_pallas=use_pallas, tp=self.tp,
-                weight_qparams=self.weight_qtree,
-                quant_collectives=self.quant_collectives,
-                sampling=self.sampling)
-        else:
-            self.chunk_size = None
-            self.prefill_step = None
-        # ---- fused mixed prefill+decode step -------------------------
+        # ---- the fused mixed prefill+decode step ---------------------
         # (Ragged Paged Attention): ONE compiled module per total-token
         # budget advances decode slots AND prefill chunks together —
         # no per-chunk engine round, no prefill/decode module split
-        if mixed_step:
-            if self.chunk_size is None:
-                self.chunk_size = int(prefill_chunk_size
-                                      or self._auto_buckets(
-                                          self.max_seq_len)[-1])
-            # a speculative all-decode pack is slots x (k+1) verify
-            # tokens, not slots x 1 — size the budget base to it
-            base_spans = max_batch_size * (self.spec_k + 1)
-            if token_budgets == "auto":
-                budgets = self._auto_budgets_mixed(base_spans,
-                                                   self.chunk_size)
-            else:
-                budgets = tuple(sorted({int(b) for b in token_budgets}))
-                if not budgets or budgets[-1] < base_spans:
-                    raise ValueError(
-                        "top token budget %r < %d (max_batch_size x "
-                        "(spec_k+1)): an all-decode step would not fit"
-                        % (token_budgets, base_spans))
-            self.token_budgets = budgets
-            if self.ep_degree > 1:
-                from ..jit.spmd import validate_ep_serving
-                validate_ep_serving(
-                    getattr(cfg, "num_local_experts", 0),
-                    self.ep_degree, budgets=budgets)
-            self.mixed = MixedStep(model, self.caches, self.bt_width,
-                                   max_spans=max_batch_size,
-                                   use_pallas=use_pallas, tp=self.tp,
-                                   weight_qparams=self.weight_qtree,
-                                   quant_collectives=
-                                   self.quant_collectives,
-                                   sampling=self.sampling,
-                                   spec_k=self.spec_k)
-            # padding tokens spread over the sink page's slots
-            self._dest_pad = (np.arange(budgets[-1], dtype=np.int32)
-                              % block_size)
+        self.chunk_size = int(prefill_chunk_size
+                              or self._auto_chunk(self.max_seq_len))
+        # a speculative all-decode pack is slots x (k+1) verify
+        # tokens, not slots x 1 — size the budget base to it
+        base_spans = max_batch_size * (self.spec_k + 1)
+        if token_budgets == "auto":
+            budgets = self._auto_budgets_mixed(base_spans,
+                                               self.chunk_size)
         else:
-            self.token_budgets = None
-            self.mixed = None
+            budgets = tuple(sorted({int(b) for b in token_budgets}))
+            if not budgets or budgets[-1] < base_spans:
+                raise ValueError(
+                    "top token budget %r < %d (max_batch_size x "
+                    "(spec_k+1)): an all-decode step would not fit"
+                    % (token_budgets, base_spans))
+        self.token_budgets = budgets
+        if self.ep_degree > 1:
+            from ..jit.spmd import validate_ep_serving
+            validate_ep_serving(
+                getattr(cfg, "num_local_experts", 0),
+                self.ep_degree, budgets=budgets)
+        self.mixed = MixedStep(model, self.caches, self.bt_width,
+                               max_spans=max_batch_size,
+                               use_pallas=use_pallas, tp=self.tp,
+                               weight_qparams=self.weight_qtree,
+                               quant_collectives=self.quant_collectives,
+                               sampling=self.sampling,
+                               spec_k=self.spec_k)
+        # padding tokens spread over the sink page's slots
+        self._dest_pad = (np.arange(budgets[-1], dtype=np.int32)
+                          % block_size)
         # ---- speculative draft engine --------------------------------
         # the draft model's OWN per-layer pools, addressed by the SAME
         # page ids as the target's (caches[0] stays the one free-list /
@@ -611,12 +509,6 @@ class ContinuousBatchingEngine:
                 "cannot be reconstructed from it — drop "
                 "host_tier_bytes or drop draft_model")
         if enable_prefix_cache:
-            if not buckets and self.mixed is None:
-                raise ValueError(
-                    "enable_prefix_cache requires bucketed prefill "
-                    "(prefill_buckets='auto'/tuple) or mixed_step=True: "
-                    "suffix-only prefill needs an offset-carrying "
-                    "compiled step")
             from .prefix_cache import HostPageTier, PrefixPageCache
             self.host_tier = (HostPageTier(int(host_tier_bytes))
                               if host_tier_bytes else None)
@@ -656,11 +548,10 @@ class ContinuousBatchingEngine:
             "allocated KV pages / pool size")
         self._m_prefill = r.histogram(
             "serving_prefill_duration_seconds",
-            "prompt prefill (bucketed compiled chunk, or the legacy "
-            "dense forward + fused cache scatter)")
+            "one warm fused step whose pack advanced a prefill chunk")
         self._m_decode = r.histogram(
             "serving_decode_step_duration_seconds",
-            "one fused batched decode step (all slots)")
+            "one warm fused step whose pack advanced a decode span")
         self._m_ttft = r.histogram(
             "serving_ttft_seconds", "admission wait + prefill to first "
             "token (time-to-first-token)")
@@ -678,9 +569,6 @@ class ContinuousBatchingEngine:
             "serving_truncated_victims_total",
             "requests finished early because the KV pool ran dry "
             "(lazy_alloc victim contract)")
-        self._m_prefill_compiles = r.counter(
-            "serving_prefill_compiles_total",
-            "bucketed PrefillStep traces (bounded by the bucket count)")
         self._m_prefix_lookups = r.counter(
             "serving_prefix_cache_lookups_total",
             "prompt admissions checked against the prefix table",
@@ -821,8 +709,7 @@ class ContinuousBatchingEngine:
             "the MoE layers", labels=("expert",))
         self._m_moe_expert = [
             self._m_moe_expert_load.labels(expert=str(e))
-            for e in range(max(0, (self.mixed.n_stats
-                                   if self.mixed is not None else 0) - 2))]
+            for e in range(max(0, self.mixed.n_stats - 2))]
         self._m_latent_row = r.gauge(
             "serving_kv_latent_row_bytes",
             "bytes one token's cached latent row takes in one layer's "
@@ -897,18 +784,6 @@ class ContinuousBatchingEngine:
             "chunk mirror; compile warmup excluded)",
             buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                      0.25, 0.5, 1.0))
-        # compile warmup never lands in a latency histogram.  Bucketed
-        # prefill tracks warmth PER BUCKET via the step's own compile
-        # counters (a call that traced is cold, everything else is warm
-        # — chunk offset and raw prompt length don't retrace).  The
-        # legacy dense path re-traces per prompt length, so its warmth
-        # stays per-length.
-        self._prefill_warm_lens = set()
-        self._decode_warm = False
-        # step()-scoped collection of requests _finish'd during
-        # admission/prefill (None outside a step: direct _admit calls,
-        # e.g. benches, skip it)
-        self._finished_this_step = None
         # the step record (see step()): the index of the step that is
         # running or, between steps, of the next one; what the running
         # step has noted so far (None between steps); the request ids
@@ -932,21 +807,13 @@ class ContinuousBatchingEngine:
         self._efficiency_failed = False
 
     @staticmethod
-    def _auto_buckets(max_seq_len: int):
-        """Geometric 32/64/.../top, top = pow2 ceil of max_seq_len
-        capped at 512 (longer prompts prefill in chunks of the top
-        bucket)."""
+    def _auto_chunk(max_seq_len: int) -> int:
+        """The default prefill chunk: the pow2 ceil of max_seq_len,
+        capped at 512 (longer prompts prefill in chunks of it)."""
         top = 1
         while top < max_seq_len:
             top *= 2
-        top = min(top, 512)
-        out = []
-        b = 32
-        while b < top:
-            out.append(b)
-            b *= 2
-        out.append(top)
-        return tuple(sorted({x for x in out if x <= top}))
+        return min(top, 512)
 
     @staticmethod
     def _auto_budgets_mixed(slots: int, chunk: int):
@@ -1032,14 +899,12 @@ class ContinuousBatchingEngine:
 
     def step(self) -> List[int]:
         """Admit waiting requests, then advance the engine one round:
-        mixed mode packs every running slot's decode token AND as many
-        pending prefill chunks as the token budget holds into one fused
-        launch; the split mode advances at most one prefill chunk, then
-        decodes every running slot.  Returns req_ids finished this
-        step — including requests that completed DURING admission
-        (a one-token budget or EOS on the first sampled token ends a
-        request inside the prefill itself; multi-engine callers key on
-        the returned ids, so those must not go missing).
+        every running slot's decode token AND as many pending prefill
+        chunks as the token budget holds, packed into one fused launch.
+        Returns the req_ids finished this step (a one-token budget or
+        EOS on the first sampled token ends a request in the step that
+        prefilled it, and a lazy-alloc victim is truncated before the
+        launch; multi-engine callers key on the returned ids).
 
         The step records itself.  Under a profiler it is the span
         ``engine.step`` (``step_num`` = its index) holding six
@@ -1049,7 +914,6 @@ class ContinuousBatchingEngine:
         ONE ``serving.step`` record (``_write_step_record``) whose
         ``step`` is the same index: the join between the two clocks."""
         t0 = time.perf_counter()
-        self._finished_this_step = fts = []
         self._rec = {"budget": 0, "n_dec": 0, "n_pre": 0, "spans": [],
                      "compiled": False}
         try:
@@ -1058,13 +922,7 @@ class ContinuousBatchingEngine:
                 self._phase("engine.admit")
                 self._admit()
                 self._rec["t_admit"] = self._phase("engine.pack")
-                if self.mixed is not None:
-                    done = self._run_mixed_step()
-                else:
-                    self._prefill_chunks()
-                    done = self._decode_batch()
-                seen = set(done)
-                done += [rid for rid in fts if rid not in seen]
+                done = self._run_mixed_step()
                 running = sum(s is not None for s in self.slots)
                 self._m_queue.set(len(self.waiting))
                 self._m_occupancy.set(
@@ -1072,21 +930,17 @@ class ContinuousBatchingEngine:
                 cache = self.caches[0]
                 self._m_kv_util.set(
                     1.0 - len(cache._free) / max(1, cache.num_blocks))
-                if self.chunk_size is not None:
-                    # mixed chunks no longer consume a dedicated engine
-                    # round, but the backlog gauge still reports what
-                    # is pending
-                    self._m_chunk_queue.set(self._pending_chunks())
+                # chunks consume no engine round of their own, but the
+                # backlog gauge still reports what is pending
+                self._m_chunk_queue.set(self._pending_chunks())
                 self._sync_prefix_stats()
                 t_end = self._phase(None)
             if self.tracer.enabled:
                 self._write_step_record(t0, t_end, running)
         finally:
             # restore the documented outside-a-step invariants even on
-            # a raising step, so direct _admit/_finish callers between
-            # steps don't feed a stale list
+            # a raising step
             self._phase(None)
-            self._finished_this_step = None
             self._rec = None
             self._admitted = []
             self._step_no += 1
@@ -1130,8 +984,8 @@ class ContinuousBatchingEngine:
         - ``attn_rows``: the q rows per kv head that the mixed launch's
           attention computes in each layer (``MixedStep.attn_rows``):
           beside ``tokens`` x the GQA group size it says how much of
-          the launch is real.  0 on the split path, which has no ragged
-          launch.  For a latent model the rows are tokens x heads.
+          the launch is real.  For a latent model the rows are tokens
+          x heads.
         - ``moe_rows``, ``moe_rows_top``: the assignments that landed
           on the experts this engine holds, summed over the routed
           layers, and the fullest held expert's; counted by the step
@@ -1144,10 +998,9 @@ class ContinuousBatchingEngine:
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.
 
-        The speculative round and the split path fill the same fields
-        with what they have: the draft launches and the split path's
-        chunk launch count as ``pack``, ``n_dec`` counts verify spans
-        (``q_len`` k+1), and the split decode pads to the slot count.
+        The speculative round fills the same fields with what it has:
+        the draft launches count as ``pack`` and ``n_dec`` counts
+        verify spans (``q_len`` k+1).
 
         Bound: ``span_log`` keeps its newest 16,384 entries, from every
         writer (eight minutes of 30 ms steps); ``spans`` is one array,
@@ -1164,8 +1017,7 @@ class ContinuousBatchingEngine:
             tokens=int(spans[:, 1].sum()), n_dec=rec["n_dec"],
             n_pre=rec["n_pre"], spans=spans,
             attn_rows=(self.mixed.attn_rows(rec["budget"], spans[:, 1])
-                       if self.mixed is not None and rec["budget"]
-                       else 0),
+                       if rec["budget"] else 0),
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
             admitted=tuple(self._admitted), running=running,
@@ -1399,10 +1251,6 @@ class ContinuousBatchingEngine:
         req.prefix_hit_tokens = 0
         self.slots[slot] = req
         self._tokens[slot] = int(prompt[-1])
-        self._seq_lens[slot] = req.seq_len
-        self._bt[slot] = self._row_for(req)[0]
-        if self.sampling:
-            self._samp[slot] = self._samp_row(req)
         if self.prefix_cache is not None:
             # re-register the COVERED full pages under the same digest
             # chain (truncate the prompt to them: pages past n_tokens
@@ -1447,8 +1295,7 @@ class ContinuousBatchingEngine:
             "waiting": len(self.waiting),
             "free_pages": len(cache._free),
             "total_pages": cache.num_blocks,
-            "chunk_queue_depth": (self._pending_chunks()
-                                  if self.chunk_size is not None else 0),
+            "chunk_queue_depth": self._pending_chunks(),
             # round 20: pages the prefix cache could reclaim RIGHT NOW
             # (table entries no live request holds) — the capacity
             # plane's saturation must not read a cache-warm idle
@@ -1486,10 +1333,9 @@ class ContinuousBatchingEngine:
         NEVER compiles.
 
         The probed launch is the engine's steady-state decode shape:
-        the SMALLEST mixed token budget (an all-decode pack fits it)
-        or the split decode step at the slot count.  Per-token numbers
-        amortize over the launch's packed token capacity — padding
-        spans do sink-page work the device genuinely executes.  The
+        the SMALLEST token budget an all-decode pack fits.  Per-token
+        numbers amortize over the launch's packed token capacity —
+        padding spans do sink-page work the device genuinely executes.  The
         numbers describe the compiled XLA module, which on CPU is the
         XLA reference attention, not the interpret-mode Pallas kernel
         (BASELINE round-17 honesty note)."""
@@ -1507,22 +1353,14 @@ class ContinuousBatchingEngine:
         if not _cost_analysis_enabled():
             return None
         try:
-            if self.mixed is not None:
-                # the steady-state all-decode launch shape: the
-                # SMALLEST budget an all-decode pack fits (explicit
-                # budget sets only validate their TOP against it, so
-                # budgets[0] can be far smaller — probing it would
-                # amortize the weights over too few tokens and inflate
-                # the per-token numbers)
-                base = self.max_batch_size * (self.spec_k + 1)
-                T = min((b for b in self.token_budgets if b >= base),
-                        default=self.token_budgets[-1])
-                stats = self.mixed.compiled_stats(T)
-                kind = "mixed"
-            else:
-                stats = self.decode_step.compiled_stats(
-                    self.max_batch_size)
-                kind = "decode"
+            # explicit budget sets only validate their TOP against the
+            # all-decode pack, so budgets[0] can be far smaller —
+            # probing it would amortize the weights over too few tokens
+            # and inflate the per-token numbers
+            base = self.max_batch_size * (self.spec_k + 1)
+            T = min((b for b in self.token_budgets if b >= base),
+                    default=self.token_budgets[-1])
+            stats = self.mixed.compiled_stats(T)
         except Exception:                             # noqa: BLE001
             self._efficiency_failed = True
             return None
@@ -1530,7 +1368,7 @@ class ContinuousBatchingEngine:
             self._efficiency_failed = True
             return None
         self._efficiency_stats = {
-            "step": kind,
+            "step": "mixed",
             "tokens_per_launch": int(stats["tokens"]),
             "flops_per_token": float(stats["flops_per_token"]),
             "hbm_bytes_per_token": float(
@@ -1582,11 +1420,6 @@ class ContinuousBatchingEngine:
                 "evictable" % self.caches[0].num_blocks)
         return blk
 
-    def _row_for(self, req: GenerationRequest) -> np.ndarray:
-        row = np.full((1, self.bt_width), self._sink, np.int32)
-        row[0, :len(req.block_ids)] = req.block_ids
-        return row
-
     # ---- admission (prefill) -------------------------------------------
     def _admit(self):
         for i in range(self.max_batch_size):
@@ -1597,10 +1430,10 @@ class ContinuousBatchingEngine:
             self.waiting.pop(0)
 
     def _try_admit(self, req: GenerationRequest, slot: int) -> bool:
-        """Match the prompt against the prefix cache, reserve pages,
-        and start (or finish) the suffix prefill.  Returns False —
-        with NO side effects — when the pool cannot cover the request
-        yet."""
+        """Match the prompt against the prefix cache, reserve pages and
+        seat the request as "prefilling": its suffix chunks ride the
+        fused step packed this same step().  Returns False — with NO
+        side effects — when the pool cannot cover the request yet."""
         if req.parent_req is not None \
                 and req.parent_req.state in ("waiting", "prefilling"):
             # n>1 group: wait for the parent generation's prefill to
@@ -1684,65 +1517,9 @@ class ContinuousBatchingEngine:
                           prefix_hit_tokens=hit_len,
                           prompt_tokens=L,
                           enqueue_ts=req.t_submit, step=self._step_no)
-        if self.sampling:
-            self._samp[slot] = self._samp_row(req)
-        if self.mixed is not None:
-            # chunks ride the fused mixed step packed this same step()
-            # — admission never runs a separate prefill dispatch
-            pass
-        elif self.prefill_step is None:
-            self._prefill_dense(req)
-        elif L - hit_len <= self.chunk_size:
-            # suffix fits one bucket: prefill at admission (short
-            # prompts keep the old admit-then-decode-same-step timing)
-            self._prefill_chunk(req)
-        # else: long suffix — chunks advance one per step() interleaved
-        # with decode (_prefill_chunks)
         return True
 
-    # ---- legacy dense prefill (prefill_buckets=None) --------------------
-    def _prefill_dense(self, req: GenerationRequest):
-        """Run the whole prompt through the model's dense path once,
-        scatter the per-layer K/V into cache pages with ONE fused call,
-        sample the first token.  Re-traces per distinct prompt length —
-        the bucketed path exists to bound exactly that."""
-        import paddle_tpu as paddle
-        from ..autograd.tape import no_grad
-        from ..jit.serving_step import prefill_scatter
-        t_prefill = time.perf_counter()
-        L = len(req.prompt_ids)
-        ids = paddle.to_tensor(req.prompt_ids[None, :].astype(np.int64))
-        with no_grad():
-            logits, kv = self.model.forward(
-                ids, caches=[(None, None)] * self.cfg.num_hidden_layers)
-        row = self._row_for(req)
-        # k/v [1, L, Hkv, D] pre-GQA-repeat — one donated scatter over
-        # ALL layers (not a Python loop of per-layer dispatches)
-        prefill_scatter(self.caches, kv, row)
-        # first-token sample: argmax of the last position ON DEVICE —
-        # only one int32 scalar crosses the host link, never the
-        # [1, V] (let alone [1, L, V]) logits
-        first = int(jnp.argmax(
-            logits._value[0, -1, :].astype(jnp.float32)))
-        t_end = time.perf_counter()
-        if L in self._prefill_warm_lens:
-            self._m_prefill.observe(t_end - t_prefill)
-        self._prefill_warm_lens.add(L)
-        self.tracer.span(req.req_id, "prefill_dense", t_prefill, t_end,
-                         tokens=L)
-        self._note_split_prefill(req.req_id, L, L, False)
-        req.prefill_pos = L
-        self._complete_prefill(req, first, row)
-
-    # ---- bucketed / chunked prefill -------------------------------------
-    def _bucket_for(self, size: int) -> int:
-        for b in self.prefill_buckets:
-            if b >= size:
-                return b
-        raise AssertionError(
-            "chunk of %d tokens exceeds the top bucket %d"
-            % (size, self.prefill_buckets[-1]))
-
+    # ---- chunked prefill ------------------------------------------------
     def _pending_chunks(self) -> int:
         n = 0
         for r in self.slots:
@@ -1751,71 +1528,7 @@ class ContinuousBatchingEngine:
                 n += -(-rem // self.chunk_size)
         return n
 
-    def _prefill_chunks(self):
-        """Advance AT MOST one pending prefill chunk (round-robin over
-        slots): a long prompt pays its prefill one chunk per engine
-        step, interleaved with decode, instead of stalling every
-        running request's TPOT for its whole length."""
-        if self.prefill_step is None:
-            return
-        n = self.max_batch_size
-        for k in range(n):
-            i = (self._chunk_rr + k) % n
-            r = self.slots[i]
-            if r is not None and r.state == "prefilling":
-                self._prefill_chunk(r)
-                self._chunk_rr = (i + 1) % n
-                return
-
-    def _prefill_chunk(self, req: GenerationRequest):
-        """Run one bucket-padded chunk through the compiled PrefillStep;
-        on the final chunk, complete admission with the on-device
-        sampled first token."""
-        L = len(req.prompt_ids)
-        start = req.prefill_pos
-        size = min(self.chunk_size, L - start)
-        bucket = self._bucket_for(size)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :size] = req.prompt_ids[start:start + size]
-        row = self._row_for(req)
-        t0 = time.perf_counter()
-        pre = self.prefill_step.total_compiles
-        first = self.prefill_step(
-            toks, start, size, row,
-            self._samp_row(req) if self.sampling else None)
-        traced = self.prefill_step.total_compiles - pre
-        if self.tp is not None:
-            self._count_collectives(
-                self.prefill_step.collective_bytes(bucket))
-        t_end = time.perf_counter()
-        if traced:
-            # first compile of this bucket: count it, keep the warmup
-            # out of the latency histogram
-            self._m_prefill_compiles.inc(traced)
-        else:
-            self._m_prefill.observe(t_end - t0)
-        self.tracer.span(req.req_id, "prefill_chunk", t0, t_end,
-                         offset=start, tokens=size,
-                         warm=not traced, step=self._step_no)
-        self._note_split_prefill(req.req_id, size, start + size,
-                                 bool(traced))
-        req.prefill_pos += size
-        if req.prefill_pos >= L:
-            self._complete_prefill(req, first, row)
-
-    def _note_split_prefill(self, rid: int, size: int, kv_len: int,
-                            traced: bool):
-        """The split path prefills in launches of its own (at admission
-        or one chunk a step): the running step's record takes each as
-        a span."""
-        rec = self._rec
-        if rec is not None:
-            rec["spans"].append((rid, size, kv_len))
-            rec["n_pre"] += size
-            rec["compiled"] |= traced
-
-    def _complete_prefill(self, req: GenerationRequest, first: int,
-                          row: np.ndarray):
+    def _complete_prefill(self, req: GenerationRequest, first: int):
         slot = req.slot
         req.seq_len = len(req.prompt_ids)
         req.draft_len = req.seq_len        # draft pool mirrored the prompt
@@ -1826,10 +1539,8 @@ class ContinuousBatchingEngine:
         self._append_token(req, first)
         if self.slots[slot] is req:         # still running after budget
             self._tokens[slot] = first
-            self._seq_lens[slot] = req.seq_len
-            self._bt[slot] = row[0]
 
-    # ---- batched decode -------------------------------------------------
+    # ---- lazy page growth -----------------------------------------------
     def _grow_pages(self) -> List[int]:
         """Lazy mode: before the fused step runs, every running slot
         must own a real page for the position it writes this step
@@ -1839,7 +1550,7 @@ class ContinuousBatchingEngine:
         (often unblocking the others) and the batch keeps decoding.
         step() never raises for pool exhaustion."""
         truncated = []
-        for i, r in enumerate(list(self.slots)):
+        for r in list(self.slots):
             if r is None or r.state != "running":
                 continue
             need = self.caches[0].blocks_needed(r.seq_len + 1)
@@ -1849,7 +1560,6 @@ class ContinuousBatchingEngine:
                 if blk is None:
                     grew = False
                     break
-                self._bt[i, len(r.block_ids)] = blk
                 r.block_ids.append(blk)
             if not grew:
                 r.truncated = True
@@ -1857,53 +1567,6 @@ class ContinuousBatchingEngine:
                 self._finish(r)
                 truncated.append(r.req_id)
         return truncated
-
-    def _decode_batch(self) -> List[int]:
-        done = self._grow_pages() if self.lazy_alloc else []
-        if not any(r is not None and r.state == "running"
-                   for r in self.slots):
-            return done
-        # ONE fused XLA call at the fixed slot count; masked slots
-        # (empty OR still prefilling) ride along — their writes hit the
-        # sink page, their token is ignored
-        rec = self._rec
-        rec["t_pack"] = rec["t_fill"] = t_decode = self._phase(None)
-        # DecodeStep returns np.asarray(...) — the host fetch inside
-        # the call is the device barrier, so this window is honest
-        nxt = self.decode_step(self._tokens, self._seq_lens, self._bt,
-                               self._samp if self.sampling else None)
-        rec["t_tokens"] = t_end = self._phase("engine.book")
-        rec["t_dispatch"] = self.decode_step.t_dispatch
-        if self._decode_warm:
-            self._m_decode.observe(t_end - t_decode)
-        rec["compiled"] |= not self._decode_warm
-        self._decode_warm = True
-        if self.tp is not None:
-            self._count_collectives(
-                self.decode_step.collective_bytes(self.max_batch_size))
-        if self.tracer.enabled:
-            running = [r for r in self.slots
-                       if r is not None and r.state == "running"]
-            for r in running:
-                self.tracer.sample_span(
-                    r.req_id, "decode_step", t_decode, t_end,
-                    every=self.trace_decode_every, step=self._step_no)
-            rec["budget"] = self.max_batch_size
-            rec["n_dec"] = len(running)
-            rec["spans"].extend(
-                (r.req_id, 1, r.seq_len + 1) for r in running)
-        for i, r in enumerate(list(self.slots)):
-            if r is None or r.state != "running":
-                continue
-            r.seq_len += 1
-            self._seq_lens[i] += 1
-            tok = int(nxt[i])
-            self._append_token(r, tok)
-            if self.slots[i] is r:
-                self._tokens[i] = tok
-            if r.state == "done":
-                done.append(r.req_id)
-        return done
 
     # ---- fused mixed prefill+decode step --------------------------------
     @staticmethod
@@ -2001,8 +1664,8 @@ class ContinuousBatchingEngine:
     def _run_mixed_step(self) -> List[int]:
         """Pack the admission mix into ONE fused MixedStep launch: build
         the per-token and per-span tables on the host (control flow),
-        pad to the smallest token budget, dispatch, then apply the same
-        bookkeeping the split decode/prefill paths used."""
+        pad to the smallest token budget, dispatch, then book every
+        span's sampled token."""
         if self.draft_step is not None:
             return self._run_spec_round()
         rec = self._rec
@@ -2079,7 +1742,6 @@ class ContinuousBatchingEngine:
             if kind == "decode":
                 i = r.slot
                 r.seq_len += 1
-                self._seq_lens[i] += 1
                 self._append_token(r, tok)
                 if self.slots[i] is r:
                     self._tokens[i] = tok
@@ -2090,7 +1752,7 @@ class ContinuousBatchingEngine:
                 if r.prefill_pos >= len(r.prompt_ids):
                     # final chunk: tok is the on-device-sampled first
                     # token (earlier chunks' samples are discarded)
-                    self._complete_prefill(r, tok, self._row_for(r))
+                    self._complete_prefill(r, tok)
                     if r.state == "done":
                         done.append(r.req_id)
         return done
@@ -2121,7 +1783,6 @@ class ContinuousBatchingEngine:
                     if blk is None:
                         ok = False
                         break
-                    self._bt[r.slot, len(r.block_ids)] = blk
                     r.block_ids.append(blk)
                 if ok:
                     break
@@ -2305,8 +1966,7 @@ class ContinuousBatchingEngine:
             if r.state == "prefilling":
                 r.prefill_pos += len(toks)
                 if r.prefill_pos >= len(r.prompt_ids):
-                    self._complete_prefill(r, int(nxt[si]),
-                                           self._row_for(r))
+                    self._complete_prefill(r, int(nxt[si]))
                     if r.state == "done":
                         done.append(r.req_id)
                 continue
@@ -2323,7 +1983,6 @@ class ContinuousBatchingEngine:
             out_toks = drafts[r.slot][:na] + [int(nxt[si])]
             for t in out_toks:
                 r.seq_len += 1
-                self._seq_lens[r.slot] += 1
                 emitted += 1
                 self._append_token(r, t)
                 if r.state == "done":
@@ -2338,7 +1997,6 @@ class ContinuousBatchingEngine:
                     keep = len(c.trim_blocks(r.block_ids,
                                              r.seq_len + 1))
                     del r.block_ids[keep:]
-                    self._bt[r.slot, keep:] = self._sink
         if emitted:
             self._m_mixed_tok_decode.inc(emitted)
         return done
@@ -2392,16 +2050,11 @@ class ContinuousBatchingEngine:
         """Mask the request's slot back to the sink page and release
         its pages through the ONE refcounted path.  Shared by
         ``_finish`` and ``preempt_request`` — every per-slot state
-        field (tokens, seq_lens, block table, sampling knobs) must be
-        cleared HERE and nowhere else, so the two release sites cannot
-        drift as new fields are added."""
+        field must be cleared HERE and nowhere else, so the two release
+        sites cannot drift as new fields are added."""
         if req.slot >= 0:
-            s = req.slot
-            self.slots[s] = None
-            self._tokens[s] = 0
-            self._seq_lens[s] = 0
-            self._bt[s, :] = self._sink
-            self._samp[s, :] = 0
+            self.slots[req.slot] = None
+            self._tokens[req.slot] = 0
         # the SINGLE release path: refcounted — pages shared with the
         # prefix table or another live request survive this drop
         self.caches[0].free_sequence(req.block_ids)
@@ -2409,10 +2062,6 @@ class ContinuousBatchingEngine:
 
     def _finish(self, req: GenerationRequest):
         req.state = "done"
-        # surface admission-time completions in this step()'s return
-        # (the decode/mixed loops build their own lists; step() dedupes)
-        if getattr(self, "_finished_this_step", None) is not None:
-            self._finished_this_step.append(req.req_id)
         n_tok = len(req.output_ids)
         self._m_requests.labels(
             outcome="truncated" if req.truncated else "completed").inc()

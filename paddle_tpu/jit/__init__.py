@@ -18,11 +18,10 @@ from .api import to_static, StaticFunction, not_to_static, ignore_module
 from .save_load import save, load, TranslatedLayer
 from .api import enable_to_static
 from .convert_ops import bounded_loops
-from .serving_step import DecodeStep
 
 __all__ = ["to_static", "StaticFunction", "save", "load", "TranslatedLayer",
            "bounded_loops",
-           "not_to_static", "enable_to_static", "DecodeStep"]
+           "not_to_static", "enable_to_static"]
 
 
 # -- translator logging knobs (parity: paddle/jit/dy2static/logging_utils

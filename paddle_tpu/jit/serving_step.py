@@ -1,32 +1,30 @@
-"""Fully-fused compiled serving steps (decode + prefill scatter).
+"""The fully-fused compiled serving step, and the page-moving helpers.
 
-The serving analog of ``TrainStep``: one engine decode step — every
-transformer layer (projections, fused RoPE, paged KV-cache append,
-paged attention, MLP), the final norm, the LM head, and greedy sampling
-— traced into ONE XLA module at a fixed slot count, with the per-layer
-KV-cache pages passed as donated arguments so the append is an in-place
-HBM update.  Parity intent: the reference's ``AnalysisPredictor::
-ZeroCopyRun`` single-graph serving execution (analysis_predictor.h:210)
-driven per token by the block_multihead_attention kernel.
+The serving analog of ``TrainStep``: one engine step — every
+transformer layer (projections, fused RoPE, paged KV-cache write,
+ragged paged attention, MLP or MoE), the final norm, the LM head and
+the sampler — traced into ONE XLA module per total-token budget
+(:class:`MixedStep`), with the per-layer KV-cache pages passed as
+donated arguments so the write is an in-place HBM update.  Parity
+intent: the reference's ``AnalysisPredictor::ZeroCopyRun`` single-graph
+serving execution (analysis_predictor.h:210) driven per token by the
+block_multihead_attention kernel.
 
-Shape policy: the batch dimension is the engine's slot count, NEVER the
-number of active requests.  Inactive slots are masked, not dropped —
-their token id is 0, their seq_len is 0, and their block-table row
-points every entry at the cache's sink page (PagedKVCache
-``sink_block``), so their writes land in a page no request owns and
-their sampled token is ignored by the host.  Admission, eviction and
-slot churn therefore never change a traced shape: the decode step
-compiles exactly once per engine lifetime (``compile_count`` asserts
-this in tests).
+Shape policy: the only traced shape that varies is the token budget.
+Every span descriptor is traced data, padding tokens write to the
+cache's sink page (PagedKVCache ``sink_block``) and padding spans' rows
+are ignored by the host, so admission, eviction and slot churn never
+change a traced shape: compiles are bounded by the budget-set size
+(``compile_counts`` asserts this in tests).
 
-The only per-step host traffic is the [slots] int32 next-token fetch —
-sampling runs on device, so the 1-token logits tensor never crosses the
-link.
+The only per-step host traffic is one packed int32 operand in and the
+[max_spans] int32 next-token fetch out — sampling runs on device, so
+the logits never cross the link.
 
-Tensor parallelism (multi-chip serving): every step accepts
+Tensor parallelism (multi-chip serving): the step accepts
 ``mesh + ShardingConfig(axis='tp')`` (or a prebuilt
-:class:`~.spmd.TPContext`, which the engine shares across its steps so
-parameters are placed once).  The SAME traced body then runs as an
+:class:`~.spmd.TPContext`, which the engine shares with its draft step
+so parameters are placed once).  The SAME traced body then runs as an
 explicit SPMD program (``shard_map`` over the tp axis): weights shard
 by the canonical per-family specs in ``jit/spmd.py`` (vocab-row
 embeddings, head-column QKV, head-row attention-out, ffn-column
@@ -35,53 +33,45 @@ over kv heads (each chip's paged-attention launch sees only its head
 shard of every page), and activations cross chip boundaries through
 exactly one psum per layer boundary (attention out, MLP out) plus one
 exact embedding psum and one exact logits all-gather.  Donation, the
-compile-count invariants, and the single packed int32 host transfer
-all survive sharding unchanged.
+compile-count invariant and the single packed int32 host transfer all
+survive sharding unchanged.
 
-Quantization (round 13): when the engine's pools are int8
-(``PagedKVCache(kv_dtype="int8")``) the same traced bodies switch to
-the quantize-on-write/dequant-on-read ops and thread the per-layer
-scale tables through as extra donated operands (EMPTY tuples on the fp
-path, so the default trace — and compiled module — stays
-byte-identical); a serving-PTQ weight tree (int8 + ``::scale``
-vectors) replaces the fp params operand and ``_materialize_params``
-dequantizes it inside the trace; ``quant_collectives`` swaps the exact
-tp logits all-gather for the EQuARX-style int8 one.  All
-tolerance-gated by ``tools/bench_serving.py --quant``
-(BENCH_QUANT_r13.json).
+Quantization: when the engine's pools are int8
+(``PagedKVCache(kv_dtype="int8")``) the traced body switches to the
+quantize-on-write/dequant-on-read ops and threads the per-layer scale
+tables through as extra donated operands (EMPTY tuples on the fp path,
+so the fp trace holds none of it); a serving-PTQ weight tree (int8 +
+``::scale`` vectors) replaces the fp params operand and
+``_materialize_params`` dequantizes it inside the trace;
+``quant_collectives`` swaps the exact tp logits all-gather for the
+EQuARX-style int8 one.  All tolerance-gated.
 
-Sampling + speculative decoding (round 14): ``sampling=True`` swaps
-the greedy argmax for the ``ops/sampling`` epilogue — per-request
-temperature / top-k / top-p with a per-slot seeded counter-based PRNG
-(``fold_in`` on the request seed + the sampled token's global
-position).  Every knob and seed is traced DATA: the split steps take
-one extra ``[..., 4]`` int32 operand (fp knobs BITCAST into the int32
-lane), the mixed step grows its packed buffer's span rows by four
-columns — so changing a temperature or a seed never retraces, and
-``temperature=0`` rows take the exact greedy argmax.  Under tp the
-epilogue runs AFTER the exact logits all-gather on replicated data, so
-tp sampling is byte-identical to single-chip.  ``spec_k=K`` puts the
-speculative VERIFY epilogue into the mixed step: spans may carry up to
-K draft tokens (an ``n_draft`` pack column), the LM head sees each
-span's K+2 gathered rows instead of 1, and the standard accept/reject
-+ rejection-resampling scan (``ops/sampling.spec_verify``) emits
-``(token, n_acc)`` per span.  ``return_probs=True`` (the draft
-model's role) additionally returns each span's filtered proposal
-distribution, device-resident, for the verifier's residual.  All off
-by default — a default-config step's operand pytree and traced body
-are byte-identical to round 13.
+Sampling + speculative decoding: ``sampling=True`` swaps the greedy
+argmax for the ``ops/sampling`` epilogue — per-request temperature /
+top-k / top-p with a per-slot seeded counter-based PRNG (``fold_in`` on
+the request seed + the sampled token's global position).  Every knob
+and seed is traced DATA: the packed buffer's span rows grow by four
+columns (fp knobs BITCAST into the int32 lane) — so changing a
+temperature or a seed never retraces, and ``temperature=0`` rows take
+the exact greedy argmax.  Under tp the epilogue runs AFTER the exact
+logits all-gather on replicated data, so tp sampling is byte-identical
+to single-chip.  ``spec_k=K`` puts the speculative VERIFY epilogue into
+the step: spans may carry up to K draft tokens (an ``n_draft`` pack
+column), the LM head sees each span's K+2 gathered rows instead of 1,
+and the standard accept/reject + rejection-resampling scan
+(``ops/sampling.spec_verify``) emits ``(token, n_acc)`` per span.
+``return_probs=True`` (the draft model's role) additionally returns
+each span's filtered proposal distribution, device-resident, for the
+verifier's residual.
 
-Kernel performance pass (round 17): every traced body routes the
-per-layer pre-attention transforms through the fused RoPE+QKV
+The per-layer pre-attention transforms run through the fused RoPE+QKV
 epilogue (``ops/pallas_kernels.rope_qkv_epilogue`` — rope(q), rope(k)
-and, on int8 pools, the per-token K/V absmax rows in ONE pass over
-the projection outputs; one Pallas kernel on TPU, a bit-identical XLA
+and, on int8 pools, the per-token K/V absmax rows in ONE pass over the
+projection outputs; one Pallas kernel on TPU, a bit-identical XLA
 reference on CPU), with the cos/sin tables built once per step
 (``rope_tables_for_positions``) instead of once per layer.  The
 quantized writes consume the epilogue's absmax rows instead of
-re-reading k/v.  fp32 outputs are byte-identical to the round-16
-wiring; the attention kernels underneath gained double-buffered page
-DMA and the int8 MXU path (see BASELINE.md "round 17").
+re-reading k/v.
 """
 from __future__ import annotations
 
@@ -100,8 +90,7 @@ from ..core.tensor import Tensor
 from .spmd import (TPContext, tp_embed, tp_gather_logits,
                    tp_gather_logits_q8, tp_serving_context)
 
-__all__ = ["DecodeStep", "PrefillStep", "MixedStep", "prefill_scatter",
-           "copy_block", "extract_blocks", "inject_blocks",
+__all__ = ["MixedStep", "copy_block", "extract_blocks", "inject_blocks",
            "migration_compiles", "migration_transfers", "STEP_SCOPES",
            "hlo_op_scopes"]
 
@@ -236,17 +225,9 @@ def latent_attention(model):
     return None
 
 
-def _refuse_latent(model, who: str) -> None:
-    if latent_attention(model) is not None:
-        raise ValueError(
-            "%s (the split prefill/decode path) is not taught the "
-            "latent (MLA) cache row: serve this model with "
-            "mixed_step=True" % who)
-
-
 def _embed(llama, tokens, tp: Optional[TPContext]) -> Tensor:
-    """Embedding lookup shared by all three traced bodies: the module's
-    gather single-chip (and pure-fsdp, whose params are full after the
+    """The traced body's embedding lookup: the module's gather
+    single-chip (and pure-fsdp, whose params are full after the
     prologue gather), the vocab-parallel masked lookup + exact psum
     under tp.  ``tokens`` already carries the body's batch shape."""
     if tp is None or tp.axis is None:
@@ -322,8 +303,8 @@ def _ffn_module(layer):
 
 def _ffn(layer, h2: Tensor, tp: Optional[TPContext], real=None,
          loads: Optional[list] = None) -> Tensor:
-    """Per-layer FFN dispatch shared by all three traced bodies, by the
-    body the layer's FFN module declares (``_step_body``): the MoE
+    """Per-layer FFN dispatch of the traced body, by the body the
+    layer's FFN module declares (``_step_body``): the MoE
     whose bank holds every expert of its router, the MoE that holds a
     share of them, and for a module that declares none its own forward
     (a Megatron-sharded dense MLP) with its psum boundary.  ``real``
@@ -552,51 +533,6 @@ def _wrap_sharded(step, tp: TPContext, params_dict, n_layers: int,
     return jax.jit(fn, donate_argnums=donate,
                    in_shardings=tp.named(in_specs),
                    out_shardings=tp.named(out_specs))
-
-
-def _prefill_scatter_impl(ks, vs, kcs, vcs, block_tables, start):
-    """Scatter one request's per-layer prompt K/V ([1, L, Hkv, D] each)
-    into the per-layer page pools in a single traced module."""
-    from ..ops.paged_attention import write_prefill_kv
-    new_k, new_v = [], []
-    for k, v, kc, vc in zip(ks, vs, kcs, vcs):
-        kc, vc = write_prefill_kv(k, v, kc, vc, block_tables, start)
-        new_k.append(kc)
-        new_v.append(vc)
-    return tuple(new_k), tuple(new_v)
-
-
-# donate the cache pools: prefill admission is an in-place HBM write.
-# One XLA dispatch per REQUEST (all layers fused), not one per layer —
-# recompiles only per distinct prompt length (the scatter is tiny).
-_prefill_scatter_j = jax.jit(_prefill_scatter_impl, donate_argnums=(2, 3))
-
-
-def prefill_scatter(caches, kv, block_table_row):
-    """Write a freshly-prefilled request's K/V into the paged caches.
-
-    caches: per-layer PagedKVCache list (rebound in place).
-    kv: per-layer (k, v) Tensors/arrays [1, L, Hkv, D] from the model's
-    dense prefill forward.  block_table_row: [1, W] int32.
-    """
-    if getattr(caches[0], "quantized", False):
-        raise NotImplementedError(
-            "prefill_scatter is the legacy dense-prefill write and does "
-            "not quantize; int8 KV pools prefill through the compiled "
-            "PrefillStep/MixedStep paths (the engine rejects the combo "
-            "at construction)")
-    ks = tuple(k._value if isinstance(k, Tensor) else jnp.asarray(k)
-               for k, _ in kv)
-    vs = tuple(v._value if isinstance(v, Tensor) else jnp.asarray(v)
-               for _, v in kv)
-    kcs = tuple(c.key_cache for c in caches)
-    vcs = tuple(c.value_cache for c in caches)
-    bt = jnp.asarray(np.asarray(block_table_row), jnp.int32)
-    start = jnp.zeros((1,), jnp.int32)
-    new_k, new_v = _prefill_scatter_j(ks, vs, kcs, vcs, bt, start)
-    for c, kc, vc in zip(caches, new_k, new_v):
-        c.key_cache = kc
-        c.value_cache = vc
 
 
 def _copy_block_impl(kcs, vcs, src, dst):
@@ -847,9 +783,9 @@ def inject_blocks(caches, buf, dest_blocks):
 def compiled_cost_stats(lowered, tokens: int) -> dict:
     """FLOPs + byte traffic of ONE compiled serving-step module — the
     serving twin of ``TrainStep.compiled_stats`` (the round-9 MFU
-    source), shared by all three step classes.  ``tokens`` is the
-    launch's packed token capacity (a budget-``T`` mixed launch
-    advances up to T real tokens; padding spans do sink-page work the
+    source).  ``tokens`` is the launch's packed token capacity (a
+    budget-``T`` launch advances up to T real tokens; padding spans do
+    sink-page work the
     device genuinely executes, so per-token numbers are the honest
     full-launch amortization).  XLA reports PER-DEVICE numbers, so the
     consumer divides by per-chip peak — never peak x device_count.
@@ -884,291 +820,6 @@ def compiled_cost_stats(lowered, tokens: int) -> dict:
             stats["hbm_bytes_per_token"] = \
                 stats["bytes_accessed"] / tokens
     return stats
-
-
-class PrefillStep:
-    """Bucketed/chunked prefill compiled into one donated XLA module per
-    LENGTH BUCKET — the prefill analog of ``DecodeStep``.
-
-    ``__call__(tokens, start, n_valid, block_table_row)`` runs one
-    padded chunk of a prompt: embeds the [1, C] bucket-padded token
-    block, and per layer projects, applies RoPE at global positions
-    ``start + i``, scatters the chunk's K/V into cache pages (padding
-    routed to the sink page), and attends causally over everything
-    cached so far (earlier chunks / shared prefix pages included).  The
-    final hidden state is sliced to the LAST VALID position before the
-    LM head — the [C, V] logits block is never materialized — and the
-    next token is sampled (greedy) on device, so the step's only host
-    traffic is one int32 scalar.
-
-    Shape policy: chunk offset (``start``) and fill level (``n_valid``)
-    are traced scalars, so total prefill compiles are bounded by the
-    BUCKET COUNT — not the prompt-length distribution, not the chunk
-    position, not the prefix-hit split.  ``compile_counts`` maps bucket
-    width -> trace count (tests and the bench gate on it).
-    """
-
-    def __init__(self, model, caches: List, bt_width: int,
-                 use_pallas: Optional[bool] = None,
-                 mesh=None, sharding=None,
-                 tp: Optional[TPContext] = None,
-                 weight_qparams=None, quant_collectives: bool = False,
-                 sampling: bool = False):
-        from ..core.device import on_tpu
-        self.model = model
-        self.caches = caches
-        self.cfg = model.config
-        self.bt_width = bt_width
-        # the chunk attention is XLA; the flag decides the rope epilogue
-        self.use_pallas = on_tpu() if use_pallas is None else use_pallas
-        self.sampling = bool(sampling)
-        self.sink = caches[0].sink
-        if self.sink < 0:
-            raise ValueError("PrefillStep needs a sink page "
-                             "(PagedKVCache(sink_block=True)) to mask "
-                             "bucket padding writes")
-        self._tp = _resolve_tp(model, mesh, sharding, tp)
-        self._quant_kv = bool(getattr(caches[0], "quantized", False))
-        self._wq = weight_qparams
-        self._q8_gather = bool(quant_collectives)
-        _ensure_quant_specs(self._tp, weight_qparams)
-        self._param_tensors = dict(model.state_dict())
-        self._fns = {}                 # bucket width -> jitted step
-        self.compile_counts = {}       # bucket width -> trace count
-
-    @property
-    def total_compiles(self) -> int:
-        return sum(self.compile_counts.values())
-
-    def collective_bytes(self, C: int):
-        """Per-chip collective payload of one sharded chunk of bucket
-        width ``C`` ({} when single-chip; one logits row)."""
-        if self._tp is None:
-            return {}
-        return self._tp.collective_bytes(self.cfg, C, 1,
-                                         quant_gather=self._q8_gather)
-
-    def _build(self, C: int):
-        from ..autograd.tape import no_grad
-        _refuse_latent(self.model, "PrefillStep")
-        from ..ops.paged_attention import (chunk_prefill_attention,
-                                           write_chunk_kv,
-                                           write_chunk_kv_q8)
-        from ..ops.pallas_kernels import (rope_qkv_epilogue,
-                                          rope_tables_for_positions)
-        model = self.model
-        cfg = self.cfg
-        llama = _inner_model(model)
-        tp = self._tp
-        deg = tp.degree if tp is not None else 1
-        H = cfg.num_attention_heads // deg      # this chip's head shard
-        Hkv = cfg.num_key_value_heads // deg
-        D = cfg.hidden_size // cfg.num_attention_heads
-        scale = 1.0 / math.sqrt(D)
-        sink = self.sink
-        use_pallas = self.use_pallas
-        quant_kv = self._quant_kv
-        q8_gather = self._q8_gather
-        pdtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-        cp_axis = tp.cp_axis if tp is not None else None
-        cp_deg = tp.cp_degree if tp is not None else 1
-        if cp_deg > 1:
-            from ..ops.online_softmax import cross_chip_merge
-            from ..ops.paged_attention import (
-                chunk_prefill_attention_partial, write_ragged_kv)
-
-        sampling = self.sampling
-        if sampling:
-            from ..ops.sampling import sample_logits
-
-        def step(params, tokens, start, n_valid, bt, samp, kcs, vcs,
-                 kss, vss):
-            self.compile_counts[C] = self.compile_counts.get(C, 0) + 1
-            params = _materialize_params(params, pdtype)
-            new_kcs, new_vcs = [], []
-            new_kss, new_vss = [], []
-            with model.bind_state(params), no_grad():
-                with jax.named_scope("embed"):
-                    x = _embed(llama, tokens, tp)
-                    if cfg.dtype == "bfloat16":
-                        x = x.astype("bfloat16")
-                with jax.named_scope("attn.rope"):
-                    pos = start + jnp.arange(C, dtype=jnp.int32)
-                    cos_t, sin_t = rope_tables_for_positions(
-                        pos, D, cfg.rope_theta)
-                for li, (layer, kc, vc) in enumerate(
-                        zip(llama.layers, kcs, vcs)):
-                    attn = layer.self_attn
-                    with jax.named_scope("attn.qkv"):
-                        h = layer.input_layernorm(x)
-                        q = attn.q_proj(h).reshape([1, C, H, D])
-                        k = attn.k_proj(h).reshape([1, C, Hkv, D])
-                        v = attn.v_proj(h).reshape([1, C, Hkv, D])
-                    with jax.named_scope("attn.rope"):
-                        qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
-                            q._value[0], k._value[0], v._value[0],
-                            cos_t, sin_t, with_amax=quant_kv,
-                            use_pallas=use_pallas)
-                    with jax.named_scope("attn.kv_write"):
-                        if quant_kv:
-                            kc, vc, ks, vs = write_chunk_kv_q8(
-                                kv_[None], v._value, kc, vc, kss[li],
-                                vss[li], bt, start, n_valid, sink,
-                                k_amax=k_amax, v_amax=v_amax)
-                            new_kss.append(ks)
-                            new_vss.append(vs)
-                        else:
-                            ks = vs = None
-                            if cp_deg > 1:
-                                # chunked prefill writes ONLY the owning
-                                # stripe (sequence-parallel scatter): the
-                                # global destination mirrors write_chunk_kv
-                                # at the GLOBAL block size, then the
-                                # stripe-local translation routes non-owned
-                                # rows to this chip's sink stripe
-                                bsl = kc.shape[1]
-                                gbs = bsl * cp_deg
-                                idx_c = jnp.arange(C, dtype=jnp.int32)
-                                pos_c = start.astype(jnp.int32) + idx_c
-                                blk_g = bt[0, pos_c // gbs]
-                                valid = idx_c < n_valid
-                                blk_g = jnp.where(valid, blk_g,
-                                                  jnp.int32(sink))
-                                goff = jnp.where(valid, pos_c % gbs, 0)
-                                blk, off = _cp_local_dest(
-                                    blk_g, goff, bsl, cp_axis, sink)
-                                kc, vc = write_ragged_kv(
-                                    kv_, v._value[0], kc, vc, blk, off)
-                            else:
-                                kc, vc = write_chunk_kv(
-                                    kv_[None], v._value, kc, vc, bt, start,
-                                    n_valid, sink)
-                    new_kcs.append(kc)
-                    new_vcs.append(vc)
-                    with jax.named_scope("attn.kernel"):
-                        if cp_deg > 1:
-                            bsl = kc.shape[1]
-                            stripe = jax.lax.axis_index(cp_axis) * bsl
-                            o_p, m_p, l_p = chunk_prefill_attention_partial(
-                                qv[None], kc, vc, bt, start, scale,
-                                stripe, bsl * cp_deg)
-                            out = cross_chip_merge(
-                                o_p[0], m_p[0], l_p[0], cp_axis)[None]
-                        else:
-                            out = chunk_prefill_attention(
-                                qv[None], kc, vc, bt, start, scale,
-                                key_scale=ks, value_scale=vs)
-                    with jax.named_scope("attn.out"):
-                        out = Tensor._from_value(out.reshape(1, C, H * D))
-                        x = x + _tp_psum(attn.o_proj(out), tp)
-                    with jax.named_scope("ffn"):
-                        h2 = layer.post_attention_layernorm(x)
-                        x = x + _ffn(layer, h2, tp)
-                with jax.named_scope("lm_head"):
-                    x = llama.norm(x)
-                    # only the last VALID position reaches the LM head:
-                    # [1, 1, h] @ [h, V], never the [C, V] logits block
-                    last = jax.lax.dynamic_slice_in_dim(
-                        x._value, n_valid - 1, 1, axis=1)
-                    last = Tensor._from_value(last)
-                    if model.lm_head is None:
-                        from ..ops.linalg import matmul
-                        logits = matmul(last, llama.embed_tokens.weight,
-                                        transpose_y=True)
-                    else:
-                        logits = model.lm_head(last)
-                    logits = _tp_logits(logits, tp, q8=q8_gather)
-            with jax.named_scope("sample"):
-                if samp is None:
-                    nxt = jnp.argmax(logits._value[0, 0]
-                                     .astype(jnp.float32)).astype(jnp.int32)
-                else:
-                    # first-token sample: counter = the prompt length
-                    # start + n_valid (= the sampled token's position)
-                    t, k, p, sd = _samp_knobs(samp[None, :])
-                    toks = sample_logits(logits._value[:, 0, :], t, k,
-                                            p, sd, (start + n_valid)[None])
-                    nxt = toks[0]
-            return (nxt, tuple(new_kcs), tuple(new_vcs),
-                    tuple(new_kss), tuple(new_vss))
-
-        if _grouped_product_experts(model, tp):
-            step = _traced_x64_off(step)
-        if sampling:
-            fn, donate, n_repl = step, (6, 7, 8, 9), 5
-        else:
-            def fn(params, tokens, start, n_valid, bt, kcs, vcs, kss,
-                   vss):
-                return step(params, tokens, start, n_valid, bt, None,
-                            kcs, vcs, kss, vss)
-            donate, n_repl = (5, 6, 7, 8), 4
-        fn = _named(fn, "prefill_step")
-        if tp is None:
-            return jax.jit(fn, donate_argnums=donate)
-        return _wrap_sharded(fn, tp, self._wq or self._param_tensors,
-                             len(self.caches), n_repl=n_repl,
-                             donate=donate,
-                             quant_kv=quant_kv)
-
-    def aot_lower(self, C: int):
-        """AOT-lower (never execute) one bucket-``C`` prefill module
-        with zero host operands — the graftlint hlo-contract artifact
-        (donation aliases the pools, no f64, the chunk host-operand
-        count stays pinned at 4)."""
-        fn = self._fns.get(C)
-        if fn is None:
-            fn = self._fns[C] = self._build(C)
-        params = _step_params(self._param_tensors, self._tp, self._wq)
-        kcs = tuple(c.key_cache for c in self.caches)
-        vcs = tuple(c.value_cache for c in self.caches)
-        kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params,
-                jnp.zeros((1, C), jnp.int32),
-                jnp.asarray(0, jnp.int32),
-                jnp.asarray(1, jnp.int32),
-                jnp.zeros((1, self.bt_width), jnp.int32)]
-        if self.sampling:
-            args.append(jnp.zeros((4,), jnp.int32))
-        return fn.lower(*args, kcs, vcs, kss, vss)
-
-    def compiled_stats(self, C: int) -> dict:
-        """Cached ``cost_analysis`` of one bucket-``C`` compiled chunk
-        (see :func:`compiled_cost_stats`; same cached jit as the real
-        call, so a later dispatch does not re-trace)."""
-        cache = getattr(self, "_cost_stats", None)
-        if cache is None:
-            cache = self._cost_stats = {}
-        if C not in cache:
-            cache[C] = compiled_cost_stats(self.aot_lower(C), C)
-        return cache[C]
-
-    def __call__(self, tokens, start: int, n_valid: int,
-                 block_table_row, samp=None) -> int:
-        """tokens: [1, C] int32 bucket-padded; returns the next token
-        after position start+n_valid-1 (meaningful on the final chunk;
-        earlier chunks' samples are discarded by the engine).  samp
-        (sampling steps): [4] int32 knobs for the request."""
-        C = int(np.asarray(tokens).shape[1])
-        fn = self._fns.get(C)
-        if fn is None:
-            fn = self._fns[C] = self._build(C)
-        params = _step_params(self._param_tensors, self._tp, self._wq)
-        kcs = tuple(c.key_cache for c in self.caches)
-        vcs = tuple(c.value_cache for c in self.caches)
-        kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params,
-                jnp.asarray(np.asarray(tokens, np.int32)),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32),
-                jnp.asarray(np.asarray(block_table_row), jnp.int32)]
-        if self.sampling:
-            if samp is None:
-                samp = np.zeros((4,), np.int32)        # greedy default
-            args.append(jnp.asarray(np.asarray(samp, np.int32)))
-        nxt, new_kcs, new_vcs, new_kss, new_vss = fn(
-            *args, kcs, vcs, kss, vss)
-        _rebind_caches(self.caches, new_kcs, new_vcs, new_kss, new_vss)
-        return int(nxt)
 
 
 class MixedStep:
@@ -1631,8 +1282,8 @@ class MixedStep:
                             tuple(new_kss), tuple(new_vss))
                 if sampling:
                     # counter = kv_len — the sampled token's global
-                    # position, the SAME counter the split steps use, so
-                    # seeded tokens agree across engines
+                    # position, so seeded tokens agree across engines
+                    # and batchings
                     nxt = sample_logits(lv, s_t, s_k, s_p, s_sd,
                                            kv_lens)
                 else:
@@ -1758,8 +1409,8 @@ class MixedStep:
     def call_packed(self, pack: np.ndarray, T: int, q_probs=None):
         """Dispatch one pre-packed step buffer (see ``new_pack``).  The
         nine per-step operands cross the host link as ONE int32
-        device_put: transfer count, not byte count, is what decode
-        parity with the split DecodeStep is made of at low occupancy.
+        device_put: transfer count, not byte count, is the budget at
+        low occupancy.
 
         Returns the [max_spans] int32 sample array; a verifier
         (``spec_k``) returns ``(tokens, n_acc)`` and takes ``q_probs``
@@ -1807,284 +1458,3 @@ class MixedStep:
         compiles through the compile cache, so on a budget that has
         run it builds nothing new."""
         return hlo_op_scopes(self.aot_lower(T).compile().as_text())
-
-
-class DecodeStep:
-    """Compile the whole per-token decode into one donated-buffer call.
-
-    ``__call__(tokens, seq_lens, block_tables)`` advances every slot by
-    one token: appends the previous token's K/V at position seq_len,
-    attends over seq_len+1 cached tokens, and returns the greedy next
-    token per slot as a host int32 array (the step's only host fetch).
-    The per-layer caches are read from — and rebound onto — the
-    PagedKVCache objects handed to the constructor.
-    """
-
-    def __init__(self, model, caches: List, use_pallas: Optional[bool]
-                 = None, mesh=None, sharding=None,
-                 tp: Optional[TPContext] = None,
-                 weight_qparams=None, quant_collectives: bool = False,
-                 sampling: bool = False):
-        from ..core.device import on_tpu
-        self.model = model
-        self.caches = caches
-        self.cfg = model.config
-        if use_pallas is None:
-            use_pallas = on_tpu()
-        self.use_pallas = use_pallas
-        self.sampling = bool(sampling)
-        self._tp = _resolve_tp(model, mesh, sharding, tp)
-        self._quant_kv = bool(getattr(caches[0], "quantized", False))
-        self._wq = weight_qparams
-        self._q8_gather = bool(quant_collectives)
-        _ensure_quant_specs(self._tp, weight_qparams)
-        # capture the param TENSORS once: per-step we only read their
-        # current values, no module-tree walk in the serving hot loop
-        self._param_tensors = dict(model.state_dict())
-        self._fn = None
-        # incremented inside the traced body: one bump per (re)trace, so
-        # tests can assert the decode step compiles exactly once across
-        # admission/eviction churn
-        self.compile_count = 0
-        self.t_dispatch = 0.0          # as MixedStep.t_dispatch
-
-    def collective_bytes(self, slots: int):
-        """Per-chip collective payload of one sharded decode step over
-        ``slots`` slots ({} when single-chip)."""
-        if self._tp is None:
-            return {}
-        return self._tp.collective_bytes(self.cfg, slots, slots,
-                                         quant_gather=self._q8_gather)
-
-    def _build(self):
-        from ..autograd.tape import no_grad
-        _refuse_latent(self.model, "DecodeStep")
-        from ..ops.paged_attention import (_paged_attention_pallas,
-                                           _paged_attention_xla,
-                                           write_decode_kv,
-                                           write_decode_kv_q8)
-        from ..ops.pallas_kernels import (rope_qkv_epilogue,
-                                          rope_tables_for_positions)
-        model = self.model
-        cfg = self.cfg
-        llama = _inner_model(model)
-        tp = self._tp
-        deg = tp.degree if tp is not None else 1
-        H = cfg.num_attention_heads // deg      # this chip's head shard
-        Hkv = cfg.num_key_value_heads // deg
-        D = cfg.hidden_size // cfg.num_attention_heads
-        scale = 1.0 / math.sqrt(D)
-        use_pallas = self.use_pallas
-        attn_fn = _paged_attention_pallas if use_pallas \
-            else _paged_attention_xla
-        quant_kv = self._quant_kv
-        q8_gather = self._q8_gather
-        pdtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-        cp_axis = tp.cp_axis if tp is not None else None
-        cp_deg = tp.cp_degree if tp is not None else 1
-        if cp_deg > 1:
-            from ..ops.online_softmax import cross_chip_merge
-            from ..ops.paged_attention import (
-                _paged_attention_xla_partial, write_ragged_kv)
-            sink = self.caches[0].sink
-            if sink < 0:
-                raise ValueError(
-                    "context-parallel DecodeStep needs a sink page "
-                    "(PagedKVCache(sink_block=True)) to absorb the "
-                    "stripe writes the chip does not own")
-
-        sampling = self.sampling
-        if sampling:
-            from ..ops.sampling import sample_logits
-
-        def step(params, tokens, seq_lens, block_tables, samp, kcs, vcs,
-                 kss, vss):
-            self.compile_count += 1
-            S = tokens.shape[0]
-            params = _materialize_params(params, pdtype)
-            new_kcs, new_vcs = [], []
-            new_kss, new_vss = [], []
-            with model.bind_state(params), no_grad():
-                with jax.named_scope("embed"):
-                    x = _embed(llama, tokens[:, None], tp)        # [S, 1, h]
-                    if cfg.dtype == "bfloat16":
-                        x = x.astype("bfloat16")
-                # one-token-per-slot rows: positions = seq_lens; rope
-                # tables built once per step, shared by every layer
-                with jax.named_scope("attn.rope"):
-                    cos_t, sin_t = rope_tables_for_positions(
-                        seq_lens, D, cfg.rope_theta)
-                for li, (layer, kc, vc) in enumerate(
-                        zip(llama.layers, kcs, vcs)):
-                    attn = layer.self_attn
-                    with jax.named_scope("attn.qkv"):
-                        h = layer.input_layernorm(x)
-                        q = attn.q_proj(h).reshape([S, 1, H, D])
-                        k = attn.k_proj(h).reshape([S, 1, Hkv, D])
-                        v = attn.v_proj(h).reshape([S, 1, Hkv, D])
-                    with jax.named_scope("attn.rope"):
-                        qv, kv_, k_amax, v_amax = rope_qkv_epilogue(
-                            q._value[:, 0], k._value[:, 0], v._value[:, 0],
-                            cos_t, sin_t, with_amax=quant_kv,
-                            use_pallas=use_pallas)
-                    with jax.named_scope("attn.kv_write"):
-                        if quant_kv:
-                            kc, vc, ks, vs = write_decode_kv_q8(
-                                kv_, v._value[:, 0], kc, vc,
-                                kss[li], vss[li], block_tables, seq_lens,
-                                k_amax=k_amax, v_amax=v_amax)
-                            new_kss.append(ks)
-                            new_vss.append(vs)
-                        else:
-                            ks = vs = None
-                            if cp_deg > 1:
-                                # global destination (block table at the
-                                # GLOBAL block size), then stripe-local
-                                # translation + the plain ragged scatter
-                                bsl = kc.shape[1]
-                                gbs = bsl * cp_deg
-                                blk_g = jnp.take_along_axis(
-                                    block_tables,
-                                    (seq_lens // gbs)[:, None],
-                                    axis=1)[:, 0]
-                                blk, off = _cp_local_dest(
-                                    blk_g, seq_lens % gbs, bsl, cp_axis,
-                                    sink)
-                                kc, vc = write_ragged_kv(
-                                    kv_, v._value[:, 0], kc, vc, blk, off)
-                            else:
-                                kc, vc = write_decode_kv(
-                                    kv_, v._value[:, 0], kc, vc,
-                                    block_tables, seq_lens)
-                    new_kcs.append(kc)
-                    new_vcs.append(vc)
-                    with jax.named_scope("attn.kernel"):
-                        if cp_deg > 1:
-                            bsl = kc.shape[1]
-                            stripe = jax.lax.axis_index(cp_axis) * bsl
-                            o_p, m_p, l_p = _paged_attention_xla_partial(
-                                qv, kc, vc, block_tables, seq_lens + 1,
-                                scale, stripe, bsl * cp_deg)
-                            out = cross_chip_merge(o_p, m_p, l_p, cp_axis)
-                        else:
-                            out = attn_fn(qv, kc, vc, block_tables,
-                                          seq_lens + 1, scale,
-                                          key_scale=ks, value_scale=vs)
-                    with jax.named_scope("attn.out"):
-                        out = Tensor._from_value(out.reshape(S, 1, H * D))
-                        x = x + _tp_psum(attn.o_proj(out), tp)
-                    with jax.named_scope("ffn"):
-                        h2 = layer.post_attention_layernorm(x)
-                        x = x + _ffn(layer, h2, tp)
-                with jax.named_scope("lm_head"):
-                    x = llama.norm(x)
-                    if model.lm_head is None:
-                        from ..ops.linalg import matmul
-                        logits = matmul(x, llama.embed_tokens.weight,
-                                        transpose_y=True)
-                    else:
-                        logits = model.lm_head(x)
-                    logits = _tp_logits(logits, tp, q8=q8_gather)
-            # sampling ON DEVICE: only the [S] token ids cross the
-            # link, never the [S, V] logits.  samp=None is the greedy
-            # default path — the exact argmax, trace unchanged.
-            with jax.named_scope("sample"):
-                if samp is None:
-                    nxt = jnp.argmax(
-                        logits._value[:, 0, :].astype(jnp.float32),
-                        axis=-1).astype(jnp.int32)
-                else:
-                    t, k, p, sd = _samp_knobs(samp)
-                    # counter = the sampled token's global position
-                    nxt = sample_logits(logits._value[:, 0, :], t, k, p,
-                                           sd, seq_lens + 1)
-            return (nxt, tuple(new_kcs), tuple(new_vcs),
-                    tuple(new_kss), tuple(new_vss))
-
-        if _grouped_product_experts(model, tp):
-            step = _traced_x64_off(step)
-        if sampling:
-            fn, donate, n_repl = step, (5, 6, 7, 8), 4
-        else:
-            # greedy default: same operand pytree (and therefore the
-            # same compiled module) as the pre-sampling step
-            def fn(params, tokens, seq_lens, block_tables, kcs, vcs,
-                   kss, vss):
-                return step(params, tokens, seq_lens, block_tables,
-                            None, kcs, vcs, kss, vss)
-            donate, n_repl = (4, 5, 6, 7), 3
-        fn = _named(fn, "decode_step")
-        if tp is None:
-            self._fn = jax.jit(fn, donate_argnums=donate)
-        else:
-            self._fn = _wrap_sharded(fn, tp,
-                                     self._wq or self._param_tensors,
-                                     len(self.caches), n_repl=n_repl,
-                                     donate=donate,
-                                     quant_kv=quant_kv)
-
-    def aot_lower(self, slots: int, device_sharding=None):
-        """AOT-lower (never execute) the decode module at ``slots``
-        slots with zero host operands — the graftlint hlo-contract
-        artifact (donation aliases the pools, no f64, the split-step
-        host-operand count stays pinned at 3).  ``device_sharding`` as
-        in ``MixedStep.aot_lower``: lower for another device than the
-        one the arrays live on (a compile-only TPU)."""
-        if self._fn is None:
-            self._build()
-        W = self.caches[0].num_blocks      # any width works for lint
-        params = _step_params(self._param_tensors, self._tp, self._wq)
-        kcs = tuple(c.key_cache for c in self.caches)
-        vcs = tuple(c.value_cache for c in self.caches)
-        kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params,
-                jnp.zeros((slots,), jnp.int32),
-                jnp.zeros((slots,), jnp.int32),
-                jnp.zeros((slots, W), jnp.int32)]
-        if self.sampling:
-            args.append(jnp.zeros((slots, 4), jnp.int32))
-        args += [kcs, vcs, kss, vss]
-        if device_sharding is not None:
-            args = _shapes_on(args, device_sharding)
-        return self._fn.lower(*args)
-
-    def compiled_stats(self, slots: int) -> dict:
-        """Cached ``cost_analysis`` of the compiled decode launch at
-        ``slots`` slots (one token per slot per launch — see
-        :func:`compiled_cost_stats`)."""
-        cache = getattr(self, "_cost_stats", None)
-        if cache is None:
-            cache = self._cost_stats = {}
-        if slots not in cache:
-            cache[slots] = compiled_cost_stats(self.aot_lower(slots),
-                                               slots)
-        return cache[slots]
-
-    def __call__(self, tokens, seq_lens, block_tables,
-                 samp=None) -> np.ndarray:
-        """samp (sampling steps only): [slots, 4] int32 per-slot knobs
-        — (temperature bits, top_k, top_p bits, seed)."""
-        if self._fn is None:
-            self._build()
-        params = _step_params(self._param_tensors, self._tp, self._wq)
-        kcs = tuple(c.key_cache for c in self.caches)
-        vcs = tuple(c.value_cache for c in self.caches)
-        kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params,
-                jnp.asarray(np.asarray(tokens, np.int32)),
-                jnp.asarray(np.asarray(seq_lens, np.int32)),
-                jnp.asarray(np.asarray(block_tables, np.int32))]
-        if self.sampling:
-            if samp is None:
-                raise ValueError(
-                    "sampling DecodeStep needs the per-slot knob array "
-                    "(engine fills it; greedy slots are temperature 0)")
-            args.append(jnp.asarray(np.asarray(samp, np.int32)))
-        with jax.profiler.TraceAnnotation("engine.dispatch"):
-            nxt, new_kcs, new_vcs, new_kss, new_vss = self._fn(
-                *args, kcs, vcs, kss, vss)
-            _rebind_caches(self.caches, new_kcs, new_vcs, new_kss,
-                           new_vss)
-        self.t_dispatch = time.perf_counter()
-        with jax.profiler.TraceAnnotation("engine.fetch"):
-            return np.asarray(nxt)

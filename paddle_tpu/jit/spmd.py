@@ -596,15 +596,13 @@ def validate_tp_serving(cfg, degree: int, pool_kv_heads: Optional[int]
 
 def validate_cp_serving(cp_degree: int, block_size: int,
                         quantized_kv: bool = False,
-                        dense_prefill: bool = False,
                         spec_decode: bool = False) -> None:
     """Every constraint context-parallel serving needs, checked at
     ENGINE CONSTRUCTION with one actionable message (round 22,
     mirroring :func:`validate_tp_serving`).  cp stripes the pool's
-    SLOT dim, so the page ``block_size`` must divide by cp; int8 KV,
-    legacy dense prefill and speculative decoding are rejected (their
-    pool/scatter layouts assume one chip holds a page's full slot
-    range)."""
+    SLOT dim, so the page ``block_size`` must divide by cp; int8 KV
+    and speculative decoding are rejected (their pool/scatter layouts
+    assume one chip holds a page's full slot range)."""
     if cp_degree <= 1:
         return
     if block_size % cp_degree:
@@ -620,12 +618,6 @@ def validate_cp_serving(cp_degree: int, block_size: int,
             f"support the int8 KV pool: the [phys_pages, Hkv] absmax "
             f"tables are page-global and would diverge across slot "
             f"stripes.  Serve with kv_dtype=None (fp32 pool) under cp.")
-    if dense_prefill:
-        raise ValueError(
-            f"context-parallel serving (cp={cp_degree}) requires the "
-            f"chunked/ragged prefill path; the legacy dense prefill "
-            f"writes whole pages per chip and cannot stripe.  Construct "
-            f"the engine with prefill_chunk_size set (paged prefill).")
     if spec_decode:
         raise ValueError(
             f"context-parallel serving (cp={cp_degree}) does not "
@@ -635,17 +627,14 @@ def validate_cp_serving(cp_degree: int, block_size: int,
 
 
 def validate_ep_serving(num_experts: int, ep_degree: int,
-                        mixed_step: bool = True,
-                        dense_prefill: bool = False,
                         spec_decode: bool = False,
                         budgets: Sequence[int] = ()) -> None:
     """Every constraint expert-parallel serving needs, checked at
     ENGINE CONSTRUCTION with one actionable message (round 24,
     mirroring :func:`validate_cp_serving`).  ep shards the expert
     banks' E dim and stripes the fused dispatch over token budgets, so
-    both E and every compiled budget must divide by ep; the dispatch
-    lives only in the mixed ragged step, so dense prefill and
-    speculative decoding are rejected."""
+    both E and every compiled budget must divide by ep; speculative
+    decoding is rejected."""
     if ep_degree <= 1:
         return
     if not num_experts:
@@ -660,13 +649,6 @@ def validate_ep_serving(num_experts: int, ep_degree: int,
             f"expert count to divide by ep (each chip owns E/ep "
             f"experts); got num_local_experts={num_experts}.  Pick an "
             f"ep that divides E, or lower ep.")
-    if not mixed_step or dense_prefill:
-        raise ValueError(
-            f"expert-parallel serving (ep={ep_degree}) requires the "
-            f"mixed ragged step: the token->expert all_to_all dispatch "
-            f"is fused into the ONE compiled mixed launch, and the "
-            f"legacy dense prefill/decode bodies have no ep stripe.  "
-            f"Construct the engine with mixed_step=True.")
     if spec_decode:
         raise ValueError(
             f"expert-parallel serving (ep={ep_degree}) does not "

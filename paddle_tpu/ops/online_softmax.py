@@ -1,29 +1,28 @@
-"""Online-softmax ``(m, l, o)`` carry math — the ONE implementation
-(round 22).
+"""Online-softmax ``(m, l, o)`` carry math — the ONE implementation.
 
-The associative flash-attention update used to live as three drifting
-copies: the ``page_math`` loops of ``_paged_decode_kernel``
-(ops/paged_attention.py) and ``_ragged_paged_kernel``
-(ops/pallas_kernels.py), and — with round 22's context-parallel
-serving — a third copy would have appeared in the cross-chip stripe
-merge.  All three now call here:
+The associative flash-attention update, shared by every site that
+carries it:
 
 - :func:`online_softmax_update` — one accumulation step over a tile of
-  masked scores, exactly the expression sequence both Pallas page loops
-  have carried since r11/r17 (byte-parity-tested against the inlined
-  originals in tests/test_serving_cp.py);
+  masked scores: the page loop of ``_paged_decode_kernel``
+  (ops/paged_attention.py, the eager ``paged_attention`` API) and the
+  key-block loops of the serving step's two Pallas launches,
+  ``_ragged_paged_kernel`` and the latent-attention kernel
+  (ops/pallas_kernels.py).  Byte-parity-tested against the inlined
+  expression sequence in tests/test_serving_cp.py;
 - :func:`merge_partials` — the SAME math lifted to merging already
   normalized per-stripe partials ``(m, l, o)``: because the update is
   associative, N stripes computed independently merge into the exact
   full-softmax result (up to float summation order);
 - :func:`cross_chip_merge` — merge_partials across a mesh axis via one
-  ``all_gather`` of the three small per-token rows (measured smaller
-  than a log-step ring for the per-span row sizes serving ships:
-  both move ``(cp-1)/cp`` of the rows per chip, the single gather in
-  one collective launch).
+  ``all_gather`` of the three small per-token rows: the mixed step's
+  context-parallel attention (``_ragged_attention_xla_partial`` per
+  slot stripe, then this).  Measured smaller than a log-step ring for
+  the per-span row sizes serving ships: both move ``(cp-1)/cp`` of the
+  rows per chip, the single gather in one collective launch.
 
 Everything is fp32-in/fp32-out with np.float32 constants so the
-globally-on x64 mode never stages an f64 op (the r11 lesson).
+globally-on x64 mode never stages an f64 op.
 """
 from __future__ import annotations
 

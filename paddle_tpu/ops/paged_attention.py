@@ -35,12 +35,8 @@ from .online_softmax import merge_partials, online_softmax_update
 
 __all__ = ["PagedKVCache", "KVPageBuffer",
            "paged_attention", "write_kv_to_cache",
-           "write_decode_kv", "write_prefill_kv", "write_chunk_kv",
            "write_ragged_kv", "write_ragged_latent",
-           "chunk_prefill_attention",
-           "chunk_prefill_attention_partial",
            "ragged_paged_attention",
-           "write_decode_kv_q8", "write_chunk_kv_q8",
            "write_ragged_kv_q8", "dequant_pages",
            "reconstruct_kv", "block_multihead_attention",
            "masked_multihead_attention"]
@@ -338,8 +334,7 @@ class PagedKVCache:
             raise NotImplementedError(
                 "PagedKVCache.append is the legacy dense-cache API and "
                 "does not quantize; an int8 pool must be written through "
-                "the compiled serving steps (write_decode_kv_q8 / "
-                "write_chunk_kv_q8 / write_ragged_kv_q8)")
+                "the compiled serving step (write_ragged_kv_q8)")
         self.key_cache, self.value_cache = _write_decode_donated(
             _val(k_new), _val(v_new), self.key_cache, self.value_cache,
             jnp.asarray(np.asarray(block_tables), jnp.int32),
@@ -401,115 +396,6 @@ def _write_prefill_impl(k_new, v_new, key_cache, value_cache, block_tables,
 _write_prefill = jax.jit(_write_prefill_impl)
 _write_prefill_donated = jax.jit(_write_prefill_impl, donate_argnums=(2, 3))
 
-# traceable (un-jitted) functional appends: COMPOSE these under an outer
-# jax.jit (the serving engine's single fused decode step) — calling the
-# jitted variants from inside a trace would nest dispatches instead of
-# fusing the scatter into the surrounding module
-write_decode_kv = _write_decode_impl
-write_prefill_kv = _write_prefill_impl
-
-
-def write_chunk_kv(k_new, v_new, key_cache, value_cache, block_table_row,
-                   start, n_valid, sink):
-    """Scatter one PADDED prefill chunk into cache pages (traceable —
-    composed inside the bucketed ``PrefillStep`` trace).
-
-    k_new/v_new: [1, C, Hkv, D] where C is the bucket width; only the
-    first ``n_valid`` positions carry real tokens.  Position i lands at
-    sequence position ``start + i``; padded positions (i >= n_valid)
-    are routed to the ``sink`` page so one compile per bucket serves
-    every prompt length that rounds up to it without corrupting live
-    pages.  start/n_valid are traced scalars: chunk offset and fill
-    level never retrace.
-    """
-    C = k_new.shape[1]
-    bs = key_cache.shape[1]
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = start.astype(jnp.int32) + idx                      # [C]
-    # OOB pos//bs for the padded tail clamps in the gather, then the
-    # where() routes those writes to the sink page anyway
-    blk = block_table_row[0, pos // bs]                      # [C]
-    valid = idx < n_valid
-    blk = jnp.where(valid, blk, jnp.int32(sink))
-    off = jnp.where(valid, pos % bs, 0)
-    key_cache = key_cache.at[blk, off].set(k_new[0])
-    value_cache = value_cache.at[blk, off].set(v_new[0])
-    return key_cache, value_cache
-
-
-def chunk_prefill_attention(q, key_cache, value_cache, block_table_row,
-                            start, scale, key_scale=None,
-                            value_scale=None):
-    """Causal attention for one padded prefill chunk over the paged
-    cache (traceable; the bucketed ``PrefillStep``'s attention body).
-
-    q: [1, C, H, D] — chunk queries at global positions start..start+C-1
-    (the chunk's own K/V must already be written to the pages).  Masks
-    keys to ``kpos <= qpos``, so chunk offset stays a traced scalar: one
-    compile per bucket covers every chunk position, every prompt length
-    in the bucket, and every prefix-cache suffix offset.  Padded queries
-    produce garbage rows the caller never reads (the sampled token comes
-    from position n_valid-1).
-
-    The page loop is CLAMPED to the chunk's used block count
-    ``ceil((start + C) / block_size)`` — a traced loop bound, so a short
-    sequence in a large pool pays attention FLOPs proportional to its
-    own fill, not the full table width.  Numerics: the row max is exact
-    over the used window (identical to the full-width masked max, since
-    every clamped-away key was -inf there), then the normalizer and the
-    weighted sum accumulate page by page in position order.
-    """
-    B, C, H, D = q.shape
-    Hkv = key_cache.shape[2]
-    bs = key_cache.shape[1]
-    W = int(block_table_row.shape[1])
-    rep = H // Hkv
-    qf = q[0].astype(jnp.float32) * jnp.float32(scale)   # [C, H, D]
-    qpos = start.astype(jnp.int32) + jnp.arange(C, dtype=jnp.int32)
-    n_used = jnp.minimum(
-        (start.astype(jnp.int32) + C + bs - 1) // bs, jnp.int32(W))
-    bt = jnp.maximum(block_table_row[0].astype(jnp.int32), 0)
-
-    def page_scores(p_idx, k):
-        # k [bs, H, D] (GQA-repeated) -> scores [H, C, bs], causal-masked
-        s = jnp.einsum("qhd,khd->hqk", qf, k)
-        cols = p_idx * bs + jnp.arange(bs, dtype=jnp.int32)
-        ok = cols[None, None, :] <= qpos[None, :, None]
-        return jnp.where(ok, s, -jnp.inf)
-
-    def gather(p_idx, cache, cache_scale):
-        page = cache[bt[p_idx]]                          # [bs, Hkv, D]
-        if cache_scale is not None:
-            page = dequant_pages(page, cache_scale[bt[p_idx]])
-        else:
-            page = page.astype(jnp.float32)
-        if rep != 1:
-            page = jnp.repeat(page, rep, axis=1)
-        return page
-
-    def max_body(p_idx, m):
-        s = page_scores(p_idx, gather(p_idx, key_cache, key_scale))
-        return jnp.maximum(m, jnp.max(s, axis=-1))
-
-    m = jax.lax.fori_loop(jnp.int32(0), n_used, max_body,
-                          jnp.full((H, C), -jnp.inf, jnp.float32))
-
-    def acc_body(p_idx, carry):
-        l, acc = carry
-        s = page_scores(p_idx, gather(p_idx, key_cache, key_scale))
-        p = jnp.exp(s - m[:, :, None])                   # -inf keys -> 0
-        l = l + jnp.sum(p, axis=-1)
-        acc = acc + jnp.einsum("hqk,khd->qhd", p,
-                               gather(p_idx, value_cache, value_scale))
-        return l, acc
-
-    l, acc = jax.lax.fori_loop(
-        jnp.int32(0), n_used, acc_body,
-        (jnp.zeros((H, C), jnp.float32),
-         jnp.zeros((C, H, D), jnp.float32)))
-    out = acc / jnp.maximum(l, 1e-30).T[:, :, None]
-    return out[None].astype(q.dtype)
-
 
 def write_ragged_kv(k_new, v_new, key_cache, value_cache, dest_blocks,
                     dest_offsets):
@@ -539,7 +425,7 @@ def write_ragged_latent(rows, latent_cache, dest_blocks, dest_offsets):
 # quantized (int8) write paths: quantize ON WRITE inside the compiled step
 # ---------------------------------------------------------------------------
 def _quant_write_tokens(cache, scale, new_vals, blks, offs, amax=None):
-    """Core of every int8 write path (traceable).
+    """Core of the int8 write path (traceable).
 
     cache [phys, bs, Hkv, D] int8, scale [phys, Hkv] fp32 absmax,
     new_vals [N, Hkv, D] float, blks/offs [N] int32 (token t lands at
@@ -580,80 +466,6 @@ def _quant_write_tokens(cache, scale, new_vals, blks, offs, amax=None):
     q = quantize_symmetric(vals, new_scale[blks][:, :, None])
     cache = cache.at[blks, offs].set(q.astype(cache.dtype))
     return cache, new_scale
-
-
-def _quant_write_one_per_page(cache, scale, new_vals, blks, offs,
-                              amax=None):
-    """``_quant_write_tokens`` specialized to AT MOST ONE token per
-    live page (the decode append: every slot writes its own sequence's
-    page; only sink duplicates, which hold garbage anyway).  The
-    rescaled page and its new token row merge into ONE scatter — half
-    the scatter traffic of the general path on the hottest write."""
-    from ..quantization.functional import quantize_symmetric
-    f32 = jnp.float32
-    bs = cache.shape[1]
-    vals = new_vals.astype(f32)
-    if amax is None:
-        amax = jnp.max(jnp.abs(vals), axis=-1)           # [N, Hkv]
-    new_scale = scale.at[blks].max(amax)
-    ratio = jnp.where(new_scale > 0,
-                      scale / jnp.maximum(new_scale, 1e-30),
-                      jnp.ones((), f32))
-    pages = jnp.round(cache[blks].astype(f32)
-                      * ratio[blks][:, None, :, None])
-    q = quantize_symmetric(vals, new_scale[blks][:, :, None])
-    row = jnp.arange(bs, dtype=jnp.int32)[None, :] == offs[:, None]
-    pages = jnp.where(row[:, :, None, None], q[:, None], pages)
-    return cache.at[blks].set(pages.astype(cache.dtype)), new_scale
-
-
-def write_decode_kv_q8(k_new, v_new, key_cache, value_cache, key_scale,
-                       value_scale, block_tables, seq_lens,
-                       k_amax=None, v_amax=None):
-    """int8 variant of ``write_decode_kv`` (the fused decode append):
-    k_new/v_new [B, Hkv, D] quantized into position seq_lens[b]'s page
-    with per-page-per-head running-max scales.  Returns
-    ``(key_cache, value_cache, key_scale, value_scale)``.
-
-    PRECONDITION (stricter than the fp variant): at most one LIVE page
-    per batch row — the fast path merges each row's token into its
-    whole rescaled page and scatters page-wise, so two rows addressing
-    the same physical page would be last-writer-wins.  The decode
-    append satisfies this by construction (every slot appends to its
-    OWN sequence's tail page; only masked slots share the sink page,
-    whose content is garbage either way).  For multi-token-per-page
-    writes use ``write_ragged_kv_q8``/``write_chunk_kv_q8``."""
-    bs = key_cache.shape[1]
-    pos = seq_lens.astype(jnp.int32)
-    blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None],
-                              axis=1)[:, 0]
-    off = pos % bs
-    key_cache, key_scale = _quant_write_one_per_page(
-        key_cache, key_scale, k_new, blk, off, amax=k_amax)
-    value_cache, value_scale = _quant_write_one_per_page(
-        value_cache, value_scale, v_new, blk, off, amax=v_amax)
-    return key_cache, value_cache, key_scale, value_scale
-
-
-def write_chunk_kv_q8(k_new, v_new, key_cache, value_cache, key_scale,
-                      value_scale, block_table_row, start, n_valid, sink,
-                      k_amax=None, v_amax=None):
-    """int8 variant of ``write_chunk_kv``: one bucket-padded prefill
-    chunk quantized into its pages (padding → sink, whose scale is
-    garbage-on-garbage, exactly like its codes)."""
-    C = k_new.shape[1]
-    bs = key_cache.shape[1]
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = start.astype(jnp.int32) + idx
-    blk = block_table_row[0, pos // bs]
-    valid = idx < n_valid
-    blk = jnp.where(valid, blk, jnp.int32(sink))
-    off = jnp.where(valid, pos % bs, 0)
-    key_cache, key_scale = _quant_write_tokens(
-        key_cache, key_scale, k_new[0], blk, off, amax=k_amax)
-    value_cache, value_scale = _quant_write_tokens(
-        value_cache, value_scale, v_new[0], blk, off, amax=v_amax)
-    return key_cache, value_cache, key_scale, value_scale
 
 
 def write_ragged_kv_q8(k_new, v_new, key_cache, value_cache, key_scale,
@@ -824,64 +636,6 @@ def _ragged_attention_xla_partial(q, key_cache, value_cache,
     s = jnp.where(valid, s, -jnp.inf)
     return _partial_softmax_rows(s, valid, v.astype(jnp.float32),
                                  "thl,tlhd->thd")
-
-
-def _paged_attention_xla_partial(q, key_cache, value_cache,
-                                 block_tables, seq_lens, scale,
-                                 stripe_offset, global_block_size):
-    """Per-stripe decode attention partial (cp shard of
-    ``_paged_attention_xla``): q [B, H, D]; returns fp32
-    ``(o [B,H,D], m [B,H], l [B,H])``."""
-    B, H, D = q.shape
-    Hkv = key_cache.shape[2]
-    bsl = key_cache.shape[1]
-    W = block_tables.shape[1]
-    bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)
-    k = key_cache[bt].reshape(B, W * bsl, Hkv, D)
-    v = value_cache[bt].reshape(B, W * bsl, Hkv, D)
-    if Hkv != H:
-        rep = H // Hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    s = jnp.einsum("bhd,blhd->bhl",
-                   q.astype(jnp.float32) * jnp.float32(scale),
-                   k.astype(jnp.float32))
-    gcol = _stripe_cols(W, bsl, stripe_offset, global_block_size)
-    valid = gcol[None, None, :] < seq_lens[:, None, None]
-    s = jnp.where(valid, s, -jnp.inf)
-    return _partial_softmax_rows(s, valid, v.astype(jnp.float32),
-                                 "bhl,blhd->bhd")
-
-
-def chunk_prefill_attention_partial(q, key_cache, value_cache,
-                                    block_table_row, start, scale,
-                                    stripe_offset, global_block_size):
-    """Per-stripe chunked-prefill attention partial (cp shard of
-    ``chunk_prefill_attention``): q [1, C, H, D] at global positions
-    start..start+C-1; returns fp32 ``(o [1,C,H,D], m [1,C,H],
-    l [1,C,H])``.  The causal ``gcol <= qpos`` mask also covers
-    never-written pages (their global columns exceed every query
-    position), so the r10 poison-page invariant survives the gather."""
-    B, C, H, D = q.shape
-    Hkv = key_cache.shape[2]
-    bsl = key_cache.shape[1]
-    W = int(block_table_row.shape[1])
-    qf = q[0].astype(jnp.float32) * jnp.float32(scale)   # [C, H, D]
-    qpos = start.astype(jnp.int32) + jnp.arange(C, dtype=jnp.int32)
-    bt = jnp.maximum(block_table_row[0].astype(jnp.int32), 0)   # [W]
-    k = key_cache[bt].reshape(W * bsl, Hkv, D)
-    v = value_cache[bt].reshape(W * bsl, Hkv, D)
-    if Hkv != H:
-        rep = H // Hkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    s = jnp.einsum("qhd,khd->qhk", qf, k.astype(jnp.float32))
-    gcol = _stripe_cols(W, bsl, stripe_offset, global_block_size)
-    valid = gcol[None, None, :] <= qpos[:, None, None]
-    s = jnp.where(valid, s, -jnp.inf)
-    o, m, l = _partial_softmax_rows(s, valid, v.astype(jnp.float32),
-                                    "qhk,khd->qhd")
-    return o[None], m[None], l[None]
 
 
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,

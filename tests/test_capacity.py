@@ -478,7 +478,7 @@ def test_capacity_e2e_real_engines(monkeypatch, tmp_path):
     def build_pool(id_base):
         return [ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=64, block_size=4,
-            mixed_step=True, prefill_chunk_size=8,
+            prefill_chunk_size=8,
             enable_prefix_cache=True, engine_id=id_base + i)
             for i in range(2)]
 

@@ -89,7 +89,7 @@ def model():
 
 def _engine(model, **kw):
     kw = dict(dict(max_batch_size=4, num_blocks=64, block_size=4,
-                   max_seq_len=96, mixed_step=True,
+                   max_seq_len=96,
                    prefill_chunk_size=8), **kw)
     return ContinuousBatchingEngine(model, **kw)
 
@@ -403,7 +403,6 @@ def test_latent_attn_rows_counts_real_sub_tiles():
     (dict(kv_dtype="int8"), "int8"),
     (dict(enable_prefix_cache=True), "enable_prefix_cache"),
     (dict(role="prefill"), "migration"),
-    (dict(mixed_step=False, prefill_buckets="auto"), "split"),
     (dict(draft_model="same", spec_k=2), "draft"),
     (dict(mesh="tp2"), "mesh"),
 ])

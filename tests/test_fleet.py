@@ -495,7 +495,7 @@ def _load_engine_server_module():
 
 _FLEET_CFG = {
     "platform": "cpu", "seed": 0, "slots": 2, "num_blocks": 96,
-    "block_size": 4, "chunk": None, "mixed_step": True,
+    "block_size": 4, "chunk": None,
     "enable_prefix_cache": False, "warm": {"prompt_len": 12,
                                            "budget": 4},
 }

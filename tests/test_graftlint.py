@@ -52,6 +52,16 @@ def test_lint_ci_clean_on_repo():
             assert f.waive_reason
 
 
+def test_hlo_artifacts_hold_one_serving_step():
+    """The serving artifacts are the mixed step's (and the migration
+    inject): no module of a split decode or prefill step is lowered."""
+    from graftlint import hlo
+    names = set(hlo.build_artifacts())    # cached from the smoke above
+    assert f"mixed_step@T{hlo.MIXED_T}" in names
+    assert not [n for n in names
+                if n.startswith(("decode_step@", "prefill_step@"))]
+
+
 # ---------------------------------------------------------------------------
 # slow lane: per-rule fixture sweep
 # ---------------------------------------------------------------------------

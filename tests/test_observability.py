@@ -534,14 +534,19 @@ def test_serving_engine_metric_families():
     assert r.get("serving_slot_occupancy_ratio").value == 1.0
     assert r.get("serving_kv_page_utilization_ratio").value > 0
     eng.run_to_completion()
-    # both prompts had distinct NEW lengths: per-length compile warmup
-    # keeps both prefills out of the latency histogram
+    # both prompts prefilled in ONE step, the first of its budget: the
+    # compile warmup stays out of the latency histogram
     assert r.get("serving_prefill_duration_seconds").count == prefill0
     assert r.get("serving_decode_step_duration_seconds").count > 0
     assert r.get("serving_ttft_seconds").count >= 2
     assert r.get("serving_tpot_seconds").count >= 2
     assert r.get("serving_tokens_total").value == tokens0 + 8
     assert r.get("serving_queue_depth").value == 0
+    # five tokens again, alone: the same budget, warm, IS observed
+    eng.add_request(np.array([9, 8, 7, 6, 5], np.int64), max_new_tokens=2)
+    eng.run_to_completion()
+    assert r.get("serving_prefill_duration_seconds").count \
+        == prefill0 + 1
 
     # pool-dry victim: lazy_alloc with a pool too small for both tails
     trunc0 = r.get("serving_truncated_victims_total").value
@@ -560,7 +565,3 @@ def test_serving_engine_metric_families():
     assert r.get("serving_truncated_victims_total").value > trunc0
     assert r.get("serving_requests_total").labels(
         outcome="truncated").value > done0
-    # eng2's two prompts share one length: second prefill (warm) IS
-    # observed
-    assert r.get("serving_prefill_duration_seconds").count \
-        == prefill0 + 1

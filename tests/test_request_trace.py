@@ -369,7 +369,7 @@ def test_kill_drill_trace_completeness_real_engines(tmp_path):
     model = _tiny_model()
     engines = [ContinuousBatchingEngine(
         model, max_batch_size=2, num_blocks=96, block_size=4,
-        mixed_step=True, prefill_chunk_size=8,
+        prefill_chunk_size=8,
         enable_prefix_cache=True, engine_id=100 + i) for i in range(2)]
     router = ServingRouter(engines)
     rng = np.random.RandomState(5)

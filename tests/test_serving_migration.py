@@ -189,7 +189,6 @@ def _engine(model, **kw):
     kw.setdefault("max_batch_size", 2)
     kw.setdefault("num_blocks", 64)
     kw.setdefault("block_size", 4)
-    kw.setdefault("mixed_step", True)
     kw.setdefault("prefill_chunk_size", 8)
     kw.setdefault("enable_prefix_cache", True)
     return ContinuousBatchingEngine(model, **kw)

@@ -21,8 +21,7 @@ contract gated here:
 - the incubate gates and the serving dispatch share one gate
   implementation (bitwise identity);
 - actionable construction-time errors for a non-dividing expert count,
-  the eager dense-prefill path, spec-decode and non-dividing token
-  budgets under ep.
+  spec-decode and non-dividing token budgets under ep.
 
 Budget note: the tier-1 suite runs AT the 870s timeout — only the ep=2
 parity test, the (sub-second) gate-identity test and the validation
@@ -62,7 +61,6 @@ def _ref_tokens(model, prompt, n):
 
 
 def _run(model, mesh=None, budget=4, **kw):
-    kw.setdefault("mixed_step", True)
     kw.setdefault("prefill_chunk_size", 4)
     eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                    num_blocks=64, block_size=4,
@@ -155,22 +153,18 @@ def test_ep2_mixed_parity_expert_shard_and_compile_bound():
 def test_ep_validation_errors_at_construction():
     """Invalid ep geometries must fail engine construction with an
     actionable message — not a shard_map shape error deep in tracing:
-    an expert count ep doesn't divide, the eager dense-prefill path and
-    non-dividing token budgets are rejected; spec-decode is rejected by
-    the shared validator."""
+    an expert count ep doesn't divide and non-dividing token budgets
+    are rejected; spec-decode is rejected by the shared validator."""
     with pytest.raises(ValueError, match="divide"):
         ContinuousBatchingEngine(_model(num_local_experts=3),
                                  max_batch_size=2, num_blocks=16,
-                                 block_size=4, mixed_step=True,
+                                 block_size=4,
                                  prefill_chunk_size=4,
                                  mesh=ep_mesh(2))   # 3 % 2 != 0
     model = _model()
-    with pytest.raises(ValueError, match="mixed"):
-        ContinuousBatchingEngine(model, max_batch_size=2, num_blocks=16,
-                                 block_size=4, mesh=ep_mesh(2))
     with pytest.raises(ValueError, match="budget"):
         ContinuousBatchingEngine(model, max_batch_size=2, num_blocks=16,
-                                 block_size=4, mixed_step=True,
+                                 block_size=4,
                                  prefill_chunk_size=4,
                                  token_budgets=(3, 8),
                                  mesh=ep_mesh(2))   # 3 % 2 != 0
@@ -179,7 +173,7 @@ def test_ep_validation_errors_at_construction():
     # ep=1 degenerates to the plain single-chip engine
     eng = ContinuousBatchingEngine(model, max_batch_size=2,
                                    num_blocks=16, block_size=4,
-                                   mixed_step=True, mesh=ep_mesh(1))
+                                   mesh=ep_mesh(1))
     assert eng.tp is None and eng.ep_degree == 1
 
 
@@ -254,7 +248,7 @@ def test_ep_prefix_cache_cow_parity_and_leak_free():
     def run(mesh):
         eng = ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=32, block_size=4,
-            mixed_step=True, prefill_chunk_size=4,
+            prefill_chunk_size=4,
             enable_prefix_cache=True, mesh=mesh)
         ra = eng.add_request(P, 4)
         eng.run_to_completion()
@@ -296,7 +290,7 @@ def test_router_pool_mixes_dense_and_moe_engines():
     def eng(model, mesh=None):
         return ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=32, block_size=4,
-            mixed_step=True, prefill_chunk_size=4, mesh=mesh)
+            prefill_chunk_size=4, mesh=mesh)
 
     e_moe_ep = eng(moe, ep_mesh(2))
     e_moe = eng(moe)
@@ -441,21 +435,3 @@ def test_one_chip_step_counts_its_experts_rows():
     assert counted() - before == sum(f["moe_rows"] for f in recs)
     e_ep, _ = _run(model, mesh=ep_mesh(2))
     assert e_ep.mixed.n_stats == 0
-
-
-def test_split_steps_run_the_sorted_product():
-    """The split path (``PrefillStep`` buckets + ``DecodeStep``) of a
-    Mixtral model calls the same ``_ffn``: same tokens as eager
-    ``generate``, and both traced steps hold the sorted form's scopes
-    and none of the buffer dispatch's (what the v5e compiler makes of
-    them: tests/test_tpu_compile.py)."""
-    model = _model()
-    refs = [_ref_tokens(model, p, 4) for p in PROMPTS]
-    eng, toks = _run(model, mixed_step=False, prefill_buckets=(4, 8),
-                     prefill_chunk_size=4)
-    assert toks == refs
-    for lowered in (eng.decode_step.aot_lower(4),
-                    eng.prefill_step.aot_lower(4)):
-        text = lowered.as_text(debug_info=True)
-        assert "/moe.sort/" in text and "/moe.experts/" in text
-        assert "/moe.dispatch/" not in text

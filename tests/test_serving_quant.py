@@ -53,7 +53,7 @@ def test_kv8_mixed_token_match_and_capacity(tiny_model):
     prompts = [rng.randint(1, cfg.vocab_size, (n,)).astype(np.int64)
                for n in (5, 3, 8)]
     budgets = [6, 8, 5]
-    kw = dict(mixed_step=True, prefill_chunk_size=8)
+    kw = dict(prefill_chunk_size=8)
     ref, ef = _run_engine(model, prompts, budgets, **kw)
     got, eq = _run_engine(model, prompts, budgets, kv_dtype="int8",
                           **kw)
@@ -124,17 +124,13 @@ def test_quant_construction_errors(tiny_model):
     base = dict(max_batch_size=4, num_blocks=64, block_size=4)
     with pytest.raises(ValueError, match="kv_dtype"):
         ContinuousBatchingEngine(model, kv_dtype="int4",
-                                 mixed_step=True, **base)
-    with pytest.raises(ValueError, match="compiled prefill"):
-        ContinuousBatchingEngine(model, kv_dtype="int8", **base)
-    with pytest.raises(ValueError, match="compiled prefill"):
-        ContinuousBatchingEngine(model, weight_quant="int8", **base)
+                                 **base)
     with pytest.raises(ValueError, match="weight_quant"):
         ContinuousBatchingEngine(model, weight_quant="fp8",
-                                 mixed_step=True, **base)
+                                 **base)
     with pytest.raises(ValueError, match="single-chip"):
         ContinuousBatchingEngine(model, quant_collectives=True,
-                                 mixed_step=True, **base)
+                                 **base)
 
 
 def test_one_symmetric_absmax_helper():
@@ -178,7 +174,7 @@ def test_w8_kv8_prefix_cow_end_to_end(tiny_model):
                                               (4,)).astype(np.int64)])
                for _ in range(3)]
     budgets = [5, 5, 5]
-    kw = dict(mixed_step=True, prefill_chunk_size=8,
+    kw = dict(prefill_chunk_size=8,
               enable_prefix_cache=True)
 
     def run(**extra):
@@ -220,7 +216,7 @@ def test_tp2_quant_collective_token_match(tiny_model):
     prompts = [rng.randint(1, cfg.vocab_size, (n,)).astype(np.int64)
                for n in (5, 3, 8)]
     budgets = [6, 8, 5]
-    kw = dict(mixed_step=True, prefill_chunk_size=8)
+    kw = dict(prefill_chunk_size=8)
     ref, _ = _run_engine(model, prompts, budgets, **kw)
     got, eng = _run_engine(model, prompts, budgets, mesh=tp_mesh(2),
                            kv_dtype="int8", quant_collectives=True,
@@ -239,14 +235,13 @@ def test_tp2_quant_collective_token_match(tiny_model):
 
 @pytest.mark.slow
 def test_quant_write_paths_match_fp32_within_bound():
-    """Per-page scale correctness sweep: decode, chunk and ragged
-    quantized writes each land within the absmax/127 quantization step
-    of what the fp32 write paths store (plus rescale slack)."""
+    """Per-page scale correctness sweep: the ragged quantized write
+    (interleaved spans, then one token a page with growing magnitudes)
+    lands within the absmax/127 quantization step of what the fp32
+    write stores (plus rescale slack)."""
     import jax.numpy as jnp
     from paddle_tpu.ops.paged_attention import (
-        PagedKVCache, dequant_pages, write_chunk_kv, write_chunk_kv_q8,
-        write_decode_kv, write_decode_kv_q8, write_ragged_kv,
-        write_ragged_kv_q8)
+        PagedKVCache, dequant_pages, write_ragged_kv, write_ragged_kv_q8)
     rng = np.random.RandomState(5)
     bs, hkv, d = 4, 2, 8
 
@@ -322,38 +317,20 @@ def test_quant_write_paths_match_fp32_within_bound():
             value_scale=cq.value_scale, pipelined=pipelined))
         np.testing.assert_allclose(d_pal, d_ref, atol=atol)
 
-    # chunk: bucket-padded prompt across pages, padding to sink
+    # one token per page per write, running-max rescale over bs steps
     cf, cq = pair()
-    C, valid = 8, 6
-    k = rng.randn(1, C, hkv, d).astype(np.float32)
-    v = rng.randn(1, C, hkv, d).astype(np.float32)
-    row = np.full((1, 4), cq.sink, np.int32)
-    row[0, :2] = [2, 3]
-    args = (jnp.asarray(np.int32(0)), jnp.asarray(np.int32(valid)),
-            cq.sink)
-    cf.key_cache, cf.value_cache = write_chunk_kv(
-        jnp.asarray(k), jnp.asarray(v), cf.key_cache, cf.value_cache,
-        row, *args)
-    (cq.key_cache, cq.value_cache, cq.key_scale,
-     cq.value_scale) = write_chunk_kv_q8(
-        jnp.asarray(k), jnp.asarray(v), cq.key_cache, cq.value_cache,
-        cq.key_scale, cq.value_scale, row, *args)
-    check(cf, cq, [2, 3])
-
-    # decode: one token per slot, running-max rescale over bs steps
-    cf, cq = pair()
-    bt = np.array([[4], [5]], np.int32)
+    blks = np.array([4, 5], np.int32)
     for step in range(bs):
         k = (rng.randn(2, hkv, d) * (1 + step)).astype(np.float32)
         v = rng.randn(2, hkv, d).astype(np.float32)
-        sl = np.full((2,), step, np.int32)
-        cf.key_cache, cf.value_cache = write_decode_kv(
+        offs = np.full((2,), step, np.int32)
+        cf.key_cache, cf.value_cache = write_ragged_kv(
             jnp.asarray(k), jnp.asarray(v), cf.key_cache,
-            cf.value_cache, bt, sl)
+            cf.value_cache, blks, offs)
         (cq.key_cache, cq.value_cache, cq.key_scale,
-         cq.value_scale) = write_decode_kv_q8(
+         cq.value_scale) = write_ragged_kv_q8(
             jnp.asarray(k), jnp.asarray(v), cq.key_cache,
-            cq.value_cache, cq.key_scale, cq.value_scale, bt, sl)
+            cq.value_cache, cq.key_scale, cq.value_scale, blks, offs)
     # growing magnitudes force repeated rescales: allow 2 quant steps
     check(cf, cq, [4, 5])
 

@@ -421,7 +421,6 @@ def _mk_prefix_engine(model, **kw):
     kw.setdefault("max_batch_size", 2)
     kw.setdefault("num_blocks", 64)
     kw.setdefault("block_size", 4)
-    kw.setdefault("mixed_step", True)
     kw.setdefault("prefill_chunk_size", 8)
     kw.setdefault("enable_prefix_cache", True)
     return ContinuousBatchingEngine(model, **kw)
@@ -476,7 +475,7 @@ def test_preempt_under_cow_and_int8_scale_pages_leak_free():
     for kv_dtype in (None, "int8"):
         eng = ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=32, block_size=4,
-            mixed_step=True, prefill_chunk_size=8,
+            prefill_chunk_size=8,
             enable_prefix_cache=True, kv_dtype=kv_dtype)
         P = np.array([5, 17, 42, 7, 99, 3, 11, 23], np.int64)
         ra = eng.add_request(P, 8)
@@ -494,7 +493,7 @@ def test_preempt_under_cow_and_int8_scale_pages_leak_free():
         # resume B on a second engine with tokens re-prefixed
         eng2 = ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=32, block_size=4,
-            mixed_step=True, prefill_chunk_size=8,
+            prefill_chunk_size=8,
             enable_prefix_cache=True, kv_dtype=kv_dtype)
         rb2 = eng2.add_request(np.concatenate([P, gen_b]),
                                8 - len(gen_b))

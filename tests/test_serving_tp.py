@@ -8,11 +8,10 @@ cross-chip traffic is one psum per layer boundary plus the exact
 embedding psum / logits all-gather.  The contract gated here:
 
 - tokens BYTE-IDENTICAL to the single-chip engine on the same workload
-  (tp=2 in tier-1; tp=4 and the split engine in the slow lane);
+  (tp=2 in tier-1; tp=4 in the slow lane);
 - per-chip KV-pool bytes exactly 1/tp (head-sharded pages);
 - compile count still bounded by the token-budget-set size;
-- actionable construction-time errors for non-divisible head counts
-  and the eager-dense-prefill path.
+- actionable construction-time errors for non-divisible head counts.
 
 Budget note: the tier-1 suite runs AT the 870s timeout — only the tp=2
 parity test and the (sub-second) validation test are unmarked; every
@@ -52,12 +51,8 @@ def _tp_mesh(tp):
     return ProcessMesh(shape=[tp], dim_names=["tp"])
 
 
-def _run(model, mesh=None, mixed=True, budget=4, **kw):
-    if mixed:
-        kw.setdefault("mixed_step", True)
-        kw.setdefault("prefill_chunk_size", 4)
-    else:
-        kw.setdefault("prefill_buckets", (4, 8, 16))
+def _run(model, mesh=None, budget=4, **kw):
+    kw.setdefault("prefill_chunk_size", 4)
     eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                    num_blocks=64, block_size=4,
                                    mesh=mesh, **kw)
@@ -73,15 +68,15 @@ def _run(model, mesh=None, mixed=True, budget=4, **kw):
 def test_tp2_mixed_parity_pool_shard_and_compile_bound():
     """tp=2 fused mixed step: tokens byte-identical to the single-chip
     mixed engine under admission churn, per-chip KV-pool bytes exactly
-    half, compiles bounded by the budget-set size, the split decode
-    module never traced, and the tp metrics published."""
+    half, compiles bounded by the budget-set size, and the tp metrics
+    published."""
     model = _model()
     e1, t1 = _run(model)
     e2, t2 = _run(model, mesh=_tp_mesh(2))
     assert t2 == t1, "tp=2 tokens diverged from the single-chip step"
     assert e2.tp_degree == 2
-    assert e2.mixed.total_compiles <= len(e2.token_budgets)
-    assert e2.decode_step.compile_count == 0
+    assert set(e2.mixed.compile_counts) <= set(e2.token_budgets)
+    assert all(v == 1 for v in e2.mixed.compile_counts.values())
     # head-sharded pools: per-chip bytes are EXACTLY 1/tp
     b1 = e1.caches[0].per_chip_pool_bytes()
     b2 = e2.caches[0].per_chip_pool_bytes()
@@ -100,20 +95,16 @@ def test_tp2_mixed_parity_pool_shard_and_compile_bound():
 def test_tp_validation_errors_at_construction():
     """Head-divisibility and pool-shape problems must fail engine
     construction with an actionable message — not a shard_map shape
-    error deep in tracing; the eager dense-prefill path is rejected
-    under tp."""
+    error deep in tracing."""
     model = _model()                       # 4 heads, 2 kv heads
     with pytest.raises(ValueError, match="divide"):
         ContinuousBatchingEngine(model, max_batch_size=2, num_blocks=16,
-                                 block_size=4, mixed_step=True,
+                                 block_size=4,
                                  mesh=_tp_mesh(4))   # kv 2 % 4 != 0
-    with pytest.raises(ValueError, match="dense"):
-        ContinuousBatchingEngine(model, max_batch_size=2, num_blocks=16,
-                                 block_size=4, mesh=_tp_mesh(2))
     # tp=1 degenerates to the plain single-chip engine
     eng = ContinuousBatchingEngine(model, max_batch_size=2,
                                    num_blocks=16, block_size=4,
-                                   mixed_step=True, mesh=_tp_mesh(1))
+                                   mesh=_tp_mesh(1))
     assert eng.tp is None and eng.tp_degree == 1
 
 
@@ -168,7 +159,7 @@ def test_tp_prefix_cache_cow_parity_and_leak_free():
     def run(mesh):
         eng = ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=32, block_size=4,
-            mixed_step=True, prefill_chunk_size=4,
+            prefill_chunk_size=4,
             enable_prefix_cache=True, mesh=mesh)
         ra = eng.add_request(P, 4)
         eng.run_to_completion()
@@ -186,17 +177,3 @@ def test_tp_prefix_cache_cow_parity_and_leak_free():
     c0 = e2.caches[0]
     assert all(c0.refcount(b) == 1 for b in cached)
     assert len(c0._free) + len(cached) == c0.num_blocks
-
-
-@pytest.mark.slow
-def test_tp_split_engine_parity():
-    """The default split path (bucketed PrefillStep + DecodeStep) under
-    tp=2: byte parity with the single-chip split engine, prefill
-    compiles still bounded by the bucket count, decode still compiles
-    once."""
-    model = _model()
-    _, t1 = _run(model, mixed=False)
-    e2, t2 = _run(model, mesh=_tp_mesh(2), mixed=False)
-    assert t2 == t1
-    assert e2.decode_step.compile_count == 1
-    assert e2.prefill_step.total_compiles <= len(e2.prefill_buckets)

@@ -230,7 +230,7 @@ def test_train_to_serve_placed_tree_identity_and_token_parity():
     placed = {k: t._value for k, t in model.state_dict().items()}
     eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                    num_blocks=64, block_size=4,
-                                   mesh=mesh, mixed_step=True,
+                                   mesh=mesh,
                                    prefill_chunk_size=4)
     prompts = [np.array([5, 7, 11], np.int64),
                np.array([2, 3, 4, 5, 6], np.int64)]
@@ -253,7 +253,7 @@ def test_train_to_serve_placed_tree_identity_and_token_parity():
     model1.eval()
     eng1 = ContinuousBatchingEngine(model1, max_batch_size=4,
                                     num_blocks=64, block_size=4,
-                                    mixed_step=True, prefill_chunk_size=4)
+                                    prefill_chunk_size=4)
     rids1 = [eng1.add_request(p, 6) for p in prompts]
     eng1.run_to_completion()
     assert [eng1.result(r) for r in rids1] == toks
@@ -276,7 +276,7 @@ def test_pure_fsdp_serving_parity():
     def run(mesh):
         eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                        num_blocks=64, block_size=4,
-                                       mesh=mesh, mixed_step=True,
+                                       mesh=mesh,
                                        prefill_chunk_size=4)
         rid = eng.add_request(np.array([7, 9, 2], np.int64), 6)
         eng.run_to_completion()

@@ -51,7 +51,6 @@ def _model(family):
 
 
 def _engine(family="llama", **kw):
-    kw.setdefault("mixed_step", True)
     kw.setdefault("prefill_chunk_size", 4)
     return ContinuousBatchingEngine(_model(family), max_batch_size=4,
                                     num_blocks=64, block_size=4, **kw)
@@ -189,18 +188,13 @@ def test_tracer_false_writes_no_record():
     assert steps > 3 and _records(eng) == [] and len(span_log) == n
 
 
-@pytest.mark.parametrize("kind", ["speculative", "split"])
-def test_other_step_kinds_write_the_record(kind):
-    if kind == "speculative":
-        from paddle_tpu.models.llama import llama_truncated_draft
-        model = _model("llama")
-        eng = ContinuousBatchingEngine(
-            model, max_batch_size=4, num_blocks=64, block_size=4,
-            mixed_step=True, prefill_chunk_size=4,
-            draft_model=llama_truncated_draft(model, 1), spec_k=2)
-    else:
-        eng = _engine(mixed_step=False, prefill_buckets=(4, 8),
-                      prefill_chunk_size=4)
+def test_speculative_round_writes_the_record():
+    from paddle_tpu.models.llama import llama_truncated_draft
+    model = _model("llama")
+    eng = ContinuousBatchingEngine(
+        model, max_batch_size=4, num_blocks=64, block_size=4,
+        prefill_chunk_size=4,
+        draft_model=llama_truncated_draft(model, 1), spec_k=2)
     rids = [eng.add_request(p, 4) for p in PROMPTS]
     steps = 0
     while eng.has_work():
@@ -369,12 +363,10 @@ def test_scopes_are_metadata_only(monkeypatch):
 
 
 def test_jitted_steps_are_named():
-    split = _engine(mixed_step=False, prefill_buckets=(4, 8),
-                    prefill_chunk_size=4)
-    for lowered, name in ((split.prefill_step.aot_lower(4), "prefill_step"),
-                          (split.decode_step.aot_lower(4), "decode_step")):
-        assert lowered.as_text().splitlines()[0].startswith(
-            f"module @jit_{name}")
+    eng = _engine()
+    for T in eng.token_budgets:
+        assert eng.mixed.aot_lower(T).as_text().splitlines()[0].startswith(
+            "module @jit_mixed_step")
     import paddle_tpu.nn as nn
     from paddle_tpu.jit.train_step import TrainStep
     paddle.seed(0)

@@ -170,7 +170,7 @@ def test_mixed_step_full_width_two_layers(v5e):
     model.eval()
     eng = ContinuousBatchingEngine(
         model, max_batch_size=SPANS, num_blocks=PAGES, block_size=BLOCK,
-        max_seq_len=WIDTH * BLOCK, mixed_step=True,
+        max_seq_len=WIDTH * BLOCK,
         prefill_chunk_size=CHUNK, use_pallas=True)
     lowered = eng.mixed.aot_lower(eng.token_budgets[-1],
                                   device_sharding=v5e)
@@ -225,12 +225,10 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
     2 layers, under the framework's own x64 setting: the optimized v5e
     program multiplies the experts' rows with XLA:TPU's grouped matmul
     (``ragged-dot*`` under ``moe.experts``) and holds no buffer an
-    expert (no shape that leads with ``[E, N*k``); the split
-    ``DecodeStep`` of the same model compiles too."""
+    expert (no shape that leads with ``[E, N*k``)."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    from paddle_tpu.jit.serving_step import (STEP_SCOPES, DecodeStep,
-                                             hlo_op_scopes)
+    from paddle_tpu.jit.serving_step import STEP_SCOPES, hlo_op_scopes
     from paddle_tpu.models.mixtral import (MixtralConfig,
                                            MixtralForCausalLM)
     assert jax.config.jax_enable_x64
@@ -254,7 +252,7 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
         blk.w_gate._value, blk.w_up._value, blk.w_down._value = up, up, down
     eng = ContinuousBatchingEngine(
         model, max_batch_size=SPANS, num_blocks=PAGES, block_size=BLOCK,
-        max_seq_len=WIDTH * BLOCK, mixed_step=True,
+        max_seq_len=WIDTH * BLOCK,
         prefill_chunk_size=CHUNK, use_pallas=True)
     top = eng.token_budgets[-1]
     assert eng.mixed.n_stats == 2 + E
@@ -270,19 +268,10 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
     assert {scopes[n] for n in grouped} == {"moe.experts"}
     # the buffers are gone: no instruction's shape leads with [E, N*k
     assert not re.search(r"\[%d,%d[,\]]" % (E, top * K), hlo)
-    # the split decode step of the same model, for the same chip
-    # at the docs cell's 16 slots.  (XLA:TPU keeps its grouped-matmul
-    # kernel only where the sorted buffer's N*k rows are a multiple of
-    # 8: at 6 slots, 12 rows, it expands the product to the dense
-    # [E, N*k, .] form itself.  Every budget and slot count of the
+    # (XLA:TPU keeps its grouped-matmul kernel only where the sorted
+    # buffer's N*k rows are a multiple of 8: at 12 rows it expands the
+    # product to the dense [E, N*k, .] form itself.  Every budget of the
     # cells is such a multiple.)
-    slots = 16
-    dec = DecodeStep(model, eng.caches, use_pallas=True)
-    dhlo = dec.aot_lower(slots, device_sharding=v5e).compile().as_text()
-    dscopes = hlo_op_scopes(dhlo)
-    assert {dscopes[n] for n in dscopes if n.startswith("ragged-dot")} \
-        == {"moe.experts"}
-    assert not re.search(r"\[%d,%d[,\]]" % (E, slots * K), dhlo)
 
 
 # the latent (MLA) launch at DeepSeek-V2's widths: 128 heads over one
@@ -334,7 +323,7 @@ def test_mixed_step_latent_two_kinds(v5e):
     model.eval()
     eng = ContinuousBatchingEngine(
         model, max_batch_size=4, num_blocks=64, block_size=128,
-        max_seq_len=2048, mixed_step=True, prefill_chunk_size=256,
+        max_seq_len=2048, prefill_chunk_size=256,
         use_pallas=True)
     top = eng.token_budgets[-1]
     lowered = eng.mixed.aot_lower(top, device_sharding=v5e)

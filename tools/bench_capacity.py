@@ -235,8 +235,7 @@ def bench_efficiency(model, knobs):
         return ContinuousBatchingEngine(
             model, max_batch_size=knobs["slots"],
             num_blocks=knobs["num_blocks"],
-            block_size=knobs["block_size"], mixed_step=True,
-            prefill_chunk_size=knobs["chunk"],
+            block_size=knobs["block_size"], prefill_chunk_size=knobs["chunk"],
             enable_prefix_cache=True, kv_dtype=kv_dtype,
             engine_id=eid)
 

@@ -57,7 +57,7 @@ def make_engines(model, n, knobs, tracer=None, id_base=None):
             model, max_batch_size=knobs["slots"],
             num_blocks=knobs["num_blocks"],
             block_size=knobs["block_size"],
-            mixed_step=True, prefill_chunk_size=knobs["chunk"],
+            prefill_chunk_size=knobs["chunk"],
             enable_prefix_cache=True, tracer=tracer, **kw))
     return out
 

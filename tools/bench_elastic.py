@@ -91,7 +91,7 @@ def _make_engines(model, n, knobs, id_base):
     return [ContinuousBatchingEngine(
         model, max_batch_size=knobs["slots"],
         num_blocks=knobs["num_blocks"], block_size=knobs["block_size"],
-        mixed_step=True, prefill_chunk_size=knobs["chunk"],
+        prefill_chunk_size=knobs["chunk"],
         enable_prefix_cache=True,
         host_tier_bytes=knobs["host_tier_bytes"],
         engine_id=id_base + i) for i in range(n)]

@@ -317,7 +317,7 @@ def _proc_config(knobs, engine_id):
     return {"platform": "cpu", "seed": 0, "engine_id": engine_id,
             "slots": knobs["slots"], "num_blocks": knobs["num_blocks"],
             "block_size": knobs["block_size"], "chunk": knobs["chunk"],
-            "mixed_step": True, "enable_prefix_cache": False,
+            "enable_prefix_cache": False,
             "warm": {"prompt_len": knobs["prefix_len"]
                      + knobs["suffix_len"], "budget": knobs["budget"]}}
 

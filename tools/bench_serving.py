@@ -1,50 +1,29 @@
-"""Serving bench: prefill + decode for the continuous-batching engine.
+"""Serving bench: modes over the continuous-batching engine (one
+serving path, the fused mixed step).  CPU dry-run artifacts: parity,
+counts and bytes, not speeds (ROADMAP D6 decides what stays).
 
-Three modes:
+Pick one mode (the modes do not combine):
 
-- default: the round-6/10 sweep (decode occupancy + bucketed/chunked/
-  prefix-cached prefill) -> BENCH_SERVE_r10.json;
-- ``--mixed`` (round-11 tentpole): the fused single-step engine
-  (``mixed_step=True``, ragged paged attention) vs the two-module
-  split engine on the SAME mixed workload -> BENCH_SERVE_r11.json with
-  mixed-workload prefill tokens/s, occupancy-matched decode tokens/s,
-  and TTFT/TPOT medians for both engines.  Gates: byte parity (decode-
-  only, mixed, chunked-long-prompt, prefix-hit) vs eager generate,
-  MixedStep compiles <= the token-budget-set size, prefill tokens/s
-  beating BENCH_SERVE_r10's recorded number, and decode tokens/s no
-  worse than 5% below r10's occupancy-matched number.  On any error ONE
-  parseable failure-marker JSON line is emitted and the run exits 1.
-- ``--tp [N]`` (round-12 tentpole): tensor-parallel multichip serving —
-  the fused mixed step shard_map'd over a ``tp`` mesh axis (shared SPMD
-  module jit/spmd.py) -> BENCH_SERVE_r12.json with a tokens/s scaling
-  curve over tp in {1, 2, 4} (capped at N).  Gates: every tp degree's
-  tokens BYTE-IDENTICAL to the single-chip (tp=1) mixed engine on the
-  same workload, per-chip KV-pool bytes == 1/tp of the tp=1 pool
-  (head-sharded pages), and compiles <= the token-budget-set size.  On
-  the CPU dryrun (forced 8 virtual devices via paddle_tpu.testing.
-  dryrun) the gate is parity + capacity, NOT raw speed — virtual
-  "chips" share the same cores, so the curve is recorded for shape
-  only; r11's single-chip decode tokens/s is carried as the provenance
-  reference.
-
-Emits a driver-readable artifact (BENCH_SERVE_r10.json at the repo root,
-or the path in argv[1]):
-
-- decode tokens/s/chip over a slot-occupancy sweep for the
-  single-compile decode step (round-6 tentpole; compile count must stay
-  1 across the sweep — occupancy is masked, never re-shaped);
-- bucketed + chunked prefill over a MIXED-LENGTH workload (round-10
-  tentpole): total PrefillStep compiles must be bounded by the bucket
-  count — before bucketing the dense path re-traced once per distinct
-  prompt length — with chunked prompts longer than the top bucket
-  interleaving with decode;
-- copy-on-write prefix caching: shared-prefix TTFT must be strictly
-  better than cold-prefix TTFT at equal prompt length (buckets warmed
-  first, so the split is compute, not compile), plus hit/miss counts.
+- ``--tp [N]``: tensor-parallel multichip serving — the fused mixed step
+  shard_map'd over a ``tp`` mesh axis (shared SPMD module jit/spmd.py)
+  -> BENCH_SERVE_r12.json with a tokens/s scaling curve over tp in
+  {1, 2, 4} (capped at N).  Gates: every tp degree's tokens
+  BYTE-IDENTICAL to the single-chip (tp=1) engine on the same workload,
+  per-chip KV-pool bytes == 1/tp of the tp=1 pool (head-sharded pages),
+  and compiles <= the token-budget-set size.  On the CPU dryrun (forced
+  8 virtual devices via paddle_tpu.testing.dryrun) the gate is parity +
+  capacity, NOT raw speed — virtual "chips" share the same cores, so
+  the curve is recorded for shape only.
+- ``--quant`` -> BENCH_QUANT_r13.json, ``--speculative`` ->
+  BENCH_SPEC_r14.json, ``--kernel`` -> BENCH_KERNEL_r17.json,
+  ``--disagg`` -> BENCH_DISAGG_r19.json, ``--cp [N]`` ->
+  BENCH_CP_r22.json, ``--moe [N]`` -> BENCH_MOE_r24.json: see each
+  ``main_*`` below.
 
 Every number is parity-gated first: engine tokens must be byte-identical
-to the model's eager ``generate`` on the bucketed, chunked, and
-prefix-hit paths before anything is trusted ("passed").
+to the model's eager ``generate`` before anything is trusted
+("passed").  On any error ONE parseable failure-marker JSON line is
+emitted and the run exits 1.
 
 Model: the 1.1B-param bench config (bench.py's second line) on TPU; the
 tiny llama config on CPU so the artifact schema is CI-checkable.
@@ -98,196 +77,11 @@ def _ref(model, prompt, budget):
     return np.asarray(out._value)[0, len(prompt):].tolist()
 
 
-def parity_gate(model):
-    """Default (legacy dense prefill) engine must stay byte-identical to
-    eager generate for a staggered 3-request mix."""
-    vocab = model.config.vocab_size
-    rng = np.random.RandomState(7)
-    prompts = [rng.randint(1, vocab, (n,)).astype(np.int64)
-               for n in (5, 3, 8)]
-    budgets = [6, 8, 5]
-    want = [_ref(model, p, n) for p, n in zip(prompts, budgets)]
-    eng = ContinuousBatchingEngine(model, max_batch_size=4,
-                                   num_blocks=64, block_size=16)
-    r0 = eng.add_request(prompts[0], budgets[0])
-    eng.step()
-    r1 = eng.add_request(prompts[1], budgets[1])
-    eng.step()
-    r2 = eng.add_request(prompts[2], budgets[2])
-    eng.run_to_completion()
-    return (eng.result(r0) == want[0] and eng.result(r1) == want[1]
-            and eng.result(r2) == want[2])
-
-
-def bench_decode(model, slots, occupancy, prompt_len, warm, steps,
-                 num_blocks, block_size):
-    """tokens/s for `occupancy` active requests in a `slots`-slot
-    engine (the compiled shape is always `slots` wide)."""
-    vocab = model.config.vocab_size
-    rng = np.random.RandomState(0)
-    eng = ContinuousBatchingEngine(model, max_batch_size=slots,
-                                   num_blocks=num_blocks,
-                                   block_size=block_size)
-    budget = warm + steps + 8           # nobody finishes mid-window
-    for _ in range(occupancy):
-        eng.add_request(rng.randint(1, vocab, (prompt_len,))
-                        .astype(np.int64), max_new_tokens=budget)
-    # prefill admission timed alone (dense forward + one fused scatter
-    # per request); the decode-step compile lands in the warm window
-    t0 = time.perf_counter()
-    eng._admit()
-    np.asarray(eng.caches[-1].key_cache[0, 0, 0, 0])  # fetch barrier
-    dt_prefill = time.perf_counter() - t0
-    for _ in range(warm + 1):
-        eng.step()
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        eng.step()
-    dt = time.perf_counter() - t0
-    assert eng.decode_step.compile_count == 1, (
-        "decode step recompiled mid-bench")
-    return {
-        "occupancy": occupancy,
-        "decode_tokens_per_sec": round(occupancy * steps / dt, 1),
-        "decode_step_ms": round(dt / steps * 1000, 3),
-        "prefill_tokens_per_sec": round(
-            occupancy * prompt_len / dt_prefill, 1),
-    }
-
-
-def bench_prefill(model, buckets, block_size, num_blocks, slots,
-                  mixed_lengths, long_len, prefix_len, suffix_len,
-                  budget):
-    """Bucketed/chunked/prefix-cached prefill on one engine.  Returns
-    the artifact section + an all-parity flag."""
-    vocab = model.config.vocab_size
-    rng = np.random.RandomState(11)
-    eng = ContinuousBatchingEngine(
-        model, max_batch_size=slots, num_blocks=num_blocks,
-        block_size=block_size, prefill_buckets=buckets,
-        enable_prefix_cache=True)
-    seen_lengths = set()
-
-    # --- mixed-length bucketed workload (warms every bucket) ----------
-    prompts = [rng.randint(1, vocab, (n,)).astype(np.int64)
-               for n in mixed_lengths]
-    want = [_ref(model, p, budget) for p in prompts]
-    t0 = time.perf_counter()
-    rids = [eng.add_request(p, budget) for p in prompts]
-    eng.run_to_completion()
-    dt_mixed = time.perf_counter() - t0
-    bucketed_ok = all(eng.result(r) == w for r, w in zip(rids, want))
-    seen_lengths |= set(mixed_lengths)
-
-    # --- chunked: one prompt longer than the top bucket ---------------
-    lp = rng.randint(1, vocab, (long_len,)).astype(np.int64)
-    want_lp = _ref(model, lp, budget)
-    rid = eng.add_request(lp, budget)
-    eng.run_to_completion()
-    chunked_ok = eng.result(rid) == want_lp
-    seen_lengths.add(long_len)
-
-    # --- prefix caching: shared vs cold TTFT at equal length ----------
-    P = rng.randint(1, vocab, (prefix_len,)).astype(np.int64)
-    first = np.concatenate(
-        [P, rng.randint(1, vocab, (suffix_len,)).astype(np.int64)])
-    eng.add_request(first, budget)       # publishes P's pages
-    eng.run_to_completion()
-    seen_lengths.add(prefix_len + suffix_len)
-    hit_ttft, miss_ttft, hit_ok = [], [], []
-    for _ in range(3):
-        bp = np.concatenate(
-            [P, rng.randint(1, vocab, (suffix_len,)).astype(np.int64)])
-        want_b = _ref(model, bp, budget)
-        rb = eng.add_request(bp, budget)
-        eng.run_to_completion()
-        hit_ok.append(eng.result(rb) == want_b)
-        r = eng.finished[rb]
-        hit_ttft.append(r.t_first_token - r.t_submit)
-        assert r.prefix_hit_tokens >= prefix_len - block_size, (
-            "expected a prefix hit")
-        cp = rng.randint(1, vocab,
-                         (prefix_len + suffix_len,)).astype(np.int64)
-        rc = eng.add_request(cp, budget)
-        eng.run_to_completion()
-        rr = eng.finished[rc]
-        miss_ttft.append(rr.t_first_token - rr.t_submit)
-    ttft_hit = statistics.median(hit_ttft)
-    ttft_miss = statistics.median(miss_ttft)
-    prefix_ok = all(hit_ok)
-
-    compiles = eng.prefill_step.total_compiles
-    assert compiles <= len(buckets), (
-        "prefill compiled %d times for %d buckets — the bucket bound "
-        "is broken" % (compiles, len(buckets)))
-    assert eng.decode_step.compile_count == 1
-    pc = eng.prefix_cache
-    lookups = pc.hits + pc.misses
-    section = {
-        "buckets": list(buckets),
-        "chunk_size": eng.chunk_size,
-        "distinct_prompt_lengths": len(seen_lengths),
-        "prefill_compile_count": compiles,
-        "compile_bound": len(buckets),
-        "compiles_without_bucketing": len(seen_lengths),
-        "mixed_workload_prefill_tokens_per_sec": round(
-            sum(mixed_lengths) / max(dt_mixed, 1e-9), 1),
-        "parity": {"bucketed": bool(bucketed_ok),
-                   "chunked": bool(chunked_ok),
-                   "prefix_hit": bool(prefix_ok)},
-        "prefix_cache": {
-            "hits": pc.hits, "misses": pc.misses,
-            "hit_rate": round(pc.hits / max(1, lookups), 3),
-            "hit_tokens": pc.hit_tokens,
-            "evictions": pc.evictions,
-            "ttft_hit_s": round(ttft_hit, 6),
-            "ttft_miss_s": round(ttft_miss, 6),
-            "ttft_speedup": round(ttft_miss / max(ttft_hit, 1e-9), 2),
-        },
-    }
-    ok = (bucketed_ok and chunked_ok and prefix_ok
-          and ttft_hit < ttft_miss)
-    print("# prefill: %d compiles for %d distinct lengths (bound %d); "
-          "TTFT hit %.1fms vs miss %.1fms; hit rate %.2f"
-          % (compiles, len(seen_lengths), len(buckets),
-             ttft_hit * 1e3, ttft_miss * 1e3,
-             section["prefix_cache"]["hit_rate"]), file=sys.stderr)
-    return section, ok
-
-
-def _median_ttft_tpot(eng, rids):
-    ttft, tpot = [], []
-    for rid in rids:
-        r = eng.finished[rid]
-        if r.t_first_token and r.t_submit:
-            ttft.append(r.t_first_token - r.t_submit)
-        n = len(r.output_ids)
-        if n > 1 and r.t_done and r.t_first_token:
-            tpot.append((r.t_done - r.t_first_token) / (n - 1))
-    return (statistics.median(ttft) if ttft else 0.0,
-            statistics.median(tpot) if tpot else 0.0)
-
-
-def _run_workload(eng, model, prompts, budget, check=True):
-    """Submit every prompt up front, run to completion; returns
-    (wall_seconds, parity_ok, (median_ttft, median_tpot))."""
-    want = [_ref(model, p, budget) for p in prompts] if check else None
-    t0 = time.perf_counter()
-    rids = [eng.add_request(p, budget) for p in prompts]
-    eng.run_to_completion()
-    dt = time.perf_counter() - t0
-    ok = True
-    if check:
-        ok = all(eng.result(r) == w for r, w in zip(rids, want))
-    return dt, ok, _median_ttft_tpot(eng, rids)
-
-
 def bench_mixed_decode(model, slots, occupancy, prompt_len, warm, steps,
                        num_blocks, block_size, chunk, mesh=None,
                        request_kw=None, **engine_kw):
-    """Occupancy-matched decode tokens/s through the fused MixedStep
-    (mirror of bench_decode so the split/mixed split is apples to
-    apples); ``mesh`` shards it over the tp axis (the --tp curve);
+    """Decode tokens/s at a given occupancy through the fused
+    MixedStep; ``mesh`` shards it over the tp axis (the --tp curve);
     ``engine_kw`` passes quantization/sampling flags through,
     ``request_kw`` per-request sampling knobs (the --speculative
     sampled-throughput guard)."""
@@ -298,7 +92,6 @@ def bench_mixed_decode(model, slots, occupancy, prompt_len, warm, steps,
     eng = ContinuousBatchingEngine(model, max_batch_size=slots,
                                    num_blocks=num_blocks,
                                    block_size=block_size,
-                                   mixed_step=True,
                                    prefill_chunk_size=chunk,
                                    # size the block table to the
                                    # workload: the compiled attention
@@ -334,244 +127,6 @@ def bench_mixed_decode(model, slots, occupancy, prompt_len, warm, steps,
         "decode_tokens_per_sec": round(occupancy * steps / dt, 1),
         "decode_step_ms": round(dt / steps * 1000, 3),
     }
-
-
-def _stripped_hlo_fingerprint(lowered):
-    """sha256 of the compiled module's optimized HLO with the volatile
-    noise stripped (per-op ``metadata={...}`` source refs, blank lines,
-    indentation) — byte-stable across re-runs of the same code on the
-    same jax/XLA.  Program identity, not a loaded runner's timing, is
-    what a refactor must preserve; this is the real regression gate
-    behind the recorded-only timing ratios below (round 25)."""
-    import hashlib
-    import re as _re
-    text = lowered.compile().as_text()
-    text = _re.sub(r",?\s*metadata=\{[^}]*\}", "", text)
-    lines = [ln.strip() for ln in text.splitlines() if ln.strip()]
-    return hashlib.sha256("\n".join(lines).encode()).hexdigest()
-
-
-def main_mixed(out_path):
-    from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    cfg, model = build_model(on_tpu)
-
-    if on_tpu:
-        wl = dict(slots=8, block_size=16, num_blocks=1024,
-                  mixed_lengths=[20, 45, 70, 100, 130, 190, 250, 300],
-                  long_len=600, prefix_len=192, suffix_len=32, budget=8,
-                  buckets=(32, 64, 128, 256), chunk=256)
-        dec = dict(slots=8, occupancy=8, prompt_len=128, warm=4,
-                   steps=32, num_blocks=8 * (-(-(128 + 64) // 16) + 2),
-                   block_size=16)
-    else:
-        # the round-10 CPU workload, verbatim, for comparability
-        wl = dict(slots=4, block_size=4, num_blocks=192,
-                  mixed_lengths=[3, 5, 6, 7, 9, 10, 11, 13],
-                  long_len=36, prefix_len=24, suffix_len=4, budget=4,
-                  buckets=(8, 16), chunk=16)
-        dec = dict(slots=4, occupancy=4, prompt_len=12, warm=2,
-                   steps=32, num_blocks=64, block_size=4)
-    vocab = cfg.vocab_size
-    rng = np.random.RandomState(11)
-    prompts = [rng.randint(1, vocab, (n,)).astype(np.int64)
-               for n in wl["mixed_lengths"]]
-    long_p = rng.randint(1, vocab, (wl["long_len"],)).astype(np.int64)
-    P = rng.randint(1, vocab, (wl["prefix_len"],)).astype(np.int64)
-    hit_p = np.concatenate(
-        [P, rng.randint(1, vocab, (wl["suffix_len"],)).astype(np.int64)])
-
-    def build(mixed):
-        kw = dict(max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
-                  block_size=wl["block_size"], enable_prefix_cache=True)
-        if mixed:
-            kw.update(mixed_step=True, prefill_chunk_size=wl["chunk"])
-        else:
-            kw.update(prefill_buckets=wl["buckets"])
-        return ContinuousBatchingEngine(model, **kw)
-
-    sections = {}
-    parity = {}
-    # warm-up workload: same lengths as the measured one but DIFFERENT
-    # tokens (seeded apart), so every compile the measured admission
-    # mix will need — all-decode, decode+chunk, multi-chunk budgets —
-    # lands before the window without seeding prefix-cache hits
-    wrng = np.random.RandomState(1107)
-    warm_prompts = [wrng.randint(1, vocab, (n,)).astype(np.int64)
-                    for n in wl["mixed_lengths"]]
-    long_w = wrng.randint(1, vocab, (wl["long_len"],)).astype(np.int64)
-
-    for name in ("split", "mixed"):
-        eng = build(mixed=(name == "mixed"))
-        # warm every compile OUT of the measured window: the long
-        # prompt (touches every bucket / the chunked budgets) TWICE —
-        # the repeat is a whole-prompt prefix hit, which warms the
-        # process-global copy-on-write dispatch — then the
-        # workload-shaped warm set (touches every admission-mix budget)
-        _run_workload(eng, model, [long_w], wl["budget"], check=False)
-        _run_workload(eng, model, [long_w], wl["budget"], check=False)
-        _run_workload(eng, model, warm_prompts, wl["budget"],
-                      check=False)
-        dt, ok_mixed, (ttft_med, tpot_med) = _run_workload(
-            eng, model, prompts, wl["budget"])
-        # long_p is FRESH tokens: a cold chunked prefill, not a prefix
-        # hit on the warm run's pages
-        dt_long, ok_long, _ = _run_workload(eng, model, [long_p],
-                                            wl["budget"])
-        _, _, (ttft_cold, _t) = _run_workload(eng, model, [hit_p],
-                                              wl["budget"])
-        _, ok_hit, (ttft_hit, _t) = _run_workload(eng, model, [hit_p],
-                                                  wl["budget"])
-        hit_req = max(eng.finished, key=lambda k: k)
-        hit_tokens = eng.finished[hit_req].prefix_hit_tokens
-        parity[name] = {"mixed_workload": bool(ok_mixed),
-                        "chunked_long_prompt": bool(ok_long),
-                        "prefix_hit": bool(ok_hit and hit_tokens > 0)}
-        sections[name] = {
-            "mixed_workload_prefill_tokens_per_sec": round(
-                sum(wl["mixed_lengths"]) / max(dt, 1e-9), 1),
-            "mixed_workload_ttft_s": round(ttft_med, 6),
-            "mixed_workload_tpot_s": round(tpot_med, 6),
-            "chunked_long_prompt_s": round(dt_long, 6),
-            "ttft_prefix_cold_s": round(ttft_cold, 6),
-            "ttft_prefix_hit_s": round(ttft_hit, 6),
-        }
-        if name == "mixed":
-            mixed_eng = eng
-            sections[name]["token_budgets"] = list(eng.token_budgets)
-            sections[name]["mixed_step_compile_count"] = \
-                eng.mixed.total_compiles
-            sections[name]["compile_bound"] = len(eng.token_budgets)
-            assert eng.mixed.total_compiles <= len(eng.token_budgets)
-            assert eng.decode_step.compile_count == 0
-        else:
-            sections[name]["prefill_compile_count"] = \
-                eng.prefill_step.total_compiles
-
-    # decode-only parity for the mixed engine (the r6 gate, fused path)
-    parity["mixed"]["decode_only"] = parity_gate_mixed(model, wl)
-
-    # occupancy-matched decode throughput: best of 3 fresh engines per
-    # side — the per-step window is sub-ms, so one loaded scheduler
-    # quantum would otherwise decide the 5% gate, not the code
-    def _best_decode(fn, *args):
-        runs = [fn(*args) for _ in range(3)]
-        return max(runs, key=lambda r: r["decode_tokens_per_sec"])
-
-    split_dec = _best_decode(
-        bench_decode, model, dec["slots"], dec["occupancy"],
-        dec["prompt_len"], dec["warm"], dec["steps"],
-        dec["num_blocks"], dec["block_size"])
-    mixed_dec = _best_decode(
-        bench_mixed_decode, model, dec["slots"], dec["occupancy"],
-        dec["prompt_len"], dec["warm"], dec["steps"],
-        dec["num_blocks"], dec["block_size"], wl["chunk"])
-    sections["split"]["decode"] = split_dec
-    sections["mixed"]["decode"] = mixed_dec
-
-    # --- gates vs the recorded round-10 artifact -----------------------
-    r10_prefill, r10_decode = None, None
-    try:
-        with open("BENCH_SERVE_r10.json") as f:
-            r10 = json.load(f)
-        r10_prefill = r10["prefill"][
-            "mixed_workload_prefill_tokens_per_sec"]
-        for row in r10.get("decode_sweep", []):
-            if row.get("occupancy") == dec["occupancy"]:
-                r10_decode = row["decode_tokens_per_sec"]
-    except Exception:
-        pass                           # fall back to the live split run
-    base_prefill = r10_prefill if r10_prefill is not None else \
-        sections["split"]["mixed_workload_prefill_tokens_per_sec"]
-    base_decode = r10_decode if r10_decode is not None else \
-        split_dec["decode_tokens_per_sec"]
-    mixed_prefill = sections["mixed"][
-        "mixed_workload_prefill_tokens_per_sec"]
-    mixed_decode = mixed_dec["decode_tokens_per_sec"]
-    # --- stripped-HLO identity: the real post-refactor gate ------------
-    # (round 25) the two CPU timing ratios flaked ±20% on loaded
-    # runners across r24 re-runs; what a refactor must actually
-    # preserve is the compiled program.  Gate: the fused mixed step's
-    # stripped optimized HLO hashes identically to the previously
-    # recorded artifact (first run after the change records it); the
-    # timing ratios move to the UNGATED `recorded` block for
-    # trend-reading.
-    fp_T = int(mixed_eng.token_budgets[0])
-    fp = _stripped_hlo_fingerprint(mixed_eng.mixed.aot_lower(fp_T))
-    prev_fp = None
-    try:
-        with open(out_path) as f:
-            prev = json.load(f).get("hlo_fingerprint") or {}
-        if prev.get("step") == f"mixed_step@T{fp_T}":
-            prev_fp = prev.get("sha256")
-    except Exception:
-        pass
-    gates = {
-        "parity": all(v for d in parity.values() for v in d.values()),
-        "mixed_step_hlo_identity": bool(prev_fp is None
-                                        or fp == prev_fp),
-        "compile_bound": sections["mixed"]["mixed_step_compile_count"]
-        <= sections["mixed"]["compile_bound"],
-    }
-    recorded = {
-        "note": "timing ratios recorded, NOT gated (r25 de-flake): "
-                "±20% scheduler noise on shared CPU runners; the "
-                "stripped-HLO identity gate is the regression check",
-        "prefill_beats_r10": bool(mixed_prefill > base_prefill),
-        "decode_within_5pct_of_r10": bool(
-            mixed_decode >= 0.95 * base_decode),
-        "prefill_vs_r10": round(
-            mixed_prefill / max(base_prefill, 1e-9), 3),
-        "decode_vs_r10": round(
-            mixed_decode / max(base_decode, 1e-9), 3),
-    }
-    ok = all(gates.values())
-    artifact = {
-        "metric": "serving_mixed_workload_prefill_tokens_per_sec",
-        "value": mixed_prefill,
-        "passed": ok,
-        "gates": gates,
-        "recorded": recorded,
-        "hlo_fingerprint": {"sha256": fp,
-                            "step": f"mixed_step@T{fp_T}"},
-        "parity": parity,
-        "baseline_r10": {"prefill_tokens_per_sec": r10_prefill,
-                         "decode_tokens_per_sec": r10_decode,
-                         "occupancy": dec["occupancy"]},
-        "split": sections["split"],
-        "mixed": sections["mixed"],
-        "speedup_prefill_vs_split_live": round(
-            mixed_prefill / max(sections["split"][
-                "mixed_workload_prefill_tokens_per_sec"], 1e-9), 2),
-        "config": {
-            "params_m": round(param_count(cfg) / 1e6),
-            "layers": cfg.num_hidden_layers,
-            "hidden": cfg.hidden_size,
-            "slots": wl["slots"],
-            "block_size": wl["block_size"],
-            "num_blocks": wl["num_blocks"],
-            "chunk": wl["chunk"],
-            "dtype": cfg.dtype,
-        },
-        "platform": dev.platform,
-        "device_kind": getattr(dev, "device_kind", ""),
-    }
-    with open(out_path, "w") as f:
-        json.dump(artifact, f, indent=1)
-    print("# mixed prefill %.1f tok/s (r10 %.1f) decode %.1f tok/s "
-          "(r10 %s) gates=%s"
-          % (mixed_prefill, base_prefill, mixed_decode,
-             r10_decode, gates), file=sys.stderr)
-    print(json.dumps({
-        "metric": artifact["metric"],
-        "value": artifact["value"],
-        "unit": "tokens/s",
-        "vs_baseline": round(mixed_prefill / max(base_prefill, 1e-9), 2)
-        if ok else 0.0,
-    }), flush=True)
-    if not ok:
-        sys.exit(1)
 
 
 SPEC_THRESHOLDS = {
@@ -691,7 +246,7 @@ def _spec_engine(model, draft, k, wl, sampling=False, **kw):
     eng = ContinuousBatchingEngine(
         model, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
         block_size=wl["block_size"], max_seq_len=wl["max_seq_len"],
-        mixed_step=True, prefill_chunk_size=wl["chunk"],
+        prefill_chunk_size=wl["chunk"],
         draft_model=draft, spec_k=k, sampling=sampling, **kw)
     return eng
 
@@ -737,7 +292,7 @@ def main_spec(out_path):
                 model, max_batch_size=wl["slots"],
                 num_blocks=wl["num_blocks"],
                 block_size=wl["block_size"],
-                max_seq_len=wl["max_seq_len"], mixed_step=True,
+                max_seq_len=wl["max_seq_len"],
                 prefill_chunk_size=wl["chunk"], sampling=sampling)
         for p in prompts:
             e.add_request(p, wl["budget"], **(samp_kw or {}))
@@ -798,7 +353,7 @@ def main_spec(out_path):
         e = ContinuousBatchingEngine(
             raw, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
             block_size=wl["block_size"], max_seq_len=wl["max_seq_len"],
-            mixed_step=True, prefill_chunk_size=wl["chunk"],
+            prefill_chunk_size=wl["chunk"],
             draft_model=llama_truncated_draft(raw, 1), spec_k=2)
         for p in prompts:
             e.add_request(p, wl["budget"])
@@ -842,8 +397,7 @@ def main_spec(out_path):
     # compiles after the first pass
     churn_eng = ContinuousBatchingEngine(
         r13_model, max_batch_size=2, num_blocks=32,
-        block_size=dec["block_size"], mixed_step=True,
-        prefill_chunk_size=dchunk, sampling=True)
+        block_size=dec["block_size"], prefill_chunk_size=dchunk, sampling=True)
     churn_knobs = [dict(temperature=1.0, seed=1),
                    dict(temperature=2.5, top_k=3, seed=9),
                    dict(temperature=0.4, top_p=0.5, seed=77),
@@ -1021,7 +575,7 @@ def _run_quant_workload(model, wl, prompts, budgets, sequential,
     per-request token lists (and the engine, for accounting)."""
     eng = ContinuousBatchingEngine(
         model, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
-        block_size=wl["block_size"], mixed_step=True,
+        block_size=wl["block_size"],
         prefill_chunk_size=wl["chunk"], enable_prefix_cache=True,
         mesh=mesh, **quant_kw)
     rids = []
@@ -1282,8 +836,7 @@ def _tp_workload_tokens(model, mesh, wl):
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     eng = ContinuousBatchingEngine(
         model, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
-        block_size=wl["block_size"], mixed_step=True,
-        prefill_chunk_size=wl["chunk"], mesh=mesh)
+        block_size=wl["block_size"], prefill_chunk_size=wl["chunk"], mesh=mesh)
     rids = []
     for i, p in enumerate(wl["prompts"]):
         rids.append(eng.add_request(p, wl["budget"]))
@@ -1461,7 +1014,7 @@ def _cp_prefix_tokens(model, mesh, wl):
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     eng = ContinuousBatchingEngine(
         model, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
-        block_size=wl["block_size"], mixed_step=True,
+        block_size=wl["block_size"],
         prefill_chunk_size=wl["chunk"], enable_prefix_cache=True,
         mesh=mesh)
     p = wl["prompts"][-1]                      # the chunked-length one
@@ -1480,8 +1033,7 @@ def _cp_decode_tokens(model, mesh, wl):
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     eng = ContinuousBatchingEngine(
         model, max_batch_size=wl["slots"], num_blocks=wl["num_blocks"],
-        block_size=wl["block_size"], mixed_step=True,
-        prefill_chunk_size=wl["chunk"], mesh=mesh)
+        block_size=wl["block_size"], prefill_chunk_size=wl["chunk"], mesh=mesh)
     rids = [eng.add_request(p[:3], wl["budget"] * 2)
             for p in wl["prompts"][:wl["slots"]]]
     eng.run_to_completion()
@@ -1725,7 +1277,7 @@ def _moe_router_drill(moe_model, dense_model, wl):
     def eng(model, mesh=None):
         return ContinuousBatchingEngine(
             model, max_batch_size=2, num_blocks=wl["num_blocks"],
-            block_size=wl["block_size"], mixed_step=True,
+            block_size=wl["block_size"],
             prefill_chunk_size=wl["chunk"], mesh=mesh)
 
     e_moe_ep = eng(moe_model, _ep_mesh_for(2))
@@ -1952,7 +1504,6 @@ def parity_gate_mixed(model, wl):
     eng = ContinuousBatchingEngine(model, max_batch_size=4,
                                    num_blocks=64,
                                    block_size=wl["block_size"],
-                                   mixed_step=True,
                                    prefill_chunk_size=wl["chunk"])
     r0 = eng.add_request(prompts[0], budgets[0])
     eng.step()
@@ -2037,7 +1588,7 @@ def _paired_decode_tps(model, dec, waves=21, steps=6):
         eng = ContinuousBatchingEngine(
             model, max_batch_size=dec["slots"],
             num_blocks=dec["num_blocks"], block_size=dec["block_size"],
-            mixed_step=True, prefill_chunk_size=dec["chunk"],
+            prefill_chunk_size=dec["chunk"],
             max_seq_len=dec["prompt_len"] + budget + dec["block_size"],
             **kw)
         for _ in range(dec["occupancy"]):
@@ -2261,8 +1812,7 @@ def _disagg_engine(model, knobs, **kw):
     kw.setdefault("block_size", knobs["block_size"])
     kw.setdefault("max_seq_len", knobs["max_seq_len"])
     kw.setdefault("prefill_chunk_size", knobs["chunk"])
-    return ContinuousBatchingEngine(model, mixed_step=True,
-                                    enable_prefix_cache=True, **kw)
+    return ContinuousBatchingEngine(model, enable_prefix_cache=True, **kw)
 
 
 def _warm_resume_engine(model, knobs, resume_len, budget, kv_dtype=None):
@@ -2451,7 +2001,7 @@ def bench_host_tier(model, knobs):
             num_blocks=hk["num_blocks"],
             block_size=knobs["block_size"],
             max_seq_len=hk["max_seq_len"],
-            prefill_chunk_size=knobs["chunk"], mixed_step=True,
+            prefill_chunk_size=knobs["chunk"],
             enable_prefix_cache=True, host_tier_bytes=tier)
         run_wave(eng, 0)
         h0, m0 = eng.prefix_cache.hits, eng.prefix_cache.misses
@@ -2549,9 +2099,9 @@ def main_disagg(out_path):
     ok = True
     gate_notes = []
 
-    # default engines untouched: the r10 staggered parity gate must
-    # still hold with zero migration/host-tier config
-    defaults_ok = parity_gate(model)
+    # an engine with zero migration/host-tier config: the staggered
+    # parity gate must hold
+    defaults_ok = parity_gate_mixed(model, knobs)
     if not defaults_ok:
         ok = False
         gate_notes.append("default-engine parity vs eager failed")
@@ -2847,8 +2397,6 @@ def main():
         args.remove("--tp")
         stray = [a for a in args if a.startswith("-")]
         if stray:
-            # '--mixed --tp 2' must not silently skip the mixed bench
-            # and write the artifact to a file named '--mixed'
             print("bench_serving: --tp cannot combine with %s — run "
                   "the modes separately" % ", ".join(stray),
                   file=sys.stderr)
@@ -2868,95 +2416,9 @@ def main():
             }), flush=True)
             sys.exit(1)
         return
-    argv = [a for a in sys.argv[1:] if a != "--mixed"]
-    if "--mixed" in sys.argv[1:]:
-        out_path = argv[0] if argv else "BENCH_SERVE_r11.json"
-        try:
-            main_mixed(out_path)
-        except SystemExit:
-            raise
-        except Exception as e:                        # noqa: BLE001
-            print(json.dumps({
-                "metric": "serving_mixed_workload_prefill_tokens_per_sec",
-                "value": 0.0,
-                "unit": "error",
-                "vs_baseline": 0.0,
-                "error": repr(e)[:300],
-            }), flush=True)
-            sys.exit(1)
-        return
-    out_path = argv[0] if argv else "BENCH_SERVE_r10.json"
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    cfg, model = build_model(on_tpu)
-
-    ok = parity_gate(model)
-    print(f"# parity gate (legacy dense prefill) vs eager generate: "
-          f"{'OK' if ok else 'FAILED'}", file=sys.stderr)
-
-    if on_tpu:
-        slots, prompt_len = 8, 128
-        num_blocks, block_size = 8 * (-(-(128 + 64) // 16) + 2), 16
-        occupancies = [1, 2, 4, 8]
-        warm, steps = 4, 32
-        pf = dict(buckets=(32, 64, 128, 256), block_size=16,
-                  num_blocks=1024, slots=8,
-                  mixed_lengths=[20, 45, 70, 100, 130, 190, 250, 300],
-                  long_len=600, prefix_len=192, suffix_len=32, budget=8)
-    else:
-        slots, prompt_len = 4, 12
-        num_blocks, block_size = 64, 4
-        occupancies = [1, 2, 4]
-        warm, steps = 2, 8
-        pf = dict(buckets=(8, 16), block_size=4, num_blocks=192, slots=4,
-                  mixed_lengths=[3, 5, 6, 7, 9, 10, 11, 13],
-                  long_len=36, prefix_len=24, suffix_len=4, budget=4)
-
-    sweep = []
-    for occ in occupancies:
-        r = bench_decode(model, slots, occ, prompt_len, warm, steps,
-                         num_blocks, block_size)
-        sweep.append(r)
-        print(f"# occ={occ}/{slots}: {r['decode_tokens_per_sec']} tok/s "
-              f"decode ({r['decode_step_ms']} ms/step), "
-              f"{r['prefill_tokens_per_sec']} tok/s prefill",
-              file=sys.stderr)
-
-    prefill_section, prefill_ok = bench_prefill(model, **pf)
-    ok = bool(ok and prefill_ok)
-
-    full = sweep[-1]
-    artifact = {
-        "metric": "serving_decode_tokens_per_sec_per_chip",
-        "value": full["decode_tokens_per_sec"],
-        "passed": ok,
-        "prefill_tokens_per_sec": full["prefill_tokens_per_sec"],
-        "decode_sweep": sweep,
-        "decode_compile_count": 1,
-        "prefill": prefill_section,
-        "config": {
-            "params_m": round(param_count(cfg) / 1e6),
-            "layers": cfg.num_hidden_layers,
-            "hidden": cfg.hidden_size,
-            "slots": slots,
-            "prompt_len": prompt_len,
-            "block_size": block_size,
-            "num_blocks": num_blocks,
-            "dtype": cfg.dtype,
-        },
-        "platform": dev.platform,
-        "device_kind": getattr(dev, "device_kind", ""),
-    }
-    with open(out_path, "w") as f:
-        json.dump(artifact, f, indent=1)
-    print(json.dumps({
-        "metric": artifact["metric"],
-        "value": artifact["value"],
-        "unit": "tokens/s",
-        "vs_baseline": 1.0 if ok else 0.0,
-    }), flush=True)
-    if not ok:
-        sys.exit(1)
+    print("bench_serving: pick a mode: --tp [N] | --quant | --speculative "
+          "| --kernel | --disagg | --cp [N] | --moe [N]", file=sys.stderr)
+    sys.exit(2)
 
 
 if __name__ == "__main__":

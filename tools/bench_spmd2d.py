@@ -135,7 +135,7 @@ def _serve(model, mesh):
     model.eval()
     eng = ContinuousBatchingEngine(model, max_batch_size=4, num_blocks=64,
                                    block_size=4, mesh=mesh,
-                                   mixed_step=True, prefill_chunk_size=4)
+                                   prefill_chunk_size=4)
     rids = [eng.add_request(np.asarray(p, np.int64), NEW_TOKENS)
             for p in PROMPTS]
     eng.run_to_completion()

@@ -22,7 +22,7 @@ to build the byte-parity in-process reference with IDENTICAL weights
       "engine_id": 0, "role": "mixed",
       "seed": 0,                  // paddle.seed before model build
       "slots": 4, "num_blocks": 64, "block_size": 4, "chunk": null,
-      "mixed_step": true, "enable_prefix_cache": true,
+      "enable_prefix_cache": true,
       "kv_dtype": null, "sampling": false,
       "warm": {"prompt_len": 12, "budget": 4},   // optional precompile
       "fault_spec": "hang:rpc.recv:ms=2000"      // optional, in-process
@@ -78,7 +78,6 @@ def build_engine_from_config(cfg: dict):
         max_batch_size=int(cfg.get("slots", 4)),
         num_blocks=int(cfg.get("num_blocks", 64)),
         block_size=int(cfg.get("block_size", 4)),
-        mixed_step=bool(cfg.get("mixed_step", True)),
         prefill_chunk_size=cfg.get("chunk"),
         enable_prefix_cache=bool(cfg.get("enable_prefix_cache", True)),
         kv_dtype=cfg.get("kv_dtype"),

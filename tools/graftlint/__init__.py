@@ -17,7 +17,7 @@ rules:
   literals (x64 is globally on for paddle parity), ``PRNGKey``
   construction, shape-dependent Python control flow.
 - **hlo-contracts** (compiled artifacts): AOT-lower the train step and
-  the three serving steps once and assert donation actually aliases
+  the serving step once and assert donation actually aliases
   the KV pools, no f64 op appears, and the packed-operand layout
   matches the pinned formula.
 - **concurrency** (AST): per-class field-access maps over every

@@ -2,7 +2,7 @@
 
 Generalizes the ``verify_sharded_update`` HLO assertions
 (``distributed/auto_parallel/dist_model.py``) into a reusable pass:
-AOT-lower the fused train step and the three serving steps ONCE over a
+AOT-lower the fused train step and the serving step ONCE over a
 tiny 1-layer model on CPU (≈2s total; artifacts are cached per
 process) and assert, from the optimized HLO text and the lowered
 operand avals, the three contracts every round since r11 has ridden
@@ -21,8 +21,7 @@ on:
 - **hlo-packed-layout**: the operand pytree matches the pinned layout.
   The mixed step carries exactly ONE int32 host operand of exactly
   ``4*T + max_spans*(bt_width+4)`` words (the round-11 "nine operands,
-  one transfer" rule: transfer COUNT is the decode budget); the split
-  decode/prefill steps stay at their pinned 3/4 int32 operands.  A new
+  one transfer" rule: transfer COUNT is the decode budget).  A new
   host operand — however small — is a second per-step transfer and
   fails here, not in a TPU latency regression three rounds later.
 
@@ -47,7 +46,7 @@ TINY = dict(num_hidden_layers=1, hidden_size=32, num_attention_heads=2,
             num_key_value_heads=2, vocab_size=64, intermediate_size=64)
 NUM_BLOCKS, BLOCK_SIZE = 8, 4
 BT_WIDTH, MAX_SPANS = 4, 2
-MIXED_T, DECODE_SLOTS, PREFILL_C = 8, 2, 8
+MIXED_T = 8
 # round 21: the 2D fsdp x tp mesh the extra artifacts lower under —
 # every TINY dim divides by 2, so the composed specs survive pruning
 MESH_FSDP, MESH_TP = 2, 2
@@ -179,9 +178,9 @@ def _avals_of(lowered) -> List[Tuple[str, Tuple[int, ...]]]:
 
 def build_artifacts() -> Dict[str, Artifact]:
     """Build + compile the step artifacts once per process (tiny
-    1-layer model, CPU platform — deterministic anywhere): the four
-    1D lowerings plus the round-21 fsdp x tp pair (2D mixed step and
-    2D train step)."""
+    1-layer model, CPU platform — deterministic anywhere): the 1D
+    lowerings (mixed step, migration inject, train step) plus the
+    round-21 fsdp x tp pair (2D mixed step and 2D train step)."""
     if _ARTIFACTS:
         return _ARTIFACTS
     from paddle_tpu.testing.dryrun import force_cpu_devices
@@ -208,8 +207,7 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     from paddle_tpu.models.llama import (LlamaForCausalLM,
                                          llama_tiny_config)
     from paddle_tpu.ops.paged_attention import PagedKVCache
-    from paddle_tpu.jit.serving_step import (DecodeStep, MixedStep,
-                                             PrefillStep)
+    from paddle_tpu.jit.serving_step import MixedStep
     cfg = llama_tiny_config(**TINY)
     model = LlamaForCausalLM(cfg)
     model.eval()
@@ -241,16 +239,6 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     art(f"mixed_step@T{MIXED_T}", mixed.aot_lower(MIXED_T),
         n_pool=2 * L, psig=pool_sig, expect_i32=1,
         packed_len=packed_len, min_aliases=2 * L)
-
-    dec = DecodeStep(model, caches(), use_pallas=False)
-    art(f"decode_step@S{DECODE_SLOTS}", dec.aot_lower(DECODE_SLOTS),
-        n_pool=2 * L, psig=pool_sig, expect_i32=3, packed_len=None,
-        min_aliases=2 * L)
-
-    pre = PrefillStep(model, caches(), bt_width=BT_WIDTH)
-    art(f"prefill_step@C{PREFILL_C}", pre.aot_lower(PREFILL_C),
-        n_pool=2 * L, psig=pool_sig, expect_i32=4, packed_len=None,
-        min_aliases=2 * L)
 
     # round 19: the page-migration inject dispatch — every pool
     # parameter donated (the scatter is an in-place HBM write) and
@@ -411,8 +399,7 @@ register(Rule(
     id="hlo-packed-layout",
     family="hlo-contracts",
     contract="the mixed step carries exactly ONE int32 host operand of "
-             "the pinned 4*T+max_spans*(bt_width+4) length; split "
-             "steps stay at their pinned 3/4 int32 operands; the "
+             "the pinned 4*T+max_spans*(bt_width+4) length; the "
              "migration inject dispatch carries exactly one (the "
              "destination ids — payload is one buffer per dtype)",
     check=lambda sources: _run(check_packed_layout),

@@ -75,7 +75,6 @@ _PER_UNIT_RE = {u: re.compile(rf"{u}_per_[a-z0-9_]+$")
 # engine must keep registering these names (see BENCH_SERVE_r10.json
 # provenance; README "Observability" inventory)
 REQUIRED_NAMES = frozenset({
-    "serving_prefill_compiles_total",
     "serving_prefill_chunk_queue_depth",
     "serving_prefix_cache_lookups_total",
     "serving_prefix_cache_hit_tokens_total",
