@@ -986,6 +986,11 @@ class ContinuousBatchingEngine:
           beside ``tokens`` x the GQA group size it says how much of
           the launch is real.  For a latent model the rows are tokens
           x heads.
+        - ``attn_blocks``, ``attn_blocks_masked``: for a latent model
+          on the Pallas launch, the key blocks one layer's launch walks
+          for the step's spans and how many of them took the masked
+          body (``MixedStep.attn_blocks``: host integers from the
+          spans' ``(q_len, kv_len)``); 0 otherwise.
         - ``moe_rows``, ``moe_rows_top``: the assignments that landed
           on the experts this engine holds, summed over the routed
           layers, and the fullest held expert's; counted by the step
@@ -1011,6 +1016,7 @@ class ContinuousBatchingEngine:
                     "t_admit"):
             t = bounds[key] = rec.get(key, t)
         spans = np.asarray(rec["spans"], np.int32).reshape(-1, 3)
+        blocks, masked = self.mixed.attn_blocks(spans[:, 1], spans[:, 2])
         span_log.record(
             STEP_SPAN, t0, t_end, cat="serving", engine=self.engine_id,
             step=self._step_no, **bounds, budget=rec["budget"],
@@ -1018,6 +1024,7 @@ class ContinuousBatchingEngine:
             n_pre=rec["n_pre"], spans=spans,
             attn_rows=(self.mixed.attn_rows(rec["budget"], spans[:, 1])
                        if rec["budget"] else 0),
+            attn_blocks=blocks, attn_blocks_masked=masked,
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
             admitted=tuple(self._admitted), running=running,

@@ -986,6 +986,18 @@ class MixedStep:
             cache.kv_dtype)
         return ragged_attn_rows(q_lens, tile, H // Hkv)
 
+    def attn_blocks(self, q_lens, kv_lens):
+        """``(blocks, masked)``: the key blocks one layer's latent
+        launch walks for spans of these lengths and how many of them
+        take its masked body (``ops/pallas_kernels.
+        latent_attn_blocks``).  ``(0, 0)`` where no such launch runs:
+        a grouped-query model, the XLA reference."""
+        if self._latent is None or not self.use_pallas:
+            return 0, 0
+        from ..ops.pallas_kernels import latent_attn_blocks
+        return latent_attn_blocks(q_lens, kv_lens,
+                                  self.caches[0].block_size, self.bt_width)
+
     def _build(self, T: int):
         from ..autograd.tape import no_grad
         from ..ops.paged_attention import (_ragged_attention_xla,

@@ -40,14 +40,18 @@ def online_softmax_update(carry, s, ok, pv_of_p):
     (initialize ``m=-inf, l=0, acc=0``).  s: ``[g, t]`` fp32 scores with
     masked lanes already set to ``-inf``; ok: the ``[g, t]`` bool mask
     (re-applied after the exp so an all-masked row's ``exp(-inf - -inf)
-    = nan`` never reaches the accumulators).  pv_of_p: callback
+    = nan`` never reaches the accumulators), or ``None`` where every
+    lane is visible (a Python-level branch: no select is traced).
+    pv_of_p: callback
     computing the ``[g, d]`` ``p @ V`` product from the ``[g, t]``
     probability tile — site-specific (fp32 matmul, int8 MXU with folded
     scales, ...).  Returns the new ``(m, l, acc)``.
     """
     m, l, acc = carry
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), np.float32(0.0))
+    p = jnp.exp(s - m_new)
+    if ok is not None:
+        p = jnp.where(ok, p, np.float32(0.0))
     alpha = jnp.exp(m - m_new)
     l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_new = acc * alpha + pv_of_p(p)

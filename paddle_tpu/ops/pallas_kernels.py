@@ -1564,11 +1564,14 @@ def _page_block_copies(bt_ref, s, n_pages, block_size: int,
     return block_copies
 
 
-def _stream_key_blocks(n_blk, block_copies, block_math):
+def _stream_key_blocks(n_blk, block_copies, block_math, lo=None, hi=None):
     """Walk a tile's key blocks through the two VMEM slots: block b+1's
     copies are issued before block b's are waited for and
     ``block_math(b, slot)`` runs.  Block 0's copies are already started
-    (beside the q tile's)."""
+    (beside the q tile's).  ``lo`` / ``hi`` walk blocks ``[lo, hi)`` of
+    the ``n_blk`` only (default: all), the prefetch still running on to
+    ``n_blk``: consecutive calls over consecutive ranges are one stream
+    under more than one body."""
     def body(b, _):
         slot = lax.rem(b, jnp.int32(2))
 
@@ -1579,7 +1582,8 @@ def _stream_key_blocks(n_blk, block_copies, block_math):
         block_math(b, slot)
         return 0
 
-    lax.fori_loop(jnp.int32(0), n_blk, body, 0)
+    lax.fori_loop(jnp.int32(0) if lo is None else lo,
+                  n_blk if hi is None else hi, body, 0)
 
 
 def _reset_softmax_state(m_s, l_s, acc_s):
@@ -1910,9 +1914,19 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
 # is no second pool.  A q tile is _LATENT_TILE_TOKENS tokens x H heads
 # rows; inside a cell the rows are walked _LATENT_SUB_TOKENS tokens at a
 # time with a traced trip count, so a one-token decode span multiplies
-# H rows and a chunk tile all of them, through one traced body.
+# H rows and a chunk tile all of them.  Keys stream in blocks of
+# _LATENT_KV_BLOCK; the body is traced twice, without a mask for the
+# blocks wholly under a tile's diagonal and with one for its last.
 _LATENT_TILE_TOKENS = 8
 _LATENT_SUB_TOKENS = 2
+# Keys of one block of the latent launch (whole pages: eight at the
+# cell's 128-token pages).  A sub-tile's float32 accumulator ([256, 512])
+# is four times its score tile at 128 keys, and its round trip, the
+# m / l update and the two lane reductions a row come once a block: the
+# launch alone reads 63 / 97 / 114 / 119 TFLOP/s at 128 / 256 / 512 /
+# 1,024 keys (PERF.md, PR 30); 2,048 would overrun _RAGGED_TILE_VMEM.
+# The grouped-query kernel keeps _RAGGED_KV_BLOCK.
+_LATENT_KV_BLOCK = 1024
 
 
 def latent_row_width(kv_lora_rank: int, rope_dim: int) -> int:
@@ -1920,6 +1934,10 @@ def latent_row_width(kv_lora_rank: int, rope_dim: int) -> int:
     rounded up to whole 128-lane tiles (576 -> 640), which is what the
     row takes in HBM's tiled layout and in VMEM whatever is declared."""
     return -(-(int(kv_lora_rank) + int(rope_dim)) // 128) * 128
+
+
+def _latent_pages_per_block(block_size: int, bt_width: int) -> int:
+    return max(1, min(_LATENT_KV_BLOCK // block_size, bt_width))
 
 
 def _latent_cell_vmem_bytes(heads: int, row: int, v_width: int,
@@ -1939,7 +1957,7 @@ def _latent_cell_vmem_bytes(heads: int, row: int, v_width: int,
 def latent_kernel_vmem_bytes(*, heads: int, kv_lora_rank: int,
                              rope_dim: int, block_size: int,
                              bt_width: int, dtype="bfloat16") -> int:
-    kb = max(1, min(_RAGGED_KV_BLOCK // block_size, bt_width))
+    kb = _latent_pages_per_block(block_size, bt_width)
     return _latent_cell_vmem_bytes(
         heads, latent_row_width(kv_lora_rank, rope_dim), kv_lora_rank,
         kb * block_size, jnp.dtype(dtype).itemsize)
@@ -1950,6 +1968,28 @@ def latent_attn_rows(q_lens, heads: int) -> int:
     spans of these lengths: whole sub-tiles of the real tokens."""
     sub = _LATENT_SUB_TOKENS
     return heads * sub * sum(-(-max(int(n), 0) // sub) for n in q_lens)
+
+
+def latent_attn_blocks(q_lens, kv_lens, block_size: int, bt_width: int):
+    """``(blocks, masked)``: the key blocks one latent launch walks for
+    spans of these ``(q_len, kv_len)`` — every q tile's, as
+    ``_tile_extent`` sizes them — and how many of them take the masked
+    body (``_latent_paged_kernel``: a block some row of the tile does
+    not see whole).  Host integers only."""
+    tile = _LATENT_TILE_TOKENS
+    kb = _latent_pages_per_block(block_size, bt_width)
+    ql = np.maximum(np.asarray(q_lens, np.int64), 0)
+    kl = np.asarray(kv_lens, np.int64)
+    per = -(-ql // tile)                          # tiles a span
+    span = np.repeat(np.arange(ql.size), per)
+    first = (np.arange(span.size) - np.repeat(np.cumsum(per) - per, per)) \
+        * tile
+    pos0 = (kl - ql)[span] + first
+    kv_end = np.minimum(kl[span], pos0 + np.minimum(ql[span] - first, tile))
+    n_pages = np.minimum(-(-kv_end // block_size), bt_width)
+    n_blk = -(-n_pages // kb)
+    whole = np.minimum(np.maximum(pos0 + 1, 0) // (kb * block_size), n_blk)
+    return int(n_blk.sum()), int((n_blk - whole).sum())
 
 
 def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,
@@ -1963,7 +2003,13 @@ def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,
     cached row a token for all heads: a page ``[block_size, row]`` is
     the key block as stored (no head-major copy), the value block is its
     first ``v_width`` columns, and the per-head loop is a loop over
-    sub-tiles of tokens with a traced bound."""
+    sub-tiles of tokens with a traced bound.
+
+    A key block that the tile's FIRST row sees whole (its last key at
+    or before that row's position, hence before ``kv_end``) is visible
+    to every row: it runs the update with no mask.  Only the tile's
+    last blocks (the diagonal's, and a partly filled last one) build
+    the mask; both bodies walk the one page stream in order."""
     (ts_ref, tr_ref, tn_ref, qoff_ref, qlen_ref, kvlen_ref,
      bt_ref) = refs[:7]
     (q_hbm, c_hbm, _, o_hbm, qbuf, obuf, m_s, l_s, acc_s, cbuf, kv_sem,
@@ -1989,6 +2035,10 @@ def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,
             block_size=bs, pages_per_span=pages_per_span,
             pages_per_block=kb)
         n_sub = (rows + (sub - 1)) // sub
+        # blocks [0, n_whole) lie wholly under the diagonal: the first
+        # row sees keys [0, pos0], all of them before kv_end
+        n_whole = jnp.minimum(
+            lax.div(jnp.maximum(pos0 + 1, 0), jnp.int32(n)), n_blk)
         block_copies = _page_block_copies(
             bt_ref, s, n_pages, bs, kb,
             [(c_hbm, cbuf, lambda slot: kv_sem.at[slot])])
@@ -2003,30 +2053,41 @@ def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,
         tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
                       jnp.int32(heads))
 
-        def block_math(b, slot):
-            cols = b * n + lax.broadcasted_iota(jnp.int32, (r, n), 1)
+        def block_math(masked: bool):
+            def math(b, slot):
+                if masked:
+                    cols = b * n + lax.broadcasted_iota(jnp.int32,
+                                                        (r, n), 1)
 
-            def sub_math(t, _):
-                k = cbuf[slot]                               # [n, row]
-                qh = qbuf[pl.ds(t * sub, sub)].reshape(r, dk)
-                sc = lax.dot_general(
-                    qh, k, _DIMNUM_NT,
-                    preferred_element_type=jnp.float32) * np.float32(scale)
-                ok = (cols <= pos0 + t * sub + tok) & (cols < kv_end)
-                sc = jnp.where(ok, sc, _F32_NEG_INF)
+                def sub_math(t, _):
+                    k = cbuf[slot]                           # [n, row]
+                    qh = qbuf[pl.ds(t * sub, sub)].reshape(r, dk)
+                    sc = lax.dot_general(
+                        qh, k, _DIMNUM_NT,
+                        preferred_element_type=jnp.float32) \
+                        * np.float32(scale)
+                    ok = None
+                    if masked:
+                        ok = (cols <= pos0 + t * sub + tok) \
+                            & (cols < kv_end)
+                        sc = jnp.where(ok, sc, _F32_NEG_INF)
 
-                def pv_of_p(p):
-                    return lax.dot_general(
-                        p.astype(k.dtype), k[:, :v_width], _DIMNUM_NN,
-                        preferred_element_type=jnp.float32)
+                    def pv_of_p(p):
+                        return lax.dot_general(
+                            p.astype(k.dtype), k[:, :v_width], _DIMNUM_NN,
+                            preferred_element_type=jnp.float32)
 
-                m_s[t], l_s[t], acc_s[t] = online_softmax_update(
-                    (m_s[t], l_s[t], acc_s[t]), sc, ok, pv_of_p)
-                return 0
+                    m_s[t], l_s[t], acc_s[t] = online_softmax_update(
+                        (m_s[t], l_s[t], acc_s[t]), sc, ok, pv_of_p)
+                    return 0
 
-            lax.fori_loop(jnp.int32(0), n_sub, sub_math, 0)
+                lax.fori_loop(jnp.int32(0), n_sub, sub_math, 0)
+            return math
 
-        _stream_key_blocks(n_blk, block_copies, block_math)
+        _stream_key_blocks(n_blk, block_copies, block_math(False),
+                           hi=n_whole)
+        _stream_key_blocks(n_blk, block_copies, block_math(True),
+                           lo=n_whole)
 
         o = acc_s[...] / jnp.maximum(l_s[...], np.float32(1e-30))
         obuf[...] = o.reshape(bq, heads, v_width).astype(obuf.dtype)
@@ -2048,7 +2109,7 @@ def _ragged_latent_attention_pallas(q, latent_cache, block_tables,
     bs = latent_cache.shape[1]
     S, W = block_tables.shape
     tile, sub = _LATENT_TILE_TOKENS, _LATENT_SUB_TOKENS
-    kb = max(1, min(_RAGGED_KV_BLOCK // bs, W))
+    kb = _latent_pages_per_block(bs, W)
     kernel = functools.partial(
         _latent_paged_kernel, block_size=bs, pages_per_span=W,
         pages_per_block=kb, scale=scale, v_width=v_width)

@@ -24,6 +24,7 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
                                            deepseek_v2_tiny_config)
+from paddle_tpu.ops.pallas_kernels import _LATENT_KV_BLOCK
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGIT_TOL = 2e-4
@@ -191,6 +192,127 @@ def test_absorbed_equals_expanded_attention(model):
         assert np.abs(got - want).max() < 1e-5
 
 
+# (q_len, kv_len) spans of one pack, in units of the committed key
+# block's width n; every case is padded to the same shapes, so the
+# interpreted kernel is traced once
+_BLOCK_CASES = {
+    "kv_n_minus_1": lambda n: [(1, n - 1)],
+    "kv_n": lambda n: [(1, n)],
+    "kv_n_plus_1": lambda n: [(1, n + 1)],
+    "kv_3n_plus_5": lambda n: [(1, 3 * n + 5)],
+    # rows at positions n - 3 .. n + 4: the diagonal leaves block 0
+    # inside the tile
+    "diagonal_crosses_mid_tile": lambda n: [(8, n + 5)],
+    "chunk_over_prefix": lambda n: [(13, 2 * n + 9)],
+    "only_block_masked": lambda n: [(1, 5)],
+    "empty_span": lambda n: [(0, 0), (3, 7)],
+    "mixed": lambda n: [(1, n - 1), (0, 0), (8, n + 5), (1, 5), (1, n),
+                        (11, 3 * n + 5), (1, n + 1)],
+}
+_BLOCK_T, _BLOCK_S, _BLOCK_PAGE = 32, 8, 8
+
+
+def _latent_pack(spans, n, seed=0):
+    """Random absorbed queries, a pool and span tables for ``spans``,
+    padded to ``_BLOCK_T`` tokens and ``_BLOCK_S`` spans."""
+    rng = np.random.default_rng(seed)
+    H, row, bs = 4, 128, _BLOCK_PAGE
+    W = -(-(3 * n + 5) // bs)
+    q = rng.standard_normal((_BLOCK_T, H, row)).astype(np.float32)
+    pool = rng.standard_normal((len(spans) * W + 1, bs, row)).astype(
+        np.float32) * 0.3
+    pages = rng.permutation(len(spans) * W).astype(np.int32)
+    bt = np.full((_BLOCK_S, W), -1, np.int32)
+    q_off = np.full(_BLOCK_S, _BLOCK_T, np.int32)
+    q_len = np.zeros(_BLOCK_S, np.int32)
+    kv_len = np.zeros(_BLOCK_S, np.int32)
+    off = 0
+    for i, (ql, kl) in enumerate(spans):
+        used = -(-kl // bs)
+        bt[i, :used] = pages[i * W:i * W + used]
+        q_off[i], q_len[i], kv_len[i] = off, ql, kl
+        off += ql
+    assert off <= _BLOCK_T
+    return (jnp.asarray(q), jnp.asarray(pool), jnp.asarray(bt),
+            jnp.asarray(q_off), jnp.asarray(q_len), jnp.asarray(kv_len))
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_latent_kernel_block_edges(case):
+    """The latent launch's two bodies (blocks wholly under a tile's
+    diagonal unmasked, the last ones masked) against the XLA fallback,
+    float32, at spans that put ``kv_len`` and the diagonal on, one short
+    of and one past a key block's edge."""
+    from paddle_tpu.ops.paged_attention import _ragged_latent_attention_xla
+    from paddle_tpu.ops.pallas_kernels import (
+        _ragged_latent_attention_pallas, latent_attn_blocks)
+    n = _LATENT_KV_BLOCK
+    spans = _BLOCK_CASES[case](n)
+    args = _latent_pack(spans, n)
+    want = _ragged_latent_attention_xla(*args, 0.09, 64)
+    got = _ragged_latent_attention_pallas(*args, 0.09, 64, interpret=True)
+    real = sum(ql for ql, _ in spans)
+    assert real
+    np.testing.assert_allclose(np.asarray(got)[:real],
+                               np.asarray(want)[:real], atol=2e-5)
+    blocks, masked = latent_attn_blocks(
+        [ql for ql, _ in spans], [kl for _, kl in spans], _BLOCK_PAGE,
+        args[2].shape[1])
+    assert blocks >= masked >= 0
+    if case.startswith("kv_n") and case != "kv_n_plus_1":
+        # a one-row span sees every key it walks: kv_len == n is one
+        # whole block, n - 1 one partly filled (masked) one
+        assert (blocks, masked) == (1, int(case == "kv_n_minus_1"))
+
+
+def _brute_force_blocks(q_lens, kv_lens, n, tile):
+    blocks = masked = 0
+    for ql, kl in zip(q_lens, kv_lens):
+        for first in range(0, max(ql, 0), tile):
+            rows = min(ql - first, tile)
+            pos = [kl - ql + first + j for j in range(rows)]
+            kv_end = min(kl, pos[-1] + 1)
+            for b in range(-(-kv_end // n)):
+                blocks += 1
+                cols = range(b * n, (b + 1) * n)
+                masked += any(c > p or c >= kv_end
+                              for p in pos for c in cols)
+    return blocks, masked
+
+
+def test_latent_attn_blocks_closed_form():
+    """``latent_attn_blocks`` against a count made key by key, row by
+    row, and the cell's own step by hand: a 512-token chunk over 8k
+    beside a one-row span at 16k + 1."""
+    from paddle_tpu.ops.pallas_kernels import (_LATENT_TILE_TOKENS,
+                                               latent_attn_blocks)
+    n = _LATENT_KV_BLOCK
+    rng = np.random.default_rng(5)
+    bs = 4
+    W = 4 * n // bs
+    for _ in range(20):
+        ql = rng.integers(0, 40, 6)
+        kl = ql + rng.integers(0, 3 * n, 6)
+        kl[ql == 0] = rng.integers(0, 2 * n, int((ql == 0).sum()))
+        assert latent_attn_blocks(ql, kl, bs, W) == _brute_force_blocks(
+            ql.tolist(), kl.tolist(), n, _LATENT_TILE_TOKENS)
+    # edges: on, before and after a block's last key
+    for kl in (n - 1, n, n + 1, 2 * n, 3 * n + 5):
+        for ql in (1, 7, 8, 9, 16):
+            if ql <= kl:
+                assert latent_attn_blocks([ql], [kl], bs, W) \
+                    == _brute_force_blocks([ql], [kl], n,
+                                           _LATENT_TILE_TOKENS)
+    assert latent_attn_blocks([], [], bs, W) == (0, 0)
+    # pages of 128, as the cell has them: 64 tiles a chunk, each
+    # walking 8,192 / n whole blocks and the diagonal's one
+    blocks, masked = latent_attn_blocks([512, 1], [8192 + 512, 16385],
+                                        128, 260)
+    tiles = 512 // _LATENT_TILE_TOKENS
+    assert masked == tiles + 1
+    assert blocks == tiles * (8192 // n + 1) + 16384 // n + 1
+
+
 def test_bfloat16_fails_the_float32_tolerances(model):
     """The same weights rounded to bfloat16 and served in bfloat16: the
     logits leave LOGIT_TOL by two orders of magnitude, so a lower
@@ -350,8 +472,10 @@ def test_step_record_and_counters_of_the_held_share(model):
     assert rows == want
     assert all(r["moe_rows_top"] * 4 >= r["moe_rows"] / 1.0001
                for r in recs if r["budget"])
-    # XLA fallback: every row of the budget, every head
+    # XLA fallback: every row of the budget, every head, no key block
     assert all(r["attn_rows"] == r["budget"] * 4 for r in recs)
+    assert all(r["attn_blocks"] == r["attn_blocks_masked"] == 0
+               for r in recs)
     after = sum(load.labels(expert=str(e)).value for e in range(4))
     assert after - before == rows
     scopes = set(eng.mixed.op_scopes(eng.token_budgets[0]).values())
@@ -389,6 +513,72 @@ def test_renormalised_gate_is_refused():
          for k, shape in REF.layer_shapes(cfg, "sparse").items()}
     with pytest.raises(ValueError, match="norm_topk_prob"):
         REF.layer(jnp.zeros((4, 64)), w, cfg, kind="sparse")
+
+
+def test_step_record_counts_the_latent_launch_blocks(model):
+    """``attn_blocks`` / ``attn_blocks_masked`` of the record: what the
+    TPU launch (sized here, never run) walks for the spans a CPU engine
+    packed, against the count made key by key."""
+    from paddle_tpu.observability import span_log
+    from paddle_tpu.ops.pallas_kernels import (_LATENT_TILE_TOKENS,
+                                               _latent_pages_per_block)
+    eng = _engine(model)
+    rng = np.random.default_rng(6)
+    for n in (19, 37, 5):
+        eng.add_request(rng.integers(1, 256, n), 5)
+    eng.run_to_completion()
+    recs = [e[5] for e in span_log.events()
+            if e[1] == "serving.step" and e[5]["engine"] == eng.engine_id]
+    launched = [r for r in recs if r["budget"]]
+    assert launched and all("attn_blocks" in r for r in recs)
+    tpu = _engine(model, use_pallas=True).mixed
+    n = 4 * _latent_pages_per_block(4, tpu.bt_width)
+    total = masked = 0
+    for r in launched:
+        q_lens, kv_lens = r["spans"][:, 1], r["spans"][:, 2]
+        got = tpu.attn_blocks(q_lens, kv_lens)
+        assert got == _brute_force_blocks(
+            q_lens.tolist(), kv_lens.tolist(), n, _LATENT_TILE_TOKENS)
+        # every tile walks a block, and its last block holds the
+        # diagonal unless the tile's first row sees it whole
+        tiles = sum(-(-int(q) // _LATENT_TILE_TOKENS) for q in q_lens)
+        assert got[0] >= tiles and got[1] <= got[0]
+        total, masked = total + got[0], masked + got[1]
+    assert 0 < masked <= total
+    assert eng.mixed.attn_blocks([8], [40]) == (0, 0)      # XLA fallback
+
+
+def test_latent_vmem_mirror_is_the_launch_scratch():
+    """``latent_kernel_vmem_bytes`` (what ``tools/check_vmem_budget.py``
+    gates) against the scratch the launch itself declares at the cell's
+    widths, read from its jaxpr, plus the live score and probability
+    tiles of one sub-tile against one key block."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    sds = jax.ShapeDtypeStruct
+    spans = sds((16,), jnp.int32)
+    (call,) = jax.make_jaxpr(
+        lambda q, c, bt, qo, ql, kl: pk._ragged_latent_attention_pallas(
+            q, c, bt, qo, ql, kl, 0.1147, 512))(
+        sds((16, 128, 640), jnp.bfloat16), sds((261, 128, 640),
+                                               jnp.bfloat16),
+        sds((16, 260), jnp.int32), spans, spans, spans).eqns
+    (launch,) = [e for e in call.params["jaxpr"].jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+    n_scratch = launch.params["grid_mapping"].num_scratch_operands
+    scratch = [v.aval for v in launch.params["jaxpr"].invars[-n_scratch:]
+               if str(v.aval.memory_space) == "vmem"]
+    assert len(scratch) == 6
+    n = _LATENT_KV_BLOCK
+    assert scratch[-1].shape == (2, n, 640)          # the two page slots
+    declared = sum(pk._tile_bytes(a.shape, a.dtype.itemsize)
+                   for a in scratch)
+    live = 2 * pk._tile_bytes((pk._LATENT_SUB_TOKENS * 128, n), 4)
+    got = pk.latent_kernel_vmem_bytes(
+        heads=128, kv_lora_rank=512, rope_dim=64, block_size=128,
+        bt_width=260)
+    assert got == declared + live
+    assert got == pk.kernel_vmem_report()["ragged_latent_bf16"]
+    assert got <= pk._RAGGED_TILE_VMEM
 
 
 def test_latent_attn_rows_counts_real_sub_tiles():

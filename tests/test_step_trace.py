@@ -132,6 +132,8 @@ def test_one_record_a_step(family):
     cfg = eng.mixed.cfg
     assert any(f["tokens"] < f["budget"] for f in launched)
     for _, _, f in recs:
+        # key blocks are the latent launch's to count
+        assert f["attn_blocks"] == f["attn_blocks_masked"] == 0
         if family == "llama":
             assert f["moe_rows"] == f["moe_rows_top"] == 0
             continue

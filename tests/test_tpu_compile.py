@@ -276,7 +276,8 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
 
 # the latent (MLA) launch at DeepSeek-V2's widths: 128 heads over one
 # 512 + 64 row a token stored 640 wide, 128-token pages, 260 a span
-LATENT = {"decode": (16, 16), "top": (1024, 16)}
+# (budgets 16 and 528 are the cell's smallest and largest)
+LATENT = {"decode": (16, 16), "chunk": (528, 16), "top": (1024, 16)}
 
 
 @pytest.mark.parametrize("pack", sorted(LATENT))
