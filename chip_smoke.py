@@ -379,6 +379,73 @@ def latent_check(heads: int = 128, kv_lora: int = 512, rope: int = 64,
             "rel_err": float(f"{err:.3e}"), "tol": RAGGED_TOL}
 
 
+# name -> (tokens, k, router width, experts held, first held, D, M)
+GROUPED_CASES = {"wide": (512, 2, 8, 8, 0, 4096, 14336),
+                 "thin": (96, 6, 160, 40, 40, 5120, 1536)}
+
+
+def grouped_check(cases=None, expect_kernels: bool = True) -> dict:
+    """The experts' product of a dropless MoE FFN
+    (``ops.moe_gate.sorted_expert_swiglu`` on the Pallas grouped
+    matmul) at both MoE cells' widths, bf16, against a plain loop over
+    the experts in float32: 8 wide experts that hold every assignment
+    (Mixtral-8x7B, tiles of 64 rows here) and 40 thin ones that hold a
+    quarter of a router 160 wide (DeepSeek-V2's share, tiles of 16),
+    one expert of each left without a row."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.moe_gate import sorted_expert_swiglu
+
+    out = {"tol": RAGGED_TOL}
+    for name, (n, k, e_all, e_held, first, d, m) in (
+            cases or GROUPED_CASES).items():
+        rng = np.random.RandomState(11)
+        keys = jax.random.split(jax.random.PRNGKey(11), 4)
+        x = jax.random.normal(keys[0], (n, d), jnp.bfloat16)
+        wg, wu = (jax.random.normal(kk, (e_held, d, m), jnp.bfloat16)
+                  * 0.02 for kk in keys[1:3])
+        wd = jax.random.normal(keys[3], (e_held, m, d), jnp.bfloat16) * 0.02
+        # k distinct experts a row, none on the last held expert
+        pool = np.delete(np.arange(e_all), first + e_held - 1)
+        top_i = np.stack([rng.permutation(pool)[:k] for _ in range(n)]
+                         ).astype(np.int32)
+        top_w = rng.rand(n, k).astype(np.float32) + 0.1
+        valid = np.arange(n) < n - 5
+        with jax.enable_x64(False):
+            got, load = jax.jit(
+                lambda *a: sorted_expert_swiglu(
+                    *a, use_pallas=expect_kernels)
+            )(x, jnp.asarray(top_i), jnp.asarray(top_w), wg, wu, wd,
+              first, jnp.asarray(valid))
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all(), f"grouped {name}: non-finite"
+        local = top_i - first
+        held = (local >= 0) & (local < e_held) & valid[:, None]
+        assert np.asarray(load).tolist() == np.bincount(
+            local[held], minlength=e_held).tolist(), f"grouped {name}: load"
+
+        @jax.jit
+        def expert(xf, g, u, dn):
+            with jax.default_matmul_precision("highest"):
+                a = xf @ g.astype(jnp.float32)
+                return (jax.nn.silu(a) * (xf @ u.astype(jnp.float32))
+                        ) @ dn.astype(jnp.float32)
+
+        want = np.zeros((n, d), np.float32)
+        xf = x.astype(jnp.float32)
+        for e in range(e_held):
+            w_e = np.sum(np.where(held & (local == e), top_w, 0.0), axis=1)
+            if w_e.any():
+                want += w_e[:, None] * np.asarray(
+                    expert(xf, wg[e], wu[e], wd[e]))
+        err = _rel_err(got, want)
+        assert err <= RAGGED_TOL, (
+            f"grouped {name}: rel err {err:.3e} > declared {RAGGED_TOL}")
+        out[name] = {"rows": int(held.sum()),
+                     "rel_err": float(f"{err:.3e}")}
+    return out
+
+
 # ---------------------------------------------------------------------------
 # phase 1: serve — ContinuousBatchingEngine
 # ---------------------------------------------------------------------------
@@ -610,6 +677,9 @@ def main() -> int:
     # the latent (MLA) launch at DeepSeek-V2's widths: a broken lowering
     # shows here in a minute and not in a cell
     report("latent_check", latent_check())
+    gc.collect()
+    # the grouped expert product at both MoE cells' widths
+    report("grouped_check", grouped_check())
     gc.collect()
     report("flash_check", flash_check(
         train_kw["batch"], cfg.num_attention_heads, train_kw["seq"],

@@ -709,7 +709,7 @@ class ContinuousBatchingEngine:
             "the MoE layers", labels=("expert",))
         self._m_moe_expert = [
             self._m_moe_expert_load.labels(expert=str(e))
-            for e in range(max(0, self.mixed.n_stats - 2))]
+            for e in range(max(0, self.mixed.n_stats - 3))]
         self._m_latent_row = r.gauge(
             "serving_kv_latent_row_bytes",
             "bytes one token's cached latent row takes in one layer's "
@@ -999,6 +999,14 @@ class ContinuousBatchingEngine:
           router reads ``tokens`` x ``top_k`` x layers, a share of
           them its share.  0 for a dense model, and under an ``ep``
           mesh, whose step does not count.
+        - ``moe_tiles``: the row tiles those rows took in the grouped
+          expert products, summed over the routed layers (each
+          expert's rows start on a tile boundary:
+          ``ceil(rows / tile)`` an expert a layer, the tile
+          ``MixedStep.moe_tile_rows(budget)`` rows); ``moe_rows`` over
+          ``moe_tiles`` x the tile's rows is how much of what the
+          experts multiplied was a token's.  Counted by the step under
+          either lowering; the Pallas launch visits exactly these.
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.
@@ -1027,6 +1035,7 @@ class ContinuousBatchingEngine:
             attn_blocks=blocks, attn_blocks_masked=masked,
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
+            moe_tiles=rec.get("moe_tiles", 0),
             admitted=tuple(self._admitted), running=running,
             waiting=len(self.waiting), compiled=rec["compiled"])
 
@@ -1714,8 +1723,9 @@ class ContinuousBatchingEngine:
                                    * self._moe_layers)
         if self.mixed.n_stats:
             stats = self.mixed.last_stats
-            rec.update(moe_rows=int(stats[0]), moe_rows_top=int(stats[1]))
-            for child, n in zip(self._m_moe_expert, stats[2:]):
+            rec.update(moe_rows=int(stats[0]), moe_rows_top=int(stats[1]),
+                       moe_tiles=int(stats[2]))
+            for child, n in zip(self._m_moe_expert, stats[3:]):
                 child.inc(int(n))
         if traced:
             # first trace of this budget: count it, keep the compile

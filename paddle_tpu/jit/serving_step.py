@@ -111,7 +111,9 @@ STEP_SCOPES = frozenset((
 # Kernels XLA:TPU makes itself and names itself (their ``op_name`` is the
 # kernel's, the path of scopes is gone): instruction-name prefix -> scope.
 # ``jax.lax.ragged_dot`` becomes ``ragged-dot-metadata`` (tile tables from
-# the group sizes) and ``ragged-dot-none`` (the grouped matmul).
+# the group sizes) and ``ragged-dot-none`` (the grouped matmul).  Only a
+# step built with ``use_pallas=False`` holds one on a TPU: the Pallas
+# grouped product (``grouped_expert_matmul``) keeps its scope path.
 _XLA_KERNEL_SCOPES = {"ragged-dot": "moe.experts"}
 
 _HLO_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
@@ -247,7 +249,7 @@ def _tp_psum(t: Tensor, tp: Optional[TPContext]) -> Tensor:
 
 
 def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext], real,
-             loads: Optional[list]) -> Tensor:
+             loads: Optional[list], use_pallas=None) -> Tensor:
     """The fused dropless MoE FFN of a bank that holds every expert of
     its router, traced into the step body in place of ``layer.mlp``
     (``ops.moe_gate.moe_ffn``): the shared top-k gate over the block's
@@ -256,10 +258,11 @@ def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext], real,
     really has, un-sorted and summed over the k.  ``real [T]`` marks
     the pack's real tokens: its padding is given to no expert and
     counted in no load; the rows each expert was given go to
-    ``loads``.  Under an ``ep`` mesh axis the gate's assignments are
-    scattered into per-expert buffers sized so that none drops, which
-    cross the axis as two ``all_to_all`` exchanges plus one
-    token-stripe ``all_gather``, and no load is counted.
+    ``loads``.  ``use_pallas`` is the step's: the grouped product is
+    the Pallas kernel or XLA's.  Under an ``ep`` mesh axis the gate's
+    assignments are scattered into per-expert buffers sized so that
+    none drops, which cross the axis as two ``all_to_all`` exchanges
+    plus one token-stripe ``all_gather``, and no load is counted.
 
     No ``_tp_psum`` boundary here: the combine output is the FULL
     activation (each assignment contributes exactly one expert's
@@ -273,13 +276,15 @@ def _moe_ffn(blk, h2: Tensor, tp: Optional[TPContext], real,
     out, load = moe_ffn(flat, blk.gate.weight._value, blk.w_gate._value,
                         blk.w_up._value, blk.w_down._value,
                         top_k=blk.top_k, ep_axis=ep_axis,
-                        ep_degree=ep_deg, valid=real)
+                        ep_degree=ep_deg, valid=real,
+                        use_pallas=use_pallas)
     if loads is not None and load is not None:
         loads.append(load)
     return Tensor._from_value(out.reshape(v.shape))
 
 
-def _held_moe_ffn(blk, h2: Tensor, real, loads: Optional[list]) -> Tensor:
+def _held_moe_ffn(blk, h2: Tensor, real, loads: Optional[list],
+                  use_pallas=None) -> Tensor:
     """A bank that holds a SHARE of its router's experts, with shared
     experts beside it (``ops.moe_gate.moe_ffn_held``: group-limited
     routing over the router's full width, the held assignments sorted
@@ -290,7 +295,8 @@ def _held_moe_ffn(blk, h2: Tensor, real, loads: Optional[list]) -> Tensor:
     v = h2._value
     with jax.named_scope("moe.shared"):
         shared = blk.shared_experts(h2)
-    out, load = blk.routed(v.reshape(-1, v.shape[-1]), real)
+    out, load = blk.routed(v.reshape(-1, v.shape[-1]), real,
+                           use_pallas=use_pallas)
     if loads is not None:
         loads.append(load)
     return shared + Tensor._from_value(out.reshape(v.shape))
@@ -302,20 +308,21 @@ def _ffn_module(layer):
 
 
 def _ffn(layer, h2: Tensor, tp: Optional[TPContext], real=None,
-         loads: Optional[list] = None) -> Tensor:
+         loads: Optional[list] = None, use_pallas=None) -> Tensor:
     """Per-layer FFN dispatch of the traced body, by the body the
     layer's FFN module declares (``_step_body``): the MoE
     whose bank holds every expert of its router, the MoE that holds a
     share of them, and for a module that declares none its own forward
     (a Megatron-sharded dense MLP) with its psum boundary.  ``real``
     and ``loads`` are the MoE bodies' (the pack's real rows in, the
-    rows each expert was given out)."""
+    rows each expert was given out), ``use_pallas`` their grouped
+    product's lowering."""
     blk = _ffn_module(layer)
     body = _step_body(blk, "dense")
     if body == "moe_full_bank":
-        return _moe_ffn(blk, h2, tp, real, loads)
+        return _moe_ffn(blk, h2, tp, real, loads, use_pallas)
     if body == "moe_held":
-        return _held_moe_ffn(blk, h2, real, loads)
+        return _held_moe_ffn(blk, h2, real, loads, use_pallas)
     return _tp_psum(blk(h2), tp)
 
 
@@ -328,11 +335,12 @@ def _real_rows(T: int, q_offsets, q_lens):
                    axis=1)
 
 
-def _grouped_product_experts(model, tp: Optional[TPContext]) -> int:
-    """The experts in the bank of the model's first FFN module whose
-    step body holds the sorted grouped product
+def _grouped_product_experts(model, tp: Optional[TPContext]):
+    """``(experts, top_k)`` of the model's first FFN module whose step
+    body holds the sorted grouped product
     (``ops.moe_gate.sorted_expert_swiglu``: a held share always, a full
-    bank everywhere but on an ``ep`` axis), 0 where no layer's does.
+    bank everywhere but on an ``ep`` axis): the experts in its bank and
+    the assignments a token makes; ``(0, 0)`` where no layer's does.
     A step that holds one reports the rows its experts were given, and
     is traced through :func:`_traced_x64_off`."""
     bodies = ("moe_held",) if tp is not None and tp.ep_degree > 1 \
@@ -340,15 +348,16 @@ def _grouped_product_experts(model, tp: Optional[TPContext]) -> int:
     for layer in _inner_model(model).layers:
         blk = _ffn_module(layer)
         if _step_body(blk, "dense") in bodies:
-            return int(blk.w_gate.shape[0])
-    return 0
+            return int(blk.w_gate.shape[0]), int(blk.top_k)
+    return 0, 0
 
 
 def _traced_x64_off(step):
-    """XLA:TPU's pass that rewrites 64-bit element types does not know
-    ragged-dot, and stops at one in a module that holds any (the
-    argmax's i64 is enough): a step with a grouped product is traced
-    with x64 off, its operands being 32-bit."""
+    """A step with a grouped product is traced with x64 off, its
+    operands being 32-bit, whichever lowering the product has: XLA:TPU's
+    pass that rewrites 64-bit element types does not know ragged-dot
+    and stops at one in a module that holds any (the argmax's i64 is
+    enough), and the Pallas kernel's scalar tables are int32."""
     def traced(*args):
         with jax.enable_x64(False):
             return step(*args)
@@ -927,10 +936,12 @@ class MixedStep:
                         "MixedStep: %s is not taught the latent "
                         "(MLA) cache row" % what)
         # a step that holds the sorted grouped product reports the rows
-        # its experts were given: [moe_rows, moe_rows_top, load x El]
-        # int32 behind the sampled tokens, in the same fetch
-        experts = _grouped_product_experts(model, self._tp)
-        self.n_stats = 2 + experts if experts else 0
+        # its experts were given and the row tiles they took: [moe_rows,
+        # moe_rows_top, moe_tiles, load x El] int32 behind the sampled
+        # tokens, in the same fetch
+        self._moe_experts, self._moe_top_k = _grouped_product_experts(
+            model, self._tp)
+        self.n_stats = 3 + self._moe_experts if self._moe_experts else 0
         self.last_stats = None         # the last call's tail (np int32)
         self._wq = weight_qparams
         self._q8_gather = bool(quant_collectives)
@@ -986,6 +997,19 @@ class MixedStep:
             cache.kv_dtype)
         return ragged_attn_rows(q_lens, tile, H // Hkv)
 
+    def moe_tile_rows(self, T: int) -> int:
+        """The rows of one row tile of a budget-``T`` step's grouped
+        expert product (``ops/pallas_kernels.grouped_tile_rows``, from
+        the ``T x top_k`` rows of its sorted buffer and the experts
+        held); 0 where the step holds no such product.  The step counts
+        the tiles its experts' rows take (``moe_tiles`` of the step
+        record) at this size under either lowering; the Pallas launch
+        visits exactly those."""
+        if not self.n_stats:
+            return 0
+        from ..ops.pallas_kernels import grouped_tile_rows
+        return grouped_tile_rows(T * self._moe_top_k, self._moe_experts)
+
     def attn_blocks(self, q_lens, kv_lens):
         """``(blocks, masked)``: the key blocks one layer's latent
         launch walks for spans of these lengths and how many of them
@@ -1023,6 +1047,7 @@ class MixedStep:
         scale = 1.0 / math.sqrt(D)
         use_pallas = self.use_pallas
         n_stats = self.n_stats
+        moe_tile = self.moe_tile_rows(T)
         quant_kv = self._quant_kv
         q8_gather = self._q8_gather
         pdtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -1234,7 +1259,8 @@ class MixedStep:
                         new_vss.append(vs)
                     with jax.named_scope("ffn"):
                         h2 = layer.post_attention_layernorm(x)
-                        x = x + _ffn(layer, h2, tp, real, loads)
+                        x = x + _ffn(layer, h2, tp, real, loads,
+                                     use_pallas)
                 with jax.named_scope("lm_head"):
                     x = llama.norm(x)
                     # only each span's sampled rows reach the LM head:
@@ -1302,11 +1328,14 @@ class MixedStep:
                     nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
                 if n_stats:
                     # rows the experts held here were given, summed over
-                    # the layers: total, the fullest expert's, each one's
+                    # the layers: total, the fullest expert's, the row
+                    # tiles they took, each expert's
                     load = sum(loads[1:], loads[0]).astype(jnp.int32)
+                    tiles = sum(jnp.sum((ld + (moe_tile - 1)) // moe_tile)
+                                for ld in loads).astype(jnp.int32)
                     nxt = jnp.concatenate(
                         [nxt, jnp.sum(load)[None], jnp.max(load)[None],
-                         load])
+                         tiles[None], load])
                 if return_probs:
                     return (nxt, filtered_probs(lv, s_t, s_k, s_p),
                             tuple(new_kcs), tuple(new_vcs),

@@ -284,6 +284,7 @@ class DeepseekV2MoE(Layer):
         self.config = config
         D, M = config.hidden_size, config.moe_intermediate_size
         E = config.n_routed_experts
+        self.top_k = config.num_experts_per_tok
         init = _attr(I.Normal(0.0, config.initializer_range))
         self.gate = _linear(D, config.router_experts, config)
         self.w_gate = self.create_parameter([E, D, M], attr=init)
@@ -293,10 +294,11 @@ class DeepseekV2MoE(Layer):
             config, M * config.n_shared_experts)
         self.last_load = None       # [El] int32 of the last forward
 
-    def routed(self, flat, valid=None):
+    def routed(self, flat, valid=None, use_pallas=None):
         """The held experts' part over raw ``flat [N, hidden]`` and the
         rows each was given; ``valid [N]`` marks the rows that are
-        tokens (a serving pack's padding is not)."""
+        tokens (a serving pack's padding is not); ``use_pallas`` is the
+        serving step's choice of the grouped product's lowering."""
         cfg = self.config
         return moe_ffn_held(
             flat, self.gate.weight._value, self.w_gate._value,
@@ -304,7 +306,8 @@ class DeepseekV2MoE(Layer):
             top_k=cfg.num_experts_per_tok,
             first_held=cfg.first_held_expert, n_group=cfg.n_group,
             topk_group=cfg.topk_group,
-            routed_scale=cfg.routed_scaling_factor, valid=valid)
+            routed_scale=cfg.routed_scaling_factor, valid=valid,
+            use_pallas=use_pallas)
 
     def forward(self, x):
         v = x._value

@@ -13,8 +13,10 @@ The ONE top-k gate / dispatch implementation in the repo.  Callers:
   the dropless MoE FFNs inside the compiled serving steps.  Each has
   its own gate; on one chip both hand their assignments to the ONE
   expert product, :func:`sorted_expert_swiglu`: the assignments sorted
-  by expert into one ``[N*k, D]`` buffer and three
-  ``jax.lax.ragged_dot``s sized by the rows each expert really has.
+  by expert into one buffer of rows and a grouped product sized by the
+  rows each expert really has (on the TPU the Pallas kernel
+  ``ops/pallas_kernels.grouped_expert_matmul``, which streams each
+  expert's weights once; elsewhere three ``jax.lax.ragged_dot``s).
   Under an ``ep`` mesh axis :func:`moe_ffn` still scatters into
   per-expert buffers (the ``all_to_all`` pair wants static per-expert
   slices: the reference's global_scatter/global_gather, emitted inside
@@ -26,8 +28,15 @@ shape branches on traced values, no PRNG.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from ..core import device as _device
+from .pallas_kernels import (grouped_buffer_rows, grouped_expert_matmul,
+                             grouped_row_starts, grouped_slot_tables,
+                             grouped_tile_rows, grouped_widths_ok)
 
 __all__ = [
     "topk_gate", "assignment_slots", "dispatch_to_buffers",
@@ -112,23 +121,53 @@ def combine_from_buffers(eo, top_i, slot, top_w, keep=None):
 
 
 def sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, first_held=0,
-                         valid=None):
+                         valid=None, use_pallas=None, interpret=False):
     """The experts' part of a dropless MoE FFN, after the gate: the
     assignments ``top_i [N, k]`` (indices into the ROUTER's experts)
     that land on the experts held here (``first_held .. first_held +
     El``, ``wg/wu [El, D, M]``, ``wd [El, M, D]``) are sorted by expert
-    into one static ``[N * k, D]`` buffer and multiplied by a grouped
-    product sized by the rows each expert really has
-    (``jax.lax.ragged_dot``: on the TPU XLA's own grouped-matmul
-    kernel), never a buffer an expert; then un-sorted and summed over
-    the k with ``top_w [N, k]`` (float32).  ``valid`` (bool ``[N]``,
-    all true if ``None``) marks the rows that are tokens: a row of
-    padding is given to no expert, its output is 0 and no load counts
-    it.  A step that holds this is traced with x64 off (XLA:TPU's
-    64-bit rewriter stops at a ragged-dot).
+    into one static buffer of rows and multiplied by a grouped product
+    sized by the rows each expert really has, never a buffer an
+    expert; then un-sorted and summed over the k with ``top_w [N, k]``
+    (float32).  ``valid`` (bool ``[N]``, all true if ``None``) marks
+    the rows that are tokens: a row of padding is given to no expert,
+    its output is 0 and no load counts it.
+
+    The grouped product.  On the TPU (``use_pallas=None`` asks
+    ``core.device.on_tpu()``; ``interpret`` runs the same kernel on
+    the CPU; widths that are whole 128-lane tiles) it is
+    ``ops/pallas_kernels.grouped_expert_matmul``, twice:
+    each expert's rows start on a boundary of the row tile
+    (``grouped_tile_rows``, from ``N * k`` and ``El``; the buffer is
+    ``grouped_buffer_rows``: at most ``tile - 1`` rows of padding an
+    expert), gate
+    and up are one launch with ``silu(g) * u`` in float32 before the
+    cast, and every weight crosses HBM once a product.  Anywhere else
+    it is three ``jax.lax.ragged_dot``s over a ``[N * k, D]`` buffer
+    with ``g`` and ``u`` rounded to ``x``'s type between: the plain
+    form the kernel is tested against.  A step that holds either is
+    traced with x64 off (XLA:TPU's 64-bit rewriter stops at a
+    ragged-dot, Mosaic at an i64 scalar).
+
+    Jitted (the lowering, ``first_held`` and ``interpret`` static), so
+    the layers of a step share one traced body: a warm start is made of
+    tracing.
 
     Returns ``(out [N, D] in x's type, load int32 [El])``: the rows
     each held expert was given."""
+    if use_pallas is None:
+        use_pallas = _device.on_tpu()
+    kernel = (use_pallas or interpret) and grouped_widths_ok(
+        x.shape[1], wg.shape[-1])
+    return _sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, valid,
+                                 first_held=first_held, kernel=kernel,
+                                 interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("first_held", "kernel", "interpret"))
+def _sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, valid, *,
+                          first_held, kernel, interpret):
     n, d = x.shape
     top_k = top_i.shape[1]
     e_held = wg.shape[0]
@@ -141,16 +180,40 @@ def sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, first_held=0,
         order = jnp.argsort(key, stable=True)
         load = jnp.sum(jax.nn.one_hot(key, e_held + 1, dtype=jnp.int32),
                        axis=0)[:e_held]
-        xs = x[order // top_k]                                # [N*k, D]
+        if kernel:
+            # row p of the sorted order is row p + shift[its expert] of
+            # the buffer whose experts each start on a tile boundary
+            tile = grouped_tile_rows(n * top_k, e_held)
+            rows = grouped_buffer_rows(n * top_k, e_held, tile)
+            tiles, starts = grouped_row_starts(load, tile)
+            ends = starts + tiles * tile        # one past an expert's tiles
+            shift = starts - (jnp.cumsum(load) - load)
+            r = jnp.arange(rows, dtype=jnp.int32)
+            owner = jnp.minimum(jnp.sum(r[:, None] >= ends[None, :],
+                                        axis=1), e_held - 1)
+            src = jnp.clip(r - shift[owner], 0, n * top_k - 1)
+            xs = x[order[src] // top_k]                       # [rows, D]
+        else:
+            xs = x[order // top_k]                            # [N*k, D]
     with jax.named_scope("moe.experts"):
-        g = jax.lax.ragged_dot(xs, wg, load)
-        u = jax.lax.ragged_dot(xs, wu, load)
-        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        ys = jax.lax.ragged_dot(h, wd, load)                  # [N*k, D]
+        if kernel:
+            slots = grouped_slot_tables(load, tile)
+            h = grouped_expert_matmul(xs, slots, wg, wu, tile=tile,
+                                      interpret=interpret)
+            ys = grouped_expert_matmul(h, slots, wd, tile=tile,
+                                       interpret=interpret)
+        else:
+            g = jax.lax.ragged_dot(xs, wg, load)
+            u = jax.lax.ragged_dot(xs, wu, load)
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+            ys = jax.lax.ragged_dot(h, wd, load)              # [N*k, D]
     with jax.named_scope("moe.combine"):
         # back to assignment order; a row no held expert computed is
         # whatever the grouped product left there: masked, not weighted
         back = jnp.argsort(order)
+        if kernel:
+            back = back + jnp.concatenate(
+                [shift, jnp.zeros((1,), shift.dtype)])[key]
         y = jnp.where(held[:, None], ys[back].astype(jnp.float32), 0.0)
         out = jnp.sum(y.reshape(n, top_k, d)
                       * top_w[..., None], axis=1).astype(x.dtype)
@@ -158,7 +221,7 @@ def sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd, first_held=0,
 
 
 def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1,
-            valid=None):
+            valid=None, use_pallas=None):
     """Dropless fused MoE FFN over a flat token block ``x [N, D]``.
 
     ``gate_w [D, E_total]`` replicated; ``wg/wu/wd`` the LOCAL expert
@@ -171,7 +234,8 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1,
     is a row of the one sorted ``[N*top_k, D]`` buffer, so none is
     dropped and no expert multiplies a row it was not given.  ``valid``
     (bool ``[N]``) marks the rows that are tokens; a pack's padding is
-    given to no expert.
+    given to no expert.  ``use_pallas`` chooses the grouped product's
+    lowering there (``None``: the Pallas kernel on a TPU).
 
     ep path (inside shard_map over ``ep_axis``): chip ``r`` gates its
     token stripe ``x[r*Tl:(r+1)*Tl]``, scatters into a per-expert send
@@ -202,7 +266,7 @@ def moe_ffn(x, gate_w, wg, wu, wd, *, top_k, ep_axis=None, ep_degree=1,
             logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
             top_w, top_i, _ = topk_gate(logits, top_k)
         return sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
-                                    valid=valid)
+                                    valid=valid, use_pallas=use_pallas)
 
     e_total = e_local * ep_degree
     tl = n // ep_degree                 # token stripe per chip
@@ -261,7 +325,8 @@ def group_limited_topk(scores, k, n_group, topk_group):
 
 
 def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
-                 n_group=1, topk_group=1, routed_scale=1.0, valid=None):
+                 n_group=1, topk_group=1, routed_scale=1.0, valid=None,
+                 use_pallas=None):
     """Dropless routed-expert FFN over ``x [N, D]`` for a bank that
     holds a SHARE of the experts its router scores: ``gate_w [D, E]``
     is the router at its full width, ``wg/wu [El, D, M]`` and ``wd [El,
@@ -274,7 +339,8 @@ def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
     would add is left out: the result is this bank's part of the sum.
     ``valid`` (bool ``[N]``, all true if ``None``) marks the rows that
     are tokens: a row of padding is given to no expert, its output is
-    0 and no load counts it.
+    0 and no load counts it.  ``use_pallas`` chooses the grouped
+    product's lowering (``None``: the Pallas kernel on a TPU).
 
     Returns ``(out [N, D] in x's type, load int32 [El])``: the rows
     each held expert was given."""
@@ -291,4 +357,5 @@ def moe_ffn_held(x, gate_w, wg, wu, wd, *, top_k, first_held=0,
                                           topk_group)
         top_w = top_w * jnp.float32(routed_scale)
     return sorted_expert_swiglu(x, top_i, top_w, wg, wu, wd,
-                                first_held=first_held, valid=valid)
+                                first_held=first_held, valid=valid,
+                                use_pallas=use_pallas)

@@ -2335,6 +2335,301 @@ def rope_qkv_epilogue(q, k, v, cos, sin, with_amax: bool = False,
 
 
 # ---------------------------------------------------------------------------
+# grouped expert matmul: the product of a dropless MoE FFN over rows
+# sorted by expert (``ops/moe_gate.sorted_expert_swiglu``)
+# ---------------------------------------------------------------------------
+# ``out[r] = xs[r] @ w[expert of r]`` for a buffer whose rows are sorted
+# by expert, each expert's rows starting on a row-tile boundary.  The grid
+# is (column tile of the weights, expert that has rows): an expert's
+# ``[K, tn]`` weight block comes in through the BlockSpec pipeline, so the
+# NEXT expert's block streams from HBM while this one's row tiles pass
+# under the block that is resident, and every weight crosses HBM once a
+# product however many row tiles its expert has.  The rows themselves stay
+# in HBM: a cell copies its expert's tiles in (two slots) and its results
+# out (two slots) itself, so nothing in VMEM is sized by the buffer and a
+# tile past an expert's last row is never touched.  Experts without rows
+# are not visited at all (their weights are not read).  With two weight
+# banks the cell is the SwiGLU's first half: ``silu(x @ wg) * (x @ wu)``
+# in float32 before the one cast, the rows read once for both.
+_GROUPED_MIN_TILE = 16          # one packed bf16 sublane tile
+_GROUPED_MAX_TILE = 128
+_GROUPED_ROW_BUCKET = 512       # a multiple of every tile
+# what one cell's buffers may take (tools/graftlint/vmem.py holds the
+# family to half a v5e core); the compiler is given a quarter more
+_GROUPED_VMEM = 48 << 20
+
+
+def grouped_tile_rows(rows: int, experts: int) -> int:
+    """The row tile of the grouped expert product, from the shapes a
+    trace sees: the largest power of two, between one packed sublane
+    tile and _GROUPED_MAX_TILE rows, that is at most HALF the rows an
+    expert has if the ``rows`` of the sorted buffer spread evenly over
+    the ``experts`` held (so padding each expert's rows to the tile
+    wastes about a quarter of them, not more)."""
+    tile = _GROUPED_MIN_TILE
+    while tile < _GROUPED_MAX_TILE and 4 * tile * experts <= rows:
+        tile *= 2
+    return tile
+
+
+def _grouped_step_rows(tile: int) -> int:
+    """Rows one product of a cell multiplies: two tiles where the tile
+    is _GROUPED_MAX_TILE (a weight tile latched into the MXU is worth
+    more the more rows pass under it; the odd last tile goes alone),
+    else the tile: smaller tiles belong to budgets whose products are
+    bound by the weights' bytes, and a second body is a second trace."""
+    return 2 * tile if tile == _GROUPED_MAX_TILE else tile
+
+
+def grouped_widths_ok(*widths: int) -> bool:
+    """The kernel's envelope: every width of the expert FFN (hidden,
+    intermediate) a whole number of 128-lane tiles.  Anything else
+    keeps XLA's grouped product."""
+    return all(w > 0 and w % _LANES == 0 for w in widths)
+
+
+def grouped_buffer_rows(rows: int, experts: int, tile: int) -> int:
+    """Rows of the sorted buffer once every expert's rows start on a
+    tile boundary: at most ``tile - 1`` rows of padding an expert, one
+    tile of slack behind the last (the launch reads rows two tiles at a
+    time where it multiplies them so), and the sum rounded up to
+    _GROUPED_ROW_BUCKET, so that the small budgets of a step, which
+    share a tile, share one traced launch too (a warm start is made of
+    tracing: a launch is traced and lowered anew for every shape)."""
+    need = (-(-(rows + experts * (tile - 1)) // tile) + 1) * tile
+    return -(-need // _GROUPED_ROW_BUCKET) * _GROUPED_ROW_BUCKET
+
+
+def _grouped_cell_vmem_bytes(k: int, tn: int, banks: int, tile: int,
+                             itemsize: int) -> int:
+    """VMEM bytes of one _grouped_matmul_kernel cell: the ``[K, tn]``
+    weight block of each bank (x2: the pipeline's next block), the two
+    slots of rows in and out (``_grouped_step_rows`` each), and the
+    float32 products of that many rows."""
+    step = _grouped_step_rows(tile)
+    total = 2 * banks * _tile_bytes((k, tn), itemsize)
+    total += 2 * _tile_bytes((step, k), itemsize)           # rows in
+    total += 2 * _tile_bytes((step, tn), itemsize)          # rows out
+    total += (banks + 1) * _tile_bytes((step, tn), 4)       # products
+    return total
+
+
+@functools.lru_cache(maxsize=None)
+def grouped_column_tile(k: int, n: int, banks: int, tile: int,
+                        itemsize: int = 2) -> int:
+    """The widest column tile (a multiple of 128 lanes that divides
+    ``n``) whose cell fits _GROUPED_VMEM: the rows are read once a
+    column tile, so fewer and wider is less traffic."""
+    fits = [tn for tn in range(_LANES, n + 1, _LANES)
+            if n % tn == 0 and _grouped_cell_vmem_bytes(
+                k, tn, banks, tile, itemsize) <= _GROUPED_VMEM]
+    if not fits:
+        raise ValueError(
+            "grouped expert matmul: no column tile of a [%d, %d] bank "
+            "fits %d MiB of VMEM" % (k, n, _GROUPED_VMEM >> 20))
+    return fits[-1]
+
+
+def grouped_kernel_vmem_bytes(*, hidden: int, ffn: int, tile: int,
+                              itemsize: int = 2) -> int:
+    """Worst-case VMEM bytes of one _grouped_matmul_kernel cell over an
+    expert FFN of these widths: the larger of its two launches (gate
+    and up in one, then down), each at the column tile the launch
+    itself would pick."""
+    return max(
+        _grouped_cell_vmem_bytes(
+            hidden, grouped_column_tile(hidden, ffn, 2, tile, itemsize),
+            2, tile, itemsize),
+        _grouped_cell_vmem_bytes(
+            ffn, grouped_column_tile(ffn, hidden, 1, tile, itemsize),
+            1, tile, itemsize))
+
+
+def grouped_row_starts(load, tile: int):
+    """``(tiles, starts)`` int32 ``[E]``: the row tiles each expert's
+    ``load`` rows take and the tile-aligned row of the sorted buffer at
+    which they start, the experts laid out in order."""
+    tiles = (load.astype(jnp.int32) + (tile - 1)) // tile
+    return tiles, (jnp.cumsum(tiles) - tiles) * tile
+
+
+def grouped_slot_tables(load, tile: int):
+    """Traced int32 ``[E]`` tables of the grouped launch from the rows
+    each expert was given: ``(expert, first_row, tiles)`` of grid slot
+    ``s``.  The experts that have rows come first, in order, each at
+    the tile-aligned row where its rows start in the sorted buffer;
+    the slots behind them repeat the last such expert with 0 tiles
+    (the same weight block: nothing is fetched for them)."""
+    e = load.shape[0]
+    tiles, first = grouped_row_starts(load, tile)
+    has = load > 0
+    rank = jnp.cumsum(has.astype(jnp.int32)) - 1   # among those with rows
+    live = rank[-1] + 1
+    s = jnp.arange(e, dtype=jnp.int32)
+    # slot s -> the expert of rank s (an [E, E] compare, no sort)
+    ranked = jnp.sum(jnp.where(has[None, :] & (rank[None, :] == s[:, None]),
+                               s[None, :], 0), axis=1)
+    expert = ranked[jnp.minimum(s, jnp.maximum(live - 1, 0))].astype(
+        jnp.int32)
+    return (expert, first[expert].astype(jnp.int32),
+            jnp.where(s < live, tiles[expert], 0).astype(jnp.int32))
+
+
+def _grouped_matmul_kernel(expert_ref, first_ref, tiles_ref, x_hbm, *refs,
+                           tile: int, tn: int, banks: int, step: int):
+    """Grid cell (column tile j, slot s): the slot's expert's row tiles
+    under its resident weight block(s), ``step`` rows a product: the
+    tile, or two tiles while two are left and the odd last one alone;
+    rows come in ``step`` at a time either way (the buffer ends in a
+    tile of slack), results go out by what was computed.  The cell's
+    first rows were asked for by the cell before it (the launch's first
+    by itself), so only the launch's first copy is waited for with
+    nothing to do."""
+    del expert_ref                      # the weight BlockSpecs' own
+    w_refs = refs[:banks]
+    o_hbm, xbuf, obuf, sem = refs[banks:]
+    j, s = pl.program_id(0), pl.program_id(1)
+    n_j, n_s = pl.num_programs(0), pl.num_programs(1)
+    n_sub = tiles_ref[s]
+    pairs = step != tile
+    n_full = n_sub // 2 if pairs else n_sub     # products of `step` rows
+
+    def rows(slot_s, c, size):  # chunk c: an expert's rows start on a tile
+        return pl.ds(pl.multiple_of(first_ref[slot_s] + c * step, tile),
+                     size)
+
+    def rows_in(slot_s, c, slot):
+        return pltpu.make_async_copy(
+            x_hbm.at[rows(slot_s, c, step)], xbuf.at[slot],
+            sem.at[0, slot])
+
+    def rows_out(c, slot, size):
+        return pltpu.make_async_copy(
+            obuf.at[slot, pl.ds(0, size)],
+            o_hbm.at[rows(s, c, size),
+                     pl.ds(pl.multiple_of(j * tn, tn), tn)],
+            sem.at[1, slot])
+
+    @pl.when((j == 0) & (s == 0) & (n_sub > 0))
+    def _first():
+        rows_in(s, 0, 0).start()
+
+    def chunk(c, size):
+        slot = lax.rem(c, jnp.int32(2))
+        if size == step:                # a lone last tile has no next
+
+            @pl.when((c + 1) * step < n_sub * tile)
+            def _prefetch():
+                rows_in(s, c + 1, 1 - slot).start()
+        rows_in(s, c, slot).wait()
+        x = xbuf[slot, pl.ds(0, size)]
+        y = jnp.dot(x, w_refs[0][...], preferred_element_type=jnp.float32)
+        if banks == 2:
+            y = jax.nn.silu(y) * jnp.dot(
+                x, w_refs[1][...], preferred_element_type=jnp.float32)
+
+        @pl.when(c >= 2)
+        def _slot_free():               # only a last chunk is a lone tile
+            rows_out(c - 2, slot, step).wait()
+        obuf[slot, pl.ds(0, size)] = y.astype(obuf.dtype)
+        rows_out(c, slot, size).start()
+
+    def full(c, _):
+        chunk(c, step)
+        return 0
+
+    lax.fori_loop(jnp.int32(0), n_full, full, 0)
+    # the next cell with rows: the slot behind this one, or (the slots
+    # with rows come first) slot 0 under the next column tile.  Its
+    # first rows go into slot 0, where it will look for them; both row
+    # slots are free once this cell's last product is done
+    behind = jnp.minimum(s + 1, n_s - 1)
+    more = (s + 1 < n_s) & (tiles_ref[behind] > 0)
+    n_chunk = n_full
+    if pairs:
+        odd = lax.rem(n_sub, jnp.int32(2)) == 1
+        n_chunk = n_full + odd.astype(jnp.int32)
+
+        @pl.when(odd)
+        def _last_tile():
+            chunk(n_full, tile)
+
+    @pl.when((n_sub > 0) & (more | (j + 1 < n_j)))
+    def _next_cell():
+        rows_in(jnp.where(more, behind, 0), 0, 0).start()
+
+    def out_done(c, size):
+        rows_out(c, lax.rem(c, jnp.int32(2)), size).wait()
+
+    # the cell's last one or two products are still on their way out;
+    # the one before the last is never a lone tile
+    @pl.when(n_chunk >= 2)
+    def _drain_before_last():
+        out_done(n_chunk - 2, step)
+    last_full = n_full >= 1
+    if pairs:
+        last_full &= jnp.logical_not(odd)
+
+        @pl.when(odd)
+        def _drain_tile():
+            out_done(n_full, tile)
+
+    @pl.when(last_full)
+    def _drain_last():
+        out_done(n_full - 1, step)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def grouped_expert_matmul(xs, slots, *banks, tile: int, interpret=False):
+    """``xs [R, K]`` rows sorted by expert, each expert's rows starting
+    on a ``tile`` boundary and a tile of slack behind the last
+    (``grouped_buffer_rows``; ``slots`` the launch's tables,
+    ``grouped_slot_tables(load, tile)``), times that expert's ``[K,
+    N]`` of ``banks``: one bank ``[E, K, N]`` gives ``xs @ w``, two
+    give ``silu(xs @ wg) * (xs @ wu)``.  Operands in their own type
+    (bf16), every product accumulated in float32, SiLU and the
+    elementwise product in float32, one cast to ``xs``'s type.  Returns
+    ``[R, N]``; a row of a tile no expert owns is never written (it
+    holds whatever the buffer held).  Jitted with the tile static, so
+    the layers of a step share one traced body a product shape."""
+    R, K = xs.shape
+    E, _, N = banks[0].shape
+    if R % tile or any(b.shape != (E, K, N) for b in banks):
+        raise ValueError(
+            "grouped expert matmul: %d rows in tiles of %d against banks "
+            "%s" % (R, tile, [b.shape for b in banks]))
+    tn = grouped_column_tile(K, N, len(banks), tile, xs.dtype.itemsize)
+    step = _grouped_step_rows(tile)
+    kernel = functools.partial(_grouped_matmul_kernel, tile=tile, tn=tn,
+                               banks=len(banks), step=step)
+    with _x64_off():
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
+        w_spec = pl.BlockSpec(
+            (None, K, tn), lambda j, s, expert, first, tiles:
+            (expert[s], 0, j))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N // tn, E),
+            in_specs=[any_spec] + [w_spec] * len(banks),
+            out_specs=any_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, step, K), xs.dtype),        # rows in
+                pltpu.VMEM((2, step, tn), xs.dtype),       # rows out
+                pltpu.SemaphoreType.DMA((2, 2)),     # [in | out, slot]
+            ])
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((R, N), xs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_GROUPED_VMEM * 5 // 4),
+            interpret=interpret,
+            name="grouped_expert_matmul",
+        )(*slots, xs, *banks)
+
+
+# ---------------------------------------------------------------------------
 # VMEM footprint audit (consumed by tools/check_vmem_budget.py)
 # ---------------------------------------------------------------------------
 # Mosaic tiles every VMEM-resident buffer to (sublane, 128) vregs; the
@@ -2485,6 +2780,11 @@ def kernel_vmem_report(envelope=None):
         # token, 128-token pages, contexts to 33,280
         "latent_heads": 128, "kv_lora_rank": 512, "latent_rope_dim": 64,
         "latent_block_size": 128, "latent_bt_width": 260,
+        # grouped expert product: the two MoE cells' banks at their top
+        # budgets' sorted rows — 8 wide experts (Mixtral-8x7B, 1,024
+        # tokens x 2) and 40 thin ones (DeepSeek-V2's share, 528 x 6)
+        "wide_experts": (2048, 8, 4096, 14336),
+        "thin_experts": (3168, 40, 5120, 1536),
         # training envelope: the default/autotuned flash tiles
         "block_q": 512, "block_k": 512,
         "bwd_block_q": _FUSED_BWD_BLOCK_Q,
@@ -2514,6 +2814,12 @@ def kernel_vmem_report(envelope=None):
             rope_dim=env["latent_rope_dim"],
             block_size=env["latent_block_size"],
             bt_width=env["latent_bt_width"]),
+        **{"grouped_expert_" + kind: grouped_kernel_vmem_bytes(
+            hidden=hidden, ffn=ffn,
+            tile=grouped_tile_rows(rows, experts))
+           for kind, (rows, experts, hidden, ffn) in (
+               ("wide", env["wide_experts"]),
+               ("thin", env["thin_experts"]))},
         "flash_fwd": flash_fwd_vmem_bytes(
             block_q=env["block_q"], block_k=env["block_k"],
             head_dim=env["head_dim"], with_rope=True),

@@ -61,6 +61,18 @@ def test_latent_check_slices_its_reference():
     assert r["rel_err"] < 1e-2          # bf16 inputs, float32 both sides
 
 
+def test_grouped_check_counts_held_valid_rows():
+    """The plain loop weights an expert's output by the assignments
+    that are held here AND tokens: 5 rows of padding and the rows held
+    elsewhere add nothing, and one held expert gets no row."""
+    r = chip_smoke.grouped_check(
+        cases={"full": (24, 2, 4, 4, 0, 16, 24),
+               "share": (24, 3, 16, 4, 4, 16, 24)}, expect_kernels=False)
+    assert r["full"]["rows"] == (24 - 5) * 2
+    assert 0 < r["share"]["rows"] < (24 - 5) * 3
+    assert max(r["full"]["rel_err"], r["share"]["rel_err"]) < 1e-2
+
+
 def test_train_phase_and_flash_check_tiny_cpu():
     r = chip_smoke.train_phase(llama_tiny_config(), **TRAIN_KW)
     assert r["compile_count"] == 1 and r["last_loss"] < r["first_loss"]

@@ -380,6 +380,116 @@ def test_sorted_product_equals_the_buffer_dispatch(n, k, e, routing):
     assert int(np.asarray(load_v).sum()) == int(valid.sum()) * k
 
 
+# the Pallas grouped product (interpret mode): name -> (tokens, k,
+# router width, experts held, first held, rows that are tokens or None)
+GROUPED_CASES = {
+    # tile 128 (256 rows an expert); an expert's rows span tiles
+    "wide_experts": (512, 2, 4, 4, 0, None),
+    # tile 16; 40 thin experts of a router 160 wide, most rows elsewhere
+    "thin_experts": (24, 6, 160, 40, 40, None),
+    "empty_expert": (32, 2, 8, 8, 0, None),
+    "one_expert": (32, 2, 8, 8, 0, None),
+    # 12 rows: not a multiple of 8 (XLA:TPU expands that ragged_dot)
+    "twelve_rows": (6, 2, 8, 8, 0, None),
+    # experts 8..15 of 32 held: rows before and behind them held elsewhere
+    "held_share": (40, 6, 32, 8, 8, None),
+    "valid_padding": (32, 2, 8, 8, 0, 19),
+}
+
+
+def _grouped_case(name, dtype):
+    import jax.numpy as jnp
+    n, k, e_all, e_held, first, n_valid = GROUPED_CASES[name]
+    d, m = 128, 256
+    rng = np.random.default_rng(sorted(GROUPED_CASES).index(name))
+    kind = name if name in ("one_expert", "empty_expert") else "balanced"
+    top_i = _routing(kind, rng, n, k, e_all)
+    x = jnp.asarray(rng.standard_normal((n, d)), dtype)
+    wg, wu = (jnp.asarray(rng.standard_normal((e_held, d, m)) * 0.1, dtype)
+              for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((e_held, m, d)) * 0.1, dtype)
+    top_w = jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32)
+    valid = None if n_valid is None else np.arange(n) < n_valid
+    return x, top_i, top_w, wg, wu, wd, first, valid
+
+
+@pytest.mark.parametrize("against", ["plain_loop_f32", "ragged_dot_bf16"])
+@pytest.mark.parametrize("name", sorted(GROUPED_CASES))
+def test_grouped_kernel_interpret(name, against):
+    """``sorted_expert_swiglu`` on the Pallas grouped product
+    (``ops/pallas_kernels.grouped_expert_matmul``, interpret mode: each
+    expert's rows on a tile boundary, gate and up one launch) against a
+    plain loop over the held experts in float32, and in bf16 against
+    the ``ragged_dot`` form it replaces on the TPU; ``load`` equal in
+    every case, rows no held expert owns (or that are padding) 0."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.moe_gate import sorted_expert_swiglu
+    from paddle_tpu.ops.pallas_kernels import grouped_tile_rows
+    f32 = against == "plain_loop_f32"
+    x, top_i, top_w, wg, wu, wd, first, valid = _grouped_case(
+        name, jnp.float32 if f32 else jnp.bfloat16)
+    n, k = top_i.shape
+    e_held = wg.shape[0]
+    assert grouped_tile_rows(n * k, e_held) == (
+        128 if name == "wide_experts" else 16)
+    args = (x, jnp.asarray(top_i), top_w, wg, wu, wd, first,
+            None if valid is None else jnp.asarray(valid))
+    with jax.enable_x64(False):
+        out, load = sorted_expert_swiglu(*args, interpret=True)
+        ref, ref_load = sorted_expert_swiglu(*args, use_pallas=False)
+    out = np.asarray(out.astype(jnp.float32))
+    local = top_i - first
+    held = (local >= 0) & (local < e_held)
+    if valid is not None:
+        held &= valid[:, None]
+    counts = np.bincount(local[held], minlength=e_held)
+    assert np.asarray(load).tolist() == counts.tolist() \
+        == np.asarray(ref_load).tolist()
+    if name == "empty_expert":
+        assert counts[-1] == 0
+    if name == "one_expert":
+        assert counts[:k].tolist() == [n] * k and not counts[k:].any()
+    if name in ("held_share", "thin_experts"):
+        assert (local < 0).any() and (local >= e_held).any()
+    assert not out[~held.any(axis=1)].any()
+    if f32:
+        xs = np.asarray(x, np.float64)
+        g, u, dn = (np.asarray(w, np.float64) for w in (wg, wu, wd))
+        want = np.zeros((n, x.shape[1]))
+        for t, j in zip(*np.nonzero(held)):
+            e = local[t, j]
+            a = xs[t] @ g[e]
+            want[t] += float(top_w[t, j]) * (
+                (a / (1 + np.exp(-a)) * (xs[t] @ u[e])) @ dn[e])
+        np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-4)
+    else:
+        ref = np.asarray(ref.astype(jnp.float32))
+        # bf16 against bf16: the kernel keeps g and u in float32 up to
+        # the one cast, the ragged_dot form rounds each to bf16 first
+        np.testing.assert_allclose(out, ref, rtol=0.05,
+                                   atol=0.02 * np.abs(ref).max())
+
+
+def test_grouped_slot_tables_skip_experts_without_rows():
+    """The launch's scalar tables: the experts that have rows first, in
+    order, each at the tile-aligned row its rows start at; the slots
+    behind them repeat the last such expert with no tile (same weight
+    block, nothing fetched); a step no held expert was given a row in
+    has no live slot."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import (grouped_buffer_rows,
+                                               grouped_slot_tables)
+    expert, first, tiles = (np.asarray(t) for t in grouped_slot_tables(
+        jnp.asarray([0, 17, 0, 16, 1, 0], jnp.int32), 16))
+    assert expert.tolist() == [1, 3, 4, 4, 4, 4]
+    assert first.tolist() == [0, 32, 48, 48, 48, 48]
+    assert tiles.tolist() == [2, 1, 1, 0, 0, 0]
+    assert grouped_buffer_rows(34, 6, 16) >= 64
+    _, _, tiles = grouped_slot_tables(jnp.zeros((4,), jnp.int32), 16)
+    assert not np.asarray(tiles).any()
+
+
 def test_moe_ffn_one_chip_is_the_gate_and_the_sorted_product():
     """``moe_ffn`` on one chip: ITS gate (``topk_gate``, renormalised
     over the k) and then the product the held path calls, with the
@@ -426,7 +536,7 @@ def test_one_chip_step_counts_its_experts_rows():
 
     before = counted()
     eng, _ = _run(model)
-    assert eng.mixed.n_stats == 2 + cfg.num_local_experts
+    assert eng.mixed.n_stats == 3 + cfg.num_local_experts
     recs = [e[5] for e in span_log.events()
             if e[1] == STEP_SPAN and e[5]["engine"] == eng.engine_id]
     per_token = cfg.num_experts_per_tok * cfg.num_hidden_layers

@@ -55,6 +55,13 @@ def _compile(fn, sharding, *shapes):
     return lowered.compile()
 
 
+def _kernel_calls(scopes, kernel_name: str):
+    """The optimized program's launches of the Pallas kernel
+    ``kernel_name`` (XLA names a ``tpu_custom_call`` after its
+    ``pallas_call(name=)``), out of ``hlo_op_scopes``'s names."""
+    return [n for n in scopes if n.startswith(kernel_name)]
+
+
 def _pool(hkv, dtype):
     return ((PAGES, BLOCK, hkv, D), dtype)
 
@@ -223,9 +230,10 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
     """One whole fused step at Mixtral-8x7B's widths (hidden 4096, 8
     experts of 14336, 2 a token, 32 x 128 heads over 8 kv heads), bf16,
     2 layers, under the framework's own x64 setting: the optimized v5e
-    program multiplies the experts' rows with XLA:TPU's grouped matmul
-    (``ragged-dot*`` under ``moe.experts``) and holds no buffer an
-    expert (no shape that leads with ``[E, N*k``)."""
+    program multiplies the experts' rows with the Pallas grouped product
+    (``grouped_expert_matmul`` under ``moe.experts``, two launches a
+    layer), holds no ``ragged_dot`` and no buffer an expert (no shape
+    that leads with ``[E, N*k``)."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     from paddle_tpu.jit.serving_step import STEP_SCOPES, hlo_op_scopes
@@ -255,23 +263,57 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
         max_seq_len=WIDTH * BLOCK,
         prefill_chunk_size=CHUNK, use_pallas=True)
     top = eng.token_budgets[-1]
-    assert eng.mixed.n_stats == 2 + E
+    assert eng.mixed.n_stats == 3 + E
     lowered = eng.mixed.aot_lower(top, device_sharding=v5e)
-    assert "ragged_dot" in lowered.as_text()
+    text = lowered.as_text()
+    assert "ragged_dot" not in text
+    assert text.count('kernel_name = "grouped_expert_matmul"') >= 1
     hlo = lowered.compile().as_text()
+    assert "ragged-dot" not in hlo
     scopes = hlo_op_scopes(hlo)
     assert {"moe.gate", "moe.sort", "moe.experts", "moe.combine",
             "attn.kernel"} <= set(scopes.values()) <= STEP_SCOPES | {None}
     assert not {"moe.dispatch", "ep.all_to_all"} & set(scopes.values())
-    grouped = [n for n in scopes if n.startswith("ragged-dot")]
-    assert len(grouped) >= 3 * cfg.num_hidden_layers
+    grouped = _kernel_calls(scopes, "grouped_expert_matmul")
+    assert len(grouped) == 2 * cfg.num_hidden_layers
     assert {scopes[n] for n in grouped} == {"moe.experts"}
     # the buffers are gone: no instruction's shape leads with [E, N*k
     assert not re.search(r"\[%d,%d[,\]]" % (E, top * K), hlo)
-    # (XLA:TPU keeps its grouped-matmul kernel only where the sorted
-    # buffer's N*k rows are a multiple of 8: at 12 rows it expands the
-    # product to the dense [E, N*k, .] form itself.  Every budget of the
-    # cells is such a multiple.)
+
+
+# the grouped expert product alone at the published widths of both MoE
+# cells: name -> (rows of the sorted buffer before padding, experts
+# held, K, N, weight banks)
+GROUPED = {
+    "mixtral_gate_up": (2048, 8, 4096, 14336, 2),
+    "mixtral_down": (2048, 8, 14336, 4096, 1),
+    "deepseek_gate_up": (3168, 40, 5120, 1536, 2),
+    "deepseek_down": (3168, 40, 1536, 5120, 1),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GROUPED))
+def test_grouped_expert_matmul(v5e, shape):
+    """``[rows, K] x [E, K, N]`` over rows sorted by expert, at the top
+    budget of each cell (Mixtral 1,024 tokens x 2, DeepSeek-V2 528 x 6):
+    whole-K weight blocks through the pipeline, row tiles sized by the
+    rows an expert has (128 / 32), more VMEM than Mosaic's default
+    limit allows (``vmem_limit_bytes``)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    rows, experts, k, n, banks = GROUPED[shape]
+    tile = pk.grouped_tile_rows(rows, experts)
+    assert tile == (128 if experts == 8 else 32)
+    buf = pk.grouped_buffer_rows(rows, experts, tile)
+    compiled = _compile(
+        lambda xs, load, *w: pk.grouped_expert_matmul(
+            xs, pk.grouped_slot_tables(load, tile), *w, tile=tile),
+        v5e, ((buf, k), jnp.bfloat16), ((experts,), jnp.int32),
+        *[((experts, k, n), jnp.bfloat16)] * banks)
+    assert "grouped_expert_matmul" in compiled.as_text()
+    # the audit's cell is the launch's own
+    tn = pk.grouped_column_tile(k, n, banks, tile)
+    assert n % tn == 0 and pk._grouped_cell_vmem_bytes(
+        k, tn, banks, tile, 2) <= pk._GROUPED_VMEM
 
 
 # the latent (MLA) launch at DeepSeek-V2's widths: 128 heads over one
@@ -335,10 +377,15 @@ def test_mixed_step_latent_two_kinds(v5e):
             "attn.unabsorb", "attn.kernel", "attn.kv_write", "moe.gate",
             "moe.sort", "moe.experts", "moe.combine", "moe.shared"} \
         <= set(scopes.values()) <= STEP_SCOPES | {None}
-    # the kernels XLA:TPU names itself keep a part of the step too
-    kernels = {s for n, s in scopes.items()
-               if n.startswith(("ragged-dot", "ragged_latent_attention"))}
-    assert kernels == {"moe.experts", "attn.kernel"}
+    assert "ragged_dot" not in lowered.as_text()
+    assert "ragged-dot" not in hlo
+    # the Pallas launches keep their scope: the latent kernel once a
+    # layer, the grouped product twice a routed layer
+    assert {scopes[n] for n in _kernel_calls(
+        scopes, "ragged_latent_attention")} == {"attn.kernel"}
+    grouped = _kernel_calls(scopes, "grouped_expert_matmul")
+    assert len(grouped) == 2
+    assert {scopes[n] for n in grouped} == {"moe.experts"}
     # the experts multiply rows, not experts x rows
     k = cfg.num_experts_per_tok
     assert not re.search(r"\[8,%d,\d+\]" % (top * k), hlo)

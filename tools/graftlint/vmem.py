@@ -16,9 +16,10 @@ the first TPU run.
 Budgets: the bench hardware (TPU v5e) has 128 MiB of VMEM per core;
 the compiler needs headroom for spills and its own operand pipelining,
 so each kernel is capped at HALF the core (64 MiB) and the serving
-kernels — which must coexist with the fused step's other fusions — at
-an eighth (16 MiB, the classic per-core figure older generations
-actually have).
+attention kernels — which must coexist with the fused step's other
+fusions — at an eighth (16 MiB, the classic per-core figure older
+generations actually have).  The grouped expert product holds whole-K
+weight blocks and is a v5e-class kernel like the training ones.
 """
 from __future__ import annotations
 
@@ -43,6 +44,10 @@ BUDGETS = {
     "paged_decode_int8": 16 * MIB,
     "rope_qkv_epilogue": 16 * MIB,
     "ragged_latent_bf16": 16 * MIB,
+    # whole-K weight blocks of an expert bank, two pipeline slots: a
+    # v5e-class kernel (vmem_limit_bytes raised past Mosaic's default)
+    "grouped_expert_wide": 64 * MIB,
+    "grouped_expert_thin": 64 * MIB,
     "flash_fwd": 64 * MIB,
     "flash_bwd_fused": 64 * MIB,
 }
