@@ -986,11 +986,11 @@ class ContinuousBatchingEngine:
           beside ``tokens`` x the GQA group size it says how much of
           the launch is real.  For a latent model the rows are tokens
           x heads.
-        - ``attn_blocks``, ``attn_blocks_masked``: for a latent model
-          on the Pallas launch, the key blocks one layer's launch walks
-          for the step's spans and how many of them took the masked
-          body (``MixedStep.attn_blocks``: host integers from the
-          spans' ``(q_len, kv_len)``); 0 otherwise.
+        - ``attn_blocks``, ``attn_blocks_masked``: on the Pallas
+          launch (latent or grouped-query), the key blocks one layer's
+          launch walks for the step's spans and how many of them took
+          the masked body (``MixedStep.attn_blocks``: host integers
+          from the spans' ``(q_len, kv_len)``); 0 on the XLA reference.
         - ``moe_rows``, ``moe_rows_top``: the assignments that landed
           on the experts this engine holds, summed over the routed
           layers, and the fullest held expert's; counted by the step

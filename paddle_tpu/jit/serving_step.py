@@ -966,6 +966,24 @@ class MixedStep:
         return self._tp.collective_bytes(self.cfg, T, self.max_spans,
                                          quant_gather=self._q8_gather)
 
+    def _ragged_geometry(self):
+        """``(tile, pages_per_block)`` of this step's grouped-query
+        Pallas launch (``ops/pallas_kernels.ragged_tile_geometry`` at
+        one chip's heads); None where the step attends otherwise: a
+        latent model, the XLA reference, a context-parallel mesh."""
+        if self._latent is not None or not self.use_pallas or (
+                self._tp is not None and self._tp.cp_degree > 1):
+            return None
+        from ..ops.pallas_kernels import ragged_tile_geometry
+        cfg = self.cfg
+        deg = self._tp.degree if self._tp is not None else 1
+        cache = self.caches[0]
+        return ragged_tile_geometry(
+            cfg.num_attention_heads // deg, cfg.num_key_value_heads // deg,
+            cache.head_dim, cache.block_size, self.bt_width,
+            "bfloat16" if cfg.dtype == "bfloat16" else "float32",
+            cache.kv_dtype)
+
     def attn_rows(self, T: int, q_lens) -> int:
         """The q rows per kv head (for a latent model: per cached row,
         i.e. tokens x heads) that a budget-``T`` launch carrying
@@ -974,9 +992,7 @@ class MixedStep:
         ragged_attn_rows``: a decode span one tile, a chunk
         ceil(q_len / tile)); the XLA reference and the context-parallel
         partial compute every row of the budget."""
-        from ..ops.pallas_kernels import (latent_attn_rows,
-                                          ragged_attn_rows,
-                                          ragged_tile_geometry)
+        from ..ops.pallas_kernels import latent_attn_rows, ragged_attn_rows
         cfg = self.cfg
         if self._latent is not None:
             # one cached row for all heads: the rows are tokens x heads
@@ -984,18 +1000,11 @@ class MixedStep:
             H = cfg.num_attention_heads
             return latent_attn_rows(q_lens, H) if self.use_pallas \
                 else T * H
-        deg = self._tp.degree if self._tp is not None else 1
-        H = cfg.num_attention_heads // deg
-        Hkv = cfg.num_key_value_heads // deg
-        if not self.use_pallas or (self._tp is not None
-                                   and self._tp.cp_degree > 1):
-            return T * (H // Hkv)
-        cache = self.caches[0]
-        tile, _ = ragged_tile_geometry(
-            H, Hkv, cache.head_dim, cache.block_size, self.bt_width,
-            "bfloat16" if cfg.dtype == "bfloat16" else "float32",
-            cache.kv_dtype)
-        return ragged_attn_rows(q_lens, tile, H // Hkv)
+        groups = cfg.num_attention_heads // cfg.num_key_value_heads
+        geometry = self._ragged_geometry()
+        if geometry is None:
+            return T * groups
+        return ragged_attn_rows(q_lens, geometry[0], groups)
 
     def moe_tile_rows(self, T: int) -> int:
         """The rows of one row tile of a budget-``T`` step's grouped
@@ -1011,16 +1020,26 @@ class MixedStep:
         return grouped_tile_rows(T * self._moe_top_k, self._moe_experts)
 
     def attn_blocks(self, q_lens, kv_lens):
-        """``(blocks, masked)``: the key blocks one layer's latent
+        """``(blocks, masked)``: the key blocks one layer's Pallas
         launch walks for spans of these lengths and how many of them
-        take its masked body (``ops/pallas_kernels.
-        latent_attn_blocks``).  ``(0, 0)`` where no such launch runs:
-        a grouped-query model, the XLA reference."""
-        if self._latent is None or not self.use_pallas:
-            return 0, 0
-        from ..ops.pallas_kernels import latent_attn_blocks
-        return latent_attn_blocks(q_lens, kv_lens,
-                                  self.caches[0].block_size, self.bt_width)
+        take its masked body (``ops/pallas_kernels.ragged_attn_blocks``
+        at the launch's own tile and key block: the latent one's, or
+        the grouped-query one's).  ``(0, 0)`` where no such launch
+        runs: the XLA reference, a context-parallel mesh."""
+        from ..ops import pallas_kernels as pk
+        bs = self.caches[0].block_size
+        if self._latent is not None:
+            if not self.use_pallas:
+                return 0, 0
+            tile = pk._LATENT_TILE_TOKENS
+            kb = pk._latent_pages_per_block(bs, self.bt_width)
+        else:
+            geometry = self._ragged_geometry()
+            if geometry is None:
+                return 0, 0
+            tile, kb = geometry
+        return pk.ragged_attn_blocks(q_lens, kv_lens, tile, kb, bs,
+                                     self.bt_width)
 
     def _build(self, T: int):
         from ..autograd.tape import no_grad

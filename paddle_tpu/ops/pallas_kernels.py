@@ -1430,12 +1430,16 @@ def sdpa_ulysses(query, key, value, mesh, axis_name: str = "sep",
 # traced and lowered anew for every token budget at every start: a
 # second size (8-token tiles for decode spans beside 128-token tiles
 # for chunks read 1-4% better end to end: PERF.md, PR 25) doubled that
-# and put seven seconds on a warm start.  Keys stream in blocks of about _RAGGED_KV_BLOCK
-# tokens (whole pages), so the matmuls have N = 128 whatever the page
-# size.
+# and put seven seconds on a warm start.  Keys stream in blocks of
+# _RAGGED_KV_BLOCK keys (whole pages): a block wholly under a tile's
+# diagonal is one unmasked update 512 keys wide; the diagonal's block
+# and the span's last are walked _RAGGED_SUB_BLOCK keys at a time, as
+# far as the tile's last row sees, under the mask.  The block halves,
+# then the tile, while a cell's buffers overrun _RAGGED_TILE_VMEM.
 _RAGGED_TILE_ROWS = 128
 _RAGGED_MIN_TILE = 8
-_RAGGED_KV_BLOCK = 128
+_RAGGED_KV_BLOCK = 512
+_RAGGED_SUB_BLOCK = 128
 # what the tile-sized buffers of one grid cell may take of the 16 MiB
 # the serving kernels are held to (tools/graftlint/vmem.py)
 _RAGGED_TILE_VMEM = 12 << 20
@@ -1464,8 +1468,8 @@ def _ragged_cell_vmem_bytes(bq: int, heads: int, kv_heads: int,
     total += _tile_bytes((kv_heads, rows, head_dim), c_itemsize)  # q2
     total += _tile_bytes((kv_heads, rows, head_dim), 4)           # acc
     total += 2 * _tile_bytes((kv_heads, rows, 1), 4)              # m, l
-    total += 4 * _tile_bytes((kv_block, kv_heads, head_dim),
-                             kv_itemsize)                   # k, v x 2 slots
+    total += 4 * _stored_block_bytes(kv_block, kv_heads, head_dim,
+                                     kv_itemsize)           # k, v x 2 slots
     total += 2 * _tile_bytes((kv_heads, kv_block, head_dim),
                              c_itemsize)                    # head-major
     total += 2 * _tile_bytes((rows, kv_block), 4)           # scores + p
@@ -1474,32 +1478,94 @@ def _ragged_cell_vmem_bytes(bq: int, heads: int, kv_heads: int,
     return total
 
 
+def _stored_block_bytes(n: int, kv_heads: int, head_dim: int,
+                        itemsize: int) -> int:
+    """VMEM bytes of ``[n, Hkv, D]`` pages as stored.  Mosaic tiles a
+    second-minor dim narrower than the type's sublane tile at the next
+    power of two, down to the type's packing, so 8 kv heads take 8
+    sublanes in f32, bf16 and int8 alike; only fewer heads than the
+    packing (bf16: 1, int8: 1-2) pad to the whole tile.  (Compile-only
+    v5e probe, PR 36: the largest ``[N, Hkv, 128]`` scratch Mosaic takes
+    is 127-128 MiB of unpadded bytes at every other width.)"""
+    pack = 4 // max(1, min(itemsize, 4))
+    sub = 8 * pack
+    if kv_heads >= sub:
+        rows = -(-kv_heads // sub) * sub
+    elif kv_heads >= pack:
+        rows = 1 << (kv_heads - 1).bit_length()
+    else:
+        rows = sub
+    return n * rows * (-(-head_dim // _VMEM_LANE) * _VMEM_LANE) * itemsize
+
+
+def _ragged_sub_pages(block_size: int, bt_width: int) -> int:
+    """Pages of one sub-block of the tail walk (_RAGGED_SUB_BLOCK keys,
+    at least a page, at most the table)."""
+    return max(1, min(_RAGGED_SUB_BLOCK // block_size, bt_width))
+
+
 @functools.lru_cache(maxsize=None)
 def ragged_tile_geometry(heads: int, kv_heads: int, head_dim: int,
                          block_size: int, bt_width: int, q_dtype,
                          kv_dtype):
     """``(tile, pages_per_block)``: the q tile in tokens and the pages
-    one key block holds, from what the launch sees — the head geometry,
-    the page size and the two dtypes, not the budget, so every budget's
-    launch has the same cell.  The tile halves while a cell's buffers
-    overrun _RAGGED_TILE_VMEM."""
+    one key block holds (whole sub-blocks), from what the launch sees —
+    the head geometry, the page size and the two dtypes, not the
+    budget, so every budget's launch has the same cell.  The widest
+    block up to _RAGGED_KV_BLOCK keys whose cell fits _RAGGED_TILE_VMEM
+    at the full tile; the tile halves only where none does."""
     q_item = jnp.dtype(q_dtype).itemsize
     kv_item = jnp.dtype(kv_dtype).itemsize
     c_item = _ragged_compute_dtype(q_dtype, kv_dtype).itemsize
-    kb = max(1, min(_RAGGED_KV_BLOCK // block_size, bt_width))
+    sub = _ragged_sub_pages(block_size, bt_width)
     tile = max(_RAGGED_MIN_TILE,
                _RAGGED_TILE_ROWS // (heads // kv_heads))
-    while tile > _RAGGED_MIN_TILE and _ragged_cell_vmem_bytes(
-            tile, heads, kv_heads, head_dim, kb * block_size, q_item,
-            c_item, kv_item) > _RAGGED_TILE_VMEM:
+    while True:
+        keys = _RAGGED_KV_BLOCK
+        while True:
+            kb = min(keys // block_size, -(-bt_width // sub) * sub)
+            kb = max(sub, kb // sub * sub)
+            fits = _ragged_cell_vmem_bytes(
+                tile, heads, kv_heads, head_dim, kb * block_size, q_item,
+                c_item, kv_item) <= _RAGGED_TILE_VMEM
+            if fits or keys <= _RAGGED_SUB_BLOCK:
+                break
+            keys //= 2
+        if fits or tile <= _RAGGED_MIN_TILE:
+            return tile, kb
         tile //= 2
-    return tile, kb
 
 
 def ragged_attn_rows(q_lens, tile: int, groups: int) -> int:
     """The q rows per kv head one launch computes for spans of these
     lengths: every tile's full height, garbage rows included."""
     return groups * tile * sum(-(-max(int(n), 0) // tile) for n in q_lens)
+
+
+def ragged_attn_blocks(q_lens, kv_lens, tile: int, pages_per_block: int,
+                       block_size: int, bt_width: int):
+    """``(blocks, masked)``: the key blocks one ragged launch of q tiles
+    of ``tile`` tokens and key blocks of ``pages_per_block`` pages walks
+    for spans of these ``(q_len, kv_len)`` — every q tile's, as
+    ``_tile_extent`` sizes them — and how many of them take the masked
+    body (a block some row of the tile does not see whole).  The same
+    rule in both launches: ``ragged_tile_geometry``'s for the
+    grouped-query one, ``_LATENT_TILE_TOKENS`` and
+    ``_latent_pages_per_block`` for the latent one.  Host integers
+    only."""
+    kb = pages_per_block
+    ql = np.maximum(np.asarray(q_lens, np.int64), 0)
+    kl = np.asarray(kv_lens, np.int64)
+    per = -(-ql // tile)                          # tiles a span
+    span = np.repeat(np.arange(ql.size), per)
+    first = (np.arange(span.size) - np.repeat(np.cumsum(per) - per, per)) \
+        * tile
+    pos0 = (kl - ql)[span] + first
+    kv_end = np.minimum(kl[span], pos0 + np.minimum(ql[span] - first, tile))
+    n_pages = np.minimum(-(-kv_end // block_size), bt_width)
+    n_blk = -(-n_pages // kb)
+    whole = np.minimum(np.maximum(pos0 + 1, 0) // (kb * block_size), n_blk)
+    return int(n_blk.sum()), int((n_blk - whole).sum())
 
 
 def _ragged_work_list(q_lens, tile: int, n_tiles: int):
@@ -1670,8 +1736,8 @@ def _kv_heads(buf):
 
 
 def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
-                         pages_per_block: int, scale: float,
-                         groups: int, quantized: bool):
+                         pages_per_block: int, pages_per_sub: int,
+                         scale: float, groups: int, quantized: bool):
     """Grid cell i: one q TILE — ``rows`` consecutive tokens of one
     span, from the work list — against every local kv head's pages
     (arXiv:2604.15464 "Ragged Paged Attention").
@@ -1688,15 +1754,23 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
     Pages arrive as stored: one contiguous ``[block_size, Hkv, D]``
     copy a page serves every kv head, ``pages_per_block`` pages make
     one key block, and blocks stream through two VMEM slots (block
-    b+1's copies are issued before block b's math); a block that has
-    arrived is laid head-major (``[Hkv, n, D]``: each head's rows are
-    shifted out of the packed sublanes once) and the math loops over
-    the kv heads with a traced index, so the body is traced once, not
-    once a head.  No copy is issued
+    b+1's copies are issued before block b's math).  No copy is issued
     and no block-table entry is read for a page past what the tile can
-    see — ``min(kv_len, position of its last row + 1)`` — so unused
-    pages are never touched (the poisoned-pages invariant) and a chunk
-    tile skips the keys its causal mask would drop anyway.
+    see — ``kv_end = min(kv_len, position of its last row + 1)`` — so
+    unused pages are never touched (the poisoned-pages invariant) and a
+    chunk tile skips the keys its causal mask would drop anyway.
+
+    How a block is walked follows what the tile observes.  A block that
+    the tile's FIRST row sees whole (its last key at or before that
+    row's position, hence before ``kv_end``) is visible to every row:
+    one update over its whole width with no mask.  The diagonal's block
+    and a partly filled last one are walked in sub-blocks of
+    ``pages_per_sub`` pages, as far as ``kv_end`` (a traced trip
+    count), each under the mask.  An arrived block is laid head-major
+    (``[Hkv, sub-block, n, D]``: each head's rows shifted out of the
+    packed sublanes once), sub-block by sub-block as far as it will be
+    walked; that copy is traced once, and only the per-head update (a
+    loop over the kv heads with a traced index) twice, once a body.
 
     Causality is positional: row r of span s sits at global position
     ``kv_len - q_len + r`` and sees keys at positions <= that, so decode
@@ -1709,7 +1783,7 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
     directly.  The tile's q rows are quantized once per cell to per-row
     int8 (``quantize_rows_symmetric``), ``q.K^T`` is int8 x int8 with
     int32 accumulate, and the per-row q scale, the per-page-per-head k
-    scales (two scalar-prefetch tables, laid along the block's columns)
+    scales (two scalar-prefetch tables, laid along the keys' columns)
     and the softmax scale fold into the scores; ``p.V`` is int8 x int8
     too, the v scales folded into p before its per-row quantization.
     """
@@ -1727,8 +1801,8 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
     hkv = kbuf.shape[2]
     bq, _, d = qbuf.shape
     r = bq * groups
-    bs, kb = block_size, pages_per_block
-    n = kb * bs
+    bs, kb, sp = block_size, pages_per_block, pages_per_sub
+    n, ns = kb * bs, sp * bs            # keys of a block, of a sub-block
     cdt = q2.dtype
 
     @pl.when(i == 0)
@@ -1743,6 +1817,10 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
             i, rows, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref,
             block_size=bs, pages_per_span=pages_per_span,
             pages_per_block=kb)
+        # blocks [0, n_whole) lie wholly under the diagonal: the first
+        # row sees keys [0, pos0], all of them before kv_end
+        n_whole = jnp.minimum(
+            lax.div(jnp.maximum(pos0 + 1, 0), jnp.int32(n)), n_blk)
         block_copies = _page_block_copies(
             bt_ref, s, n_pages, bs, kb,
             [(k_hbm, kbuf, lambda slot: kv_sem.at[slot, 0]),
@@ -1770,36 +1848,44 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
         tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
                       jnp.int32(groups))
         qpos = pos0 + tok
-        page_of_col = lax.div(
-            lax.broadcasted_iota(jnp.int32, (1, n), 1), jnp.int32(bs))
 
-        def col_scales(tab_ref, h, b):
-            """[1, n] f32: each column's page's scale of kv head h."""
-            out = jnp.zeros((1, n), jnp.float32)
-            for j in range(kb):
-                p = jnp.minimum(b * kb + j, n_pages - 1)
+        def col_scales(tab_ref, h, p0, width):
+            """[1, width] f32: each column's page's scale of kv head h,
+            the columns' pages starting at the span's page p0."""
+            page_of_col = lax.div(
+                lax.broadcasted_iota(jnp.int32, (1, width), 1),
+                jnp.int32(bs))
+            out = jnp.zeros((1, width), jnp.float32)
+            for j in range(width // bs):
+                p = jnp.minimum(p0 + j, n_pages - 1)
                 out = jnp.where(page_of_col == j,
                                 tab_ref[h, bt_ref[s, p]], out)
             return out
 
-        def head_math(h, b):
-            """Key block b against kv head h (a traced index: the
-            per-head state and the head-major block are indexed on
-            their leading dim, so the body is traced once)."""
-            k, v, qh = k_hm[h], v_hm[h], q2[h]
+        def head_math(h, k, v, c0, p0, masked: bool):
+            """Keys ``[c0, c0 + width)`` of the span (``k``, ``v``:
+            ``[width, D]`` of kv head h, from the span's page p0)
+            against the head's q rows (a traced head index: the state
+            and the head-major block are indexed on their leading
+            dim)."""
+            width = k.shape[0]
+            qh = q2[h]
             if quantized:
                 si = lax.dot_general(qh, k, _DIMNUM_NT,
                                      preferred_element_type=jnp.int32)
                 sc = fold_int8_scores(si, qs_s[h],
-                                      col_scales(ks_ref, h, b), scale)
-                v_s = col_scales(vs_ref, h, b)
+                                      col_scales(ks_ref, h, p0, width),
+                                      scale)
+                v_s = col_scales(vs_ref, h, p0, width)
             else:
                 sc = lax.dot_general(
                     qh, k, _DIMNUM_NT,
                     preferred_element_type=jnp.float32) * np.float32(scale)
-            cols = b * n + lax.broadcasted_iota(jnp.int32, (r, n), 1)
-            ok = (cols <= qpos) & (cols < kv_end)
-            sc = jnp.where(ok, sc, _F32_NEG_INF)
+            ok = None
+            if masked:
+                cols = c0 + lax.broadcasted_iota(jnp.int32, (r, width), 1)
+                ok = (cols <= qpos) & (cols < kv_end)
+                sc = jnp.where(ok, sc, _F32_NEG_INF)
 
             def pv_of_p(p):
                 if quantized:
@@ -1813,14 +1899,43 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
 
             m_s[h], l_s[h], acc_s[h] = online_softmax_update(
                 (m_s[h], l_s[h], acc_s[h]), sc, ok, pv_of_p)
-            return b
 
         def block_math(b, slot):
-            # the block head-major: [n, Hkv, D] as stored -> [Hkv, n, D]
-            for src, dst in ((kbuf, k_hm), (vbuf, v_hm)):
-                for h, rows_h in _kv_heads(src.at[slot]):
-                    dst[h] = rows_h.astype(cdt)
-            lax.fori_loop(jnp.int32(0), jnp.int32(hkv), head_math, b)
+            whole = b < n_whole
+            # the sub-blocks walked: all of a whole block; of the
+            # diagonal's or the span's last, as far as kv_end
+            n_sub = jnp.where(
+                whole, jnp.int32(kb // sp),
+                jnp.clip(lax.div(kv_end - b * n + (ns - 1), jnp.int32(ns)),
+                         1, kb // sp))
+
+            def lay_head_major(c, _):
+                # [ns, Hkv, D] as stored -> [Hkv, ns, D]
+                for src, dst in ((kbuf, k_hm), (vbuf, v_hm)):
+                    for h, rows_h in _kv_heads(
+                            src.at[slot, pl.ds(c * ns, ns)]):
+                        dst[h, c] = rows_h.astype(cdt)
+                return 0
+            lax.fori_loop(jnp.int32(0), n_sub, lay_head_major, 0)
+
+            @pl.when(whole)
+            def _unmasked():
+                def head(h, _):
+                    head_math(h, k_hm[h].reshape(n, d),
+                              v_hm[h].reshape(n, d), b * n, b * kb, False)
+                    return 0
+                lax.fori_loop(jnp.int32(0), jnp.int32(hkv), head, 0)
+
+            @pl.when(jnp.logical_not(whole))
+            def _masked():
+                def head(h, _):
+                    def sub(c, _):
+                        head_math(h, k_hm[h, c], v_hm[h, c],
+                                  b * n + c * ns, b * kb + c * sp, True)
+                        return 0
+                    lax.fori_loop(jnp.int32(0), n_sub, sub, 0)
+                    return 0
+                lax.fori_loop(jnp.int32(0), jnp.int32(hkv), head, 0)
 
         _stream_key_blocks(n_blk, block_copies, block_math)
 
@@ -1870,12 +1985,13 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
     quantized = key_scale is not None
     tile, kb = ragged_tile_geometry(H, Hkv, D, bs, W, q.dtype,
                                     key_cache.dtype)
+    sp = _ragged_sub_pages(bs, W)
     cdt = _ragged_compute_dtype(q.dtype, key_cache.dtype)
     rows = tile * groups
 
     kernel = functools.partial(
         _ragged_paged_kernel, block_size=bs, pages_per_span=W,
-        pages_per_block=kb, scale=scale, groups=groups,
+        pages_per_block=kb, pages_per_sub=sp, scale=scale, groups=groups,
         quantized=quantized)
     with _x64_off(), jax.named_scope("attn.kernel"):
         # [phys, Hkv] -> [Hkv, phys] so the kernel indexes [h, page]
@@ -1890,8 +2006,9 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
             pltpu.VMEM((Hkv, rows, D), jnp.float32),       # acc
             pltpu.VMEM((2, kb * bs, Hkv, D), key_cache.dtype),
             pltpu.VMEM((2, kb * bs, Hkv, D), value_cache.dtype),
-            pltpu.VMEM((Hkv, kb * bs, D), cdt),   # block, head-major
-            pltpu.VMEM((Hkv, kb * bs, D), cdt),
+            # the block head-major, sub-block by sub-block
+            pltpu.VMEM((Hkv, kb // sp, sp * bs, D), cdt),
+            pltpu.VMEM((Hkv, kb // sp, sp * bs, D), cdt),
             pltpu.SemaphoreType.DMA((2, 2)),     # [slot, k | v]
             pltpu.SemaphoreType.DMA((2,)),       # q in, o out
         ] + ([pltpu.VMEM((Hkv, rows, 1), jnp.float32)]   # q scales
@@ -1925,7 +2042,6 @@ _LATENT_SUB_TOKENS = 2
 # m / l update and the two lane reductions a row come once a block: the
 # launch alone reads 63 / 97 / 114 / 119 TFLOP/s at 128 / 256 / 512 /
 # 1,024 keys (PERF.md, PR 30); 2,048 would overrun _RAGGED_TILE_VMEM.
-# The grouped-query kernel keeps _RAGGED_KV_BLOCK.
 _LATENT_KV_BLOCK = 1024
 
 
@@ -1968,28 +2084,6 @@ def latent_attn_rows(q_lens, heads: int) -> int:
     spans of these lengths: whole sub-tiles of the real tokens."""
     sub = _LATENT_SUB_TOKENS
     return heads * sub * sum(-(-max(int(n), 0) // sub) for n in q_lens)
-
-
-def latent_attn_blocks(q_lens, kv_lens, block_size: int, bt_width: int):
-    """``(blocks, masked)``: the key blocks one latent launch walks for
-    spans of these ``(q_len, kv_len)`` — every q tile's, as
-    ``_tile_extent`` sizes them — and how many of them take the masked
-    body (``_latent_paged_kernel``: a block some row of the tile does
-    not see whole).  Host integers only."""
-    tile = _LATENT_TILE_TOKENS
-    kb = _latent_pages_per_block(block_size, bt_width)
-    ql = np.maximum(np.asarray(q_lens, np.int64), 0)
-    kl = np.asarray(kv_lens, np.int64)
-    per = -(-ql // tile)                          # tiles a span
-    span = np.repeat(np.arange(ql.size), per)
-    first = (np.arange(span.size) - np.repeat(np.cumsum(per) - per, per)) \
-        * tile
-    pos0 = (kl - ql)[span] + first
-    kv_end = np.minimum(kl[span], pos0 + np.minimum(ql[span] - first, tile))
-    n_pages = np.minimum(-(-kv_end // block_size), bt_width)
-    n_blk = -(-n_pages // kb)
-    whole = np.minimum(np.maximum(pos0 + 1, 0) // (kb * block_size), n_blk)
-    return int(n_blk.sum()), int((n_blk - whole).sum())
 
 
 def _latent_paged_kernel(*refs, block_size: int, pages_per_span: int,

@@ -928,26 +928,62 @@ def test_vmem_budget_lint():
     assert grown["flash_bwd_fused"] > 1.5 * base["flash_bwd_fused"]
     # and the ragged cell's accounting is visible: its page blocks
     # hold pages AS STORED ([tokens, Hkv, D], two slots each of K and
-    # V: 8 heads pad to a whole sublane tile in bf16 and in int8
-    # alike), so an int8 pool's cell differs from the bf16 pool's by
-    # its folded q, its head-major block and its q scales; the q tile
-    # is 128 rows a kv head, so fewer heads a group means more tokens
-    from paddle_tpu.ops.pallas_kernels import (_tile_bytes,
+    # V: 8 heads take 8 sublanes in bf16 and in int8 alike, as Mosaic
+    # lays them out), so an int8 pool's cell differs from the bf16
+    # pool's by its slots, its folded q, its head-major block and its
+    # q scales; the q tile is 128 rows a kv head, so fewer heads a
+    # group means more tokens
+    from paddle_tpu.ops.pallas_kernels import (_stored_block_bytes,
+                                               _tile_bytes,
                                                ragged_kernel_vmem_bytes,
                                                ragged_tile_geometry)
     cell = dict(heads=32, kv_heads=8, head_dim=128, block_size=16,
                 bt_width=224)
     bf16 = ragged_kernel_vmem_bytes(**cell)
     int8 = ragged_kernel_vmem_bytes(kv_dtype="int8", **cell)
-    assert bf16 - int8 == 4 * (_tile_bytes((128, 8, 128), 2)
-                               - _tile_bytes((128, 8, 128), 1)) \
+    assert bf16 - int8 == 4 * (_stored_block_bytes(512, 8, 128, 2)
+                               - _stored_block_bytes(512, 8, 128, 1)) \
         + _tile_bytes((8, 128, 128), 2) - _tile_bytes((8, 128, 128), 1) \
-        + 2 * (_tile_bytes((8, 128, 128), 2)
-               - _tile_bytes((8, 128, 128), 1)) \
+        + 2 * (_tile_bytes((8, 512, 128), 2)
+               - _tile_bytes((8, 512, 128), 1)) \
         - _tile_bytes((8, 128, 1), 4)
+    assert _stored_block_bytes(512, 8, 128, 2) == 512 * 8 * 128 * 2
     assert ragged_tile_geometry(32, 8, 128, 16, 224, "bfloat16",
-                                "bfloat16") == (32, 8)
+                                "bfloat16") == (32, 32)
     # (float32 MHA: 128 tokens would overrun the cell's VMEM, so the
     # tile halves; a 4-page table caps the key block)
     assert ragged_tile_geometry(32, 32, 128, 16, 4, "float32",
                                 "float32") == (32, 4)
+
+
+# (heads, kv heads, block-table width, kv dtype): the docs cell's and
+# the chat cell's geometry (32 / 8 / 128, pages of 16, bf16), an int8
+# pool, and a tensor-parallel 4-way shard (8 / 2 heads a chip)
+_CELL_GEOMETRY = {
+    "docs_bf16": (32, 8, 388, "bfloat16"),
+    "chat_bf16": (32, 8, 224, "bfloat16"),
+    "docs_int8": (32, 8, 388, "int8"),
+    "chat_tp4_bf16": (8, 2, 224, "bfloat16"),
+    "chat_tp4_int8": (8, 2, 224, "int8"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CELL_GEOMETRY))
+def test_ragged_geometry_keeps_the_tile(case):
+    """The grouped-query launch's cell at the cells' geometry: a 32-token
+    q tile (128 rows a kv head) beside 512-key blocks of 32 pages — the
+    wider block never buys its room by halving the tile, which would
+    double every chunk's K/V re-reads — within _RAGGED_TILE_VMEM and
+    the 16 MiB the serving kernels are held to."""
+    from paddle_tpu.ops.pallas_kernels import (_RAGGED_KV_BLOCK,
+                                               _RAGGED_TILE_VMEM,
+                                               ragged_kernel_vmem_bytes,
+                                               ragged_tile_geometry)
+    heads, kv_heads, width, kv_dtype = _CELL_GEOMETRY[case]
+    assert ragged_tile_geometry(heads, kv_heads, 128, 16, width,
+                                "bfloat16", kv_dtype) \
+        == (32, _RAGGED_KV_BLOCK // 16) == (32, 32)
+    used = ragged_kernel_vmem_bytes(heads=heads, kv_heads=kv_heads,
+                                    head_dim=128, block_size=16,
+                                    bt_width=width, kv_dtype=kv_dtype)
+    assert used <= _RAGGED_TILE_VMEM < 16 << 20

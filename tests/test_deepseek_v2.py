@@ -245,7 +245,8 @@ def test_latent_kernel_block_edges(case):
     of and one past a key block's edge."""
     from paddle_tpu.ops.paged_attention import _ragged_latent_attention_xla
     from paddle_tpu.ops.pallas_kernels import (
-        _ragged_latent_attention_pallas, latent_attn_blocks)
+        _LATENT_TILE_TOKENS, _latent_pages_per_block,
+        _ragged_latent_attention_pallas, ragged_attn_blocks)
     n = _LATENT_KV_BLOCK
     spans = _BLOCK_CASES[case](n)
     args = _latent_pack(spans, n)
@@ -255,9 +256,11 @@ def test_latent_kernel_block_edges(case):
     assert real
     np.testing.assert_allclose(np.asarray(got)[:real],
                                np.asarray(want)[:real], atol=2e-5)
-    blocks, masked = latent_attn_blocks(
-        [ql for ql, _ in spans], [kl for _, kl in spans], _BLOCK_PAGE,
-        args[2].shape[1])
+    W = args[2].shape[1]
+    blocks, masked = ragged_attn_blocks(
+        [ql for ql, _ in spans], [kl for _, kl in spans],
+        _LATENT_TILE_TOKENS, _latent_pages_per_block(_BLOCK_PAGE, W),
+        _BLOCK_PAGE, W)
     assert blocks >= masked >= 0
     if case.startswith("kv_n") and case != "kv_n_plus_1":
         # a one-row span sees every key it walks: kv_len == n is one
@@ -280,37 +283,58 @@ def _brute_force_blocks(q_lens, kv_lens, n, tile):
     return blocks, masked
 
 
-def test_latent_attn_blocks_closed_form():
-    """``latent_attn_blocks`` against a count made key by key, row by
-    row, and the cell's own step by hand: a 512-token chunk over 8k
-    beside a one-row span at 16k + 1."""
+@pytest.mark.parametrize("launch", ["latent", "grouped_query"])
+def test_attn_blocks_closed_form(launch):
+    """``ragged_attn_blocks`` at each launch's tile and key block (the
+    latent one's 8 tokens and 1,024 keys, the grouped-query one's 32
+    tokens and 512 keys at the cells' 32 / 8 / 128 heads in bf16)
+    against a count made key by key, row by row, and each cell's own
+    step by hand."""
     from paddle_tpu.ops.pallas_kernels import (_LATENT_TILE_TOKENS,
-                                               latent_attn_blocks)
-    n = _LATENT_KV_BLOCK
+                                               _latent_pages_per_block,
+                                               ragged_attn_blocks,
+                                               ragged_tile_geometry)
+    if launch == "latent":
+        bs, n = 4, _LATENT_KV_BLOCK
+        W = 4 * n // bs
+        tile, kb = _LATENT_TILE_TOKENS, _latent_pages_per_block(bs, W)
+    else:
+        bs, W = 16, 388                     # docs' pages and table
+        tile, kb = ragged_tile_geometry(32, 8, 128, bs, W, "bfloat16",
+                                        "bfloat16")
+        n = kb * bs
+        assert (tile, n) == (32, 512)
+
+    def count(ql, kl, page=bs, width=W):
+        return ragged_attn_blocks(ql, kl, tile, kb, page, width)
+
     rng = np.random.default_rng(5)
-    bs = 4
-    W = 4 * n // bs
     for _ in range(20):
-        ql = rng.integers(0, 40, 6)
+        ql = rng.integers(0, 5 * tile, 6)
         kl = ql + rng.integers(0, 3 * n, 6)
         kl[ql == 0] = rng.integers(0, 2 * n, int((ql == 0).sum()))
-        assert latent_attn_blocks(ql, kl, bs, W) == _brute_force_blocks(
-            ql.tolist(), kl.tolist(), n, _LATENT_TILE_TOKENS)
+        assert count(ql, kl) == _brute_force_blocks(
+            ql.tolist(), kl.tolist(), n, tile)
     # edges: on, before and after a block's last key
     for kl in (n - 1, n, n + 1, 2 * n, 3 * n + 5):
-        for ql in (1, 7, 8, 9, 16):
+        for ql in (1, tile - 1, tile, tile + 1, 2 * tile):
             if ql <= kl:
-                assert latent_attn_blocks([ql], [kl], bs, W) \
-                    == _brute_force_blocks([ql], [kl], n,
-                                           _LATENT_TILE_TOKENS)
-    assert latent_attn_blocks([], [], bs, W) == (0, 0)
-    # pages of 128, as the cell has them: 64 tiles a chunk, each
-    # walking 8,192 / n whole blocks and the diagonal's one
-    blocks, masked = latent_attn_blocks([512, 1], [8192 + 512, 16385],
-                                        128, 260)
-    tiles = 512 // _LATENT_TILE_TOKENS
-    assert masked == tiles + 1
-    assert blocks == tiles * (8192 // n + 1) + 16384 // n + 1
+                assert count([ql], [kl]) == _brute_force_blocks(
+                    [ql], [kl], n, tile)
+    assert count([], []) == (0, 0)
+    if launch == "latent":
+        # pages of 128, as the cell has them: 64 tiles a chunk, each
+        # walking 8,192 / n whole blocks and the diagonal's one
+        kb = _latent_pages_per_block(128, 260)
+        blocks, masked = count([512, 1], [8192 + 512, 16385], 128, 260)
+        tiles = 512 // tile
+        assert masked == tiles + 1
+        assert blocks == tiles * (8192 // n + 1) + 16384 // n + 1
+    else:
+        # docs' step: a chunk at prefix 1,024 (16 tiles of two whole
+        # blocks and the diagonal's) beside a decode span at 3,000
+        # (five whole blocks and a partly filled sixth)
+        assert count([512, 1], [1536, 3000]) == (16 * 3 + 6, 16 + 1)
 
 
 def test_bfloat16_fails_the_float32_tolerances(model):

@@ -790,15 +790,29 @@ RAGGED_SWEEP = {
     # a chunk of several q tiles (32 tokens at its GQA 4:1) that
     # starts mid-page behind a prefix, between two decode spans
     "chunk_over_one_tile": [(1, 300), (140, 290), (1, 7)],
+    # "wide_": 16-token pages, tables at least two 512-key blocks long
+    # (blocks wholly under a tile's diagonal take the unmasked body,
+    # the rest the 128-key tail walk): a chunk whose tiles start past
+    # one whole block; decode spans at one block of keys, one past it
+    # and one past two; a chunk tile whose diagonal straddles a block
+    # edge; and all of them in one pack
+    "wide_chunk_past_block": [(64, 664), (1, 9)],
+    "wide_decode_on_block_edge": [(1, 512), (1, 513), (1, 1025)],
+    "wide_diagonal_straddles_block": [(40, 530), (0, 1)],
+    "wide_mix": [(1, 512), (64, 664), (1, 513), (40, 530), (1, 1100),
+                 (0, 1)],
 }
 _TIER1_RAGGED = {("ragged_mix", "float32"),
-                 ("chunk_over_one_tile", "bfloat16")}
+                 ("chunk_over_one_tile", "bfloat16"),
+                 ("wide_mix", "float32")}
+# pages of the "wide_" cases' tables: two whole 512-key blocks and more
+WIDE_TABLE = 2 * 512 // 16 + 2
 
 
-def _ragged_case(spans, bs, Hkv, H, D, dtype, seed=42):
+def _ragged_case(spans, bs, Hkv, H, D, dtype, seed=42, min_width=2):
     """The packed operands of one sweep case, pools in ``dtype``."""
     rng_ = np.random.RandomState(seed)
-    W = max(2, max(-(-kv // bs) for _, kv in spans))
+    W = max(min_width, max(-(-kv // bs) for _, kv in spans))
     nb = W * len(spans)
     kc = jnp.asarray(rng_.randn(nb, bs, Hkv, D), dtype)
     vc = jnp.asarray(rng_.randn(nb, bs, Hkv, D), dtype)
@@ -833,9 +847,10 @@ def test_ragged_kernel_interpret_matches_reference_sweep(case, dtype):
     from paddle_tpu.ops.paged_attention import (_ragged_attention_xla,
                                                 ragged_paged_attention)
     spans = RAGGED_SWEEP[case]
-    bs, H = (16, 8) if case == "chunk_over_one_tile" else (4, 4)
+    wide = case.startswith("wide_")
+    bs, H = (16, 8) if wide or case == "chunk_over_one_tile" else (4, 4)
     q, kc, vc, bt, q_offsets, q_lens, kv_lens = _ragged_case(
-        spans, bs, 2, H, 16, dtype)
+        spans, bs, 2, H, 16, dtype, min_width=WIDE_TABLE if wide else 2)
     want = _ragged_attention_xla(
         q.astype(jnp.float32), kc.astype(jnp.float32),
         vc.astype(jnp.float32), jnp.asarray(bt), jnp.asarray(q_offsets),
@@ -854,7 +869,8 @@ def test_ragged_kernel_interpret_matches_reference_sweep(case, dtype):
 # round 17: double-buffered page DMA + fused RoPE+QKV epilogue
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
+@pytest.mark.parametrize("geometry", ["small_pages", "wide_blocks"])
+def test_ragged_pipelined_prefetch_clamp_poisoned_pages(geometry):
     """r11 poison invariant, extended to the double-buffered kernel:
     prefetching page i+1 while attending page i must NEVER touch a
     page past the span's used block count — including the last-page
@@ -863,17 +879,23 @@ def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
     the poison page the padded table entries point at) is NaN'd; the
     kernel's output must be BYTE-IDENTICAL to its clean-pool run —
     for the ragged launch (whose key blocks hold several pages, so a
-    partly filled block must not fetch its tail) and for both decode
-    kernels — and match the XLA reference on the clean pool."""
+    partly filled block must not fetch its tail; ``wide_blocks``: 16-
+    token pages, 512-key blocks, the 128-key tail walk) and for both
+    decode kernels — and match the XLA reference on the clean pool."""
     from paddle_tpu.ops.paged_attention import _ragged_attention_xla
-    bs, Hkv, H, D, nb = 4, 2, 4, 16, 32
+    if geometry == "small_pages":
+        bs, Hkv, H, D, nb, W = 4, 2, 4, 16, 32, 4
+        # last span uses ALL W=4 pages: the prefetch-clamp boundary case
+        spans = [(1, 5), (4, 12), (8, 8), (1, 16), (2, 16)]
+    else:
+        bs, Hkv, H, D, W = 16, 2, 8, 16, WIDE_TABLE
+        spans = [(1, 512), (64, 664), (1, 513), (40, 530),
+                 (3, W * bs)]
+        nb = sum(-(-kv // bs) for _, kv in spans) + 24
     rng_ = np.random.RandomState(3)
     kc = jnp.asarray(rng_.randn(nb, bs, Hkv, D).astype(np.float32))
     vc = jnp.asarray(rng_.randn(nb, bs, Hkv, D).astype(np.float32))
     cache = PagedKVCache(nb, bs, Hkv, D)
-    # last span uses ALL W=4 pages: the prefetch-clamp boundary case
-    spans = [(1, 5), (4, 12), (8, 8), (1, 16), (2, 16)]
-    W = 4
     poison = cache.allocate_block()
     rows, used_pages = [], {poison}
     for q_len, kv_len in spans:
@@ -904,6 +926,8 @@ def test_ragged_pipelined_prefetch_clamp_poisoned_pages():
     np.testing.assert_array_equal(clean, poisoned)
     np.testing.assert_allclose(clean, np.asarray(want),
                                rtol=2e-4, atol=2e-5)
+    if geometry != "small_pages":
+        return
     # decode kernel: same invariant (full-table sequence included)
     sl = np.asarray([5, 16], np.int32)
     bt2 = np.stack([rows[0], rows[3]])

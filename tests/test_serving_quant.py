@@ -482,6 +482,18 @@ def test_int8_mxu_kernel_parity_sweep():
                 ([(1, 30), (20, 29), (9, 9)], 8)):   # mid-page, > tile
             err, atol = _int8_parity_case(cq, spans, W, 4, 16, rng_)
             assert err <= atol, (rounds, growth, spans, err, atol)
+    # the wide-block walk: 16-token pages, tables two 512-key blocks
+    # long (the unmasked body's column scales span a block's 32 pages,
+    # the 128-key tail walk's a sub-block's 8): a chunk whose tiles
+    # start past one whole block, decode spans at one block of keys and
+    # one past it, a chunk tile whose diagonal straddles a block edge
+    rng_ = np.random.RandomState(36)
+    cq = _q8_pool(nb=16, bs=16, hkv=2, d=16, rounds=2, mag_growth=2.0,
+                  rng_=rng_)
+    err, atol = _int8_parity_case(
+        cq, [(1, 512), (64, 664), (1, 513), (40, 530), (1, 1100), (0, 1)],
+        70, 8, 16, rng_)
+    assert err <= atol, ("wide blocks", err, atol)
     # decode kernel, same declared tolerance
     rng_ = np.random.RandomState(7)
     cq = _q8_pool(nb=8, bs=4, hkv=2, d=16, rounds=3, mag_growth=2.0,

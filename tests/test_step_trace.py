@@ -131,9 +131,18 @@ def test_one_record_a_step(family):
     # padding on none; a dense model counts nothing
     cfg = eng.mixed.cfg
     assert any(f["tokens"] < f["budget"] for f in launched)
+    # key blocks: what the engine's own launch walks (the CPU engine
+    # attends through the XLA reference, which walks none), and what
+    # the TPU launch of the same model (sized here, never run) walks:
+    # every tile a block at least, the diagonal's block masked
+    tpu = _engine(family, use_pallas=True).mixed
     for _, _, f in recs:
-        # key blocks are the latent launch's to count
-        assert f["attn_blocks"] == f["attn_blocks_masked"] == 0
+        q_lens, kv_lens = f["spans"][:, 1], f["spans"][:, 2]
+        assert (f["attn_blocks"], f["attn_blocks_masked"]) \
+            == eng.mixed.attn_blocks(q_lens, kv_lens) == (0, 0)
+        if f["budget"]:
+            blocks, masked = tpu.attn_blocks(q_lens, kv_lens)
+            assert 0 < masked <= blocks
         if family == "llama":
             assert f["moe_rows"] == f["moe_rows_top"] == 0
             assert f["moe_tiles"] == 0 and not eng.mixed.moe_tile_rows(16)

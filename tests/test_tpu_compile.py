@@ -133,12 +133,22 @@ RAGGED = {"decode": (SPANS, SPANS, WIDTH, PAGES),
           "cell1024": (1024, 64, 224, 4096)}
 
 
+# the ragged launch also at one chip's shard of the GQA cells under a
+# 4-way tensor-parallel mesh (8 q heads over 2 kv heads).  Its int8 arm
+# has never compiled (the parent too, PR 36): Mosaic refuses the
+# per-head slice of a page whose 2 heads are fewer than int8's 4 a word
+RAGGED_HEADS = dict(HEADS, tp4=(2, 4))
+_TP4_INT8 = pytest.mark.xfail(
+    strict=True, reason="2-head int8 pages: Mosaic refuses the per-head "
+    "slice (on the parent too; PERF.md section 7)")
+
+
 @pytest.mark.parametrize("kv", ["fp", "int8"])
 @pytest.mark.parametrize("heads,pack", [
-    (h, p) for h in sorted(HEADS) for p in ("decode", "chunk")]
+    (h, p) for h in sorted(RAGGED_HEADS) for p in ("decode", "chunk")]
     + [("gqa4", p) for p in ("cell64", "cell1024")])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ragged_span(v5e, dtype, heads, pack, kv):
+def test_ragged_span(v5e, dtype, heads, pack, kv, request):
     """The table of ISSUE 21 (bf16 x MHA, GQA x chunks and every int8
     case were MosaicErrors once) and the cells' own shapes.  Every
     descriptor is traced data, so one compile a budget covers any mix
@@ -147,8 +157,10 @@ def test_ragged_span(v5e, dtype, heads, pack, kv):
     each head's rows shifted out of the packed sublanes."""
     from paddle_tpu.ops.pallas_kernels import \
         _ragged_paged_attention_pallas
-    hkv, groups = HEADS[heads]
+    hkv, groups = RAGGED_HEADS[heads]
     quant = kv == "int8"
+    if quant and heads == "tp4":
+        request.applymarker(_TP4_INT8)
     tokens, n_spans, width, pages = RAGGED[pack]
     spans = ((n_spans,), jnp.int32)
     pool = ((pages, BLOCK, hkv, D), jnp.int8 if quant else dtype)
@@ -162,6 +174,30 @@ def test_ragged_span(v5e, dtype, heads, pack, kv):
             q, kc, vc, bt, qo, ql, kl, D ** -0.5,
             key_scale=ks, value_scale=vs)
     _compile(fn, v5e, *shapes)
+
+
+def _assert_no_pass_over_pools(eng, hlo, scopes):
+    """No pass over a pool: the only instructions of the optimized
+    program that produce or take a whole layer's pool are the donated
+    scatter of attn.kv_write (aliased in place), the ragged kernel that
+    reads its pages, and the plumbing that hands the buffers through —
+    no convert, no copy, no transpose of one."""
+    cache = eng.caches[0].key_cache
+    pool = "bf16[%s]" % ",".join(str(d) for d in cache.shape)
+    ops = set()
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if m and pool in line:
+            ops.add(m.group(2))
+    assert "custom-call" in ops
+    assert ops <= {"parameter", "tuple", "get-tuple-element", "bitcast",
+                   "fusion", "scatter", "custom-call",
+                   "dynamic-update-slice"}, ops
+    for line in hlo.splitlines():
+        if pool in line and " fusion(" in line:
+            # a fusion over a pool is the scatter that writes it
+            name = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+            assert scopes[name] == "attn.kv_write", line
 
 
 def test_mixed_step_full_width_two_layers(v5e):
@@ -200,26 +236,7 @@ def test_mixed_step_full_width_two_layers(v5e):
     assert {"attn.kernel", "attn.kv_write", "attn.qkv", "attn.out",
             "ffn", "lm_head", "embed"} <= set(scopes.values()) \
         <= STEP_SCOPES | {None}
-    # no pass over a pool: the only instructions of the optimized
-    # program that produce or take a whole layer's pool are the
-    # donated scatter of attn.kv_write (aliased in place), the ragged
-    # kernel that reads its pages, and the plumbing that hands the
-    # buffers through — no convert, no copy, no transpose of one
-    cache = eng.caches[0].key_cache
-    pool = "bf16[%s]" % ",".join(str(d) for d in cache.shape)
-    ops = set()
-    for line in hlo.splitlines():
-        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
-        if m and pool in line:
-            ops.add(m.group(2))
-    assert ops <= {"parameter", "tuple", "get-tuple-element", "bitcast",
-                   "fusion", "scatter", "custom-call",
-                   "dynamic-update-slice"}, ops
-    for line in hlo.splitlines():
-        if pool in line and " fusion(" in line:
-            # a fusion over a pool is the scatter that writes it
-            name = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
-            assert scopes[name] == "attn.kv_write", line
+    _assert_no_pass_over_pools(eng, hlo, scopes)
     mem = compiled.memory_analysis()
     # pools are donated: aliased, not copied
     assert mem.alias_size_in_bytes >= sum(
@@ -258,9 +275,12 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
     for layer in model.mixtral.layers:
         blk = layer.block_sparse_moe
         blk.w_gate._value, blk.w_up._value, blk.w_down._value = up, up, down
+    # pools of 2,048 pages (64 MiB each, as the MHA step's 512): a pool
+    # of 16 MiB is one the compiler may stage through VMEM whole, which
+    # no deployment's is
     eng = ContinuousBatchingEngine(
-        model, max_batch_size=SPANS, num_blocks=PAGES, block_size=BLOCK,
-        max_seq_len=WIDTH * BLOCK,
+        model, max_batch_size=SPANS, num_blocks=4 * PAGES,
+        block_size=BLOCK, max_seq_len=WIDTH * BLOCK,
         prefill_chunk_size=CHUNK, use_pallas=True)
     top = eng.token_budgets[-1]
     assert eng.mixed.n_stats == 3 + E
@@ -279,6 +299,9 @@ def test_mixed_step_mixtral_full_width_two_layers(v5e):
     assert {scopes[n] for n in grouped} == {"moe.experts"}
     # the buffers are gone: no instruction's shape leads with [E, N*k
     assert not re.search(r"\[%d,%d[,\]]" % (E, top * K), hlo)
+    # the grouped-query pools ([pages, 16, 8, 128]) pass through as
+    # stored, as the MHA step's do
+    _assert_no_pass_over_pools(eng, hlo, scopes)
 
 
 # the grouped expert product alone at the published widths of both MoE
