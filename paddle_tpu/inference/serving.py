@@ -68,6 +68,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..observability.compile_time import compile_seconds
 from ..observability.trace_merge import span_log
 from ..ops.paged_attention import PagedKVCache
 
@@ -907,18 +908,22 @@ class ContinuousBatchingEngine:
         launch; multi-engine callers key on the returned ids).
 
         The step records itself.  Under a profiler it is the span
-        ``engine.step`` (``step_num`` = its index) holding six
-        consecutive phase spans ``engine.admit | pack | fill | dispatch
-        | fetch | book`` on the device trace's own clock; and, unless
-        the engine was built with ``tracer=False``, it ends by writing
-        ONE ``serving.step`` record (``_write_step_record``) whose
-        ``step`` is the same index: the join between the two clocks."""
+        ``engine.step`` (``step_num`` = its index, ``engine`` = this
+        engine's id) holding seven consecutive phase spans
+        ``engine.admit | pack | fill | dispatch | launch | fetch | book``
+        on the device trace's own clock; and, unless the engine was
+        built with ``tracer=False``, it ends by writing ONE
+        ``serving.step`` record (``_write_step_record``) whose
+        ``engine`` and ``step`` are the same: the join between the two
+        clocks (``observability.step_clock``)."""
         t0 = time.perf_counter()
         self._rec = {"budget": 0, "n_dec": 0, "n_pre": 0, "spans": [],
-                     "compiled": False}
+                     "compiled": False, "trace_s": 0.0, "lower_s": 0.0,
+                     "compile_s": 0.0}
         try:
             with jax.profiler.StepTraceAnnotation(
-                    "engine.step", step_num=self._step_no):
+                    "engine.step", step_num=self._step_no,
+                    engine=self.engine_id):
                 self._phase("engine.admit")
                 self._admit()
                 self._rec["t_admit"] = self._phase("engine.pack")
@@ -948,7 +953,7 @@ class ContinuousBatchingEngine:
 
     def _phase(self, name: Optional[str]) -> float:
         """Close the step's open phase span and open ``name`` (``None``
-        opens none: the launch's two phases are ``MixedStep``'s own).
+        opens none: the launch's three phases are ``MixedStep``'s own).
         A step's phases are consecutive, so one instant ends one and
         starts the next; returns that instant."""
         if self._phase_ann is not None:
@@ -966,10 +971,12 @@ class ContinuousBatchingEngine:
         - ``engine``, ``step``: this engine's id in the process; the
           step's index from 0, counted by the engine (= the
           ``step_num`` of the profiler span ``engine.step``).
-        - ``t_admit``, ``t_pack``, ``t_fill``, ``t_dispatch``,
-          ``t_tokens``: phase boundaries.  ``_admit`` done; pages
-          grown, spans chosen and their tokens listed; the pack filled;
-          the jitted call has RETURNED (work enqueued); the sampled
+        - ``t_admit``, ``t_pack``, ``t_fill``, ``t_launch``,
+          ``t_dispatch``, ``t_tokens``: phase boundaries.  ``_admit``
+          done; pages grown, spans chosen and their tokens listed; the
+          pack filled; the launch's operands gathered and the jitted
+          call MADE; the jitted call has RETURNED (work enqueued); the
+          sampled
           token ids are on the host, which is where the wait for the
           device ends and the instant at which every token this step
           emits (``spans[:, 0]``) reached the host.  ``t_end`` closes
@@ -1010,18 +1017,24 @@ class ContinuousBatchingEngine:
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: the launch traced a module.
+        - ``trace_s``, ``lower_s``, ``compile_s``: the seconds the
+          launch spent tracing jaxprs, lowering them to MLIR, and
+          compiling or loading from the compilation cache
+          (``observability.compile_seconds`` across the launch); 0.0 on
+          every step that built nothing.
 
         The speculative round fills the same fields with what it has:
-        the draft launches count as ``pack`` and ``n_dec`` counts
-        verify spans (``q_len`` k+1).
+        the draft launches count as ``pack``, ``n_dec`` counts verify
+        spans (``q_len`` k+1), and the build seconds are the verify
+        launch's, as ``compiled`` is.
 
         Bound: ``span_log`` keeps its newest 16,384 entries, from every
         writer (eight minutes of 30 ms steps); ``spans`` is one array,
         so a full 64-slot record stays under 1 KB."""
         rec = self._rec
         bounds, t = {}, t_end
-        for key in ("t_tokens", "t_dispatch", "t_fill", "t_pack",
-                    "t_admit"):
+        for key in ("t_tokens", "t_dispatch", "t_launch", "t_fill",
+                    "t_pack", "t_admit"):
             t = bounds[key] = rec.get(key, t)
         spans = np.asarray(rec["spans"], np.int32).reshape(-1, 3)
         blocks, masked = self.mixed.attn_blocks(spans[:, 1], spans[:, 2])
@@ -1037,7 +1050,17 @@ class ContinuousBatchingEngine:
             moe_rows_top=rec.get("moe_rows_top", 0),
             moe_tiles=rec.get("moe_tiles", 0),
             admitted=tuple(self._admitted), running=running,
-            waiting=len(self.waiting), compiled=rec["compiled"])
+            waiting=len(self.waiting), compiled=rec["compiled"],
+            trace_s=rec["trace_s"], lower_s=rec["lower_s"],
+            compile_s=rec["compile_s"])
+
+    @staticmethod
+    def _record_build(rec: dict, before: Dict[str, float]):
+        """The launch's own build seconds into the record: what
+        ``compile_seconds`` grew by since ``before``."""
+        after = compile_seconds()
+        for phase in ("trace", "lower", "compile"):
+            rec[phase + "_s"] = after[phase] - before[phase]
 
     def run_to_completion(self) -> Dict[int, List[int]]:
         while self.has_work():
@@ -1701,9 +1724,12 @@ class ContinuousBatchingEngine:
 
         rec["t_fill"] = t0 = self._phase(None)
         pre = self.mixed.total_compiles
+        built = compile_seconds()
         nxt = self.mixed.call_packed(pack, B)
         rec["t_tokens"] = t1 = self._phase("engine.book")
         rec["t_dispatch"] = self.mixed.t_dispatch
+        rec["t_launch"] = self.mixed.t_launch
+        self._record_build(rec, built)
         traced = self.mixed.total_compiles - pre
         dt = t1 - t0
         if self.tp is not None:
@@ -1945,9 +1971,12 @@ class ContinuousBatchingEngine:
 
         rec["t_fill"] = t0 = self._phase(None)
         pre = self.mixed.total_compiles
+        built = compile_seconds()
         nxt, n_acc = self.mixed.call_packed(pack, B, q_probs=q_probs)
         rec["t_tokens"] = t1 = self._phase("engine.book")
         rec["t_dispatch"] = self.mixed.t_dispatch
+        rec["t_launch"] = self.mixed.t_launch
+        self._record_build(rec, built)
         traced = self.mixed.total_compiles - pre
         dt = t1 - t0
         n_pre = sum(size for _, size, _ in chunk_spans)

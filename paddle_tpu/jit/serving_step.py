@@ -949,8 +949,10 @@ class MixedStep:
         self._param_tensors = dict(model.state_dict())
         self._fns = {}                 # token budget -> jitted step
         self.compile_counts = {}       # token budget -> trace count
-        # perf_counter instant at which the last call_packed's jitted
-        # call RETURNED (work enqueued, nothing fetched yet)
+        # perf_counter instants at which the last call_packed's jitted
+        # call was MADE (operands gathered) and RETURNED (work enqueued,
+        # nothing fetched yet)
+        self.t_launch = 0.0
         self.t_dispatch = 0.0
 
     @property
@@ -1493,6 +1495,10 @@ class MixedStep:
                         "sampled speculative verify needs the draft's "
                         "q_probs tuple (zeros when no span drafts)")
                 args.append(tuple(q_probs))
+        # the operands are gathered: what follows is the jitted call
+        # alone (the step record's t_launch .. t_dispatch)
+        self.t_launch = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.launch"):
             out = fn(*args, kcs, vcs, kss, vss)
             n_out = 2 if self.spec_k or self.return_probs else 1
             _rebind_caches(self.caches, *out[n_out:])

@@ -7,7 +7,10 @@ Counter/Gauge/Histogram instruments, per-step :class:`StepTelemetry`
 live/peak HBM, NaN/Inf loss sentinel), Prometheus text / HTTP
 (`/metrics`, `/healthz`) / JSON exporters, and a merger folding host
 ``RecordEvent`` spans, runtime step/checkpoint/comm markers and the
-``jax.profiler`` device trace into one chrome://tracing JSON.
+``jax.profiler`` device trace into one chrome://tracing JSON (on the
+profiler's clock where the serving steps join the two:
+:func:`step_clock`), and the seconds the process spent building XLA
+programs (:func:`compile_seconds`).
 
 Every built-in subsystem records into :func:`default_registry`:
 
@@ -52,7 +55,9 @@ from .telemetry import (StepTelemetry, device_peak_flops,
                         PEAK_FLOPS_BY_KIND, CHECK_NAN_ENV,
                         PEAK_FLOPS_ENV)
 from .trace_merge import (SpanLog, span_log, record_span, record_instant,
-                          merge_chrome_trace, load_device_trace_events)
+                          merge_chrome_trace, load_device_trace_events,
+                          step_clock)
+from .compile_time import compile_seconds
 from .request_trace import (RequestTracer, NullRequestTracer,
                             NULL_TRACER, resolve_tracer,
                             LatencyReservoir, validate_span_chain,
@@ -72,7 +77,8 @@ __all__ = [
     "StepTelemetry", "device_peak_flops", "PEAK_FLOPS_BY_KIND",
     "CHECK_NAN_ENV", "PEAK_FLOPS_ENV",
     "SpanLog", "span_log", "record_span", "record_instant",
-    "merge_chrome_trace", "load_device_trace_events",
+    "merge_chrome_trace", "load_device_trace_events", "step_clock",
+    "compile_seconds",
     "RequestTracer", "NullRequestTracer", "NULL_TRACER",
     "resolve_tracer", "LatencyReservoir", "validate_span_chain",
     "fleet_trace",

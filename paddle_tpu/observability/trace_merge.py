@@ -10,10 +10,15 @@ folds all three into ONE chrome://tracing JSON so a single load shows
 the train loop, the checkpoint writer and the XLA device activity
 side by side.
 
-Clock domains: host spans are ``time.perf_counter`` based, the device
-trace has its own epoch; each source is shifted so its earliest event
-sits at t=0 (alignment at trace start — sub-trace ordering is exact,
-cross-trace skew is bounded by the capture window).
+Clock domains: host spans, the span log and caller groups share
+``time.perf_counter``; the profiler's trace has a clock of its own.  Where
+the span log holds ``serving.step`` records and the trace holds the
+``engine.step`` events of the same steps, :func:`step_clock` measures the
+offset between the two clocks and every perf_counter source is put on
+the trace's timeline by it: a step record lands on its profiler span.
+Where nothing joins them (a host-only run, a trace without serving
+steps), the perf_counter sources start at t=0 together and the trace at
+its own t=0, and how far the two timelines lie apart is not known.
 """
 from __future__ import annotations
 
@@ -22,14 +27,22 @@ import glob
 import gzip
 import json
 import os
+import statistics
 import threading
 import time
 from typing import Dict, Iterable, List, Optional
 
 __all__ = ["SpanLog", "span_log", "record_span", "record_instant",
-           "load_device_trace_events", "merge_chrome_trace"]
+           "load_device_trace_events", "merge_chrome_trace", "step_clock"]
 
 _SPAN_LOG_CAP = 16384
+# the serving step: its record in the span log
+# (``inference.serving.STEP_SPAN``), its span in the profiler's trace, and
+# the device line whose events are whole executions of its module
+STEP_RECORD = "serving.step"
+STEP_EVENT = "engine.step"
+MODULES_LINE = "XLA Modules"
+STEP_MODULE = "mixed_step"
 
 
 class SpanLog:
@@ -180,18 +193,21 @@ def _span_log_events_json(entries, pid: int,
     return out
 
 
-def _device_events_json(events: List[dict], pid_base: int) -> List[dict]:
+def _device_events_json(events: List[dict], pid_base: int,
+                        ts0: Optional[float] = None) -> List[dict]:
     """Re-base the device trace: pids offset so they never collide with
-    the host groups, timestamps shifted to t=0 at the earliest event."""
+    the host groups, timestamps shifted by ``ts0`` (µs; by default the
+    earliest event's, which puts it at t=0)."""
     if not events:
         return []
-    ts_vals = []
-    for e in events:
-        try:
-            ts_vals.append(float(e["ts"]))
-        except (KeyError, TypeError, ValueError):
-            pass
-    ts0 = min(ts_vals) if ts_vals else 0.0   # metadata-only trace: keep
+    if ts0 is None:
+        ts_vals = []
+        for e in events:
+            try:
+                ts_vals.append(float(e["ts"]))
+            except (KeyError, TypeError, ValueError):
+                pass
+        ts0 = min(ts_vals) if ts_vals else 0.0  # metadata-only: keep
     out = []
     for e in events:
         ev = dict(e)
@@ -237,6 +253,113 @@ def _extra_group_json(name: str, group_events: List[dict], pid: int,
     return out
 
 
+def _step_records(records) -> List[dict]:
+    """``serving.step`` records as dicts of their fields with ``t0`` and
+    ``t_end`` beside them; span-log entries are converted, dicts kept."""
+    out = []
+    for r in records:
+        if isinstance(r, dict):
+            out.append(r)
+        elif r[1] == STEP_RECORD:
+            out.append(dict(r[5], t0=r[3], t_end=r[4]))
+    return out
+
+
+def _device_modules(trace_events: List[dict]) -> List[tuple]:
+    """``(start, end)`` seconds of every execution of the serving step's
+    module on the trace's first device, in order; none without a device
+    plane (the CPU)."""
+    procs, lines = {}, {}
+    for e in trace_events:
+        if e.get("ph") != "M":
+            continue
+        name = (e.get("args") or {}).get("name", "")
+        if e.get("name") == "process_name":
+            procs[e.get("pid")] = name
+        elif e.get("name") == "thread_name":
+            lines[(e.get("pid"), e.get("tid"))] = name
+    devices = sorted((name, pid) for pid, name in procs.items()
+                     if str(name).startswith("/device:"))
+    if not devices:
+        return []
+    pid = devices[0][1]
+    return sorted(
+        (float(e["ts"]) * 1e-6, (float(e["ts"]) + float(e["dur"])) * 1e-6)
+        for e in trace_events
+        if e.get("ph") == "X" and e.get("pid") == pid
+        and lines.get((pid, e.get("tid"))) == MODULES_LINE
+        and STEP_MODULE in str(e.get("name", "")))
+
+
+def step_clock(trace_events: List[dict], records) -> Optional[dict]:
+    """One clock for the step records, the profiler's host spans and the
+    device plane.
+
+    ``trace_events``: the profiler's chrome trace events
+    (:func:`load_device_trace_events`); ``records``: ``serving.step``
+    records, as span-log entries or as dicts with ``t0`` / ``t_end``.
+    Each record is paired with the ``engine.step`` event of the same
+    engine and ``step_num``.  Returns ``None`` where none pairs, else:
+
+    - ``offset_s``: trace time minus ``perf_counter``, the median over
+      the paired steps (a record's ``t0`` against its span's start), and
+      ``offset_spread_s`` its third quartile less its first;
+    - ``lead_s``: ``[lo, hi]``, the causal bounds of the lead L of the
+      device plane's clock over the host's, with the host's instants put
+      on the trace's clock by ``offset_s``.  A step's module cannot end
+      on the device after its tokens reached the host, so L >= max over
+      steps of (module end - ``t_tokens``); it cannot start before its
+      launch call began, so L <= min over steps of (module start -
+      ``t_launch``).  ``lead_width_s`` is hi - lo.  The launched steps
+      pair in order with the executions of the step's module on the
+      first device (``launches`` of them); where the counts differ, or
+      there is no device plane, the three are ``None``.
+    """
+    recs = {(r.get("engine"), r.get("step")): r
+            for r in _step_records(records)}
+    spans = {}
+    for e in trace_events:
+        if e.get("name") != STEP_EVENT or e.get("ph") != "X":
+            continue
+        args = e.get("args") or {}
+        try:
+            key = (int(args["engine"]), int(args["step_num"]))
+        except (KeyError, TypeError, ValueError):
+            continue
+        spans.setdefault(key, []).append(float(e["ts"]) * 1e-6)
+    # a step seen twice (two captures in one directory) pairs with neither
+    paired = [(ts[0], recs[key]) for key, ts in spans.items()
+              if key in recs and len(ts) == 1]
+    if not paired:
+        return None
+    offsets = sorted(ts - r["t0"] for ts, r in paired)
+    q = (statistics.quantiles(offsets, n=4) if len(offsets) > 1
+         else [offsets[0]] * 3)
+    out = {"steps": len(paired), "offset_s": statistics.median(offsets),
+           "offset_spread_s": q[2] - q[0],
+           "launches": 0, "lead_s": None, "lead_width_s": None}
+    launched = sorted((r for _, r in paired if r.get("budget")),
+                      key=lambda r: r["t_launch"])
+    modules = _device_modules(trace_events)
+    if launched and len(modules) == len(launched):
+        off = out["offset_s"]
+        lo = max(end - (r["t_tokens"] + off)
+                 for (_, end), r in zip(modules, launched))
+        hi = min(start - (r["t_launch"] + off)
+                 for (start, _), r in zip(modules, launched))
+        out.update(launches=len(launched), lead_s=[lo, hi],
+                   lead_width_s=hi - lo)
+    return out
+
+
+def _plain(value):
+    """JSON for a numpy value in a span's args (a step record's
+    ``spans`` is an int32 array)."""
+    if hasattr(value, "tolist"):
+        return value.tolist()
+    raise TypeError(f"{type(value).__name__} is not JSON serializable")
+
+
 def merge_chrome_trace(path: str, host_events=None,
                        runtime_events=None,
                        device_trace_dir: Optional[str] = None,
@@ -249,7 +372,10 @@ def merge_chrome_trace(path: str, host_events=None,
     runtime_events: a :class:`SpanLog` / its ``events()`` list; defaults
     to the process-wide :data:`span_log`.
     device_trace_dir: a ``jax.profiler`` trace dir; missing/empty dirs
-    degrade to a host-only trace (the device-less CPU contract).
+    degrade to a host-only trace (the device-less CPU contract).  Where
+    its ``engine.step`` events pair with ``serving.step`` records in
+    ``runtime_events`` (:func:`step_clock`), every perf_counter source is
+    put on the trace's clock; otherwise each clock starts at its own t=0.
     extra_groups: ``[(process_name, chrome_event_dicts)]`` — additional
     track groups (one pid each) whose ``ts``/``dur`` are ABSOLUTE
     perf_counter seconds on the same clock as the host/runtime spans;
@@ -270,21 +396,27 @@ def merge_chrome_trace(path: str, host_events=None,
     runtime_events = list(runtime_events or [])
     extra_groups = [(str(n), list(evs or []))
                     for n, evs in (extra_groups or [])]
+    device = load_device_trace_events(device_trace_dir)
     # host spans, runtime spans and extra groups share the perf_counter
     # clock: ONE t0 across all of them, or a checkpoint 45s into the
-    # profile would render at t=0 next to the first host span
-    starts = [e.start for e in host_events] \
-        + [e[3] for e in runtime_events]
-    for _name, evs in extra_groups:
-        starts += [float(e["ts"]) for e in evs if "ts" in e]
-    t0 = min(starts) if starts else None
+    # profile would render at t=0 next to the first host span.  Where the
+    # serving steps join that clock to the trace's, t0 is the trace's own
+    # zero and the trace keeps its timestamps
+    clock = step_clock(device, runtime_events) if device else None
+    if clock is not None:
+        t0, ts0 = -clock["offset_s"], 0.0
+    else:
+        starts = [e.start for e in host_events] \
+            + [e[3] for e in runtime_events]
+        for _name, evs in extra_groups:
+            starts += [float(e["ts"]) for e in evs if "ts" in e]
+        t0, ts0 = (min(starts) if starts else None), None
     events: List[dict] = []
     events.extend(_host_events_json(host_events, pid, t0))
     events.extend(_span_log_events_json(runtime_events, pid + 1, t0))
     for i, (name, evs) in enumerate(extra_groups):
         events.extend(_extra_group_json(name, evs, pid + 2 + i, t0))
-    events.extend(_device_events_json(
-        load_device_trace_events(device_trace_dir), 1_000_000))
+    events.extend(_device_events_json(device, 1_000_000, ts0))
     # deterministic serialization: spans by (ts, pid, tid, name) —
     # ties included — then metadata (tools that peek at traceEvents[0]
     # must still see a real span)
@@ -313,5 +445,5 @@ def merge_chrome_trace(path: str, host_events=None,
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
     with open(path, "w") as f:
-        json.dump(out, f)
+        json.dump(out, f, default=_plain)
     return path

@@ -397,6 +397,50 @@ def test_merge_chrome_trace_with_runtime_spans(tmp_path):
     assert abs((span["ts"] - host_ev["ts"]) - 2.0 * 1e6) < 1e3
 
 
+def test_merge_chrome_trace_lands_a_step_on_its_span(tmp_path):
+    """Where the span log's step records pair with the profiler's
+    ``engine.step`` spans, the merged file has ONE clock, the trace's:
+    each ``serving.step`` record lies on its step's span, and the trace
+    keeps its own timestamps."""
+    import jax
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
+    from paddle_tpu.observability import load_device_trace_events, span_log
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_tiny_config(
+        num_hidden_layers=1, hidden_size=32, num_attention_heads=2,
+        num_key_value_heads=1, vocab_size=64, intermediate_size=64))
+    model.eval()
+    eng = ContinuousBatchingEngine(model, max_batch_size=2, num_blocks=16,
+                                   block_size=4, prefill_chunk_size=4)
+    eng.add_request(np.arange(1, 8, dtype=np.int64), 5)
+    eng.step()                                   # compiles outside the trace
+    trace_dir = str(tmp_path / "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        while eng.has_work():
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    out = merge_chrome_trace(str(tmp_path / "merged.json"),
+                             runtime_events=span_log,
+                             device_trace_dir=trace_dir)
+    events = json.load(open(out))["traceEvents"]
+    spans = {(int(e["args"]["engine"]), int(e["args"]["step_num"])):
+             e["ts"] for e in events if e.get("name") == "engine.step"}
+    landed = [(e["ts"], spans[(e["args"]["engine"], e["args"]["step"])])
+              for e in events if e.get("name") == "serving.step"
+              and (e["args"]["engine"], e["args"]["step"]) in spans]
+    assert len(landed) == len(spans) >= 3
+    assert all(abs(rec - span) < 200 for rec, span in landed)
+    # the profiler's events keep the trace's own timestamps
+    raw = sorted(e["ts"] for e in load_device_trace_events(trace_dir)
+                 if e.get("name") == "engine.step")
+    assert sorted(spans.values()) == pytest.approx(raw)
+
+
 # ---------------------------------------------------------------------------
 # checkpoint failure counter under fault injection
 # ---------------------------------------------------------------------------

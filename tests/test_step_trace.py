@@ -3,8 +3,10 @@
 Every ``ContinuousBatchingEngine.step()`` writes ONE ``serving.step``
 record to the process-wide ``observability.span_log`` (unless the engine
 was built with ``tracer=False``), is one ``engine.step`` profiler span
-holding six consecutive phase spans, and its requests' tracer entries
-name the step that caused them.  Inside the compiled ``MixedStep`` every
+holding seven consecutive phase spans, and its requests' tracer entries
+name the step that caused them.  The record counts the seconds its launch
+spent building a module, and ``observability.step_clock`` joins it to
+the profiler's clock.  Inside the compiled ``MixedStep`` every
 part of the body sits under a ``jax.named_scope`` that the optimized
 HLO keeps as metadata, so ``MixedStep.op_scopes`` can say which part a
 device op belongs to; every Pallas kernel and every jitted step has a
@@ -24,14 +26,18 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.serving import (STEP_SPAN,
                                           ContinuousBatchingEngine)
 from paddle_tpu.jit.serving_step import STEP_SCOPES
-from paddle_tpu.observability import default_registry, span_log
+from paddle_tpu.observability import (compile_seconds, default_registry,
+                                      span_log, step_clock)
 
 PROMPTS = [np.array([7, 9, 2], np.int64),
            np.array([3, 14, 15, 92, 65], np.int64),
            np.arange(1, 11, dtype=np.int64)]     # 10 tokens: chunked
-BOUNDS = ("t_admit", "t_pack", "t_fill", "t_dispatch", "t_tokens")
+BOUNDS = ("t_admit", "t_pack", "t_fill", "t_launch", "t_dispatch",
+          "t_tokens")
 PHASES = ("engine.admit", "engine.pack", "engine.fill",
-          "engine.dispatch", "engine.fetch", "engine.book")
+          "engine.dispatch", "engine.launch", "engine.fetch",
+          "engine.book")
+BUILD = ("trace_s", "lower_s", "compile_s")
 
 
 def _model(family):
@@ -235,21 +241,29 @@ def test_speculative_round_writes_the_record():
     assert sorted(r for _, _, f in recs for r in f["admitted"]) == rids
 
 
+def _profile(eng, steps, trace_dir):
+    """Step ``eng`` ``steps`` times under the profiler, as the benchmark
+    traces it; returns the first traced step's index."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        first = eng._step_no
+        for _ in range(steps):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    return first
+
+
 def test_profiler_sees_the_step_and_its_six_phases(tmp_path):
+    """(Seven since the launch call became a phase of its own.)"""
     from jax.profiler import ProfileData
     eng = _engine()
     eng.add_request(PROMPTS[2], 6)
     eng.step()                                   # compiles outside the trace
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 2
-    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
-    try:
-        first = eng._step_no
-        for _ in range(3):
-            eng.step()
-    finally:
-        jax.profiler.stop_trace()
+    first = _profile(eng, 3, str(tmp_path))
     path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
                                   "*", "*.xplane.pb"))[0]
     events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
@@ -260,12 +274,161 @@ def test_profiler_sees_the_step_and_its_six_phases(tmp_path):
     steps = sorted(e for e in events if e[0] == "engine.step")
     assert [e[3]["step_num"] for e in steps] == [first, first + 1,
                                                  first + 2]
+    assert {e[3]["engine"] for e in steps} == {eng.engine_id}
     for _, s, e, _ in steps:
         inside = sorted((a, b, n) for n, a, b, _ in events
                         if n != "engine.step" and s <= a and b <= e)
         assert tuple(n for _, _, n in inside) == PHASES
         for (_, b0, _), (a1, _, _) in zip(inside, inside[1:]):
             assert b0 <= a1                       # consecutive, not nested
+
+
+@pytest.mark.parametrize("round_", ["mixed", "speculative"])
+def test_the_record_counts_its_own_build_seconds(round_):
+    """Trace, lowering and compile seconds of the launch: above 0 on
+    exactly the steps that traced a module, 0.0 on every other."""
+    if round_ == "speculative":
+        from paddle_tpu.models.llama import llama_truncated_draft
+        model = _model("llama")
+        eng = ContinuousBatchingEngine(
+            model, max_batch_size=4, num_blocks=64, block_size=4,
+            prefill_chunk_size=4,
+            draft_model=llama_truncated_draft(model, 1), spec_k=2)
+    else:
+        eng = _engine()
+    before = compile_seconds()
+    _drive(eng)
+    recs = [f for _, _, f in _records(eng)]
+    assert any(f["compiled"] for f in recs)
+    assert any(f["budget"] and not f["compiled"] for f in recs)
+    for f in recs:
+        built = [f[k] for k in BUILD]
+        if f["compiled"]:
+            assert min(built) >= 0.0 and sum(built) > 0, f
+        else:
+            assert built == [0.0, 0.0, 0.0], f
+    # the process-wide counter grew by at least what the launches built
+    # (the draft's modules too, which its round's record leaves out)
+    after = compile_seconds()
+    grown = sum(after[k] - before[k] for k in ("trace", "lower", "compile"))
+    assert grown >= sum(f[k] for f in recs for k in BUILD) - 1e-9
+
+
+def test_compile_seconds_counts_a_nested_trace_once():
+    """A jitted function that calls another reports the inner trace and
+    then the outer one that holds it: the outer's seconds are counted,
+    the inner's not again."""
+    @jax.jit
+    def inner(x):
+        return x * 2
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + 1
+
+    from paddle_tpu.observability.compile_time import COMPILE_EVENTS
+    seen = []
+
+    def spy(event, duration, **_):
+        if COMPILE_EVENTS.get(event) == "trace":
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(spy)
+    try:
+        before = compile_seconds()["trace"]
+        outer(np.ones(3, np.float32))
+        traced = compile_seconds()["trace"] - before
+    finally:
+        jax.monitoring.unregister_event_duration_listener(spy)
+    assert len(seen) >= 2                 # inner first, then outer
+    assert traced == pytest.approx(max(seen))
+
+
+def test_step_clock_joins_a_cpu_profile(tmp_path):
+    """Each record pairs with the profiler's span of its engine and step;
+    the offset between the two clocks holds to well under a step.  The
+    CPU's trace has no device plane: no lead."""
+    eng = _engine()
+    for p in PROMPTS:
+        eng.add_request(p, 6)
+    eng.step()                                   # compiles outside the trace
+    first = _profile(eng, 6, str(tmp_path))
+    other = _engine()                            # the same step numbers
+    other.add_request(PROMPTS[2], 12)
+    other.run_to_completion()
+    assert len(_records(other)) > first + 6
+    from paddle_tpu.observability import load_device_trace_events
+    clock = step_clock(load_device_trace_events(str(tmp_path)),
+                       span_log.events())
+    assert clock["steps"] == 6
+    assert 0 <= clock["offset_spread_s"] < 200e-6
+    assert clock["lead_s"] is None and clock["launches"] == 0
+    # two captures in one directory would repeat every step: none of
+    # them pairs, and nothing joins
+    assert step_clock(load_device_trace_events(str(tmp_path)) * 2,
+                      span_log.events()) is None
+
+
+def _synthetic_trace(records, offset, lead, run=0.004):
+    """A chrome trace of ``records`` as the profiler writes one on a
+    chip: the host's ``engine.step`` spans ``offset`` seconds ahead of
+    ``perf_counter``, and on the device one execution of the step's
+    module a launched step, ``run`` seconds long, starting 0.5 ms after
+    its launch call, on a clock ``lead`` seconds ahead of the host's."""
+    us = 1e6
+    events = [{"ph": "M", "pid": 7, "name": "process_name",
+               "args": {"name": "/device:TPU:0"}},
+              {"ph": "M", "pid": 7, "tid": 2, "name": "thread_name",
+               "args": {"name": "XLA Modules"}},
+              {"ph": "M", "pid": 7, "tid": 3, "name": "thread_name",
+               "args": {"name": "XLA Ops"}},
+              {"ph": "M", "pid": 9, "name": "process_name",
+               "args": {"name": "/host:CPU"}}]
+    for r in records:
+        events.append({"ph": "X", "pid": 9, "tid": 1, "name": "engine.step",
+                       "ts": (r["t0"] + offset) * us,
+                       "dur": (r["t_end"] - r["t0"]) * us,
+                       "args": {"step_num": str(r["step"]),
+                                "engine": str(r["engine"])}})
+        if r["budget"]:
+            start = r["t_launch"] + 0.0005 + offset + lead
+            for tid, name in ((2, "jit_mixed_step(17)"), (3, "fusion.3")):
+                events.append({"ph": "X", "pid": 7, "tid": tid,
+                               "name": name, "ts": start * us,
+                               "dur": run * us})
+    return events
+
+
+def test_step_clock_bounds_a_synthetic_device_plane():
+    """Built with a known offset and lead, the join returns the offset,
+    and bounds on the lead that hold it: the lower from the step that
+    reached the host soonest after its module ended, the upper from the
+    module that started soonest after its launch call."""
+    t, records = 100.0, []
+    for step, (budget, wait) in enumerate([(8, 0.0045), (0, 0.0),
+                                           (4, 0.0060), (8, 0.0047)]):
+        r = {"engine": 3, "step": step, "budget": budget, "t0": t,
+             "t_launch": t + 0.001, "t_tokens": t + 0.001 + wait,
+             "t_end": t + 0.002 + wait}
+        records.append(r)
+        t = r["t_end"] + 0.003
+    offset, lead = -12345.678, 0.0015
+    clock = step_clock(_synthetic_trace(records, offset, lead), records)
+    assert clock["steps"] == 4 and clock["launches"] == 3
+    assert clock["offset_s"] == pytest.approx(offset, abs=1e-6)
+    assert clock["offset_spread_s"] < 1e-6
+    lo, hi = clock["lead_s"]
+    # module end = launch + 4.5 ms + lead; the quickest fetch ended with it
+    assert lo == pytest.approx(lead, abs=1e-6)
+    assert hi == pytest.approx(lead + 0.0005, abs=1e-6)
+    assert clock["lead_width_s"] == pytest.approx(0.0005, abs=1e-6)
+    # another engine's records, or a device line the join cannot pair
+    # one to one with the launches, give nothing to bound
+    assert step_clock(_synthetic_trace(records, offset, lead),
+                      [dict(r, engine=4) for r in records]) is None
+    clock = step_clock(_synthetic_trace(records, offset, lead)[:-2],
+                       records)
+    assert clock["steps"] == 4 and clock["lead_s"] is None
 
 
 @pytest.mark.parametrize("family", ["llama", "mixtral"])
