@@ -55,6 +55,19 @@ compute, and the only per-step host traffic is the one packed int32
 operand in and the [slots] int32 next-token fetch out (a speculative
 round adds the k [slots] draft-token fetches and the verifier's [slots]
 accepted-count row — draft DISTRIBUTIONS stay on device).
+
+One launch stays in flight across ``step()`` calls: a call packs launch
+N+1 from the slots as they will stand once launch N is booked, enqueues
+it, and only then fetches and books N.  A decode token N samples reaches
+N+1 on the device (``MixedStep``'s src row), so the chip never waits
+for the host's bookkeeping, admission and packing between two steps.
+What a caller sees of a request (``output_ids``, ``prefill_pos``,
+``seq_len``, ``state``, the ids ``step()`` returns) changes only when
+the step that did the work is booked.  A path that changes slots from
+outside or needs every token on the host settles first (fetches and
+books the launch in flight): the speculative round, which never runs
+ahead, ``preempt_request``, ``extract_request``, ``inject_request`` and
+a lazy-alloc victim.
 """
 from __future__ import annotations
 
@@ -123,6 +136,16 @@ class GenerationRequest:
     t_submit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+
+
+@dataclass
+class _Launch:
+    """A launch enqueued and not booked yet: what booking it needs."""
+    spans: list          # (req, kind, size, start) in pack order
+    budget: int
+    t_fill: float        # its pack filled, the launch call about to begin
+    traced: bool         # it traced a module
+    step: int            # the step() call that launched it
 
 
 class ContinuousBatchingEngine:
@@ -634,6 +657,19 @@ class ContinuousBatchingEngine:
             self._m_mixed_span_tokens.labels(kind="decode")
         self._m_mixed_tok_prefill = \
             self._m_mixed_span_tokens.labels(kind="prefill")
+        self._m_launches = r.counter(
+            "serving_launches_total",
+            "fused mixed-step launches, by how they were enqueued "
+            "(ahead = while the launch before was still unfetched, so "
+            "the chip ran it while the host booked that one; settled = "
+            "with nothing in flight)", labels=("mode",))
+        self._m_launch_ahead = self._m_launches.labels(mode="ahead")
+        self._m_launch_settled = self._m_launches.labels(mode="settled")
+        self._m_discarded = r.counter(
+            "serving_discarded_rows_total",
+            "rows a launch computed for requests that had already ended "
+            "(on EOS in the launch before, still in flight when this "
+            "one was packed); dropped at booking")
         self._m_tp_degree = r.gauge(
             "serving_tp_degree",
             "tensor-parallel degree of the most recently constructed "
@@ -793,6 +829,10 @@ class ContinuousBatchingEngine:
         self._rec: Optional[Dict] = None
         self._admitted: List[int] = []
         self._phase_ann = None
+        # the launch enqueued and not booked yet (at most one), and the
+        # request ids finished since step() last returned
+        self._inflight: Optional[_Launch] = None
+        self._done_backlog: List[int] = []
         # per-ENGINE cumulative host counters (round 20): the
         # process-wide prometheus counters aggregate across every
         # engine in the process, so the capacity plane's per-engine
@@ -895,31 +935,39 @@ class ContinuousBatchingEngine:
         return ids[0] if n == 1 else ids
 
     def has_work(self) -> bool:
-        return bool(self.waiting) or any(s is not None
-                                         for s in self.slots)
+        return (bool(self.waiting) or self._inflight is not None
+                or bool(self._done_backlog)
+                or any(s is not None for s in self.slots))
 
     def step(self) -> List[int]:
-        """Admit waiting requests, then advance the engine one round:
-        every running slot's decode token AND as many pending prefill
-        chunks as the token budget holds, packed into one fused launch.
-        Returns the req_ids finished this step (a one-token budget or
-        EOS on the first sampled token ends a request in the step that
-        prefilled it, and a lazy-alloc victim is truncated before the
-        launch; multi-engine callers key on the returned ids).
+        """Admit waiting requests, enqueue the next round — every slot's
+        decode token AND as many pending prefill chunks as the token
+        budget holds, packed into one fused launch from the slots as
+        they will stand once the launch in flight is booked — then
+        fetch and book the launch in flight.  Returns the req_ids
+        finished since the last call: by the step booked here (its EOS
+        or its last token; a one-token budget or EOS on the first
+        sampled token ends a request in the step that prefilled it), a
+        lazy-alloc victim truncated before the launch, or a settle
+        outside ``step()``.  Multi-engine callers key on the returned
+        ids.
 
         The step records itself.  Under a profiler it is the span
         ``engine.step`` (``step_num`` = its index, ``engine`` = this
         engine's id) holding seven consecutive phase spans
         ``engine.admit | pack | fill | dispatch | launch | fetch | book``
-        on the device trace's own clock; and, unless the engine was
-        built with ``tracer=False``, it ends by writing ONE
-        ``serving.step`` record (``_write_step_record``) whose
-        ``engine`` and ``step`` are the same: the join between the two
-        clocks (``observability.step_clock``)."""
+        on the device trace's own clock (a call that books nothing has
+        no fetch or book, one that launches nothing no dispatch or
+        launch); and, unless the engine was built with
+        ``tracer=False``, it ends by writing ONE ``serving.step``
+        record (``_write_step_record``) whose ``engine`` and ``step``
+        are the same: the join between the two clocks
+        (``observability.step_clock``)."""
         t0 = time.perf_counter()
         self._rec = {"budget": 0, "n_dec": 0, "n_pre": 0, "spans": [],
                      "compiled": False, "trace_s": 0.0, "lower_s": 0.0,
-                     "compile_s": 0.0}
+                     "compile_s": 0.0, "ahead": 0, "discarded": 0,
+                     "launch_step": -1}
         try:
             with jax.profiler.StepTraceAnnotation(
                     "engine.step", step_num=self._step_no,
@@ -927,7 +975,7 @@ class ContinuousBatchingEngine:
                 self._phase("engine.admit")
                 self._admit()
                 self._rec["t_admit"] = self._phase("engine.pack")
-                done = self._run_mixed_step()
+                self._run_mixed_step()
                 running = sum(s is not None for s in self.slots)
                 self._m_queue.set(len(self.waiting))
                 self._m_occupancy.set(
@@ -949,6 +997,7 @@ class ContinuousBatchingEngine:
             self._rec = None
             self._admitted = []
             self._step_no += 1
+        done, self._done_backlog = self._done_backlog, []
         return done
 
     def _phase(self, name: Optional[str]) -> float:
@@ -971,23 +1020,38 @@ class ContinuousBatchingEngine:
         - ``engine``, ``step``: this engine's id in the process; the
           step's index from 0, counted by the engine (= the
           ``step_num`` of the profiler span ``engine.step``).
+        The work fields describe the step this call BOOKED: the launch
+        in flight when it began (made by the call before), or nothing
+        (the first launch of a run, or after a settle).  The boundaries
+        are this call's own phases and never decrease.
+
         - ``t_admit``, ``t_pack``, ``t_fill``, ``t_launch``,
           ``t_dispatch``, ``t_tokens``: phase boundaries.  ``_admit``
           done; pages grown, spans chosen and their tokens listed; the
-          pack filled; the launch's operands gathered and the jitted
-          call MADE; the jitted call has RETURNED (work enqueued); the
-          sampled
-          token ids are on the host, which is where the wait for the
-          device ends and the instant at which every token this step
-          emits (``spans[:, 0]``) reached the host.  ``t_end`` closes
-          the bookkeeping.  A step that launched nothing has its
-          missing boundaries at the next one it has.
-        - ``budget``, ``tokens``, ``n_dec``, ``n_pre``: the padded
-          token budget launched (0: nothing launched), the real tokens
-          in the pack, its decode spans, its prefill tokens.
+          pack filled (where nothing is launched: the fetch begins);
+          this call's launch's operands gathered and the jitted call
+          MADE; the jitted call has RETURNED (work enqueued); the
+          booked step's sampled token ids are on the host, which is
+          where the wait for the device ends and the instant at which
+          every token it emits (``spans[:, 0]``) reached the host.
+          ``t_end`` closes the bookkeeping.  A boundary the call did
+          not pass (no launch, nothing booked, or a booking that a
+          lazy-alloc victim forced before the pack) sits at the next
+          one it has.
+        - ``budget``, ``tokens``, ``n_dec``, ``n_pre``: the booked
+          step's padded token budget (0: nothing booked), the real
+          tokens it carried, its decode spans, its prefill tokens.
         - ``spans``: int32 ``[n, 3]`` rows ``(req_id, q_len, kv_len)``
-          in pack order, as packed.  A prefix-cache hit is not in it:
-          it was never computed.
+          of the booked step in pack order, as packed.  A prefix-cache
+          hit is not in it: it was never computed.  Nor is a
+          ``discarded`` row.
+        - ``ahead``: 1 where this call's launch was enqueued while an
+          earlier launch was still unfetched.
+        - ``discarded``: the booked step's rows computed for requests
+          that had already ended (on EOS in the step before, which was
+          still in flight when this one was packed).
+        - ``launch_step``: the ``step`` of the call that launched the
+          booked step (-1: nothing booked).
         - ``attn_rows``: the q rows per kv head that the mixed launch's
           attention computes in each layer (``MixedStep.attn_rows``):
           beside ``tokens`` x the GQA group size it says how much of
@@ -1016,17 +1080,20 @@ class ContinuousBatchingEngine:
           either lowering; the Pallas launch visits exactly these.
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
-          the step's end.  ``compiled``: the launch traced a module.
-        - ``trace_s``, ``lower_s``, ``compile_s``: the seconds the
-          launch spent tracing jaxprs, lowering them to MLIR, and
-          compiling or loading from the compilation cache
+          the step's end.  ``compiled``: this call's launch traced a
+          module.
+        - ``trace_s``, ``lower_s``, ``compile_s``: the seconds this
+          call's launch spent tracing jaxprs, lowering them to MLIR,
+          and compiling or loading from the compilation cache
           (``observability.compile_seconds`` across the launch); 0.0 on
           every step that built nothing.
 
-        The speculative round fills the same fields with what it has:
-        the draft launches count as ``pack``, ``n_dec`` counts verify
-        spans (``q_len`` k+1), and the build seconds are the verify
-        launch's, as ``compiled`` is.
+        The speculative round never runs ahead: it books its own launch,
+        and fills the same fields with what it has: the draft launches
+        count as ``pack``, ``n_dec`` counts verify spans (``q_len``
+        k+1), and the build seconds are the verify launch's, as
+        ``compiled`` is.  A launch that a settle outside ``step()``
+        books (preempt, extract, inject) is in no record.
 
         Bound: ``span_log`` keeps its newest 16,384 entries, from every
         writer (eight minutes of 30 ms steps); ``spans`` is one array,
@@ -1049,6 +1116,8 @@ class ContinuousBatchingEngine:
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
             moe_tiles=rec.get("moe_tiles", 0),
+            ahead=rec["ahead"], discarded=rec["discarded"],
+            launch_step=rec["launch_step"],
             admitted=tuple(self._admitted), running=running,
             waiting=len(self.waiting), compiled=rec["compiled"],
             trace_s=rec["trace_s"], lower_s=rec["lower_s"],
@@ -1086,7 +1155,10 @@ class ContinuousBatchingEngine:
         per-request state).  The request is NOT finished: no outcome
         counter fires, nothing lands in ``finished``.  Raises KeyError
         when ``req_id`` is neither waiting nor on a slot (already
-        finished requests are not preemptible)."""
+        finished requests are not preemptible).  The launch in flight
+        is booked first (``_settle``), so the generated ids are every
+        token the engine has computed."""
+        self._settle()
         for i, r in enumerate(self.waiting):
             if r.req_id == req_id:
                 self.waiting.pop(i)
@@ -1138,6 +1210,7 @@ class ContinuousBatchingEngine:
         parallel pools are head-sharded; a speculative engine's draft
         KV cannot travel) — the caller then falls back to the r15
         re-prefill resume."""
+        self._settle()
         buf = None
         if self.migration_geometry() is not None:
             for r in self.slots:
@@ -1233,6 +1306,9 @@ class ContinuousBatchingEngine:
                 "the resume prompt has %d — a migrated request resumes "
                 "with exactly its last token pending (n_tokens == "
                 "len(prompt_ids) - 1)" % (buffer.n_tokens, L))
+        # slots and pages as booked: what the launch in flight frees
+        # counts, and the new slot joins no launch already packed
+        self._settle()
         cache = self.caches[0]
         n_cov = cache.blocks_needed(buffer.n_tokens)
         if buffer.n_pages != n_cov:
@@ -1580,19 +1656,19 @@ class ContinuousBatchingEngine:
             self._tokens[slot] = first
 
     # ---- lazy page growth -----------------------------------------------
-    def _grow_pages(self) -> List[int]:
-        """Lazy mode: before the fused step runs, every running slot
-        must own a real page for the position it writes this step
-        (seq_len).  A slot that needs a page neither the pool nor
-        prefix-cache eviction can supply is the VICTIM: it is finished
-        early with ``truncated=True`` — its pages return to the pool
-        (often unblocking the others) and the batch keeps decoding.
-        step() never raises for pool exhaustion."""
-        truncated = []
-        for r in list(self.slots):
-            if r is None or r.state != "running":
-                continue
-            need = self.caches[0].blocks_needed(r.seq_len + 1)
+    def _grow_pages(self, decodes) -> bool:
+        """Lazy mode: before the fused step runs, every decode span
+        ``(req, "decode", 1, pos, src)`` must own a real page for the
+        position it writes.  With nothing in flight a slot that needs
+        a page neither the pool nor prefix-cache eviction can supply is
+        the VICTIM: it is finished early with ``truncated=True`` — its
+        pages return to the pool (often unblocking the others) and the
+        batch keeps decoding; step() never raises for pool exhaustion.
+        With a launch in flight the slots are not final yet: returns
+        False at the first such slot, truncating nothing, and the
+        caller settles before it picks a victim."""
+        for r, _kind, _size, pos, _src in decodes:
+            need = self.caches[0].blocks_needed(pos + 1)
             grew = True
             while len(r.block_ids) < need:
                 blk = self._try_alloc()
@@ -1600,12 +1676,15 @@ class ContinuousBatchingEngine:
                     grew = False
                     break
                 r.block_ids.append(blk)
-            if not grew:
-                r.truncated = True
-                self._m_truncated.inc()
-                self._finish(r)
-                truncated.append(r.req_id)
-        return truncated
+            if grew:
+                continue
+            if self._inflight is not None:
+                return False
+            r.truncated = True
+            self._m_truncated.inc()
+            self._finish(r)
+            self._done_backlog.append(r.req_id)
+        return True
 
     # ---- fused mixed prefill+decode step --------------------------------
     @staticmethod
@@ -1621,7 +1700,7 @@ class ContinuousBatchingEngine:
         row[3] = (req.seed ^ seed_xor) & 0x7FFFFFFF
         return row
 
-    def _fill_mixed_pack(self, mx, budgets, spans):
+    def _fill_mixed_pack(self, mx, budgets, spans, src=None):
         """Fill one MixedStep pack from span tuples
         ``(req, tokens, start, n_draft, seed_xor, masked)``: the span's
         tokens land at global positions ``start..start+m-1`` (kv_len =
@@ -1629,13 +1708,16 @@ class ContinuousBatchingEngine:
         columns when the step compiles them.  ``masked`` spans keep the
         padding descriptor (writes to the sink page, all-sink block
         table) but still occupy their span row, so output/probs rows
-        stay slot-aligned across launches.  Returns ``(pack, B)``."""
+        stay slot-aligned across launches.  ``src[i]`` >= 0 makes span
+        i's one token the previous launch's sampled token of that row
+        (its ``tokens`` entry is then a placeholder).  Returns
+        ``(pack, B)``."""
         total = sum(len(t) for _, t, _, _, _, _ in spans)
         B = next(b for b in budgets if b >= total)
         bs = self.block_size
         W = self.bt_width
         pack, tok_tab, span_tab = mx.new_pack(B)
-        tokens, positions, dest_blocks, dest_offsets = tok_tab
+        tokens, positions, dest_blocks, dest_offsets, src_row = tok_tab
         tokens[:] = 0
         positions[:] = 0
         # padding tokens: distinct sink-page slots (garbage on garbage)
@@ -1674,6 +1756,8 @@ class ContinuousBatchingEngine:
                 row[sc:sc + 4] = self._samp_row(r, sxor)
             pos = np.arange(start, start + m, dtype=np.int32)
             tokens[off:off + m] = toks
+            if src is not None and src[si] >= 0:
+                src_row[off] = src[si]
             positions[off:off + m] = pos
             dest_blocks[off:off + m] = [r.block_ids[p // bs]
                                         for p in pos]
@@ -1681,106 +1765,189 @@ class ContinuousBatchingEngine:
             off += m
         return pack, B
 
-    def _pack_spans(self):
-        """Choose this step's ragged span set: every running slot's
-        decode token (all must advance), then pending prefill chunks
-        round-robin over prefilling slots while the TOP budget has room
-        — multiple chunks per step, the round-robin latency killer.
-        The chunk half is ``_pick_chunks`` — the ONE chunk-selection
-        policy, shared with the speculative round's draft mirror."""
-        spans = []                    # (req, kind, size, start)
-        total = 0
+    def _decode_spans(self):
+        """The next launch's decode spans and where its chunks start,
+        from the slots as they will stand once the launch in flight is
+        booked: ``([(req, "decode", 1, pos, src)], {req_id:
+        prefill_pos})``.  Every slot that will be running decodes (all
+        must advance), in slot order.  A slot whose decode token or
+        final chunk is in flight decodes at the position after it from
+        that launch's sampled token (``src`` = its span row there; -1:
+        the host's ``_tokens``); one whose token in flight is its
+        ``max_new_tokens``-th is left out.  A prompt with a chunk in
+        flight continues where that chunk ends."""
+        inflight = {}
+        if self._inflight is not None:
+            inflight = {r.req_id: (si, kind, size, start)
+                        for si, (r, kind, size, start)
+                        in enumerate(self._inflight.spans)}
+        decodes, chunk_at = [], {}
         for r in self.slots:
-            if r is not None and r.state == "running":
-                spans.append((r, "decode", 1, r.seq_len))
-                total += 1
-        for r, size, start in self._pick_chunks(
-                self.token_budgets[-1] - total):
-            spans.append((r, "prefill", size, start))
-            total += size
-        return spans, total
+            if r is None:
+                continue
+            f = inflight.get(r.req_id)
+            if f is None:
+                if r.state == "running":
+                    decodes.append((r, "decode", 1, r.seq_len, -1))
+                continue
+            si, kind, size, start = f
+            end = start + size
+            if kind == "prefill":
+                chunk_at[r.req_id] = end
+                if end < len(r.prompt_ids):
+                    continue
+            if len(r.output_ids) + 1 < r.max_new_tokens:
+                decodes.append((r, "decode", 1, end, si))
+        return decodes, chunk_at
 
-    def _run_mixed_step(self) -> List[int]:
-        """Pack the admission mix into ONE fused MixedStep launch: build
-        the per-token and per-span tables on the host (control flow),
-        pad to the smallest token budget, dispatch, then book every
-        span's sampled token."""
+    def _run_mixed_step(self):
+        """Pack the next launch, enqueue it, and only then fetch and
+        book the launch in flight: one launch stays in flight across
+        ``step()`` calls, so the device runs step N+1 while the host
+        books step N, returns to its caller, admits and packs N+2.  The
+        pack holds every slot's decode token (from the device where
+        step N samples it) and pending prefill chunks round-robin while
+        the TOP budget has room — multiple chunks per step, the
+        round-robin latency killer — padded to the smallest token
+        budget.  Finished request ids go to ``_done_backlog``."""
         if self.draft_step is not None:
-            return self._run_spec_round()
+            self._done_backlog += self._run_spec_round()
+            return
         rec = self._rec
-        done = self._grow_pages() if self.lazy_alloc else []
-        spans, total = self._pack_spans()
+        decodes, chunk_at = self._decode_spans()
+        if self.lazy_alloc:
+            if not self._grow_pages(decodes):
+                # a page the pool cannot supply: the victim is picked
+                # on the slots as booked
+                self._settle()
+                decodes, chunk_at = self._decode_spans()
+                self._grow_pages(decodes)
+            decodes = [d for d in decodes if d[0].state != "done"]
+        spans = decodes + [
+            (r, "prefill", size, start, -1) for r, size, start in
+            self._pick_chunks(self.token_budgets[-1] - len(decodes),
+                              chunk_at)]
+        booked = self._inflight
         if not spans:
-            return done
+            if booked is not None:
+                # nothing to launch: the wait for the launch in flight
+                rec["t_fill"] = self._phase(None)
+                self._inflight = None
+                nxt = self.mixed.fetch()
+                rec["t_tokens"] = self._phase("engine.book")
+                self._book(booked, nxt, rec["t_tokens"])
+            return
         fill = [(r,
-                 np.asarray([self._tokens[r.slot]], np.int32)
+                 np.asarray([self._tokens[r.slot] if src < 0 else 0],
+                            np.int32)
                  if kind == "decode"
                  else r.prompt_ids[start:start + size].astype(np.int32),
                  start, 0, 0, False)
-                for r, kind, size, start in spans]
+                for r, kind, size, start, src in spans]
         rec["t_pack"] = self._phase("engine.fill")
         pack, B = self._fill_mixed_pack(self.mixed, self.token_budgets,
-                                        fill)
+                                        fill, [sp[4] for sp in spans])
 
         rec["t_fill"] = t0 = self._phase(None)
         pre = self.mixed.total_compiles
         built = compile_seconds()
-        nxt = self.mixed.call_packed(pack, B)
-        rec["t_tokens"] = t1 = self._phase("engine.book")
+        if booked is None:
+            self.mixed.launch(pack, B)
+        else:
+            # enqueue this launch, then wait for the one in flight
+            nxt = self.mixed.call_packed(pack, B)
+            rec["t_tokens"] = self._phase("engine.book")
         rec["t_dispatch"] = self.mixed.t_dispatch
         rec["t_launch"] = self.mixed.t_launch
         self._record_build(rec, built)
         traced = self.mixed.total_compiles - pre
-        dt = t1 - t0
+        rec.update(compiled=bool(traced), ahead=int(booked is not None))
+        (self._m_launch_settled if booked is None
+         else self._m_launch_ahead).inc()
+        if traced:
+            # first trace of this budget: count it, keep the compile
+            # warmup out of every latency histogram
+            self._m_mixed_compiles.inc(traced)
         if self.tp is not None:
             self._count_collectives(self.mixed.collective_bytes(B))
+        self._inflight = _Launch([sp[:4] for sp in spans], B, t0,
+                                 bool(traced), self._step_no)
+        if booked is not None:
+            self._book(booked, nxt, rec["t_tokens"])
+
+    def _settle(self):
+        """Fetch and book the launch in flight, if there is one: what a
+        path runs first that changes slots from outside or picks a
+        lazy-alloc victim.  The requests it finishes wait in
+        ``_done_backlog`` for the next ``step()`` to return."""
+        booked, self._inflight = self._inflight, None
+        if booked is not None:
+            nxt = self.mixed.fetch()
+            self._book(booked, nxt, time.perf_counter())
+
+    def _book(self, launch: _Launch, nxt, t_tokens: float):
+        """Book a fetched launch: every span's sampled token, in pack
+        order, and — inside a ``step()`` — what it carried into the
+        step's record.  A span of a request that ended (on EOS) in the
+        launch before was packed before that was known: its row is
+        dropped and counted in ``discarded``."""
+        kept = [(si, sp) for si, sp in enumerate(launch.spans)
+                if sp[0].state != "done"]
+        spans = [sp for _, sp in kept]
+        discarded = len(launch.spans) - len(kept)
         n_dec = sum(1 for _, kind, _, _ in spans if kind == "decode")
-        n_pre = total - n_dec
-        rec.update(budget=B, n_dec=n_dec, n_pre=n_pre,
-                   compiled=bool(traced))
+        n_pre = sum(size for _, kind, size, _ in spans
+                    if kind != "decode")
+        if discarded:
+            self._m_discarded.inc(discarded)
         if n_dec:
             self._m_mixed_tok_decode.inc(n_dec)
         if n_pre:
             self._m_mixed_tok_prefill.inc(n_pre)
         if self._moe_layers:
-            # dropless dispatch: every real token lands on exactly
-            # top_k experts per MoE layer, none are dropped
-            self._m_moe_routed.inc(total * self._moe_topk
-                                   * self._moe_layers)
+            # dropless dispatch: every token the launch carried lands on
+            # exactly top_k experts per MoE layer, none are dropped
+            self._m_moe_routed.inc((n_dec + n_pre + discarded)
+                                   * self._moe_topk * self._moe_layers)
+        rec = self._rec
+        if rec is not None:
+            rec.update(budget=launch.budget, n_dec=n_dec, n_pre=n_pre,
+                       discarded=discarded, launch_step=launch.step)
         if self.mixed.n_stats:
             stats = self.mixed.last_stats
-            rec.update(moe_rows=int(stats[0]), moe_rows_top=int(stats[1]),
-                       moe_tiles=int(stats[2]))
+            if rec is not None:
+                rec.update(moe_rows=int(stats[0]),
+                           moe_rows_top=int(stats[1]),
+                           moe_tiles=int(stats[2]))
             for child, n in zip(self._m_moe_expert, stats[3:]):
                 child.inc(int(n))
-        if traced:
-            # first trace of this budget: count it, keep the compile
-            # warmup out of every latency histogram
-            self._m_mixed_compiles.inc(traced)
-        else:
+        t0 = launch.t_fill
+        if not launch.traced:
             # the fused step IS both the decode round and the prefill
-            # round — classify its (warm) duration into whichever
-            # histograms the pack actually advanced
+            # round — classify its (warm) launch-to-tokens time into
+            # whichever histograms the pack actually advanced
             if n_dec:
-                self._m_decode.observe(dt)
+                self._m_decode.observe(t_tokens - t0)
             if n_pre:
-                self._m_prefill.observe(dt)
+                self._m_prefill.observe(t_tokens - t0)
         if self.tracer.enabled:
-            rec["spans"] = [(r.req_id, size, start + size)
-                            for r, _, size, start in spans]
+            if rec is not None:
+                rec["spans"] = [(r.req_id, size, start + size)
+                                for r, _, size, start in spans]
             # every span in the pack shares the one launch window
             for r, kind, size, start in spans:
                 if kind == "decode":
                     self.tracer.sample_span(
-                        r.req_id, "decode_step", t0, t1,
+                        r.req_id, "decode_step", t0, t_tokens,
                         every=self.trace_decode_every,
                         step=self._step_no)
                 else:
-                    self.tracer.span(r.req_id, "prefill_chunk", t0, t1,
-                                     offset=start, tokens=size,
-                                     warm=not traced, step=self._step_no)
+                    self.tracer.span(r.req_id, "prefill_chunk", t0,
+                                     t_tokens, offset=start, tokens=size,
+                                     warm=not launch.traced,
+                                     step=self._step_no)
 
-        for si, (r, kind, size, start) in enumerate(spans):
+        for si, (r, kind, size, start) in kept:
             tok = int(nxt[si])
             if kind == "decode":
                 i = r.slot
@@ -1789,7 +1956,7 @@ class ContinuousBatchingEngine:
                 if self.slots[i] is r:
                     self._tokens[i] = tok
                 if r.state == "done":
-                    done.append(r.req_id)
+                    self._done_backlog.append(r.req_id)
             else:
                 r.prefill_pos += size
                 if r.prefill_pos >= len(r.prompt_ids):
@@ -1797,8 +1964,7 @@ class ContinuousBatchingEngine:
                     # token (earlier chunks' samples are discarded)
                     self._complete_prefill(r, tok)
                     if r.state == "done":
-                        done.append(r.req_id)
-        return done
+                        self._done_backlog.append(r.req_id)
 
     # ---- speculative decoding (draft_model=) ----------------------------
     def _spec_k_eff(self, req: GenerationRequest) -> int:
@@ -1832,11 +1998,14 @@ class ContinuousBatchingEngine:
                 k -= 1
             keff[r.slot] = k
 
-    def _pick_chunks(self, room: int):
+    def _pick_chunks(self, room: int, at=None):
         """Pending prefill chunks for this round, round-robin over
-        prefilling slots while ``room`` holds (the same policy as
-        ``_pack_spans``; shared by the draft mirror and the verify
-        pack, which must see identical chunk work)."""
+        prefilling slots while ``room`` holds: the ONE chunk-selection
+        policy, shared by the mixed step, the draft mirror and the
+        verify pack (which must see identical chunk work).  ``at``
+        maps a request id to where its next chunk starts when that is
+        not its ``prefill_pos`` (a chunk of it is in flight)."""
+        at = at or {}
         spans = []
         n = self.max_batch_size
         advanced_first = None
@@ -1847,11 +2016,11 @@ class ContinuousBatchingEngine:
                 continue
             if room <= 0:
                 break
-            size = min(self.chunk_size,
-                       len(r.prompt_ids) - r.prefill_pos, room)
+            pos = at.get(r.req_id, r.prefill_pos)
+            size = min(self.chunk_size, len(r.prompt_ids) - pos, room)
             if size <= 0:
                 continue
-            spans.append((r, size, r.prefill_pos))
+            spans.append((r, size, pos))
             room -= size
             if advanced_first is None:
                 advanced_first = i
@@ -1927,7 +2096,9 @@ class ContinuousBatchingEngine:
         corrected/bonus token.  Greedy output is byte-identical to the
         non-speculative engine; sampled output is distribution-exact
         (rejection-resampling on device)."""
-        done = self._grow_pages() if self.lazy_alloc else []
+        done = []
+        if self.lazy_alloc:
+            self._grow_pages(self._decode_spans()[0])
         keff: Dict[int, int] = {}
         for r in self.slots:
             if r is not None and r.state == "running":
@@ -1981,7 +2152,8 @@ class ContinuousBatchingEngine:
         dt = t1 - t0
         n_pre = sum(size for _, size, _ in chunk_spans)
         rec.update(budget=B, n_dec=len(run_spans), n_pre=n_pre,
-                   compiled=bool(traced))
+                   compiled=bool(traced), launch_step=self._step_no)
+        self._m_launch_settled.inc()
         if traced:
             self._m_mixed_compiles.inc(traced)
         else:

@@ -75,6 +75,7 @@ re-reading k/v.
 """
 from __future__ import annotations
 
+import collections
 import math
 import re
 import time
@@ -857,6 +858,14 @@ class MixedStep:
     — there is no separate prefill/decode module split and no per-chunk
     engine round.  ``compile_counts`` maps budget -> trace count (tests
     and the bench gate on it).
+
+    Launches chain on the device: every launch takes the previous
+    launch's sampled-token array (``out[0]``, device-resident, never
+    donated; zeros before the first) and a token row whose ``src`` is
+    not -1 reads its token from that array's row ``src`` instead of the
+    pack.  A caller may therefore enqueue launch N+1 before it fetches
+    launch N (``launch`` / ``fetch``): a decode token never has to
+    cross to the host and back between two steps.
     """
 
     def __init__(self, model, caches: List, bt_width: int,
@@ -942,15 +951,20 @@ class MixedStep:
         self._moe_experts, self._moe_top_k = _grouped_product_experts(
             model, self._tp)
         self.n_stats = 3 + self._moe_experts if self._moe_experts else 0
-        self.last_stats = None         # the last call's tail (np int32)
+        self.last_stats = None         # the last fetch's tail (np int32)
+        # the newest launch's out[0], the next launch's ``prev`` operand
+        # (``_prev_operand`` makes the zeros the first launch reads)
+        self._prev = None
+        # device outputs of the launches not fetched yet, oldest first
+        self._unfetched = collections.deque()
         self._wq = weight_qparams
         self._q8_gather = bool(quant_collectives)
         _ensure_quant_specs(self._tp, weight_qparams)
         self._param_tensors = dict(model.state_dict())
         self._fns = {}                 # token budget -> jitted step
         self.compile_counts = {}       # token budget -> trace count
-        # perf_counter instants at which the last call_packed's jitted
-        # call was MADE (operands gathered) and RETURNED (work enqueued,
+        # perf_counter instants at which the last launch's jitted call
+        # was MADE (operands gathered) and RETURNED (work enqueued,
         # nothing fetched yet)
         self.t_launch = 0.0
         self.t_dispatch = 0.0
@@ -1120,20 +1134,24 @@ class MixedStep:
             from ..ops.sampling import (filtered_probs, sample_logits,
                                         spec_verify)
 
-        def step(params, pack, q_probs, kcs, vcs, kss, vss):
+        def step(params, pack, prev, q_probs, kcs, vcs, kss, vss):
             self.compile_counts[T] = self.compile_counts.get(T, 0) + 1
             # unpack the single host buffer (free at trace level —
-            # slices of a constant layout): rows 0-3 of the leading
-            # [4, T] block are tokens / positions / dest block / dest
-            # offset; the trailing [S, W+EX] block is the block table
-            # columns then q_offset / q_len / kv_len / sample_row
+            # slices of a constant layout): rows 0-4 of the leading
+            # [5, T] block are tokens / positions / dest block / dest
+            # offset / src; the trailing [S, W+EX] block is the block
+            # table columns then q_offset / q_len / kv_len / sample_row
             # (+ n_draft under spec, + the 4 bitcast sampling-knob
             # columns under sampling).  ONE device_put per step instead
-            # of nine — transfer count, not byte count, is the
+            # of ten — transfer count, not byte count, is the
             # decode-parity budget at low occupancy.
-            tok_tab = pack[:4 * T].reshape(4, T)
-            span_tab = pack[4 * T:].reshape(S, W + EX)
-            tokens = tok_tab[0]
+            tok_tab = pack[:5 * T].reshape(5, T)
+            span_tab = pack[5 * T:].reshape(S, W + EX)
+            # a row whose src is a span row of the previous launch
+            # takes that launch's sampled token, still on the device
+            src = tok_tab[4]
+            tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
+                               tok_tab[0])
             positions = tok_tab[1]
             dest_blocks = tok_tab[2]
             dest_offsets = tok_tab[3]
@@ -1367,18 +1385,19 @@ class MixedStep:
         if n_stats:
             step = _traced_x64_off(step)
         if spec_k and sampling:
-            fn, donate = step, (3, 4, 5, 6)
+            fn, donate = step, (4, 5, 6, 7)
         else:
-            # no draft-probs operand: same pytree as round 13 when
-            # sampling/spec are both off
-            def fn(params, pack, kcs, vcs, kss, vss):
-                return step(params, pack, None, kcs, vcs, kss, vss)
-            donate = (2, 3, 4, 5)
+            # no draft-probs operand when sampling/spec are not both on
+            def fn(params, pack, prev, kcs, vcs, kss, vss):
+                return step(params, pack, prev, None, kcs, vcs, kss, vss)
+            donate = (3, 4, 5, 6)
         fn = _named(fn, "mixed_step")
         if tp is None:
             return jax.jit(fn, donate_argnums=donate)
+        # pack and prev replicate (the verifier, the one step with a
+        # q_probs operand, is single-chip)
         return _wrap_sharded(fn, tp, self._wq or self._param_tensors,
-                             len(self.caches), n_repl=1,
+                             len(self.caches), n_repl=2,
                              donate=donate,
                              quant_kv=self._quant_kv)
 
@@ -1407,28 +1426,33 @@ class MixedStep:
 
     def new_pack(self, T: int):
         """Allocate the step's single host buffer: ``(pack, tok_tab,
-        span_tab)`` where tok_tab [4, T] (rows tokens / positions /
-        dest block / dest offset) and span_tab
+        span_tab)`` where tok_tab [5, T] (rows tokens / positions /
+        dest block / dest offset / src) and span_tab
         [max_spans, bt_width+row_extra] (block-table columns then
         q_offset / q_len / kv_len / sample_row, + n_draft under spec,
         + the 4 bitcast sampling-knob columns under sampling) are VIEWS
-        into pack — fill them, then hand pack to ``call_packed``.  The
-        extra tail columns come pre-zeroed (greedy, no drafts), so a
-        caller that only fills the round-13 layout stays correct."""
+        into pack — fill them, then hand pack to ``launch`` or
+        ``call_packed``.  The src row comes as -1 (every token from the
+        pack) and the extra tail columns pre-zeroed (greedy, no
+        drafts), so a caller that fills only tokens, positions,
+        destinations and the four span descriptors stays correct."""
         S, W = self.max_spans, self.bt_width
-        pack = np.empty(4 * T + S * (W + self.row_extra), np.int32)
-        span_tab = pack[4 * T:].reshape(S, W + self.row_extra)
+        pack = np.empty(5 * T + S * (W + self.row_extra), np.int32)
+        tok_tab = pack[:5 * T].reshape(5, T)
+        tok_tab[4] = -1
+        span_tab = pack[5 * T:].reshape(S, W + self.row_extra)
         if self.row_extra > 4:
             span_tab[:, W + 4:] = 0
-        return pack, pack[:4 * T].reshape(4, T), span_tab
+        return pack, tok_tab, span_tab
 
     def aot_lower(self, T: int, device_sharding=None):
         """AOT-lower (never execute) one budget-``T`` module with a
         zero pack and the caches' current pools — the artifact the
         graftlint hlo-contract pass asserts over (donation aliases the
         pools, no f64 op, ONE packed int32 host operand of the pinned
-        length).  Uses the same cached jit as ``call_packed``, so a
-        subsequent real call does not re-trace.
+        length beside the device-resident ``prev``).  Uses the same
+        cached jit as ``launch``, so a subsequent real call does not
+        re-trace.
 
         ``device_sharding`` lowers for ANOTHER device than the one the
         arrays live on: every operand becomes a ``ShapeDtypeStruct``
@@ -1445,7 +1469,8 @@ class MixedStep:
         kcs = tuple(c.key_cache for c in self.caches)
         vcs = tuple(c.value_cache for c in self.caches)
         kss, vss = _cache_scales(self.caches, self._quant_kv)
-        args = [params, jnp.asarray(pack)]
+        args = [params, jnp.asarray(pack),
+                self._prev_operand(params, kcs + vcs)]
         if self.spec_k and self.sampling:
             V = self.cfg.vocab_size
             args.append(tuple(
@@ -1459,7 +1484,7 @@ class MixedStep:
     def compiled_stats(self, T: int) -> dict:
         """Cached ``cost_analysis`` of one budget-``T`` compiled mixed
         launch (see :func:`compiled_cost_stats`) — the capacity plane's
-        per-token FLOPs/HBM source.  Reuses the ``call_packed`` jit
+        per-token FLOPs/HBM source.  Reuses the ``launch`` jit
         cache, so a later real call does not re-trace."""
         cache = getattr(self, "_cost_stats", None)
         if cache is None:
@@ -1468,17 +1493,45 @@ class MixedStep:
             cache[T] = compiled_cost_stats(self.aot_lower(T), T)
         return cache[T]
 
-    def call_packed(self, pack: np.ndarray, T: int, q_probs=None):
-        """Dispatch one pre-packed step buffer (see ``new_pack``).  The
-        nine per-step operands cross the host link as ONE int32
-        device_put: transfer count, not byte count, is the budget at
-        low occupancy.
+    def _prev_operand(self, params, pools):
+        """The ``prev`` operand: the newest launch's sampled-token array,
+        or before the first launch zeros placed as that array will be.
+        A plain jit's output is committed to a device exactly where an
+        operand is, and a committed operand is part of the signature:
+        zeros left uncommitted beside committed weights would build the
+        module a second time at the second launch.  Under a mesh the
+        output is replicated over it, and so are the zeros."""
+        if self._prev is None:
+            prev = jnp.zeros((self.max_spans + self.n_stats,), jnp.int32)
+            if self._tp is not None:
+                prev = jax.device_put(prev,
+                                      self._tp.named(PartitionSpec()))
+            else:
+                placed = next(
+                    (x for x in jax.tree_util.tree_leaves((params, pools))
+                     if getattr(x, "committed", False)), None)
+                if placed is not None:
+                    prev = jax.device_put(prev, placed.sharding)
+            self._prev = prev
+        return self._prev
 
-        Returns the [max_spans] int32 sample array; a verifier
-        (``spec_k``) returns ``(tokens, n_acc)`` and takes ``q_probs``
-        (a tuple of K device-resident [max_spans, V] draft
-        distributions) when sampled; a draft (``return_probs``)
-        returns ``(tokens, probs)`` with probs left ON DEVICE."""
+    def call_packed(self, pack: np.ndarray, T: int, q_probs=None):
+        """``launch`` one pre-packed step buffer, then ``fetch``: the
+        tokens of the oldest launch not fetched yet — this one's where
+        none was in flight, the launch before's where one was (that
+        launch then overlaps this one's enqueue)."""
+        self.launch(pack, T, q_probs)
+        return self.fetch()
+
+    def launch(self, pack: np.ndarray, T: int, q_probs=None):
+        """Enqueue one pre-packed step buffer (see ``new_pack``) and
+        return its device outputs, fetching nothing.  The ten per-step
+        operands cross the host link as ONE int32 device_put: transfer
+        count, not byte count, is the budget at low occupancy; the
+        previous launch's sampled tokens (``prev``, what the pack's src
+        row reads) stay on the device.  A sampled verifier (``spec_k``)
+        takes ``q_probs``, a tuple of K device-resident
+        [max_spans, V] draft distributions."""
         with jax.profiler.TraceAnnotation("engine.dispatch"):
             fn = self._fns.get(T)
             if fn is None:
@@ -1488,7 +1541,8 @@ class MixedStep:
             kcs = tuple(c.key_cache for c in self.caches)
             vcs = tuple(c.value_cache for c in self.caches)
             kss, vss = _cache_scales(self.caches, self._quant_kv)
-            args = [params, jnp.asarray(pack)]
+            args = [params, jnp.asarray(pack),
+                    self._prev_operand(params, kcs + vcs)]
             if self.spec_k and self.sampling:
                 if q_probs is None:
                     raise ValueError(
@@ -1502,9 +1556,20 @@ class MixedStep:
             out = fn(*args, kcs, vcs, kss, vss)
             n_out = 2 if self.spec_k or self.return_probs else 1
             _rebind_caches(self.caches, *out[n_out:])
+        self._prev = out[0]
+        self._unfetched.append(out[:n_out])
         # the launch is enqueued: what follows is the wait for the
         # device (the step record's t_dispatch .. t_tokens)
         self.t_dispatch = time.perf_counter()
+        return out
+
+    def fetch(self):
+        """The host tokens of the oldest launch not fetched yet: its
+        [max_spans] int32 sample array, the step's counts behind them
+        into ``last_stats``.  A verifier (``spec_k``) returns
+        ``(tokens, n_acc)``; a draft (``return_probs``) returns
+        ``(tokens, probs)`` with probs left ON DEVICE."""
+        out = self._unfetched.popleft()
         with jax.profiler.TraceAnnotation("engine.fetch"):
             nxt = np.asarray(out[0])
             if self.n_stats:
@@ -1520,7 +1585,7 @@ class MixedStep:
         """``{optimized-HLO instruction name: scope}`` of the compiled
         budget-``T`` module (see :func:`hlo_op_scopes`): what a trace
         reader needs to give each device op of a launch its part of
-        the step.  Lowers through the ``call_packed`` jit cache and
+        the step.  Lowers through the ``launch`` jit cache and
         compiles through the compile cache, so on a budget that has
         run it builds nothing new."""
         return hlo_op_scopes(self.aot_lower(T).compile().as_text())

@@ -310,10 +310,16 @@ def step_clock(trace_events: List[dict], records) -> Optional[dict]:
       on the device after its tokens reached the host, so L >= max over
       steps of (module end - ``t_tokens``); it cannot start before its
       launch call began, so L <= min over steps of (module start -
-      ``t_launch``).  ``lead_width_s`` is hi - lo.  The launched steps
-      pair in order with the executions of the step's module on the
-      first device (``launches`` of them); where the counts differ, or
-      there is no device plane, the three are ``None``.
+      ``t_launch``).  ``lead_width_s`` is hi - lo.  A booked step's
+      launch call is the ``t_launch`` of the record its ``launch_step``
+      names (the record itself where it books its own launch; the one
+      before where a launch stays in flight across steps).  The booked
+      steps whose launch call is in the trace pair in order with the
+      executions of the step's module on the first device
+      (``launches`` of them); one execution more, the launch still in
+      flight when the trace stopped, is left out.  Where the counts
+      differ otherwise, or there is no device plane, the three are
+      ``None``.
     """
     recs = {(r.get("engine"), r.get("step")): r
             for r in _step_records(records)}
@@ -338,16 +344,22 @@ def step_clock(trace_events: List[dict], records) -> Optional[dict]:
     out = {"steps": len(paired), "offset_s": statistics.median(offsets),
            "offset_spread_s": q[2] - q[0],
            "launches": 0, "lead_s": None, "lead_width_s": None}
-    launched = sorted((r for _, r in paired if r.get("budget")),
-                      key=lambda r: r["t_launch"])
+    traced = {(r.get("engine"), r.get("step")): r for _, r in paired}
+    booked = []                      # (launch call, tokens on the host)
+    for r in sorted((r for _, r in paired if r.get("budget")),
+                    key=lambda r: r["t_tokens"]):
+        launcher = traced.get((r.get("engine"),
+                               r.get("launch_step", r.get("step"))))
+        if launcher is not None:
+            booked.append((launcher["t_launch"], r["t_tokens"]))
     modules = _device_modules(trace_events)
-    if launched and len(modules) == len(launched):
+    if booked and len(modules) in (len(booked), len(booked) + 1):
         off = out["offset_s"]
-        lo = max(end - (r["t_tokens"] + off)
-                 for (_, end), r in zip(modules, launched))
-        hi = min(start - (r["t_launch"] + off)
-                 for (start, _), r in zip(modules, launched))
-        out.update(launches=len(launched), lead_s=[lo, hi],
+        lo = max(end - (t_tokens + off)
+                 for (_, end), (_, t_tokens) in zip(modules, booked))
+        hi = min(start - (t_launch + off)
+                 for (start, _), (t_launch, _) in zip(modules, booked))
+        out.update(launches=len(booked), lead_s=[lo, hi],
                    lead_width_s=hi - lo)
     return out
 

@@ -405,8 +405,11 @@ def test_two_engine_requeue_parity():
     assert reqs.value >= lost
     # regression: a request that completes DURING admission (budget 1,
     # dense prefill) must surface in step()'s return — the router keys
-    # on it (it used to go missing and wedge run_to_completion)
+    # on it (it used to go missing and wedge run_to_completion).  The
+    # call that launches the prefill returns before its token is
+    # fetched; the next call books it and returns the id
     rid1 = e2.add_request(prompts[0], max_new_tokens=1)
+    assert rid1 not in e2.step()
     assert rid1 in e2.step()
     r1 = router.submit(prompts[1], max_new_tokens=1)
     assert router.run_to_completion()[r1] \

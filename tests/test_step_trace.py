@@ -75,11 +75,11 @@ def _drive(eng, budget=4):
     given = []
     fill = eng._fill_mixed_pack
 
-    def spy(mx, budgets, spans):
+    def spy(mx, budgets, spans, *src):
         if mx is eng.mixed:
             given.append([(r.req_id, len(toks), start + len(toks))
                           for r, toks, start, _, _, _ in spans])
-        return fill(mx, budgets, spans)
+        return fill(mx, budgets, spans, *src)
 
     eng._fill_mixed_pack = spy
     steps = 0
@@ -119,10 +119,14 @@ def test_one_record_a_step(family):
         if f["budget"]:
             assert f["budget"] in eng.token_budgets
     budgets = set()
+    by_step = {f["step"]: f for _, _, f in recs}
     for f, rows in zip(launched, given):
         assert f["spans"].tolist() == [list(r) for r in rows]
         # a fresh engine traces each budget's module at its first launch
-        assert f["compiled"] == (f["budget"] not in budgets)
+        # (``compiled`` is the launching call's, a record's budget the
+        # step it booked)
+        assert by_step[f["launch_step"]]["compiled"] \
+            == (f["budget"] not in budgets)
         budgets.add(f["budget"])
     # the counters the engine already keeps say the same
     assert sum(f["n_dec"] for f in launched) \

@@ -20,10 +20,12 @@ on:
   trace-safety rule catches the line, this rule proves the artifact.
 - **hlo-packed-layout**: the operand pytree matches the pinned layout.
   The mixed step carries exactly ONE int32 host operand of exactly
-  ``4*T + max_spans*(bt_width+4)`` words (the round-11 "nine operands,
-  one transfer" rule: transfer COUNT is the decode budget).  A new
-  host operand — however small — is a second per-step transfer and
-  fails here, not in a TPU latency regression three rounds later.
+  ``5*T + max_spans*(bt_width+4)`` words (the round-11 "nine operands,
+  one transfer" rule: transfer COUNT is the decode budget) and beside
+  it exactly one int32 ``[max_spans]`` operand, the previous launch's
+  sampled tokens, which never leaves the device.  A new host operand
+  — however small — is a second per-step transfer and fails here, not
+  in a TPU latency regression three rounds later.
 
 The check functions are pure text/aval predicates so the self-test can
 feed doctored artifacts; only :func:`build_artifacts` imports jax.
@@ -61,9 +63,12 @@ class Artifact:
     avals: List[Tuple[str, Tuple[int, ...]]]
     n_pool_params: int            # pool leaves that must alias
     pool_sig: Optional[str]       # e.g. "f32[8,4,2,16]" (None: train)
-    expect_i32: Optional[int]     # pinned int32 host-operand count
+    expect_i32: Optional[int]     # pinned int32 operand count
     packed_len: Optional[int]     # pinned single-pack length (mixed)
     min_aliases: int = 0          # lower bound on alias entries
+    # the mixed step's device-resident [max_spans] previous-launch
+    # tokens: the one int32 operand beside the pack that is no transfer
+    prev_len: Optional[int] = None
 
 
 # -- pure text/aval predicates (self-testable) ------------------------------
@@ -148,7 +153,7 @@ def check_packed_layout(art: Artifact) -> List[Finding]:
         if len(i32) != art.expect_i32:
             out.append(Finding(
                 "hlo-packed-layout", where, 0,
-                f"{len(i32)} int32 host operand(s) in the lowered "
+                f"{len(i32)} int32 operand(s) in the lowered "
                 f"signature, pinned layout says {art.expect_i32} — "
                 f"every extra operand is an extra per-step host "
                 f"transfer (round-11: transfer COUNT is the decode "
@@ -161,8 +166,16 @@ def check_packed_layout(art: Artifact) -> List[Finding]:
                     f"no int32[{art.packed_len}] pack operand in the "
                     f"lowered signature (got {lens}) — the mixed "
                     f"step's pack no longer matches the pinned "
-                    f"4*T + max_spans*(bt_width+4) layout; update the "
+                    f"5*T + max_spans*(bt_width+4) layout; update the "
                     f"pin ONLY with the engine-side pack writer"))
+        if art.prev_len is not None and not any(
+                shp == (art.prev_len,) for _dt, shp in i32):
+            out.append(Finding(
+                "hlo-packed-layout", where, 0,
+                f"no int32[{art.prev_len}] previous-launch token "
+                f"operand in the lowered signature — the step no "
+                f"longer reads the launch before's samples on the "
+                f"device"))
     return out
 
 
@@ -225,20 +238,21 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     pool_sig = "f32[" + ",".join(str(d) for d in probe.shape) + "]"
 
     def art(name, lowered, n_pool, psig, expect_i32, packed_len,
-            min_aliases):
+            min_aliases, prev_len=None):
         avals = _avals_of(lowered)
         text = lowered.compile().as_text()
         _ARTIFACTS[name] = Artifact(
             name=name, text=text, avals=avals, n_pool_params=n_pool,
             pool_sig=psig, expect_i32=expect_i32,
-            packed_len=packed_len, min_aliases=min_aliases)
+            packed_len=packed_len, min_aliases=min_aliases,
+            prev_len=prev_len)
 
     mixed = MixedStep(model, caches(), bt_width=BT_WIDTH,
                       max_spans=MAX_SPANS, use_pallas=False)
-    packed_len = 4 * MIXED_T + MAX_SPANS * (BT_WIDTH + mixed.row_extra)
+    packed_len = 5 * MIXED_T + MAX_SPANS * (BT_WIDTH + mixed.row_extra)
     art(f"mixed_step@T{MIXED_T}", mixed.aot_lower(MIXED_T),
-        n_pool=2 * L, psig=pool_sig, expect_i32=1,
-        packed_len=packed_len, min_aliases=2 * L)
+        n_pool=2 * L, psig=pool_sig, expect_i32=2,
+        packed_len=packed_len, min_aliases=2 * L, prev_len=MAX_SPANS)
 
     # round 19: the page-migration inject dispatch — every pool
     # parameter donated (the scatter is an in-place HBM write) and
@@ -286,8 +300,8 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     shard_shape[2] //= MESH_TP
     pool_sig_2d = "f32[" + ",".join(str(d) for d in shard_shape) + "]"
     art(f"mixed_step_2d@T{MIXED_T}", mixed2d.aot_lower(MIXED_T),
-        n_pool=2 * L, psig=pool_sig_2d, expect_i32=1,
-        packed_len=packed_len, min_aliases=2 * L)
+        n_pool=2 * L, psig=pool_sig_2d, expect_i32=2,
+        packed_len=packed_len, min_aliases=2 * L, prev_len=MAX_SPANS)
 
     # round 22: the same contracts under a cp=2 context-parallel mesh
     # — the pools enter SLOT-striped (block_size/cp per chip), the
@@ -306,8 +320,8 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
     pool_sig_cp = "f32[" + ",".join(str(d) for d in cp_shard_shape) \
         + "]"
     art(f"mixed_step_cp@T{MIXED_T}", mixedcp.aot_lower(MIXED_T),
-        n_pool=2 * L, psig=pool_sig_cp, expect_i32=1,
-        packed_len=packed_len, min_aliases=2 * L)
+        n_pool=2 * L, psig=pool_sig_cp, expect_i32=2,
+        packed_len=packed_len, min_aliases=2 * L, prev_len=MAX_SPANS)
 
     # round 24: the same contracts under an ep=2 expert-parallel mesh
     # with a tiny Mixtral — the MoE dispatch's all_to_all pair and
@@ -328,8 +342,8 @@ def _build_artifacts_seeded() -> Dict[str, Artifact]:
                         max_spans=MAX_SPANS,
                         use_pallas=False, mesh=meshep)
     art(f"mixed_step_ep@T{MIXED_T}", mixedep.aot_lower(MIXED_T),
-        n_pool=2 * L, psig=pool_sig, expect_i32=1,
-        packed_len=packed_len, min_aliases=2 * L)
+        n_pool=2 * L, psig=pool_sig, expect_i32=2,
+        packed_len=packed_len, min_aliases=2 * L, prev_len=MAX_SPANS)
 
     model2d = LlamaForCausalLM(cfg)
     opt2d = paddle.optimizer.SGD(0.1,
@@ -399,7 +413,8 @@ register(Rule(
     id="hlo-packed-layout",
     family="hlo-contracts",
     contract="the mixed step carries exactly ONE int32 host operand of "
-             "the pinned 4*T+max_spans*(bt_width+4) length; the "
+             "the pinned 5*T+max_spans*(bt_width+4) length, beside the "
+             "device-resident [max_spans] previous-launch tokens; the "
              "migration inject dispatch carries exactly one (the "
              "destination ids — payload is one buffer per dtype)",
     check=lambda sources: _run(check_packed_layout),

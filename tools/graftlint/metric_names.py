@@ -147,6 +147,9 @@ REQUIRED_NAMES = frozenset({
     "elastic_warmup_restored_pages_total",
     "redistribute_bytes_total",
     "router_engine_pool_size",
+    # one launch kept in flight across engine steps
+    "serving_launches_total",
+    "serving_discarded_rows_total",
 })
 
 # ---------------------------------------------------------------------------
@@ -202,6 +205,9 @@ LABEL_DOMAINS = {
     # spmd param all-gather sites (round 21):
     # spmd_allgather_bytes_total{site}
     "site": frozenset({"train_params", "serving_params"}),
+    # how a serving launch was enqueued: behind one still in flight,
+    # or with nothing in flight (serving_launches_total{mode})
+    "mode": frozenset({"ahead", "settled"}),
     "engine": DYNAMIC,              # engine ids: bounded by pool size
     "expert": DYNAMIC,              # index within the bank an engine
                                     # holds: bounded by the model config
