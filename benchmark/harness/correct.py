@@ -83,14 +83,15 @@ def served_gap(cell, seed: int, samples, margin_min: float = 0.0,
     ``margin_min`` in every layer that routes.  The share left out is
     reported and held to a cap.
 
-    Layer ``li`` gets the leaves of its kind (``spec.family_layers``), and
-    a family with kinds has its ``layer`` handed the kind."""
+    Layer ``li`` gets the leaves of its kind, drawn as its reference
+    names them (``spec.family_layers``), and a family with kinds has its
+    ``layer`` handed the kind."""
     import jax
     import jax.numpy as jnp
 
     config, ref = cell.config, cell.reference()
     dtype = config["dtype"]
-    kinds, shapes = family_layers(ref, config)
+    kinds, shapes, draws = family_layers(ref, config)
 
     def layer_of(kind, prec):       # one program a kind and a precision
         kw = {} if kind is None else {"kind": kind}
@@ -114,7 +115,7 @@ def served_gap(cell, seed: int, samples, margin_min: float = 0.0,
     flipped = [np.zeros(len(r), bool) for r in rows]
     t0 = time.perf_counter()
     for li, kind in enumerate(kinds):
-        w = weights.make_group(seed, li, shapes[kind], dtype)
+        w = weights.make_group(seed, li, shapes[kind], dtype, draws[kind])
         for i, x in enumerate(xs):
             xs[i], route = layers[kind](x, w)
             if route is not None:

@@ -60,7 +60,7 @@ def build_model(cell, seed: int):
     prog = config["program"]
     mod = importlib.import_module(prog["module"])
     ref = cell.reference()
-    kinds, shapes = family_layers(ref, config)
+    kinds, shapes, draws = family_layers(ref, config)
     cfg = program_config(config, num_hidden_layers=0)
     model = getattr(mod, prog["model_class"])(cfg)
     if config["dtype"] != "float32":
@@ -92,7 +92,8 @@ def build_model(cell, seed: int):
         jax.block_until_ready([p._value for p in layer.parameters()])
         t1 = time.perf_counter()
         t_init += t1 - t0
-        vals = weights.make_group(seed, li, shapes[kind], config["dtype"])
+        vals = weights.make_group(seed, li, shapes[kind], config["dtype"],
+                                  draws[kind])
         params = dict(layer.named_parameters())
         if set(params) != set(vals):
             raise RuntimeError(

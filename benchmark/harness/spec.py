@@ -42,7 +42,15 @@ What a new FAMILY brings (the whole contract; ``PERF.md`` repeats it):
   one kind also defines ``layer_kinds(config) -> [kind of each layer]``;
   the harness then calls ``layer_shapes(config, kind)`` and
   ``layer(x, w, config, prec, kind=kind)``.  A family without
-  ``layer_kinds`` is never handed a kind.
+  ``layer_kinds`` is never handed a kind.  A family with a leaf that is
+  neither a matrix nor a norm gain (a decay's ``A_log``, a time step's
+  ``dt_bias``) also defines ``leaf_draws(config, kind=None) -> {leaf:
+  draw}``, handed the kind as ``layer_shapes`` is: each draw a tuple of
+  ``weights.py``'s vocabulary, refused with the leaf and family named
+  where it is not one or names no leaf of the shapes.  The program's
+  build and the reference's forward both take it from here
+  (``family_layers``); a leaf it does not name, the top's leaves and
+  every leaf of a family without it keep ``weights.py``'s rule.
 - ``cells/<workload>.json`` (engine, load, ``correct``'s limits; the
   optional ``kernels`` are the Pallas names the v5e rehearsal looks for),
   a traffic mix, readers for its metrics (with a counts module of its
@@ -125,14 +133,25 @@ def reference_module(family: str, bench_dir: str = BENCH_DIR):
 
 
 def family_layers(ref, config: dict):
-    """The kind of each layer and ``{kind: {leaf: shape}}``.  A family
-    that defines no ``layer_kinds`` has one kind, ``None``, and its
-    ``layer_shapes`` and ``layer`` are never handed one."""
+    """The kind of each layer, ``{kind: {leaf: shape}}`` and ``{kind:
+    {leaf: draw}}`` (``weights.py``; empty where the family defines no
+    ``leaf_draws``).  A family that defines no ``layer_kinds`` has one
+    kind, ``None``, and its ``layer_shapes``, ``leaf_draws`` and ``layer``
+    are never handed one."""
+    from . import weights      # imports jax, which run.py defers
     depth = int(config["num_hidden_layers"])
     if not hasattr(ref, "layer_kinds"):
-        return [None] * depth, {None: ref.layer_shapes(config)}
-    kinds = list(ref.layer_kinds(config))
-    if len(kinds) != depth:
-        raise ValueError(f"layer_kinds names {len(kinds)} layers, the "
-                         f"configuration has {depth}")
-    return kinds, {k: ref.layer_shapes(config, k) for k in set(kinds)}
+        kinds, args = [None] * depth, {None: ()}
+    else:
+        kinds = list(ref.layer_kinds(config))
+        if len(kinds) != depth:
+            raise ValueError(f"layer_kinds names {len(kinds)} layers, the "
+                             f"configuration has {depth}")
+        args = {k: (k,) for k in set(kinds)}
+    shapes = {k: ref.layer_shapes(config, *a) for k, a in args.items()}
+    draws = {k: dict(ref.leaf_draws(config, *a))
+             if hasattr(ref, "leaf_draws") else {} for k, a in args.items()}
+    for k, d in draws.items():
+        weights.check_draws(d, shapes[k], f"references/{config['family']}"
+                            f".py, layer kind {k!r}")
+    return kinds, shapes, draws

@@ -89,6 +89,33 @@ def test_family_of_two_kinds_of_layer_comes_as_files_only(root):
         os.path.join(tinytree.BENCH, "references", "densefirst.py"))
 
 
+def test_named_draws_reach_the_program_and_the_reference(root,
+                                                        monkeypatch):
+    """``densefirst`` names the draw of its post-attention gains, (0.5,
+    1.5).  With the program's build handed no draws, so today's gains of
+    1 +- 0.17, and the reference the named ones, the float32 agreement
+    above fails: both sides read the named values."""
+    from harness import program, spec
+    ref = spec.reference_module("densefirst", os.path.join(root,
+                                                           "benchmark"))
+    cfg = spec.Cell("tiny-densefirst.docs", root).config
+    _, _, draws = spec.family_layers(ref, cfg)
+    assert draws == {kind: {"post_attention_layernorm.weight":
+                            ("uniform", 0.5, 1.5)}
+                     for kind in ("dense", "sparse")}
+    family_layers = spec.family_layers
+
+    def no_draws(ref, config):
+        kinds, shapes, _ = family_layers(ref, config)
+        return kinds, shapes, {kind: {} for kind in shapes}
+    monkeypatch.setattr(program, "family_layers", no_draws)
+    result, rc = bench_run.run_cell("tiny-densefirst.docs", 12, 2.0, False,
+                                    root=root, require_chip=False)
+    assert rc == 0 and result["correct"] is False
+    value, limit = result["compared"]["logit_gap_max"]
+    assert value > limit
+
+
 def test_kinds_that_disagree_with_the_layers_fail_at_the_leaves(root):
     """The reference says layer 0 is dense; a configuration that maps the
     kinds to the other classes builds a sparse layer there."""
@@ -104,9 +131,11 @@ def test_kinds_that_disagree_with_the_layers_fail_at_the_leaves(root):
 
 
 # sha256 (16 hex digits) of every group's leaves, in the order of the
-# reference's shapes, as float32 bytes: taken on PR 25's tree (one
-# ``layer_class``, one ``layer_shapes``) at tinytree's sizes, seed
-# 2**31 + 7, groups top, layer 0, layer 1
+# reference's shapes, as float32 bytes, at tinytree's sizes, seed
+# 2**31 + 7, groups top, layer 0, layer 1: mistral's and mixtral's taken
+# while the harness had one ``layer_class`` and one ``layer_shapes``,
+# deepseek_v2's (layer 0 dense, layer 1 sparse) before a reference could
+# name a leaf's draw
 PARENTS_GROUPS = {
     ("mistral", "float32"): ["23c097ad2bab10cb", "ec24dadad0f1e2bc",
                              "f10ee93e6cbc6703"],
@@ -116,22 +145,34 @@ PARENTS_GROUPS = {
                              "3a4699a8967ad4c7"],
     ("mixtral", "bfloat16"): ["b101362a087826cf", "83937e1bfa725a5d",
                               "98027114ade32000"],
+    ("deepseek_v2", "float32"): ["23c097ad2bab10cb", "6ef09cabb3657965",
+                                 "c6efe2d0fb89e055"],
+    ("deepseek_v2", "bfloat16"): ["b101362a087826cf", "ce18dac0ef158c34",
+                                  "63284078bf9e7294"],
 }
 
 
 @pytest.mark.parametrize("family,dtype", sorted(PARENTS_GROUPS))
 def test_seeded_weights_are_the_parents(root, family, dtype):
     """A family of one kind gets the group index, the shapes and so the
-    values it got before layers could be of more than one kind."""
+    values it got before layers could be of more than one kind; every
+    family, those of two kinds too, the values it got before a reference
+    could name a leaf's draw."""
     import numpy as np
     from harness import spec, weights
-    cell = spec.Cell(f"tiny-{family}."
-                     + ("chat" if family == "mistral" else "docs"), root)
-    cfg, ref = dict(cell.config, dtype=dtype), cell.reference()
-    kinds, shapes = spec.family_layers(ref, cfg)
-    assert kinds == [None, None]
+    if family == "deepseek_v2":
+        cfg, ref = tinytree.DEEPSEEK, spec.reference_module(family)
+    else:
+        cell = spec.Cell(f"tiny-{family}."
+                         + ("chat" if family == "mistral" else "docs"), root)
+        cfg, ref = cell.config, cell.reference()
+    cfg = dict(cfg, dtype=dtype)
+    kinds, shapes, draws = spec.family_layers(ref, cfg)
+    assert kinds[:2] == (["dense", "sparse"] if family == "deepseek_v2"
+                         else [None, None])
+    assert draws == {kind: {} for kind in shapes}
     groups = [(weights.TOP, ref.top_shapes(cfg))] + [
-        (li, shapes[kind]) for li, kind in enumerate(kinds)]
+        (li, shapes[kinds[li]]) for li in (0, 1)]
     sums = []
     for group, leaves in groups:
         vals = weights.make_group(2 ** 31 + 7, group, leaves, dtype)

@@ -12,23 +12,7 @@ import pytest
 import run as bench_run
 import tinytree
 
-TINY = {
-    "vocab_size": 512, "hidden_size": 128, "intermediate_size": 256,
-    "moe_intermediate_size": 64, "num_hidden_layers": 3,
-    "num_attention_heads": 4, "num_key_value_heads": 4,
-    "q_lora_rank": 48, "kv_lora_rank": 32, "qk_nope_head_dim": 32,
-    "qk_rope_head_dim": 16, "v_head_dim": 32, "n_routed_experts": 4,
-    "router_experts": 16, "first_held_expert": 4, "n_shared_experts": 2,
-    "num_experts_per_tok": 3, "n_group": 4, "topk_group": 2,
-    "routed_scaling_factor": 2.5, "norm_topk_prob": False,
-    "first_k_dense_replace": 1, "max_position_embeddings": 512,
-    "rms_norm_eps": 1e-06, "rope_theta": 10000,
-    "rope_scaling": {"type": "yarn", "factor": 4,
-                     "original_max_position_embeddings": 32,
-                     "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
-                     "mscale_all_dim": 0.707},
-    "dtype": "float32", "family": "deepseek_v2", "source": "test",
-    "reduced": []}
+TINY = tinytree.DEEPSEEK
 CELL = "tiny-deepseek.longdocs"
 
 

@@ -18,8 +18,31 @@ TINY = {"vocab_size": 512, "hidden_size": 128, "intermediate_size": 256,
         "dtype": "float32", "source": "test", "reduced": []}
 
 
+# DeepSeek-V2 at a tiny size: latent attention, a dense layer 0, then
+# layers that hold 4 of the 16 experts their router scores
+DEEPSEEK = {
+    "vocab_size": 512, "hidden_size": 128, "intermediate_size": 256,
+    "moe_intermediate_size": 64, "num_hidden_layers": 3,
+    "num_attention_heads": 4, "num_key_value_heads": 4,
+    "q_lora_rank": 48, "kv_lora_rank": 32, "qk_nope_head_dim": 32,
+    "qk_rope_head_dim": 16, "v_head_dim": 32, "n_routed_experts": 4,
+    "router_experts": 16, "first_held_expert": 4, "n_shared_experts": 2,
+    "num_experts_per_tok": 3, "n_group": 4, "topk_group": 2,
+    "routed_scaling_factor": 2.5, "norm_topk_prob": False,
+    "first_k_dense_replace": 1, "max_position_embeddings": 512,
+    "rms_norm_eps": 1e-06, "rope_theta": 10000,
+    "rope_scaling": {"type": "yarn", "factor": 4,
+                     "original_max_position_embeddings": 32,
+                     "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+                     "mscale_all_dim": 0.707},
+    "dtype": "float32", "family": "deepseek_v2", "source": "test",
+    "reduced": []}
+
+
 # The family a later PR brings: layers of two kinds, layer 0 dense and the
-# rest sparse, its reference a dozen lines over its siblings'.
+# rest sparse, its reference a dozen lines over its siblings'.  It names
+# the draw of one leaf (``leaf_draws``): its post-attention norm's gain is
+# drawn from (0.5, 1.5), not from today's 1 +- 0.17.
 DENSEFIRST = '''"""Throw-away family: ``first_k_dense_replace`` dense layers (as
 ``mistral``), then sparse ones (as ``mixtral``)."""
 import importlib.util
@@ -49,6 +72,10 @@ def layer_kinds(cfg):
 
 def layer_shapes(cfg, kind):
     return _OF[kind].layer_shapes(cfg)
+
+
+def leaf_draws(cfg, kind=None):
+    return {"post_attention_layernorm.weight": ("uniform", 0.5, 1.5)}
 
 
 def layer(x, w, cfg, prec=None, kind=None):
