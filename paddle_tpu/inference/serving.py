@@ -391,14 +391,43 @@ class ContinuousBatchingEngine:
                         % (what, type(model).__name__))
         latent_width = self.latent.latent_row \
             if self.latent is not None else None
+        # a model with linear-attention layers keeps, beside the pools of
+        # its other layers, a fixed-size state a slot (``SlotStates``);
+        # what moves, shares, shards or drafts K/V pages is not taught it
+        from ..jit.serving_step import (SlotStates, layer_pools,
+                                        linear_attention)
+        linear = linear_attention(model)
+        if linear:
+            for on, what in (
+                    (kv_dtype == "int8", "kv_dtype='int8'"),
+                    (self.tp is not None, "a mesh (tp/cp/ep shard pools "
+                     "over kv heads or slots)"),
+                    (draft_model is not None, "a draft model"),
+                    (role != "mixed", "role=%r (migration moves K and V "
+                     "pages, not a slot's state)" % (role,)),
+                    (enable_prefix_cache, "enable_prefix_cache (a prefix "
+                     "hit skips the tokens that build the state)")):
+                if on:
+                    raise ValueError(
+                        "ContinuousBatchingEngine: %s is not taught the "
+                        "per-slot linear-attention state of %s"
+                        % (what, type(model).__name__))
+        # the map from layer to pool: an index into ``caches`` for a
+        # layer with K/V (or latent) pages, None for a linear layer
+        self.layer_pools = layer_pools(model)
         self.caches = [
             PagedKVCache(num_blocks, block_size,
-                         cfg.num_key_value_heads, self.head_dim, dtype,
+                         getattr(getattr(layer, "self_attn", None),
+                                 "pool_heads", cfg.num_key_value_heads),
+                         self.head_dim, dtype,
                          sink_block=True, kv_dtype=kv_dtype,
                          latent_width=getattr(
                              getattr(layer, "self_attn", None),
                              "latent_row", None))
-            for layer in _inner_model(model).layers]
+            for layer, j in zip(_inner_model(model).layers,
+                                self.layer_pools) if j is not None]
+        self.states = SlotStates(linear, max_batch_size, dtype) \
+            if linear else None
         # per-channel absmax PTQ: quantize ONCE at construction; every
         # step consumes the same int8+scales tree via dequant-on-use
         if weight_quant == "int8":
@@ -465,7 +494,7 @@ class ContinuousBatchingEngine:
                                weight_qparams=self.weight_qtree,
                                quant_collectives=self.quant_collectives,
                                sampling=self.sampling,
-                               spec_k=self.spec_k)
+                               spec_k=self.spec_k, states=self.states)
         # padding tokens spread over the sink page's slots
         self._dest_pad = (np.arange(budgets[-1], dtype=np.int32)
                           % block_size)
@@ -755,6 +784,18 @@ class ContinuousBatchingEngine:
         self._m_latent_row.set(
             latent_width * self.caches[0].key_cache.dtype.itemsize
             if latent_width else 0)
+        self._m_linear_state = r.gauge(
+            "serving_linear_state_bytes",
+            "bytes one slot's linear-attention state and convolution tail "
+            "take over every linear layer of the most recently "
+            "constructed engine (0 = no linear-attention layer)")
+        self._m_linear_state.set(
+            self.states.slot_bytes() if self.states is not None else 0)
+        self._m_state_fresh = r.counter(
+            "serving_state_fresh_total",
+            "spans of a model with linear-attention layers that started "
+            "from zero state (a request's first chunk: the step reads "
+            "zeros for it, whatever its slot held)")
         self._m_ep_collective = r.counter(
             "serving_ep_collective_bytes_total",
             "per-chip bytes moved by the expert-parallel dispatch "
@@ -1078,6 +1119,12 @@ class ContinuousBatchingEngine:
           ``moe_tiles`` x the tile's rows is how much of what the
           experts multiplied was a token's.  Counted by the step under
           either lowering; the Pallas launch visits exactly these.
+        - ``gdn_rows``, ``gdn_states``, ``gdn_chunks``: for a model with
+          linear-attention layers, summed over those layers: the token
+          rows through them, the slot states read and written (one a
+          span a layer), and the sub-chunks of the chunked scan (a
+          chunk span's ``ceil(q_len / 64)``; a one-token span takes the
+          decode update and no sub-chunk).  0 for any other model.
         - ``admitted``: request ids admitted since the last record.
         - ``running``, ``waiting``: occupied slots and queue depth at
           the step's end.  ``compiled``: this call's launch traced a
@@ -1116,12 +1163,25 @@ class ContinuousBatchingEngine:
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
             moe_tiles=rec.get("moe_tiles", 0),
+            **self._gdn_counts(spans[:, 1]),
             ahead=rec["ahead"], discarded=rec["discarded"],
             launch_step=rec["launch_step"],
             admitted=tuple(self._admitted), running=running,
             waiting=len(self.waiting), compiled=rec["compiled"],
             trace_s=rec["trace_s"], lower_s=rec["lower_s"],
             compile_s=rec["compile_s"])
+
+    def _gdn_counts(self, q_lens) -> Dict[str, int]:
+        """The record's linear-attention fields for spans of these
+        lengths, summed over the linear layers (all 0 where none)."""
+        n = self.states.layers if self.states is not None else 0
+        if not n:
+            return {"gdn_rows": 0, "gdn_states": 0, "gdn_chunks": 0}
+        from ..ops.pallas_kernels import gdn_sub_chunks
+        q = np.asarray(q_lens)
+        return {"gdn_rows": n * int(q.sum()),
+                "gdn_states": n * int(np.sum(q > 0)),
+                "gdn_chunks": n * gdn_sub_chunks(q)}
 
     @staticmethod
     def _record_build(rec: dict, before: Dict[str, float]):
@@ -1193,7 +1253,7 @@ class ContinuousBatchingEngine:
         take (a failed migration degrades to paying the prefill
         twice)."""
         if self.tp is not None or self.draft_step is not None \
-                or self.latent is not None:
+                or self.latent is not None or self.states is not None:
             return None
         return (len(self.caches),) + self.caches[0].page_geometry()
 
@@ -1272,6 +1332,11 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "page migration is not taught the latent (MLA) cache "
                 "row: a KVPageBuffer carries K and V pages per kv head")
+        if self.states is not None:
+            raise ValueError(
+                "page migration is not taught the per-slot linear-"
+                "attention state: a KVPageBuffer carries K and V pages "
+                "and no slot's state (resume through add_request)")
         if self.draft_step is not None:
             raise ValueError(
                 "a speculative engine cannot accept migrated pages: "
@@ -1750,6 +1815,8 @@ class ContinuousBatchingEngine:
                 continue
             row[W + 2] = start + m
             row[:len(r.block_ids)] = r.block_ids
+            if mx.slot_col is not None:
+                row[mx.slot_col] = r.slot
             if nd_col >= 0:
                 row[nd_col] = nd
             if mx.sampling:
@@ -1904,6 +1971,11 @@ class ContinuousBatchingEngine:
             self._m_mixed_tok_decode.inc(n_dec)
         if n_pre:
             self._m_mixed_tok_prefill.inc(n_pre)
+        if self.states is not None:
+            fresh = sum(1 for _, kind, _, start in spans
+                        if kind != "decode" and start == 0)
+            if fresh:
+                self._m_state_fresh.inc(fresh)
         if self._moe_layers:
             # dropless dispatch: every token the launch carried lands on
             # exactly top_k experts per MoE layer, none are dropped

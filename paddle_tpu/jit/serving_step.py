@@ -107,7 +107,11 @@ STEP_SCOPES = frozenset((
     "moe.combine", "ep.all_to_all", "lm_head", "sample",
     # a latent-attention layer (MLA) and a held share of routed experts
     "attn.q_lora", "attn.kv_latent", "attn.absorb", "attn.unabsorb",
-    "moe.shared", "moe.sort"))
+    "moe.shared", "moe.sort",
+    # a gated-delta linear-attention layer (Gated DeltaNet) and a full
+    # attention with whole-projection q/k norms
+    "gdn.proj", "gdn.conv", "gdn.kernel", "gdn.gate_norm", "gdn.out",
+    "attn.qk_norm"))
 
 # Kernels XLA:TPU makes itself and names itself (their ``op_name`` is the
 # kernel's, the path of scopes is gone): instruction-name prefix -> scope.
@@ -193,18 +197,19 @@ def _resolve_tp(model, mesh, sharding, tp: Optional[TPContext]
 
 def _inner_model(model):
     """The decoder stack behind a CausalLM wrapper — ``model.llama``
-    (dense) or ``model.mixtral`` (MoE, round 24).  Both expose the same
-    ``embed_tokens / layers / norm`` surface, which is everything the
-    traced bodies touch; per-layer FFN dispatch branches on the LAYER
-    (``block_sparse_moe`` vs ``mlp``), not the wrapper."""
-    for name in ("llama", "mixtral", "deepseek"):
+    (dense), ``model.mixtral`` (MoE, round 24), ``model.deepseek`` or
+    ``model.olmo_hybrid``.  All expose the same ``embed_tokens / layers /
+    norm`` surface, which is everything the traced bodies touch;
+    per-layer dispatch branches on the LAYER's modules, not the
+    wrapper."""
+    for name in ("llama", "mixtral", "deepseek", "olmo_hybrid"):
         inner = getattr(model, name, None)
         if inner is not None:
             return inner
     raise ValueError(
         "serving steps need a LlamaForCausalLM-shaped model (an inner "
-        ".llama, .mixtral or .deepseek decoder stack); got %r"
-        % type(model).__name__)
+        ".llama, .mixtral, .deepseek or .olmo_hybrid decoder stack); got "
+        "%r" % type(model).__name__)
 
 
 def _step_body(module, default: str) -> str:
@@ -226,6 +231,58 @@ def latent_attention(model):
         if _step_body(at, "gqa") == "mla":
             return at
     return None
+
+
+def layer_pools(model) -> List[Optional[int]]:
+    """The map from layer to pool: for each decoder layer the index of
+    its pool in the engine's ``caches``, or ``None`` for a layer whose
+    attention keeps a fixed-size state a slot instead (``serving_body``
+    "gated_delta": ``SlotStates``).  The pools are the other layers',
+    in order, so ``caches[0]`` is the first of them."""
+    out, n = [], 0
+    for layer in _inner_model(model).layers:
+        linear = _step_body(getattr(layer, "self_attn", None),
+                            "gqa") == "gated_delta"
+        out.append(None if linear else n)
+        n += not linear
+    return out
+
+
+def linear_attention(model):
+    """The model's linear-attention modules, in layer order: what
+    ``SlotStates`` holds a state for.  Empty where no layer has one."""
+    return [layer.self_attn for layer, j in zip(_inner_model(model).layers,
+                                                layer_pools(model))
+            if j is None]
+
+
+class SlotStates:
+    """What a linear-attention layer keeps a slot, on the device, for
+    every such layer: ``states[i]`` ``[slots + 1, heads, d_v, d_k]``
+    float32 and ``tails[i]`` ``[slots + 1, K - 1, channels]`` (the
+    convolution's last inputs) in the model's type.  The last row is the
+    sink that the pack's padding spans read and write.  They enter the
+    mixed step as donated operands and come back aliased, as the pools
+    do; a span whose first position is 0 reads zeros in their place
+    inside the step, so nothing resets a slot from the host."""
+
+    def __init__(self, modules, slots: int, dtype):
+        self.sink = int(slots)
+        self.states = tuple(
+            jnp.zeros((slots + 1, m.num_heads, m.dv, m.dk), jnp.float32)
+            for m in modules)
+        self.tails = tuple(
+            jnp.zeros((slots + 1, m.conv_k - 1, m.conv_channels), dtype)
+            for m in modules)
+
+    @property
+    def layers(self) -> int:
+        return len(self.states)
+
+    def slot_bytes(self) -> int:
+        """One slot's state and tail, over every linear layer."""
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for a in self.states + self.tails)
 
 
 def _embed(llama, tokens, tp: Optional[TPContext]) -> Tensor:
@@ -527,9 +584,11 @@ def _wrap_sharded(step, tp: TPContext, params_dict, n_layers: int,
     pspecs = {k: tp.specs[k] for k in params_dict}
     pools = (tp.layout.kv_pool(),) * n_layers
     spools = (tp.layout.kv_scale(),) * n_layers if quant_kv else ()
+    # the last operand and output: the per-slot states, which a sharded
+    # step never has (an empty tuple)
     in_specs = (pspecs,) + (repl,) * n_repl + (pools, pools,
-                                               spools, spools)
-    out_specs = (repl, pools, pools, spools, spools)
+                                               spools, spools, ())
+    out_specs = (repl, pools, pools, spools, spools, ())
     if tp.fsdp_axis is not None:
         inner, faxis = step, tp.fsdp_axis
 
@@ -875,10 +934,15 @@ class MixedStep:
                  tp: Optional[TPContext] = None,
                  weight_qparams=None, quant_collectives: bool = False,
                  sampling: bool = False, spec_k: int = 0,
-                 return_probs: bool = False):
+                 return_probs: bool = False,
+                 states: Optional[SlotStates] = None):
         from ..core.device import on_tpu
         self.model = model
         self.caches = caches
+        # the map from layer to pool, and the per-slot state of the
+        # layers that have none (a linear-attention layer)
+        self._pools = layer_pools(model)
+        self.states = states
         self.cfg = model.config
         self.bt_width = bt_width
         self.max_spans = max_spans
@@ -901,6 +965,17 @@ class MixedStep:
         # layout, so default packs are byte-identical.
         self.row_extra = (4 + (1 if self.spec_k else 0)
                           + (4 if self.sampling else 0))
+        # a model with linear-attention layers: one more column, the
+        # span's slot (the row of the state bank it reads and writes)
+        n_lin = sum(j is None for j in self._pools)
+        if n_lin != (states.layers if states is not None else 0):
+            raise ValueError(
+                "MixedStep: %d linear-attention layer(s) need a SlotStates "
+                "of as many; got %r" % (n_lin, states))
+        self.slot_col = None
+        if n_lin:
+            self.slot_col = bt_width + self.row_extra
+            self.row_extra += 1
         self.sink = caches[0].sink
         if self.sink < 0:
             raise ValueError("MixedStep needs a sink page "
@@ -926,11 +1001,15 @@ class MixedStep:
         # token; what shards, quantizes or drafts K/V pages is not
         # taught that row
         self._latent = latent_attention(model)
-        for li, (layer, c) in enumerate(zip(_inner_model(model).layers,
-                                            caches)):
+        if len(caches) != len(self._pools) - n_lin:
+            raise ValueError(
+                "MixedStep: %d pools for the %d layers that keep K/V "
+                "pages" % (len(caches), len(self._pools) - n_lin))
+        for li, (layer, j) in enumerate(zip(_inner_model(model).layers,
+                                            self._pools)):
             at = getattr(layer, "self_attn", None)
-            if (_step_body(at, "gqa") == "mla") != bool(
-                    getattr(c, "latent", False)):
+            if j is not None and (_step_body(at, "gqa") == "mla") != bool(
+                    getattr(caches[j], "latent", False)):
                 raise ValueError(
                     "MixedStep: layer %d's pool does not fit its "
                     "attention: a latent-attention layer needs a "
@@ -944,6 +1023,15 @@ class MixedStep:
                     raise ValueError(
                         "MixedStep: %s is not taught the latent "
                         "(MLA) cache row" % what)
+        if self.slot_col is not None:
+            for on, what in ((self._tp is not None, "a mesh (tp/cp/ep)"),
+                             (self.spec_k or self.return_probs,
+                              "speculative decoding"),
+                             (self._quant_kv, "int8 pools")):
+                if on:
+                    raise ValueError(
+                        "MixedStep: %s is not taught the per-slot state "
+                        "of a linear-attention layer" % what)
         # a step that holds the sorted grouped product reports the rows
         # its experts were given and the row tiles they took: [moe_rows,
         # moe_rows_top, moe_tiles, load x El] int32 behind the sampled
@@ -994,8 +1082,11 @@ class MixedStep:
         cfg = self.cfg
         deg = self._tp.degree if self._tp is not None else 1
         cache = self.caches[0]
+        # the heads the launch sees: the pool's (a pool may store more
+        # than the model has, ``pool_heads``), one group size
+        hkv = cache.num_kv_heads // deg
         return ragged_tile_geometry(
-            cfg.num_attention_heads // deg, cfg.num_key_value_heads // deg,
+            hkv * (cfg.num_attention_heads // cfg.num_key_value_heads), hkv,
             cache.head_dim, cache.block_size, self.bt_width,
             "bfloat16" if cfg.dtype == "bfloat16" else "float32",
             cache.kv_dtype)
@@ -1134,7 +1225,15 @@ class MixedStep:
             from ..ops.sampling import (filtered_probs, sample_logits,
                                         spec_verify)
 
-        def step(params, pack, prev, q_probs, kcs, vcs, kss, vss):
+        slot_col = self.slot_col
+        post_norm_layers = [bool(getattr(layer, "post_norm", False))
+                            for layer in llama.layers]
+        pools = self._pools
+        if slot_col is not None:
+            from ..ops.gated_delta import ragged_causal_conv, \
+                ragged_gated_delta
+
+        def step(params, pack, prev, q_probs, kcs, vcs, kss, vss, sts):
             self.compile_counts[T] = self.compile_counts.get(T, 0) + 1
             # unpack the single host buffer (free at trace level —
             # slices of a constant layout): rows 0-4 of the leading
@@ -1174,9 +1273,12 @@ class MixedStep:
                 col += 1
             if sampling:
                 s_t, s_k, s_p, s_sd = _samp_knobs(span_tab[:, col:col + 4])
+            if slot_col is not None:
+                slots = span_tab[:, slot_col]
             params = _materialize_params(params, pdtype)
             new_kcs, new_vcs = [], []
             new_kss, new_vss = [], []
+            new_sts, new_tls = [], []
             with model.bind_state(params), no_grad():
                 with jax.named_scope("embed"):
                     x = _embed(llama, tokens[None, :], tp)     # [1, T, h]
@@ -1281,25 +1383,93 @@ class MixedStep:
                         x = x + at.o_proj(out)
                     return x, kc, None, None, None
 
-                attention = {"gqa": gqa_attention, "mla": mla_attention}
+                def qk_norm_attention(layer, x, li, kc, vc):
+                    """Full attention of a post-norm block with
+                    whole-projection q/k norms and no rope (Olmo
+                    hybrid's) over K and V pools of ``pool_heads`` heads
+                    (one group; the heads past the model's are zeros):
+                    x plus the normed block output, and the pools as
+                    written."""
+                    at = layer.self_attn
+                    pad = ((0, 0), (0, at.pool_heads - H), (0, 0))
+                    with jax.named_scope("attn.qkv"):
+                        q = at.q_proj(x)
+                        k = at.k_proj(x)
+                        v = jnp.pad(at.v_proj(x)._value[0].reshape(
+                            T, Hkv, D), pad)
+                    with jax.named_scope("attn.qk_norm"):
+                        q = jnp.pad(at.q_norm(q)._value[0].reshape(
+                            T, H, D), pad)
+                        k = jnp.pad(at.k_norm(k)._value[0].reshape(
+                            T, Hkv, D), pad)
+                    with jax.named_scope("attn.kv_write"):
+                        kc, vc = write_ragged_kv(k, v, kc, vc, dest_blocks,
+                                                 dest_offsets)
+                    out = attn(q, kc, vc, bt, q_offsets, q_lens, kv_lens)
+                    with jax.named_scope("attn.out"):
+                        out = at.o_proj(Tensor._from_value(
+                            out[:, :H].reshape(1, T, H * D)))
+                        x = x + layer.post_attention_layernorm(out)
+                    return x, kc, vc, None, None
+
+                def gated_delta_layer(layer, x, state, tail):
+                    """Gated DeltaNet of a post-norm block over the
+                    slots' states: x plus the normed block output, and
+                    the state and convolution tail banks as written."""
+                    at = layer.self_attn
+                    with jax.named_scope("gdn.proj"):
+                        qkv, z, b, a = at.projections(x)
+                        beta, g = at.gates(b[0], a[0])
+                    with jax.named_scope("gdn.conv"):
+                        conv, tail = ragged_causal_conv(
+                            qkv[0], at.conv_weight._value, tail, slots,
+                            q_offsets, q_lens, kv_lens)
+                        q, k, v = at.qkv_heads(conv)
+                    with jax.named_scope("gdn.kernel"):
+                        o, state = ragged_gated_delta(
+                            q, k, v, beta, g, state, slots, q_offsets,
+                            q_lens, kv_lens, use_pallas=use_pallas)
+                    with jax.named_scope("gdn.gate_norm"):
+                        o = at.gate_norm(o, z[0])
+                    with jax.named_scope("gdn.out"):
+                        out = at.o_proj(Tensor._from_value(o[None]))
+                        x = x + layer.post_attention_layernorm(out)
+                    return x, state, tail
+
+                attention = {"gqa": gqa_attention, "mla": mla_attention,
+                             "gqa_qk_norm": qk_norm_attention}
                 loads = []      # [El] int32 a sorted MoE layer
                 real = None
                 if n_stats:
                     with jax.named_scope("moe.sort"):
                         real = _real_rows(T, q_offsets, q_lens)
-                for li, (layer, kc, vc) in enumerate(
-                        zip(llama.layers, kcs, vcs)):
-                    body = attention[_step_body(layer.self_attn, "gqa")]
-                    x, kc, vc, ks, vs = body(layer, x, li, kc, vc)
-                    new_kcs.append(kc)
-                    new_vcs.append(vc)
-                    if quant_kv:
-                        new_kss.append(ks)
-                        new_vss.append(vs)
+                for li, layer in enumerate(llama.layers):
+                    j = pools[li]
+                    if j is None:
+                        m = len(new_sts)
+                        x, st, tl = gated_delta_layer(layer, x, sts[0][m],
+                                                      sts[1][m])
+                        new_sts.append(st)
+                        new_tls.append(tl)
+                    else:
+                        body = attention[_step_body(layer.self_attn,
+                                                    "gqa")]
+                        x, kc, vc, ks, vs = body(layer, x, j, kcs[j],
+                                                 vcs[j])
+                        new_kcs.append(kc)
+                        new_vcs.append(vc)
+                        if quant_kv:
+                            new_kss.append(ks)
+                            new_vss.append(vs)
                     with jax.named_scope("ffn"):
-                        h2 = layer.post_attention_layernorm(x)
-                        x = x + _ffn(layer, h2, tp, real, loads,
-                                     use_pallas)
+                        if post_norm_layers[li]:
+                            x = x + layer.post_feedforward_layernorm(
+                                _ffn(layer, x, tp, real, loads, use_pallas))
+                        else:
+                            h2 = layer.post_attention_layernorm(x)
+                            x = x + _ffn(layer, h2, tp, real, loads,
+                                         use_pallas)
+                sts_out = (tuple(new_sts), tuple(new_tls)) if sts else ()
                 with jax.named_scope("lm_head"):
                     x = llama.norm(x)
                     # only each span's sampled rows reach the LM head:
@@ -1356,7 +1526,7 @@ class MixedStep:
                                          axis=-1).astype(jnp.int32)
                     nxt = jnp.where(n_draft > 0, e_v, e_p)
                     return (nxt, n_acc, tuple(new_kcs), tuple(new_vcs),
-                            tuple(new_kss), tuple(new_vss))
+                            tuple(new_kss), tuple(new_vss), sts_out)
                 if sampling:
                     # counter = kv_len — the sampled token's global
                     # position, so seeded tokens agree across engines
@@ -1378,19 +1548,20 @@ class MixedStep:
                 if return_probs:
                     return (nxt, filtered_probs(lv, s_t, s_k, s_p),
                             tuple(new_kcs), tuple(new_vcs),
-                            tuple(new_kss), tuple(new_vss))
+                            tuple(new_kss), tuple(new_vss), sts_out)
                 return (nxt, tuple(new_kcs), tuple(new_vcs),
-                        tuple(new_kss), tuple(new_vss))
+                        tuple(new_kss), tuple(new_vss), sts_out)
 
         if n_stats:
             step = _traced_x64_off(step)
         if spec_k and sampling:
-            fn, donate = step, (4, 5, 6, 7)
+            fn, donate = step, (4, 5, 6, 7, 8)
         else:
             # no draft-probs operand when sampling/spec are not both on
-            def fn(params, pack, prev, kcs, vcs, kss, vss):
-                return step(params, pack, prev, None, kcs, vcs, kss, vss)
-            donate = (3, 4, 5, 6)
+            def fn(params, pack, prev, kcs, vcs, kss, vss, sts):
+                return step(params, pack, prev, None, kcs, vcs, kss, vss,
+                            sts)
+            donate = (3, 4, 5, 6, 7)
         fn = _named(fn, "mixed_step")
         if tp is None:
             return jax.jit(fn, donate_argnums=donate)
@@ -1430,7 +1601,8 @@ class MixedStep:
         dest block / dest offset / src) and span_tab
         [max_spans, bt_width+row_extra] (block-table columns then
         q_offset / q_len / kv_len / sample_row, + n_draft under spec,
-        + the 4 bitcast sampling-knob columns under sampling) are VIEWS
+        + the 4 bitcast sampling-knob columns under sampling, + the
+        span's slot where the model has linear-attention layers) are VIEWS
         into pack — fill them, then hand pack to ``launch`` or
         ``call_packed``.  The src row comes as -1 (every token from the
         pack) and the extra tail columns pre-zeroed (greedy, no
@@ -1443,6 +1615,9 @@ class MixedStep:
         span_tab = pack[5 * T:].reshape(S, W + self.row_extra)
         if self.row_extra > 4:
             span_tab[:, W + 4:] = 0
+        if self.slot_col is not None:
+            # a padding span reads and writes the sink row of the bank
+            span_tab[:, self.slot_col] = self.states.sink
         return pack, tok_tab, span_tab
 
     def aot_lower(self, T: int, device_sharding=None):
@@ -1476,7 +1651,7 @@ class MixedStep:
             args.append(tuple(
                 jnp.zeros((self.max_spans, V), jnp.float32)
                 for _ in range(self.spec_k)))
-        args += [kcs, vcs, kss, vss]
+        args += [kcs, vcs, kss, vss, self._state_operands()]
         if device_sharding is not None:
             args = _shapes_on(args, device_sharding)
         return fn.lower(*args)
@@ -1492,6 +1667,14 @@ class MixedStep:
         if T not in cache:
             cache[T] = compiled_cost_stats(self.aot_lower(T), T)
         return cache[T]
+
+    def _state_operands(self):
+        """``(states, tails)`` of the engine's ``SlotStates``, or an
+        empty tuple (the step of a model with no linear-attention
+        layer traces nothing for it)."""
+        if self.states is None:
+            return ()
+        return (self.states.states, self.states.tails)
 
     def _prev_operand(self, params, pools):
         """The ``prev`` operand: the newest launch's sampled-token array,
@@ -1551,11 +1734,14 @@ class MixedStep:
                 args.append(tuple(q_probs))
         # the operands are gathered: what follows is the jitted call
         # alone (the step record's t_launch .. t_dispatch)
+        sts = self._state_operands()
         self.t_launch = time.perf_counter()
         with jax.profiler.TraceAnnotation("engine.launch"):
-            out = fn(*args, kcs, vcs, kss, vss)
+            out = fn(*args, kcs, vcs, kss, vss, sts)
             n_out = 2 if self.spec_k or self.return_probs else 1
-            _rebind_caches(self.caches, *out[n_out:])
+            _rebind_caches(self.caches, *out[n_out:n_out + 4])
+            if sts:
+                self.states.states, self.states.tails = out[n_out + 4]
         self._prev = out[0]
         self._unfetched.append(out[:n_out])
         # the launch is enqueued: what follows is the wait for the

@@ -19,3 +19,5 @@ from .mixtral import (MixtralConfig, MixtralModel, MixtralForCausalLM,
                       mixtral_tiny_config, shard_mixtral)
 from .deepseek_v2 import (DeepseekV2Config, DeepseekV2Model,
                           DeepseekV2ForCausalLM, deepseek_v2_tiny_config)
+from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridModel,
+                          OlmoHybridForCausalLM, olmo_hybrid_tiny_config)

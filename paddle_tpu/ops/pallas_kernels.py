@@ -2724,6 +2724,296 @@ def grouped_expert_matmul(xs, slots, *banks, tile: int, interpret=False):
 
 
 # ---------------------------------------------------------------------------
+# ragged gated delta rule (Gated DeltaNet linear attention): one grid cell
+# a span of the pack, its slot's state read once and written once
+# ---------------------------------------------------------------------------
+# The rule and its sequential form are ``ops/gated_delta.py``.  A span's
+# state ``[H, dv, dk]`` float32 comes in and goes out through the BlockSpec
+# pipeline (the bank aliased in and out, the block of the span's slot), so
+# the next span's state streams while this one computes.  Rows come from
+# the token-major pack by DMA at the span's offset, every head at once,
+# with head widths padded to whole lanes: ``q`` carries beta and g in the
+# two lanes after its ``dk`` (one copy for the three).  A decode span is
+# the one-token update on the vector unit (``_gdn_decode_head``); a chunk
+# is walked in sub-chunks of _GDN_CHUNK rows in FLA's chunked form
+# (``_gdn_chunk_head``: the WY/UT representation within a sub-chunk, the
+# decays cumulated in float32, the state carried to the next).  Every
+# product is float32 (``Precision.HIGHEST``).  Output rows are written a
+# whole window at a time: the rows past a span's end are finite garbage
+# that the next span, later in the sequential grid, overwrites (the
+# ragged attention launch's rule).
+_GDN_CHUNK = 64
+_GDN_DECODE_ROWS = 8
+_GDN_VMEM = 96 << 20
+_HI = lax.Precision.HIGHEST
+
+
+def _gdn_widths(dk: int, dv: int):
+    """``(dk_pad, dv_pad)``: q/k rows with beta and g behind ``dk``, and
+    v / o rows, in whole 128-lane tiles."""
+    return (-(-(dk + 2) // _LANES) * _LANES, -(-dv // _LANES) * _LANES)
+
+
+def gdn_sub_chunks(q_lens) -> int:
+    """The sub-chunks of _GDN_CHUNK rows the launch scans for spans of
+    these lengths (a one-token span takes the decode update, no
+    sub-chunk)."""
+    q = np.asarray(q_lens, np.int64)
+    q = q[q > 1]
+    return int(np.sum(-(-q // _GDN_CHUNK)))
+
+
+def _gdn_lane(x, lane: int):
+    """Column ``lane`` of ``x [R, L]`` as ``[R, 1]``, by a masked lane
+    sum: a layout Mosaic broadcasts along one axis at a time."""
+    at = lax.broadcasted_iota(jnp.int32, x.shape, 1) == lane
+    return jnp.sum(jnp.where(at, x, 0.0), axis=1, keepdims=True)
+
+
+def _gdn_decode_head(st, h, qbuf, kbuf, vbuf, obuf, *, dk: int, dv: int):
+    """Head ``h`` of a one-token span: ``S' = e^g S + beta (v - e^g S k)
+    k^T``, ``o = S' q``, as broadcasts and lane / sublane sums (no
+    matrix unit: a matrix-vector product).  ``v`` and ``o`` change
+    between row and column through an identity mask."""
+    qrow = qbuf[pl.ds(0, 1), h, :]                       # [1, dk_pad]
+    krow = kbuf[pl.ds(0, 1), h, :][:, :dk]
+    vrow = vbuf[pl.ds(0, 1), h, :]                       # [1, dv_pad]
+    q = qrow[:, :dk]
+    beta = jnp.broadcast_to(_gdn_lane(qrow, dk), (dv, 1))
+    decay = jnp.broadcast_to(jnp.exp(_gdn_lane(qrow, dk + 1)), (1, dk))
+    dvp = vrow.shape[1]
+    eye = (lax.broadcasted_iota(jnp.int32, (dv, dvp), 0)
+           == lax.broadcasted_iota(jnp.int32, (dv, dvp), 1)
+           ).astype(jnp.float32)
+    s = st[0, h] * decay                                 # [dv, dk]
+    sk = jnp.sum(s * krow, axis=1, keepdims=True)        # [dv, 1]
+    vcol = jnp.sum(eye * vrow, axis=1, keepdims=True)
+    s = s + (beta * (vcol - sk)) * krow
+    st[0, h] = s
+    ocol = jnp.sum(s * q, axis=1, keepdims=True)
+    obuf[pl.ds(0, 1), h, :] = jnp.sum(eye * ocol, axis=0, keepdims=True)
+
+
+def _unit_lower_inverse(a, t_i, i_i):
+    """``(I + a)^-1`` of a strictly lower triangular ``a [C, C]`` (C a
+    power of two, at least 4), by blocks: the 4 x 4 diagonal blocks as
+    ``I - P + P^2 - P^3`` (exact, ``P^4 = 0``), then doubling: with ``X``
+    the inverse of the diagonal blocks of size ``s`` and ``B`` the part
+    of ``a`` below them in blocks of ``2s``, ``X - X B X`` is the inverse
+    of the diagonal blocks of size ``2s``.  Each level multiplies
+    inverses of the size before, so no power of ``a`` beyond the third
+    is formed: the series ``sum (-a)^k`` over the whole chunk cancels
+    terms of 1e16 to an answer of order 1 where keys are alike, as
+    SiLU'd keys are, and ``beta`` nears 2."""
+    C = a.shape[0]
+    f32 = jnp.float32
+
+    def dot(x, y):
+        return jnp.dot(x, y, precision=_HI, preferred_element_type=f32)
+
+    p = -jnp.where((t_i // 4) == (i_i // 4), a, 0.0)
+    p2 = dot(p, p)
+    eye = (t_i == i_i).astype(f32)
+    x = eye + p + dot(p2, eye + p)
+    s = 4
+    while s < C:
+        b = jnp.where(((t_i // (2 * s)) == (i_i // (2 * s)))
+                      & ((t_i // s) % 2 == 1) & ((i_i // s) % 2 == 0),
+                      a, 0.0)
+        x = x - dot(x, dot(b, x))
+        s *= 2
+    return x
+
+
+def _gdn_chunk_head(st, h, qbuf, kbuf, vbuf, obuf, rows, *, dk: int,
+                    dv: int):
+    """Head ``h`` of one sub-chunk of ``rows`` real rows (the rest
+    masked: no key, no beta, no decay).  With ``G`` the decay cumulated
+    within the sub-chunk, ``Gam[t, i] = exp(G_t - G_i)`` for ``i <= t``
+    and ``A = strict_lower(beta k k^T Gam)``: ``T = (I + A)^-1``
+    (``_unit_lower_inverse``, by blocks), ``W =
+    T (beta e^G k)``, ``U = T (beta v) - W S^T``, ``o = e^G q S^T +
+    lower(q k^T Gam) U`` and ``S' = e^G_C S + U^T (e^(G_C - G) k)``."""
+    C = qbuf.shape[0]
+    f32 = jnp.float32
+    qrow = qbuf[:, h, :]                                   # [C, dk_pad]
+    valid = lax.broadcasted_iota(jnp.int32, (C, 1), 0) < rows
+    q = qrow[:, :dk]
+    k = jnp.where(valid, kbuf[:, h, :][:, :dk], 0.0)
+    v = vbuf[:, h, :][:, :dv]
+    beta = jnp.where(valid, _gdn_lane(qrow, dk), 0.0)        # [C, 1]
+    g = jnp.where(valid, _gdn_lane(qrow, dk + 1), 0.0)
+    t_i = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    i_i = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = (t_i == i_i).astype(f32)
+    incl, strict = t_i >= i_i, t_i > i_i
+    g_row = jnp.sum(eye * g, axis=0, keepdims=True)         # [1, C]
+    G = jnp.sum(jnp.where(incl, g_row, 0.0), axis=1, keepdims=True)
+    G_row = jnp.sum(eye * G, axis=0, keepdims=True)
+    gam = jnp.where(incl, jnp.exp(jnp.where(incl, G - G_row, 0.0)), 0.0)
+    G_last = G[C - 1:C, :]                                   # [1, 1]
+    kk = lax.dot_general(k, k, _DIMNUM_NT, precision=_HI,
+                         preferred_element_type=f32)
+    a = jnp.where(strict, beta * kk * gam, 0.0)
+    t_inv = _unit_lower_inverse(a, t_i, i_i)
+    w = jnp.dot(t_inv, k * (beta * jnp.exp(G)), precision=_HI,
+                preferred_element_type=f32)                  # [C, dk]
+    u = jnp.dot(t_inv, v * beta, precision=_HI,
+                preferred_element_type=f32)                  # [C, dv]
+    s = st[0, h]                                             # [dv, dk]
+    u = u - lax.dot_general(w, s, _DIMNUM_NT, precision=_HI,
+                            preferred_element_type=f32)
+    qk = lax.dot_general(q, k, _DIMNUM_NT, precision=_HI,
+                         preferred_element_type=f32)
+    o = (jnp.exp(G) * lax.dot_general(q, s, _DIMNUM_NT, precision=_HI,
+                                      preferred_element_type=f32)
+         + jnp.dot(jnp.where(incl, qk * gam, 0.0), u, precision=_HI,
+                   preferred_element_type=f32))              # [C, dv]
+    st[0, h] = jnp.exp(G_last) * s + lax.dot_general(
+        u, k * jnp.exp(G_last - G), _DIMNUM_TN, precision=_HI,
+        preferred_element_type=f32)
+    obuf[:, h, :] = jnp.concatenate(
+        [o, jnp.zeros((C, obuf.shape[2] - dv), f32)], axis=1) \
+        if obuf.shape[2] > dv else o
+
+
+def _gated_delta_kernel(slot_ref, qoff_ref, qlen_ref, kvlen_ref, q_hbm,
+                        k_hbm, v_hbm, st_in, o_init, o_hbm, st, qbuf,
+                        kbuf, vbuf, obuf, sem, *, heads: int, dk: int,
+                        dv: int):
+    """Grid cell s: span s of the pack against its slot's state (the
+    section's comment).  ``heads`` of the windows' head rows are real."""
+    del slot_ref, o_init
+    s = pl.program_id(0)
+    n = qlen_ref[s]
+    off = qoff_ref[s]
+    H = heads
+
+    @pl.when(s == 0)
+    def _clear():               # the rows a window writes past its span
+        obuf[...] = jnp.zeros(obuf.shape, jnp.float32)
+
+    @pl.when((n == 0) | (kvlen_ref[s] > n))
+    def _carry():               # a padding span keeps what it read
+        st[...] = st_in[...]
+
+    @pl.when((n > 0) & (kvlen_ref[s] == n))
+    def _fresh():
+        st[...] = jnp.zeros(st.shape, jnp.float32)
+
+    def window(row0, m):
+        copies = [pltpu.make_async_copy(src.at[pl.ds(row0, m)],
+                                        buf.at[pl.ds(0, m)], sem.at[i])
+                  for i, (src, buf) in enumerate(
+                      ((q_hbm, qbuf), (k_hbm, kbuf), (v_hbm, vbuf)))]
+        for c in copies:
+            c.start()
+        for c in copies:
+            c.wait()
+
+    def write(row0, m):
+        c = pltpu.make_async_copy(obuf.at[pl.ds(0, m)],
+                                  o_hbm.at[pl.ds(row0, m)], sem.at[3])
+        c.start()
+        c.wait()
+
+    @pl.when(n == 1)
+    def _decode():
+        window(off, _GDN_DECODE_ROWS)
+
+        def head(h, _):
+            _gdn_decode_head(st, h, qbuf, kbuf, vbuf, obuf, dk=dk, dv=dv)
+            return 0
+        lax.fori_loop(0, H, head, 0)
+        write(off, _GDN_DECODE_ROWS)
+
+    @pl.when(n > 1)
+    def _chunks():
+        def sub_chunk(c, _):
+            row0 = off + c * _GDN_CHUNK
+            rows = n - c * _GDN_CHUNK
+            window(row0, _GDN_CHUNK)
+
+            def head(h, _):
+                _gdn_chunk_head(st, h, qbuf, kbuf, vbuf, obuf, rows,
+                                dk=dk, dv=dv)
+                return 0
+            lax.fori_loop(0, H, head, 0)
+            write(row0, _GDN_CHUNK)
+            return 0
+        lax.fori_loop(0, (n + _GDN_CHUNK - 1) // _GDN_CHUNK, sub_chunk, 0)
+
+
+def gdn_kernel_vmem_bytes(*, heads: int, dk: int, dv: int) -> int:
+    """The launch's VMEM: the state block in and out, two buffers each,
+    and the q / k / v / o windows of one sub-chunk."""
+    dkp, dvp = _gdn_widths(dk, dv)
+    C, hp = _GDN_CHUNK, -(-heads // 8) * 8
+    return (4 * _tile_bytes((heads, dv, dk), 4)
+            + 2 * _tile_bytes((C, hp, dkp), 4)
+            + 2 * _tile_bytes((C, hp, dvp), 4))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ragged_gated_delta(q, k, v, beta, g, state, slots, q_offsets, q_lens,
+                       kv_lens, interpret=False):
+    """The rule over every span of the pack (``ops/gated_delta.
+    ragged_gated_delta_xla`` is the same contract, token by token): ``q``,
+    ``k [T, H, dk]`` (normed, q scaled), ``v [T, H, dv]``, ``beta``, ``g
+    [T, H]``, ``state [slots, H, dv, dk]`` float32, the bank ALIASED in
+    and out; span tables ``[S]`` in pack order.  Returns ``(o [T, H, dv]
+    float32, state)``; a row no span owns holds finite garbage, read by
+    nothing."""
+    T, H, dk = q.shape
+    dv = v.shape[-1]
+    dkp, dvp = _gdn_widths(dk, dv)
+    Hp = -(-H // 8) * 8           # a DMA moves whole sublane tiles of heads
+    f32 = jnp.float32
+    pad = _GDN_CHUNK
+    with _x64_off():
+        qp = jnp.concatenate(
+            [q.astype(f32), beta.astype(f32)[..., None],
+             g.astype(f32)[..., None],
+             jnp.zeros((T, H, dkp - dk - 2), f32)], axis=-1)
+        qp = jnp.pad(qp, ((0, pad), (0, Hp - H), (0, 0)))
+        kp = jnp.pad(k.astype(f32), ((0, pad), (0, Hp - H), (0, dkp - dk)))
+        vp = jnp.pad(v.astype(f32), ((0, pad), (0, Hp - H), (0, dvp - dv)))
+        o_init = jnp.zeros((T + pad, Hp, dvp), f32)
+        prefetch = [slots.astype(jnp.int32), q_offsets.astype(jnp.int32),
+                    q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32)]
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
+        st_spec = pl.BlockSpec((1, H, dv, dk),
+                               lambda s, slot, *_: (slot[s], 0, 0, 0))
+        kernel = functools.partial(_gated_delta_kernel, heads=H, dk=dk,
+                                   dv=dv)
+        o, state = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(slots.shape[0],),
+                in_specs=[any_spec, any_spec, any_spec, st_spec, any_spec],
+                out_specs=[any_spec, st_spec],
+                scratch_shapes=[
+                    pltpu.VMEM((_GDN_CHUNK, Hp, dkp), f32),     # q | b | g
+                    pltpu.VMEM((_GDN_CHUNK, Hp, dkp), f32),     # k
+                    pltpu.VMEM((_GDN_CHUNK, Hp, dvp), f32),     # v
+                    pltpu.VMEM((_GDN_CHUNK, Hp, dvp), f32),     # o
+                    pltpu.SemaphoreType.DMA((4,)),
+                ]),
+            out_shape=[jax.ShapeDtypeStruct(o_init.shape, f32),
+                       jax.ShapeDtypeStruct(state.shape, f32)],
+            input_output_aliases={len(prefetch) + 4: 0,
+                                  len(prefetch) + 3: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_GDN_VMEM),
+            interpret=interpret,
+            name="ragged_gated_delta",
+        )(*prefetch, qp, kp, vp, state, o_init)
+    return o[:T, :H, :dv], state
+
+
+# ---------------------------------------------------------------------------
 # VMEM footprint audit (consumed by tools/check_vmem_budget.py)
 # ---------------------------------------------------------------------------
 # Mosaic tiles every VMEM-resident buffer to (sublane, 128) vregs; the
@@ -2879,6 +3169,8 @@ def kernel_vmem_report(envelope=None):
         # tokens x 2) and 40 thin ones (DeepSeek-V2's share, 528 x 6)
         "wide_experts": (2048, 8, 4096, 14336),
         "thin_experts": (3168, 40, 5120, 1536),
+        # gated delta rule: Olmo-Hybrid's 30 linear heads of 96 / 192
+        "gdn": (30, 96, 192),
         # training envelope: the default/autotuned flash tiles
         "block_q": 512, "block_k": 512,
         "bwd_block_q": _FUSED_BWD_BLOCK_Q,
@@ -2914,6 +3206,8 @@ def kernel_vmem_report(envelope=None):
            for kind, (rows, experts, hidden, ffn) in (
                ("wide", env["wide_experts"]),
                ("thin", env["thin_experts"]))},
+        "ragged_gated_delta": gdn_kernel_vmem_bytes(
+            heads=env["gdn"][0], dk=env["gdn"][1], dv=env["gdn"][2]),
         "flash_fwd": flash_fwd_vmem_bytes(
             block_q=env["block_q"], block_k=env["block_k"],
             head_dim=env["head_dim"], with_rope=True),

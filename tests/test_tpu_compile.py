@@ -414,3 +414,46 @@ def test_mixed_step_latent_two_kinds(v5e):
     assert not re.search(r"\[8,%d,\d+\]" % (top * k), hlo)
     assert not re.search(r"\[32,%d,\d+\]" % (top * k), hlo)
     assert [c.value_cache for c in eng.caches] == [None, None]
+
+
+def test_mixed_step_olmo_hybrid_full_width(v5e):
+    """One fused step of an Olmo-Hybrid layer pair at the published
+    widths (hidden 3840, ffn 11008; a Gated DeltaNet layer of 30 heads of
+    96 / 192 with a 4-tap convolution, a full-attention layer of 30 x 128
+    heads; the vocabulary cut, it shapes no kernel), 64 slots, bf16: the
+    optimized v5e program launches ``ragged_gated_delta`` once and the
+    grouped-query launch once, every new scope owns an op, and the state
+    banks, like the pools, are donated."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.jit.serving_step import STEP_SCOPES, hlo_op_scopes
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                               OlmoHybridForCausalLM)
+    paddle.seed(0)
+    cfg = OlmoHybridConfig(
+        vocab_size=1024, num_hidden_layers=2, dtype="bfloat16",
+        layer_types=["linear_attention", "full_attention"])
+    model = OlmoHybridForCausalLM(cfg)
+    model.bfloat16()
+    model.eval()
+    eng = ContinuousBatchingEngine(
+        model, max_batch_size=64, num_blocks=PAGES, block_size=BLOCK,
+        max_seq_len=3328, prefill_chunk_size=512, use_pallas=True)
+    top = eng.token_budgets[-1]
+    lowered = eng.mixed.aot_lower(top, device_sharding=v5e)
+    text = lowered.as_text()
+    for name in ("ragged_gated_delta", "ragged_paged_attention"):
+        assert f'kernel_name = "{name}"' in text
+    compiled = lowered.compile()
+    scopes = hlo_op_scopes(compiled.as_text())
+    assert {"gdn.proj", "gdn.conv", "gdn.kernel", "gdn.gate_norm",
+            "gdn.out", "attn.qkv", "attn.qk_norm", "attn.kv_write",
+            "attn.kernel", "attn.out", "ffn", "lm_head"} \
+        <= set(scopes.values()) <= STEP_SCOPES | {None}
+    assert [scopes[n] for n in _kernel_calls(
+        scopes, "ragged_gated_delta")] == ["gdn.kernel"]
+    assert "rope" not in " ".join(n for n in scopes if "custom" in n)
+    mem = compiled.memory_analysis()
+    banks = sum(int(a.nbytes) for a in eng.states.states + eng.states.tails)
+    assert mem.alias_size_in_bytes >= banks + sum(
+        2 * int(c.key_cache.nbytes) for c in eng.caches)

@@ -150,6 +150,9 @@ REQUIRED_NAMES = frozenset({
     # one launch kept in flight across engine steps
     "serving_launches_total",
     "serving_discarded_rows_total",
+    # linear-attention layers: a fixed-size state a slot
+    "serving_linear_state_bytes",
+    "serving_state_fresh_total",
 })
 
 # ---------------------------------------------------------------------------

@@ -48,6 +48,9 @@ BUDGETS = {
     # v5e-class kernel (vmem_limit_bytes raised past Mosaic's default)
     "grouped_expert_wide": 64 * MIB,
     "grouped_expert_thin": 64 * MIB,
+    # a span's whole float32 state block, in and out, two pipeline slots
+    # each (vmem_limit_bytes raised past Mosaic's default)
+    "ragged_gated_delta": 64 * MIB,
     "flash_fwd": 64 * MIB,
     "flash_bwd_fused": 64 * MIB,
 }
