@@ -699,6 +699,11 @@ class ContinuousBatchingEngine:
             "rows a launch computed for requests that had already ended "
             "(on EOS in the launch before, still in flight when this "
             "one was packed); dropped at booking")
+        self._m_decode_cells = r.counter(
+            "serving_attn_decode_cells_total",
+            "grid cells of the grouped-query attention launch that took "
+            "its decode cell (one-token spans), summed over the layers "
+            "that launch it; 0 on the XLA reference")
         self._m_tp_degree = r.gauge(
             "serving_tp_degree",
             "tensor-parallel degree of the most recently constructed "
@@ -1103,6 +1108,12 @@ class ContinuousBatchingEngine:
           launch walks for the step's spans and how many of them took
           the masked body (``MixedStep.attn_blocks``: host integers
           from the spans' ``(q_len, kv_len)``); 0 on the XLA reference.
+        - ``attn_decode_cells``: the grouped-query launch's grid cells
+          that took its decode cell (one-token spans), summed over the
+          layers that launch it (``MixedStep.attn_decode_cells``: host
+          integers from the spans, by the rule the kernel follows); 0
+          on the XLA reference and for a latent model.  The counter
+          ``serving_attn_decode_cells_total`` sums it at booking.
         - ``moe_rows``, ``moe_rows_top``: the assignments that landed
           on the experts this engine holds, summed over the routed
           layers, and the fullest held expert's; counted by the step
@@ -1160,6 +1171,7 @@ class ContinuousBatchingEngine:
             attn_rows=(self.mixed.attn_rows(rec["budget"], spans[:, 1])
                        if rec["budget"] else 0),
             attn_blocks=blocks, attn_blocks_masked=masked,
+            attn_decode_cells=self.mixed.attn_decode_cells(spans[:, 1]),
             moe_rows=rec.get("moe_rows", 0),
             moe_rows_top=rec.get("moe_rows_top", 0),
             moe_tiles=rec.get("moe_tiles", 0),
@@ -1967,6 +1979,10 @@ class ContinuousBatchingEngine:
                     if kind != "decode")
         if discarded:
             self._m_discarded.inc(discarded)
+        decode_cells = self.mixed.attn_decode_cells(
+            [size for _, _, size, _ in spans])
+        if decode_cells:
+            self._m_decode_cells.inc(decode_cells)
         if n_dec:
             self._m_mixed_tok_decode.inc(n_dec)
         if n_pre:

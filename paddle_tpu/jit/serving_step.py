@@ -1096,9 +1096,10 @@ class MixedStep:
         i.e. tokens x heads) that a budget-``T`` launch carrying
         spans of lengths ``q_lens`` computes in each layer's attention.
         The Pallas launch computes whole tiles (``ops/pallas_kernels.
-        ragged_attn_rows``: a decode span one tile, a chunk
-        ceil(q_len / tile)); the XLA reference and the context-parallel
-        partial compute every row of the budget."""
+        ragged_attn_rows``: a chunk ceil(q_len / tile) tiles, a span
+        that takes the decode cell its token's rows); the XLA reference
+        and the context-parallel partial compute every row of the
+        budget."""
         from ..ops.pallas_kernels import latent_attn_rows, ragged_attn_rows
         cfg = self.cfg
         if self._latent is not None:
@@ -1111,7 +1112,23 @@ class MixedStep:
         geometry = self._ragged_geometry()
         if geometry is None:
             return T * groups
-        return ragged_attn_rows(q_lens, geometry[0], groups)
+        return ragged_attn_rows(q_lens, geometry[0], groups,
+                                self._quant_kv)
+
+    def attn_decode_cells(self, q_lens) -> int:
+        """The grid cells of the grouped-query Pallas launch that took
+        its decode cell for spans of these lengths, summed over the
+        layers that launch it (one a pool): ``ops/pallas_kernels.
+        ragged_decode_cell``, the rule the kernel itself follows.  0
+        where no such launch runs: a latent model, the XLA reference, a
+        context-parallel mesh."""
+        if self._ragged_geometry() is None:
+            return 0
+        from ..ops.pallas_kernels import ragged_decode_cell
+        cfg = self.cfg
+        groups = cfg.num_attention_heads // cfg.num_key_value_heads
+        return len(self.caches) * int(np.sum(ragged_decode_cell(
+            np.asarray(q_lens), groups, self._quant_kv)))
 
     def moe_tile_rows(self, T: int) -> int:
         """The rows of one row tile of a budget-``T`` step's grouped

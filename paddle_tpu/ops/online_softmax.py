@@ -33,7 +33,7 @@ import numpy as np
 __all__ = ["online_softmax_update", "merge_partials", "cross_chip_merge"]
 
 
-def online_softmax_update(carry, s, ok, pv_of_p):
+def online_softmax_update(carry, s, ok, pv_of_p, axis=1):
     """One online-softmax accumulation step over a masked score tile.
 
     carry: ``(m [g,1], l [g,1], acc [g,d])`` fp32 running state
@@ -45,15 +45,18 @@ def online_softmax_update(carry, s, ok, pv_of_p):
     pv_of_p: callback
     computing the ``[g, d]`` ``p @ V`` product from the ``[g, t]``
     probability tile — site-specific (fp32 matmul, int8 MXU with folded
-    scales, ...).  Returns the new ``(m, l, acc)``.
+    scales, ...).  ``axis``: the keys' axis of ``s`` (the state keeps it
+    at size 1): 0 where keys lead, as in the grouped-query launch's
+    decode cell (``s [t, Hkv, 1]``, state ``[1, Hkv, 1]`` /
+    ``[1, Hkv, d]``).  Returns the new ``(m, l, acc)``.
     """
     m, l, acc = carry
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.maximum(m, jnp.max(s, axis=axis, keepdims=True))
     p = jnp.exp(s - m_new)
     if ok is not None:
         p = jnp.where(ok, p, np.float32(0.0))
     alpha = jnp.exp(m - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_new = l * alpha + jnp.sum(p, axis=axis, keepdims=True)
     acc_new = acc * alpha + pv_of_p(p)
     return m_new, l_new, acc_new
 

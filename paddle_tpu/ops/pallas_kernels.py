@@ -1425,12 +1425,14 @@ def sdpa_ulysses(query, key, value, mesh, axis_name: str = "sep",
 # ---------------------------------------------------------------------------
 # Tile sizes.  A span's q rows are cut into tiles of as many tokens as
 # give one kv head _RAGGED_TILE_ROWS query rows (the MXU's height; 32
-# tokens at GQA 4:1): a decode or speculative-verify span is one tile,
-# a chunk ceil(q_len / tile).  ONE size, because the kernel's body is
-# traced and lowered anew for every token budget at every start: a
-# second size (8-token tiles for decode spans beside 128-token tiles
-# for chunks read 1-4% better end to end: PERF.md, PR 25) doubled that
-# and put seven seconds on a warm start.  Keys stream in blocks of
+# tokens at GQA 4:1): a speculative-verify span is one tile, a chunk
+# ceil(q_len / tile).  ONE size, because the kernel's body is traced
+# and lowered anew for every token budget at every start: a second
+# size (8-token tiles for decode spans beside 128-token tiles for
+# chunks read 1-4% better end to end: PERF.md section 6) was a second
+# launch, doubled that and put seven seconds on a warm start.  At
+# one group a one-token span takes the decode cell, a second body of
+# the SAME launch (``_ragged_decode_cell``).  Keys stream in blocks of
 # _RAGGED_KV_BLOCK keys (whole pages): a block wholly under a tile's
 # diagonal is one unmasked update 512 keys wide; the diagonal's block
 # and the span's last are walked _RAGGED_SUB_BLOCK keys at a time, as
@@ -1443,6 +1445,10 @@ _RAGGED_SUB_BLOCK = 128
 # what the tile-sized buffers of one grid cell may take of the 16 MiB
 # the serving kernels are held to (tools/graftlint/vmem.py)
 _RAGGED_TILE_VMEM = 12 << 20
+# Page rows (one key of one kv head: [key, head] pairs) the decode cell
+# takes a step: 32 float32 vregs of K, then of V, live at once.  256 to
+# 4,096 read alike on the chip, unrolled or not (PERF.md section 6).
+_RAGGED_DECODE_ROWS = 256
 
 
 def _ragged_compute_dtype(q_dtype, kv_dtype):
@@ -1461,9 +1467,12 @@ def _ragged_cell_vmem_bytes(bq: int, heads: int, kv_heads: int,
     head folded q / m / l / acc state (and the q rows' scales of an
     int8 pool), the 2-slot K and V page blocks ([kv_block, Hkv, D]
     each, as stored), the current block head-major, and the live score
-    and probability tiles.  Mirrors the scratch_shapes in
-    _ragged_paged_attention_pallas — edit both."""
-    rows = bq * (heads // kv_heads)
+    and probability tiles; beside them the decode cell's m / l / acc
+    (``[1, Hkv, 1 | D]``, where ``ragged_decode_cell`` engages).
+    Mirrors the scratch_shapes in _ragged_paged_attention_pallas — edit
+    both."""
+    groups = heads // kv_heads
+    rows = bq * groups
     total = 2 * _tile_bytes((bq, heads, head_dim), q_itemsize)    # q, o
     total += _tile_bytes((kv_heads, rows, head_dim), c_itemsize)  # q2
     total += _tile_bytes((kv_heads, rows, head_dim), 4)           # acc
@@ -1475,6 +1484,9 @@ def _ragged_cell_vmem_bytes(bq: int, heads: int, kv_heads: int,
     total += 2 * _tile_bytes((rows, kv_block), 4)           # scores + p
     if kv_itemsize == 1:
         total += _tile_bytes((kv_heads, rows, 1), 4)        # q scales
+    if ragged_decode_cell(1, groups, kv_itemsize == 1):     # decode cell
+        total += 2 * _tile_bytes((kv_heads, 1), 4)
+        total += _tile_bytes((kv_heads, head_dim), 4)
     return total
 
 
@@ -1536,10 +1548,26 @@ def ragged_tile_geometry(heads: int, kv_heads: int, head_dim: int,
         tile //= 2
 
 
-def ragged_attn_rows(q_lens, tile: int, groups: int) -> int:
+def ragged_decode_cell(q_len, groups: int, quantized: bool):
+    """Whether a span of ``q_len`` query tokens takes the grouped-query
+    launch's decode cell (``_ragged_decode_cell``): a one-token span, at
+    one group, of an fp pool.  At 4 groups the tile cell is the faster
+    (its q rows fill the MXU: PERF.md section 6), and an int8 pool folds
+    its scales into int8 products there.  The ONE rule for the kernel's
+    choice (``q_len`` a traced scalar) and for the step record's
+    ``attn_decode_cells`` (host integers: an array of span lengths gives
+    one bool a span; a launch without the cell a plain False)."""
+    return q_len == 1 if groups == 1 and not quantized else False
+
+
+def ragged_attn_rows(q_lens, tile: int, groups: int,
+                     quantized: bool = False) -> int:
     """The q rows per kv head one launch computes for spans of these
-    lengths: every tile's full height, garbage rows included."""
-    return groups * tile * sum(-(-max(int(n), 0) // tile) for n in q_lens)
+    lengths: every tile's full height, garbage rows included; a span
+    that takes the decode cell its token's one row."""
+    ql = np.maximum(np.asarray(q_lens, np.int64), 0)
+    dec = ragged_decode_cell(ql, groups, quantized)
+    return groups * int(np.sum(np.where(dec, 1, tile * (-(-ql // tile)))))
 
 
 def ragged_attn_blocks(q_lens, kv_lens, tile: int, pages_per_block: int,
@@ -1735,6 +1763,100 @@ def _kv_heads(buf):
             yield hp * pack + lane, rows
 
 
+def _ragged_decode_cell(decode, i, ts_ref, tr_ref, qoff_ref, qlen_ref,
+                        kvlen_ref, bt_ref, q_hbm, k_hbm, v_hbm, o_hbm, qbuf,
+                        obuf, kbuf, vbuf, kv_sem, qo_sem, m_s, l_s, acc_s, *,
+                        block_size: int, pages_per_span: int,
+                        pages_per_block: int, scale: float):
+    """Grid cell i of ``_ragged_paged_kernel`` where ``decode`` holds:
+    a one-token span at one group (``ragged_decode_cell``).  Its token's
+    ``[H, D]`` q row comes in and its output row goes out, nothing
+    more; every kv head is worked at once on the pages AS STORED, on
+    the vector unit: the q row broadcast over a step's keys ``[c, Hkv,
+    D]``, a lane sum to scores ``[c, Hkv, 1]``, the online softmax over
+    the leading key axis, and ``p x V`` summed over the keys into
+    ``[1, Hkv, D]`` — no head-major copy, no loop over the heads, no
+    MXU.  Keys stream in the tile cell's blocks through the same two
+    slots; a block wholly before ``kv_end`` is walked unmasked, the
+    last one as far as ``kv_end`` (a traced trip count), masked by
+    ``kv_end`` alone: a lone token has no diagonal.
+    Everything is float32: q upcast with the softmax scale folded in,
+    K and V upcast, products and sums float32 (the tile cell rounds p
+    to the pool's type for ``p.V``; this one does not).  The state
+    ``m_s, l_s [1, Hkv, 1]``, ``acc_s [1, Hkv, D]`` lives in VMEM
+    between blocks and in vregs within one."""
+    bs, kb = block_size, pages_per_block
+    hkv = acc_s.shape[1]
+    n = kb * bs
+    # keys a step: _RAGGED_DECODE_ROWS page rows, a power of two that
+    # divides the block
+    ck = math.gcd(n, 1 << (max(1, _RAGGED_DECODE_ROWS // hkv)
+                           .bit_length() - 1))
+    f32 = jnp.float32
+
+    def walk_blocks(n_whole, n_blk, kv_end, block_copies, q):
+        def block_math(b, slot):
+            def step(c, state, masked):
+                k = kbuf[slot, pl.ds(c * ck, ck)].astype(f32)
+                sc = jnp.sum(k * q, axis=2, keepdims=True)   # [ck, Hkv, 1]
+                if masked:
+                    cols = b * n + c * ck + lax.broadcasted_iota(
+                        jnp.int32, sc.shape, 0)
+                    sc = jnp.where(cols < kv_end, sc, _F32_NEG_INF)
+                v = vbuf[slot, pl.ds(c * ck, ck)].astype(f32)
+                # every step holds a visible key: m stays finite
+                return online_softmax_update(
+                    state, sc, None,
+                    lambda p: jnp.sum(p * v, axis=0, keepdims=True),
+                    axis=0)
+
+            def walk(masked: bool):
+                state = (m_s[...], l_s[...], acc_s[...])
+                if masked:
+                    steps = jnp.clip(lax.div(kv_end - b * n + (ck - 1),
+                                             jnp.int32(ck)), 1, n // ck)
+                    state = lax.fori_loop(
+                        jnp.int32(0), steps,
+                        lambda c, st: step(c, st, True), state)
+                else:
+                    state = lax.fori_loop(
+                        0, n // ck, lambda c, st: step(c, st, False),
+                        state)
+                m_s[...], l_s[...], acc_s[...] = state
+
+            whole = b < n_whole
+            pl.when(whole)(lambda: walk(False))
+            pl.when(jnp.logical_not(whole))(lambda: walk(True))
+
+        _stream_key_blocks(n_blk, block_copies, block_math)
+
+    @pl.when(decode)
+    def _decode():
+        s, tok0, _, kv_end, n_pages, n_blk = _tile_extent(
+            i, jnp.int32(1), ts_ref, tr_ref, qoff_ref, qlen_ref,
+            kvlen_ref, block_size=bs, pages_per_span=pages_per_span,
+            pages_per_block=kb)
+        block_copies = _page_block_copies(
+            bt_ref, s, n_pages, bs, kb,
+            [(k_hbm, kbuf, lambda slot: kv_sem.at[slot, 0]),
+             (v_hbm, vbuf, lambda slot: kv_sem.at[slot, 1])])
+        q_copy = pltpu.make_async_copy(q_hbm.at[pl.ds(tok0, 1)],
+                                       qbuf.at[pl.ds(0, 1)], qo_sem.at[0])
+        q_copy.start()
+        block_copies(jnp.int32(0), 0, lambda c: c.start())
+        q_copy.wait()
+
+        m_s[...] = jnp.full(m_s.shape, _F32_NEG_INF, f32)
+        l_s[...] = jnp.zeros(l_s.shape, f32)
+        acc_s[...] = jnp.zeros(acc_s.shape, f32)
+        walk_blocks(jnp.minimum(lax.div(kv_end, jnp.int32(n)), n_blk),
+                    n_blk, kv_end, block_copies,
+                    (qbuf[0].astype(f32) * np.float32(scale))[None])
+        o = acc_s[...] / jnp.maximum(l_s[...], np.float32(1e-30))
+        obuf[0] = o[0].astype(obuf.dtype)
+        _write_tile(obuf.at[pl.ds(0, 1)], o_hbm, tok0, qo_sem.at[1])
+
+
 def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
                          pages_per_block: int, pages_per_sub: int,
                          scale: float, groups: int, quantized: bool):
@@ -1744,12 +1866,22 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
 
     Rows follow tokens.  The tile's tokens are DMA'd from the
     token-major pack ``[T, H, D]`` at their real offset and its output
-    rows go back the same way; a decode or verify span costs one
-    tile, a chunk ceil(q_len / tile) tiles, a ``q_len == 0`` span
-    nothing.  A tile writes its full height: the
-    rows past ``rows`` are finite garbage that land on the NEXT tile's
-    tokens (tiles run in token order on one core, so the owner
-    overwrites them) or in the pack's padding.
+    rows go back the same way; a verify span costs one tile, a chunk
+    ceil(q_len / tile) tiles, a ``q_len == 0`` span nothing.  A tile
+    writes its full height: the rows past ``rows`` are finite garbage
+    that land on the NEXT tile's tokens (tiles run in token order on
+    one core, so the owner overwrites them) or in the pack's padding.
+
+    A one-token span (``ragged_decode_cell``: a decode step, or a
+    chunk of one token) takes the DECODE CELL instead, a second body of
+    the same launch chosen per grid cell from ``q_len``
+    (``_ragged_decode_cell``): it copies in its token's q row and
+    writes back its output row alone, and works every kv head at once
+    on the vector unit.  It writes no row past its own, so the rows a
+    tile cell's garbage reaches are still overwritten by their owners,
+    which run later, decode cells included.  An int8 pool keeps the
+    tile cell for every span (its scales fold into int8 products on
+    the MXU).
 
     Pages arrive as stored: one contiguous ``[block_size, Hkv, D]``
     copy a page serves every kv head, ``pages_per_block`` pages make
@@ -1811,7 +1943,19 @@ def _ragged_paged_kernel(*refs, block_size: int, pages_per_span: int,
         # unfetched rows hold: make that finite once
         vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
 
-    @pl.when(rows > 0)
+    tile_cell = rows > 0
+    if ragged_decode_cell(1, groups, quantized):    # the launch has one
+        decode = tile_cell & ragged_decode_cell(qlen_ref[ts_ref[i]],
+                                                groups, quantized)
+        tile_cell = tile_cell & jnp.logical_not(decode)
+        _ragged_decode_cell(
+            decode, i, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref,
+            bt_ref, q_hbm, k_hbm, v_hbm, o_hbm, qbuf, obuf, kbuf, vbuf,
+            kv_sem, qo_sem, *refs[n_pref + 17:n_pref + 20],
+            block_size=bs, pages_per_span=pages_per_span,
+            pages_per_block=kb, scale=scale)
+
+    @pl.when(tile_cell)
     def _tile():
         s, tok0, pos0, kv_end, n_pages, n_blk = _tile_extent(
             i, rows, ts_ref, tr_ref, qoff_ref, qlen_ref, kvlen_ref,
@@ -2013,6 +2157,9 @@ def _ragged_paged_attention_pallas(q, key_cache, value_cache,
             pltpu.SemaphoreType.DMA((2,)),       # q in, o out
         ] + ([pltpu.VMEM((Hkv, rows, 1), jnp.float32)]   # q scales
              if quantized else [])
+        if ragged_decode_cell(1, groups, quantized):
+            scratch += [pltpu.VMEM((1, Hkv, 1), jnp.float32)] * 2 \
+                + [pltpu.VMEM((1, Hkv, D), jnp.float32)]  # decode m, l, acc
         return _ragged_launch(
             kernel, "ragged_paged_attention", q,
             (key_cache, value_cache), D, block_tables, q_offsets, q_lens,
@@ -3160,6 +3307,10 @@ def kernel_vmem_report(envelope=None):
         # grouping up to 8
         "heads": 32, "kv_heads": 8, "bt_width": 388,
         "groups": 8, "head_dim": 128, "block_size": 16,
+        # ... and the one-group cell (Olmo-Hybrid's 30 heads over 30,
+        # stored as 32 kv heads; a 208-page table): bf16, the decode
+        # cell's state beside the tile's
+        "one_group_kv_heads": 32, "one_group_bt_width": 208,
         # latent (MLA) envelope: 128 heads over one 512 + 64 row a
         # token, 128-token pages, contexts to 33,280
         "latent_heads": 128, "kv_lora_rank": 512, "latent_rope_dim": 64,
@@ -3185,6 +3336,10 @@ def kernel_vmem_report(envelope=None):
             q_dtype="float32", kv_dtype="float32", **ragged),
         "ragged_paged_int8": ragged_kernel_vmem_bytes(
             kv_dtype="int8", **ragged),
+        "ragged_paged_one_group": ragged_kernel_vmem_bytes(
+            **dict(ragged, heads=env["one_group_kv_heads"],
+                   kv_heads=env["one_group_kv_heads"],
+                   bt_width=env["one_group_bt_width"])),
         "paged_decode_fp32": decode_kernel_vmem_bytes(
             groups=env["groups"], head_dim=env["head_dim"],
             block_size=env["block_size"]),

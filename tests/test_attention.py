@@ -987,3 +987,186 @@ def test_ragged_geometry_keeps_the_tile(case):
                                     head_dim=128, block_size=16,
                                     bt_width=width, kv_dtype=kv_dtype)
     assert used <= _RAGGED_TILE_VMEM < 16 << 20
+
+
+# ---------------------------------------------------------------------------
+# the grouped-query launch's decode cell: one-token spans take a body of
+# their own (one q row in, one out, every kv head at once on the pages
+# as stored), chosen per grid cell from q_len
+# ---------------------------------------------------------------------------
+_DECODE_CONTEXTS = [1, 15, 16, 17, 127, 128, 129, 1100, 3328]
+# name -> (q heads, kv heads, table width, spans [(q_len, kv_len)],
+# pool dtype); pages of 16, head_dim 128: the cells' own geometry
+_DECODE_PACKS = {
+    # Olmo-Hybrid's full layers: one group, a 208-page table (a 64-token
+    # tile, 128-key blocks), the table's last key included
+    "one_group": (32, 32, 208, [(1, c) for c in _DECODE_CONTEXTS],
+                  "bfloat16"),
+    # Mistral's and Mixtral's: 4 groups, 512-key blocks
+    "four_groups": (32, 8, 224, [(1, c) for c in _DECODE_CONTEXTS],
+                    "bfloat16"),
+    # one-token spans between a 512-token chunk and a 40-token one
+    # (whose tile's garbage rows land on the decode tokens after it),
+    # and q_len 0 padding spans on the sink page; in bf16 and float32
+    "mixed_pack": (32, 32, 208, [(1, 17), (512, 700), (1, 1100),
+                                 (40, 90), (1, 129), (1, 1), (0, 1),
+                                 (0, 1)], "bfloat16"),
+    "mixed_pack_fp32": (32, 32, 64, [(1, 17), (512, 700), (1, 1000),
+                                    (40, 90), (1, 129), (0, 1)],
+                        "float32"),
+}
+
+
+def _sink_pack(spans, H, Hkv, W, dtype, seed=0):
+    """Operands of one launch over a pool whose LAST page is the sink:
+    NaN, where a freed slot's table points (every entry of a padding
+    span, every entry past a live span's pages), so a cell that reads a
+    page it does not own poisons its output."""
+    rs = np.random.RandomState(seed)
+    bs, D = 16, 128
+    used = [-(-kv // bs) if q else 0 for q, kv in spans]
+    sink = sum(used)
+    kc = rs.randn(sink + 1, bs, Hkv, D).astype(np.float32)
+    vc = rs.randn(sink + 1, bs, Hkv, D).astype(np.float32)
+    kc[sink] = vc[sink] = np.nan
+    bt = np.full((len(spans), W), sink, np.int32)
+    order, start = rs.permutation(sink), 0
+    for s, n in enumerate(used):
+        bt[s, :n] = order[start:start + n]
+        start += n
+    T = sum(q for q, _ in spans)
+    q = rs.randn(T, H, D).astype(np.float32)
+    qo = np.cumsum([0] + [ql for ql, _ in spans[:-1]]).astype(np.int32)
+    qo = np.where([ql > 0 for ql, _ in spans], qo, T).astype(np.int32)
+    return (jnp.asarray(q, dtype), jnp.asarray(kc, dtype),
+            jnp.asarray(vc, dtype), bt, qo,
+            np.asarray([ql for ql, _ in spans], np.int32),
+            np.asarray([kv for _, kv in spans], np.int32))
+
+
+def _span_reference(q, kc, vc, bt, qo, ql, kl, rows=8):
+    """``_ragged_attention_xla`` in float32, one span at a time and a few
+    of its rows at a time (a row sits at its own position, so a piece
+    of a chunk is a span of its own), against that span's pages only:
+    the reference's gather is a row's whole table, too large for a
+    512-token chunk over 700 keys."""
+    from paddle_tpu.ops.paged_attention import _ragged_attention_xla
+    f32 = [np.asarray(x.astype(jnp.float32)) for x in (q, kc, vc)]
+    q, kc, vc = f32
+    out = np.zeros(q.shape, np.float32)
+    ref = jax.jit(_ragged_attention_xla, static_argnames="scale")
+    for s, (n, kv) in enumerate(zip(ql, kl)):
+        pages = -(-int(kv) // kc.shape[1])
+        for r0 in range(0, int(n), rows):
+            r = min(rows, int(n) - r0)
+            piece = np.zeros((rows, q.shape[1], q.shape[2]), np.float32)
+            piece[:r] = q[qo[s] + r0:qo[s] + r0 + r]
+            got = ref(jnp.asarray(piece), jnp.asarray(kc), jnp.asarray(vc),
+                      jnp.asarray(bt[s:s + 1, :pages]),
+                      jnp.asarray([0, rows], jnp.int32),
+                      jnp.asarray([r, 0], jnp.int32),
+                      jnp.asarray([int(kv) - int(n) + r0 + r, 1],
+                                  jnp.int32), scale=q.shape[2] ** -0.5)
+            out[qo[s] + r0:qo[s] + r0 + r] = np.asarray(got)[:r]
+    return out
+
+
+@pytest.mark.parametrize("pack", sorted(_DECODE_PACKS))
+def test_ragged_decode_cell_matches_reference(pack):
+    """The launch with its decode cell (interpret mode) against the XLA
+    reference at the cells' geometry: every one-token span takes the
+    decode cell (``ragged_decode_cell``), the rest the tile cell, in ONE
+    launch; a context of one key, at a page's edges, at a 128-key
+    block's edges, mid-table and the table's last key; no page past a
+    span's own is read (the NaN sink stays out of every row) and every
+    row a tile's garbage reached is rewritten by its owner."""
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    from paddle_tpu.ops.pallas_kernels import (ragged_decode_cell,
+                                               ragged_tile_geometry)
+    H, Hkv, W, spans, dtype = _DECODE_PACKS[pack]
+    args = _sink_pack(spans, H, Hkv, W, dtype)
+    q_lens = args[5]
+    # one group: every one-token span takes the decode cell; 4 groups:
+    # none does (the tile cell is the faster there)
+    assert int(np.sum(ragged_decode_cell(q_lens, H // Hkv, False))) \
+        == (sum(1 for q, _ in spans if q == 1) if H == Hkv else 0)
+    if dtype == "bfloat16":
+        assert ragged_tile_geometry(H, Hkv, 128, 16, W, dtype, dtype) \
+            == ((64, 8) if H == Hkv else (32, 32))
+    got = np.asarray(ragged_paged_attention(*args, interpret=True),
+                     np.float32)
+    want = _span_reference(*args)
+    real = np.concatenate([np.arange(o, o + n) for o, n
+                           in zip(args[4], q_lens) if n])
+    assert np.isfinite(got[real]).all()
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(got[real], want[real], err_msg=pack, **tol)
+
+
+def test_ragged_decode_cell_int8_takes_the_tile():
+    """An int8 pool keeps the tile cell for every span, at one group
+    too (its scales fold into int8 products on the MXU): the rule says
+    so, and one-token spans beside a chunk match the dequantizing
+    reference within the declared int8 tolerance."""
+    from paddle_tpu.ops.paged_attention import (KERNEL_INT8_REL_TOL,
+                                                PagedKVCache, dequant_pages,
+                                                ragged_paged_attention,
+                                                write_ragged_kv_q8)
+    from paddle_tpu.ops.pallas_kernels import ragged_decode_cell
+    assert not np.any(ragged_decode_cell(np.asarray([1, 1, 5]), 1, True))
+    assert ragged_decode_cell(np.int32(1), 1, False)
+    rs = np.random.RandomState(5)
+    bs, hkv, d, nb, W = 16, 32, 128, 24, 8
+    cq = PagedKVCache(nb, bs, hkv, d, sink_block=True, kv_dtype="int8")
+    n = bs * nb
+    (cq.key_cache, cq.value_cache, cq.key_scale,
+     cq.value_scale) = write_ragged_kv_q8(
+        jnp.asarray(rs.randn(n, hkv, d), jnp.float32),
+        jnp.asarray(rs.randn(n, hkv, d), jnp.float32), cq.key_cache,
+        cq.value_cache, cq.key_scale, cq.value_scale,
+        jnp.asarray(np.repeat(np.arange(nb, dtype=np.int32), bs)),
+        jnp.asarray(np.tile(np.arange(bs, dtype=np.int32), nb)))
+    spans = [(1, 17), (40, 90), (1, 100), (1, 1), (0, 1)]
+    bt = np.full((len(spans), W), cq.sink, np.int32)
+    for s, (ql, kv) in enumerate(spans):
+        if ql:
+            bt[s, :-(-kv // bs)] = (np.arange(-(-kv // bs)) + 3 * s) % nb
+    T = sum(ql for ql, _ in spans)
+    q = rs.randn(T, 32, d).astype(np.float32)
+    qo = np.asarray([0, 1, 41, 42, T], np.int32)
+    ql = np.asarray([s[0] for s in spans], np.int32)
+    kl = np.asarray([s[1] for s in spans], np.int32)
+    common = (bt, qo, ql, kl)
+    kw = dict(key_scale=cq.key_scale, value_scale=cq.value_scale)
+    want = np.asarray(ragged_paged_attention(
+        q, cq.key_cache, cq.value_cache, *common, use_pallas=False, **kw))
+    got = np.asarray(ragged_paged_attention(
+        q, cq.key_cache, cq.value_cache, *common, interpret=True, **kw))
+    vmag = float(np.abs(np.asarray(dequant_pages(
+        cq.value_cache, cq.value_scale))).max())
+    np.testing.assert_allclose(got[:T], want[:T],
+                               atol=KERNEL_INT8_REL_TOL * vmag)
+
+
+def test_ragged_geometry_one_group():
+    """The one-group cell keeps its tile and block: 64 tokens and 8
+    pages at 32 kv heads over a 208-page table (128 tokens or 512-key
+    blocks overrun _RAGGED_TILE_VMEM there), 32 and 32 at 32 over 8 kv
+    heads with 224- and 388-page tables; the decode cell's state fits
+    beside either, and the VMEM lint gates the one-group cell."""
+    from paddle_tpu.ops.pallas_kernels import (_RAGGED_TILE_VMEM,
+                                               kernel_vmem_report,
+                                               ragged_kernel_vmem_bytes,
+                                               ragged_tile_geometry)
+    cells = {(32, 32, 208): (64, 8), (32, 8, 224): (32, 32),
+             (32, 8, 388): (32, 32)}
+    for (heads, kv_heads, width), want in cells.items():
+        assert ragged_tile_geometry(heads, kv_heads, 128, 16, width,
+                                    "bfloat16", "bfloat16") == want
+        assert ragged_kernel_vmem_bytes(
+            heads=heads, kv_heads=kv_heads, head_dim=128, block_size=16,
+            bt_width=width) <= _RAGGED_TILE_VMEM
+    assert kernel_vmem_report()["ragged_paged_one_group"] \
+        == ragged_kernel_vmem_bytes(heads=32, kv_heads=32, head_dim=128,
+                                    block_size=16, bt_width=208)

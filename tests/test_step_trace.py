@@ -46,6 +46,10 @@ def _model(family):
         from paddle_tpu.models.mixtral import (MixtralForCausalLM,
                                                mixtral_tiny_config)
         model = MixtralForCausalLM(mixtral_tiny_config(num_hidden_layers=2))
+    elif family == "olmo":
+        from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
+                                                   olmo_hybrid_tiny_config)
+        model = OlmoHybridForCausalLM(olmo_hybrid_tiny_config())
     else:
         from paddle_tpu.models.llama import (LlamaForCausalLM,
                                              llama_tiny_config)
@@ -539,6 +543,42 @@ def test_attn_rows_follow_the_spans():
     assert ragged_attn_rows([1] * 40 + [512, 512] + [0] * 22, 32, 4) \
         == 4 * (40 * 32 + 1024)
     assert ragged_attn_rows([131, 7, 33], 32, 4) == 4 * 32 * (5 + 1 + 2)
+
+
+@pytest.mark.parametrize("family,per_span", [("llama", 0), ("olmo", 2)])
+def test_attn_decode_cells_count_one_token_spans(family, per_span):
+    """The record's ``attn_decode_cells``: the grouped-query launch's
+    grid cells that took its decode cell, summed over the layers that
+    launch it.  The CPU engine attends through the XLA reference: 0.
+    The TPU launch of the same model (sized here, never run): at one
+    group (the hybrid's 4 heads over 4) each one-token span times the
+    full-attention layers (2 of its 8); at 2 groups (the llama's 4
+    over 2) none, the tile cell takes them.
+    ``serving_attn_decode_cells_total`` sums what the records hold,
+    booked from the same spans."""
+    fam = default_registry().get("serving_attn_decode_cells_total")
+    eng = _engine(family)
+    before = fam.value
+    _drive(eng)
+    recs = [f for _, _, f in _records(eng)]
+    assert recs and all(f["attn_decode_cells"] == 0 for f in recs)
+    assert fam.value == before
+    tpu = _engine(family, use_pallas=True).mixed
+    assert len(tpu.caches) == 2
+    ones = 0
+    for f in recs:
+        q_lens = f["spans"][:, 1]
+        assert tpu.attn_decode_cells(q_lens) \
+            == per_span * int(np.sum(q_lens == 1))
+        ones += int(np.sum(q_lens == 1))
+    assert ones
+    # an engine that books as the TPU launch would
+    eng = _engine(family)
+    eng.mixed.attn_decode_cells = tpu.attn_decode_cells
+    before = fam.value
+    _drive(eng)
+    counted = sum(f["attn_decode_cells"] for _, _, f in _records(eng))
+    assert counted == fam.value - before == per_span * ones
 
 
 def test_scopes_are_metadata_only(monkeypatch):

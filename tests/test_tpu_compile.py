@@ -13,6 +13,8 @@ chip_smoke.py's job; whether it COMPILES is checked here, on every PR.
 Shapes are the published ones: head_dim 128, 16-token pages, the
 serving chunk 256, training sequence 2048.
 """
+import base64
+import hashlib
 import re
 
 import jax
@@ -47,12 +49,16 @@ def as_if_on_tpu(monkeypatch):
     monkeypatch.setattr(_device, "on_tpu", lambda: True)
 
 
-def _compile(fn, sharding, *shapes):
+def _lower(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
             for s, d in shapes]
     lowered = jax.jit(fn).lower(*args)
     assert "tpu_custom_call" in lowered.as_text(), "no Mosaic kernel"
-    return lowered.compile()
+    return lowered
+
+
+def _compile(fn, sharding, *shapes):
+    return _lower(fn, sharding, *shapes).compile()
 
 
 def _kernel_calls(scopes, kernel_name: str):
@@ -64,6 +70,37 @@ def _kernel_calls(scopes, kernel_name: str):
 
 def _pool(hkv, dtype):
     return ((PAGES, BLOCK, hkv, D), dtype)
+
+
+def _mosaic_bodies(text: str, debug_info: bool = False):
+    """The Mosaic programs of a lowered module's ``tpu_custom_call``s,
+    as MLIR text (``backend_config``'s body: base64 MLIR bytecode).
+    Without debug info two lowerings print the same text exactly when
+    they are the same program; with it, each op names the Python
+    function that traced it."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           text):
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            out.append(module.operation.get_asm(
+                enable_debug_info=debug_info))
+    return out
+
+
+def _decode_body(text: str) -> bool:
+    """Whether a lowered module's grouped-query launch holds the decode
+    cell (``ops/pallas_kernels._ragged_decode_cell``): its lane sums of
+    q x K over ``[keys, Hkv, D]`` as stored are the launch's only
+    reductions over the third dim of a 3-d vector."""
+    return any("reduction_dims = array<i64: 2>" in body
+               for body in _mosaic_bodies(text))
 
 
 @pytest.mark.parametrize("seq,d", [(2048, 128), (2048, 64), (1024, 128)])
@@ -173,7 +210,11 @@ def test_ragged_span(v5e, dtype, heads, pack, kv, request):
         return _ragged_paged_attention_pallas(
             q, kc, vc, bt, qo, ql, kl, D ** -0.5,
             key_scale=ks, value_scale=vs)
-    _compile(fn, v5e, *shapes)
+    lowered = _lower(fn, v5e, *shapes)
+    # at one group one-token spans take the decode cell, a body of the
+    # same launch; an int8 pool and 4 groups keep the tile cell
+    assert _decode_body(lowered.as_text()) == (not quant and groups == 1)
+    lowered.compile()
 
 
 def _assert_no_pass_over_pools(eng, hlo, scopes):
@@ -221,6 +262,7 @@ def test_mixed_step_full_width_two_layers(v5e):
     assert text.splitlines()[0].startswith("module @jit_mixed_step")
     for name in ("ragged_paged_attention", "rope_qkv_epilogue"):
         assert f'kernel_name = "{name}"' in text
+    assert _decode_body(text)
     compiled = lowered.compile()
     # the v5e program keeps the step's named scopes: each Mosaic kernel
     # and the ops a trace could not tell apart (every matmul a `fusion`)
@@ -363,6 +405,64 @@ def test_ragged_latent(v5e, pack):
     assert "ragged_latent_attention" in compiled.as_text()
 
 
+# sha256 of a launch's Mosaic program (no debug info), as it lowered
+# before the grouped-query launch gained its decode cell: the latent
+# launch shares that launch's helpers, and the grouped-query launch at 4
+# groups (the chat and docs cells') keeps the tile cell for every span,
+# so a change that moves either program shows here.  Regenerate a digest
+# only with a change meant for that launch.
+GQA_MOSAIC = {
+    "cell64": "536063c9e711019d76655987304a7887"
+              "cef3d8899aa3b36aa92a41ad4e817159",
+    "cell1024": "7803bea3492cd10a539af520d1aeb001"
+                "e5532de7b2d7d7b5ca73a5a24c174425",
+}
+LATENT_MOSAIC = {
+    "decode": "b876f5b1afce41d8628652d9334a98b7"
+              "bcb75e537f0ffdea1a2f2983cc7ab204",
+    "chunk": "86b1162ef4520f270d0bb50465b4674b"
+             "4ce0aa86d63827d3b174af19235bf59b",
+    "top": "f342057ad17c1f0f545eae6db9e82815"
+           "99c644daa46fe1273551704da521e99c",
+}
+
+
+@pytest.mark.parametrize("pack", sorted(LATENT))
+def test_ragged_latent_mosaic_unchanged(v5e, pack):
+    """The latent launch lowers to the same Mosaic program as before."""
+    from paddle_tpu.ops.pallas_kernels import \
+        _ragged_latent_attention_pallas
+    tokens, n_spans = LATENT[pack]
+    spans = ((n_spans,), jnp.int32)
+    lowered = _lower(
+        lambda q, c, bt, qo, ql, kl: _ragged_latent_attention_pallas(
+            q, c, bt, qo, ql, kl, 0.1147, 512),
+        v5e, ((tokens, 128, 640), jnp.bfloat16),
+        ((4161, 128, 640), jnp.bfloat16), ((n_spans, 260), jnp.int32),
+        spans, spans, spans)
+    (body,) = _mosaic_bodies(lowered.as_text())
+    assert hashlib.sha256(body.encode()).hexdigest() == LATENT_MOSAIC[pack]
+
+
+@pytest.mark.parametrize("pack", sorted(GQA_MOSAIC))
+def test_ragged_span_four_groups_unchanged(v5e, pack):
+    """At 4 groups (32 q heads over 8 kv heads, the chat cell's pool and
+    table) the grouped-query launch lowers to the same Mosaic program as
+    before its one-group decode cell."""
+    from paddle_tpu.ops.pallas_kernels import \
+        _ragged_paged_attention_pallas
+    tokens, n_spans, width, pages = RAGGED[pack]
+    spans = ((n_spans,), jnp.int32)
+    pool = ((pages, BLOCK, 8, D), jnp.bfloat16)
+    lowered = _lower(
+        lambda q, kc, vc, bt, qo, ql, kl: _ragged_paged_attention_pallas(
+            q, kc, vc, bt, qo, ql, kl, D ** -0.5),
+        v5e, ((tokens, 32, D), jnp.bfloat16), pool, pool,
+        ((n_spans, width), jnp.int32), spans, spans, spans)
+    (body,) = _mosaic_bodies(lowered.as_text())
+    assert hashlib.sha256(body.encode()).hexdigest() == GQA_MOSAIC[pack]
+
+
 def test_mixed_step_latent_two_kinds(v5e):
     """One fused step of a DeepSeek-V2-shaped model (a dense layer, then
     a layer holding 8 of 32 experts; widths cut, the kernel's geometry
@@ -444,6 +544,7 @@ def test_mixed_step_olmo_hybrid_full_width(v5e):
     text = lowered.as_text()
     for name in ("ragged_gated_delta", "ragged_paged_attention"):
         assert f'kernel_name = "{name}"' in text
+    assert _decode_body(text)
     compiled = lowered.compile()
     scopes = hlo_op_scopes(compiled.as_text())
     assert {"gdn.proj", "gdn.conv", "gdn.kernel", "gdn.gate_norm",

@@ -153,6 +153,8 @@ REQUIRED_NAMES = frozenset({
     # linear-attention layers: a fixed-size state a slot
     "serving_linear_state_bytes",
     "serving_state_fresh_total",
+    # the grouped-query launch's decode cell
+    "serving_attn_decode_cells_total",
 })
 
 # ---------------------------------------------------------------------------

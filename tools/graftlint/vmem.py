@@ -40,6 +40,7 @@ VMEM_PER_CORE = 128 * MIB
 BUDGETS = {
     "ragged_paged_fp32": 16 * MIB,
     "ragged_paged_int8": 16 * MIB,
+    "ragged_paged_one_group": 16 * MIB,
     "paged_decode_fp32": 16 * MIB,
     "paged_decode_int8": 16 * MIB,
     "rope_qkv_epilogue": 16 * MIB,
